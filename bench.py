@@ -18,10 +18,28 @@ varied on the third) and reports cold vs warm call latency plus slab-pool
 hit counts — ``warm_over_cold`` is the ratio BASELINE.json gates.
 """
 
+import os
+
 from bench_all import bench_logreg, bench_warm_fit
 
 
+def _refuse_unasked_cpu():
+    """This is the chip entry: a run that finds no TPU fails instead of
+    quietly measuring XLA:CPU (every record carries ``platform`` /
+    ``device_kind`` / ``device_count`` either way).  A CPU run must be
+    asked for by name: ``JAX_PLATFORMS=cpu python bench.py``."""
+    import jax
+
+    platform = jax.devices()[0].platform
+    if platform != "tpu" and os.environ.get("JAX_PLATFORMS") != "cpu":
+        raise SystemExit(
+            f"bench.py: JAX found platform {platform!r}, not a TPU; refusing "
+            "to record CPU numbers as chip numbers (set JAX_PLATFORMS=cpu "
+            "to run the CPU check on purpose)")
+
+
 def main():
+    _refuse_unasked_cpu()
     from flink_ml_tpu import obs
 
     obs.enable()

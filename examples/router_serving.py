@@ -23,8 +23,13 @@ The script:
 5. prints throughput, request-latency p50/p99, the zero-failure count,
    and the death/respawn/deploy accounting.
 
-Run: python examples/router_serving.py [--requests N] [--threads K]
-     [--replicas R]
+Run: JAX_PLATFORMS=cpu python examples/router_serving.py [--requests N]
+     [--threads K] [--replicas R]
+
+On a TPU host this example fails at replica boot, by design: the parent
+fits the model, so it holds the chip, and each child is pinned to the
+parent's platform instead of quietly serving from XLA:CPU.  A fleet that
+shares a host's chips is ROADMAP S7.
 """
 
 import argparse

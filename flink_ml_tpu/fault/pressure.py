@@ -68,6 +68,7 @@ __all__ = [
     "current_caps",
     "current_limits",
     "enabled",
+    "is_compile_failure",
     "is_oom",
     "maybe_oom",
     "note_oom",
@@ -118,6 +119,30 @@ _ALLOC_MARKERS = (
 )
 
 
+def is_compile_failure(exc: BaseException) -> bool:
+    """Is this a kernel that does not fit the chip's VMEM — a COMPILE
+    failure wearing the allocator's status code?
+
+    VMEM is the compiler's scratchpad, sized per kernel when Mosaic/XLA
+    compiles it; running out means the kernel does not compile at its
+    tiling, whatever the batch.  Both texts were taken from a v5e (jax
+    0.9.0, libtpu 0.0.34, PR 21):
+
+    * ``RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem while
+      allocating on stack for %tpu_custom_call.1 ... Scoped allocation
+      with size 32.00M and limit 16.00M exceeded scoped vmem limit``;
+    * ``RESOURCE_EXHAUSTED: Allocation (size=268435456) would exceed
+      memory (size=134217728) :: #allocation2 [shape = ..., space=vmem``.
+
+    Neither is HBM pressure (a smaller batch compiles the same kernel) nor
+    transient (a retry compiles it again), so :func:`is_oom` and
+    :func:`~flink_ml_tpu.fault.retry.is_transient` both answer False and
+    the error propagates out of ``serve.dispatch`` and ``train_glm``
+    instead of being bisected, retried, or served from the fallback.  A
+    real HBM exhaustion names ``HBM`` and never ``vmem``."""
+    return isinstance(exc, Exception) and "vmem" in str(exc).lower()
+
+
 def is_oom(exc: BaseException) -> bool:
     """Is this failure deterministic allocator exhaustion?
 
@@ -125,12 +150,13 @@ def is_oom(exc: BaseException) -> bool:
     about memory/allocation/bytes, "out of memory", "ran out of memory"),
     host ``MemoryError``, and the synthetic ``fault.oom`` injection.
     False for everything else — including RESOURCE_EXHAUSTED quota/RPC
-    errors, which a retry plausibly fixes."""
+    errors, which a retry plausibly fixes, and a kernel that overflows
+    VMEM at compile time (:func:`is_compile_failure`)."""
     if isinstance(exc, InjectedFault):
         return getattr(exc, "point", None) == OOM_POINT
     if isinstance(exc, MemoryError):
         return True
-    if not isinstance(exc, Exception):
+    if not isinstance(exc, Exception) or is_compile_failure(exc):
         return False
     low = str(exc).lower()
     if any(m in low for m in _OOM_MARKERS):

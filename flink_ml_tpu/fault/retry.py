@@ -73,12 +73,14 @@ _DETERMINISTIC_ERRNOS = frozenset(
 
 def is_transient(exc: BaseException) -> bool:
     """Would retrying this failure plausibly succeed?"""
-    from flink_ml_tpu.fault.pressure import is_oom
+    from flink_ml_tpu.fault.pressure import is_compile_failure, is_oom
 
-    if is_oom(exc):
+    if is_oom(exc) or is_compile_failure(exc):
         # allocator exhaustion is DETERMINISTIC: the same batch fails
         # identically, so a same-size retry only triples the latency —
-        # recovery belongs to fault.pressure's bisection, not here
+        # recovery belongs to fault.pressure's bisection, not here.  A
+        # kernel that overflows VMEM carries RESOURCE_EXHAUSTED too, and
+        # is a compile failure nobody may absorb.
         return False
     if isinstance(exc, InjectedFault):
         return True

@@ -245,14 +245,6 @@ class OnlineLogisticRegression(Estimator, GlmTrainParams, HasWindowMs, HasAllowe
         checkpoint=None,
         window_hook=None,
     ) -> Tuple[LogisticRegressionModel, StreamingResult]:
-        # the streaming path compiles bare jits without building a mesh, so
-        # it must finish the deferred compile-cache decision itself (the
-        # mesh layer's hook never runs here)
-        from flink_ml_tpu.utils.compile_cache import (
-            ensure_compilation_cache_for_backend,
-        )
-
-        ensure_compilation_cache_for_backend()
         self._dim, training_source = self._infer_dim(training_source)
         lr = self.get_learning_rate()
         reg = self.get_reg()

@@ -1,7 +1,8 @@
 """ctypes bindings for the native ingestion library (loader.cpp).
 
-The shared library is built lazily on first use (``make`` in this directory)
-and the table sources fall back to pure Python when it is unavailable —
+The shared library is built lazily on first use (``make`` in this directory,
+redone whenever the recorded digest of its sources differs) and the table
+sources fall back to pure Python when it is unavailable —
 ``FLINK_ML_TPU_NO_NATIVE=1`` forces the fallback.  API consumed by
 ``flink_ml_tpu.table.sources._native_lib``:
 
@@ -24,6 +25,7 @@ Streaming (bounded memory — the out-of-core path):
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -33,10 +35,19 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SO = os.path.join(_DIR, "libflinkmltpu.so")
+_DIGEST = _SO + ".digest"
 
 _lock = threading.Lock()
 _lib = None
 _tried = False
+
+
+def _source_digest() -> str:
+    h = hashlib.sha256()
+    for name in ("loader.cpp", "Makefile"):
+        with open(os.path.join(_DIR, name), "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
 
 
 def _load():
@@ -47,26 +58,31 @@ def _load():
         _tried = True
         if os.environ.get("FLINK_ML_TPU_NO_NATIVE"):
             return None
-        # rebuild only when the .so is missing or older than its sources — a
-        # cheap mtime stat instead of forking make in every process (which
-        # would also race concurrent builders and always fail in read-only
-        # installs)
-        sources = (os.path.join(_DIR, "loader.cpp"), os.path.join(_DIR, "Makefile"))
+        # rebuild whenever the .so was not built from THESE sources: the
+        # digest of loader.cpp + Makefile it was built from is recorded
+        # beside it.  Not by mtime — a copied or exported tree does not
+        # preserve mtimes, and an ignored .so can ride along with sources
+        # it no longer matches.  make -B for the same reason (make's own
+        # staleness test is the mtime one).
+        want = _source_digest()
         try:
-            stale = not os.path.exists(_SO) or os.path.getmtime(_SO) < max(
-                os.path.getmtime(p) for p in sources
-            )
+            with open(_DIGEST) as f:
+                have = f.read().strip()
         except OSError:
-            stale = not os.path.exists(_SO)
-        if stale:
+            have = None
+        if not os.path.exists(_SO) or have != want:
             try:
                 subprocess.run(
-                    ["make", "-C", _DIR],
+                    ["make", "-B", "-C", _DIR],
                     check=True,
                     capture_output=True,
                     timeout=120,
                 )
-            except Exception:
+                with open(_DIGEST, "w") as f:
+                    f.write(want + "\n")
+            except (OSError, subprocess.SubprocessError):
+                # no compiler / read-only install: a prebuilt .so keeps
+                # serving, a missing one selects the pure-Python parsers
                 if not os.path.exists(_SO):
                     return None
         try:

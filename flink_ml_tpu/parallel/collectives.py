@@ -16,51 +16,20 @@ import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
 
-#: THE jax.shard_map version probe — every legacy-JAX branch in the repo
-#: (the wrapper below, pvary, lib.common.fetch_flat) keys off this single
-#: constant so a future boundary change edits one line
-HAS_NATIVE_SHARD_MAP = hasattr(jax, "shard_map")
-
-
 def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across JAX versions — the ONE shard_map entry point.
-
-    Newer JAX exposes ``jax.shard_map(..., check_vma=...)``; older releases
-    only ship ``jax.experimental.shard_map.shard_map(..., check_rep=...)``
-    (same semantics, earlier name).  Every shard_map call in the repo routes
-    through here so the version probe lives in one place.
-
-    On the legacy path ``check_rep`` is forced off: the old replication
-    checker has no rule for ``lax.while_loop`` (the fused training epoch
-    loop) and aborts compilation outright.  The check is a lint — outputs
-    declared replicated really are (every training program psums its
-    grads/loss before the replicated update) — so losing it on old JAX
-    costs verification, not correctness; new JAX keeps the full check.
-    """
-    if HAS_NATIVE_SHARD_MAP:
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
+    """``jax.shard_map`` with the repo's default (strict ``check_vma``) —
+    the ONE shard_map entry point, so a program that relaxes the
+    varying-axes check is a visible ``check_vma=False`` at its call."""
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+        check_vma=check_vma,
     )
 
 
 def pvary(x, axes=("data",)):
     """Mark a replicated value as varying over mesh axes (vma) inside a
-    shard_map — ``jax.lax.pcast`` on current JAX, ``jax.lax.pvary`` on the
-    intermediate releases that shipped it under that name, and the identity
-    on legacy JAX whose shard_map has no vma tracking (the wrapper above
-    runs it with the replication check off, so no cast is needed)."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, tuple(axes), to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, tuple(axes))
-    return x
+    shard_map."""
+    return jax.lax.pcast(x, tuple(axes), to="varying")
 
 
 def psum(x, axis_name: str = "data"):

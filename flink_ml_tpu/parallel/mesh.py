@@ -22,13 +22,18 @@ from flink_ml_tpu.fault.watchdog import with_timeout
 from flink_ml_tpu.utils import knobs
 
 
+def backend_initialized() -> bool:
+    """Has this process created a JAX backend yet — and so, on a TPU host,
+    taken the chip?  Asking for devices would create one; this only looks.
+    (The ONE use of jax's private probe: launchers that must not take the
+    chip from their children read it here.)"""
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized()
+
+
 def default_mesh(axis_names: Sequence[str] = ("data",), devices=None) -> Mesh:
     """All available devices laid out on the first axis (pure data parallel)."""
-    from flink_ml_tpu.utils.compile_cache import (
-        ensure_compilation_cache_for_backend,
-    )
-
-    ensure_compilation_cache_for_backend()
     devices = list(jax.devices()) if devices is None else list(devices)
     shape = [len(devices)] + [1] * (len(axis_names) - 1)
     arr = np.array(devices).reshape(shape)
@@ -37,11 +42,6 @@ def default_mesh(axis_names: Sequence[str] = ("data",), devices=None) -> Mesh:
 
 def create_mesh(axes: Dict[str, int], devices=None) -> Mesh:
     """Mesh from an ordered ``{axis_name: size}`` spec, e.g. {'data': 4, 'model': 2}."""
-    from flink_ml_tpu.utils.compile_cache import (
-        ensure_compilation_cache_for_backend,
-    )
-
-    ensure_compilation_cache_for_backend()
     devices = list(jax.devices()) if devices is None else list(devices)
     total = math.prod(axes.values())
     if total != len(devices):
@@ -222,13 +222,12 @@ def _concat_placed_fn(mesh: Mesh, spec: P, n_parts: int):
     consumes.  lru_cached so repeated placements reuse the compiled
     executable (jit's own cache then covers varying shapes per arity).
 
-    The slices are DONATED: the assembly transiently needs output + not-
-    yet-copied inputs, and donation lets the runtime release each slice as
-    it is consumed instead of holding all of them alongside the full
-    output (a ~2x device-memory spike at exactly the sizes this path
-    targets).  CPU ignores donation (and would warn about it), so the
-    donate list is empty there — the virtual-device test mesh has no
-    memory cliff to manage."""
+    The assembly transiently holds the slices alongside the full output (a
+    ~2x device-memory spike at exactly the sizes this path targets).  The
+    slices are NOT donated: XLA can only alias a donated input to an output
+    of its own shape, and no slice has the output's — on the v5e every
+    slice came back "Some donated buffers were not usable" (PR 21), so the
+    donation this function used to request never freed anything."""
     sharding = NamedSharding(mesh, spec)
 
     def concat(*parts):
@@ -236,8 +235,7 @@ def _concat_placed_fn(mesh: Mesh, spec: P, n_parts: int):
 
         return jnp.concatenate(parts, axis=0)
 
-    donate = tuple(range(n_parts)) if jax.default_backend() != "cpu" else ()
-    return jax.jit(concat, out_shardings=sharding, donate_argnums=donate)
+    return jax.jit(concat, out_shardings=sharding)
 
 
 def _put_chunked(mesh: Mesh, x: np.ndarray, spec: P, chunk_bytes: int):
@@ -264,9 +262,7 @@ def _put_chunked(mesh: Mesh, x: np.ndarray, spec: P, chunk_bytes: int):
             yield jax.device_put(x[lo : lo + rows_per_chunk], sharding)
 
     parts = list(prefetch_iter(pieces(), depth=2, name="h2d-prefetch"))
-    out = _concat_placed_fn(mesh, spec, len(parts))(*parts)
-    del parts  # donated to the concat: drop the refs so slices free early
-    return out
+    return _concat_placed_fn(mesh, spec, len(parts))(*parts)
 
 
 def shard_batch_prefetched(mesh: Mesh, batch, axis: str = "data",

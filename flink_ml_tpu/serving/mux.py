@@ -271,7 +271,10 @@ def _mux_dispatch_fn(run0: FusedRun, token: str, mesh, width: int,
                 _WARM_MUX.move_to_end(key)
         if memo is not None:
             return memo, False
-        loaded = store.load(key)
+        loaded = store.load(
+            key,
+            recompile=lambda: _mux_apply_fn(run0, token, mesh, width),
+        )
         if loaded is not None:
             fn = loaded
         else:
@@ -284,8 +287,10 @@ def _mux_dispatch_fn(run0: FusedRun, token: str, mesh, width: int,
             while len(_WARM_MUX) > _WARM_MUX_CAPACITY:
                 _WARM_MUX.popitem(last=False)
         return fn, loaded is not None
-    except Exception:
-        # the warm layer can slow a dispatch down, never break it
+    except Exception as exc:
+        # the warm layer can slow a dispatch down, never break it — but
+        # the degrade is counted
+        store.note_degraded("dispatch", exc)
         return _mux_apply_fn(run0, token, mesh, width), False
 
 

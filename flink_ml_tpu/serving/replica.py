@@ -539,18 +539,33 @@ def _package_root() -> str:
         os.path.abspath(flink_ml_tpu.__file__)))
 
 
-def _cache_env(env: Dict[str, str]) -> None:
-    """Hand the parent's RESOLVED compile-cache and warm-artifact dirs to
-    a child replica, the way the trace sink dirs ride (the runtime may
-    have picked a directory that is in neither os.environ nor the child's
-    defaults) — otherwise a kill -9 -> respawn replica silently points at
-    a different ``~/.cache`` and recompiles the whole ladder."""
+def _child_env(env: Dict[str, str]) -> None:
+    """What a child replica must inherit from the parent's RESOLVED state
+    (the runtime may have picked values that are in neither os.environ nor
+    the child's defaults):
+
+    * the platform — ``JAX_PLATFORMS`` pinned to the parent's backend
+      once the parent has one.  A chip belongs to one process: with the
+      variable unset, a child whose parent holds the TPU falls through to
+      XLA:CPU and the fleet silently serves from the host.  Pinned, that
+      child dies at boot and :meth:`ReplicaProcess.spawn` raises with its
+      log tail.  A parent that stayed off JAX holds no chip, so its
+      children inherit the environment untouched (asking for the backend
+      here would make the parent take the chip);
+    * the compile cache — through JAX's own ``JAX_COMPILATION_CACHE_DIR``,
+      so a kill -9 -> respawn replica replays the ladder's compiles;
+    * the warm-artifact store (``FMT_WARM_DIR``)."""
+    import jax
+
+    from flink_ml_tpu.parallel.mesh import backend_initialized
     from flink_ml_tpu.serving import warmstart
     from flink_ml_tpu.utils import compile_cache
 
+    if backend_initialized():
+        env["JAX_PLATFORMS"] = jax.default_backend()
     d = compile_cache.cache_dir()
     if d:
-        env["FMT_COMPILE_CACHE"] = d
+        env["JAX_COMPILATION_CACHE_DIR"] = d
     store = warmstart.active()
     if store is not None:
         env.setdefault("FMT_WARM_DIR", store.root)
@@ -610,7 +625,7 @@ class ReplicaProcess:
             env["FMT_TRACE_DIR"] = trace.trace_dir()
             env.setdefault("FMT_TRACE_SAMPLE", str(trace.sample_rate()))
             env.setdefault("FMT_TRACE_TAIL", ",".join(trace.tail_modes()))
-        _cache_env(env)
+        _child_env(env)
         env["PYTHONPATH"] = _package_root() + (
             os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
         )

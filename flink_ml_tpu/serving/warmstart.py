@@ -2,8 +2,8 @@
 
 The persistent XLA compilation cache (``utils/compile_cache``) replays
 *compiles* across processes, but a respawned replica still pays tracing,
-lowering, and cache lookup per fused program — and on CPU the cache is
-deliberately deferred.  This layer goes one level higher: after a fused
+lowering, and cache lookup per fused program — and an explicit CPU run
+has no default cache.  This layer goes one level higher: after a fused
 program compiles, the finished executable is serialized via JAX's AOT
 path (``jax.experimental.serialize_executable``) and persisted next to
 the model artifact; a kill -9 → respawn replica (or a second process in a
@@ -18,9 +18,10 @@ entry is written with the model-artifact sidecar-commit CRC scheme
 (``serve/integrity``) using per-writer tmp names: N replicas warming the
 same ladder concurrently coordinate by write-to-tmp + atomic rename,
 last writer wins.  A torn write, corrupt entry, fingerprint mismatch, or
-deserialization failure is *detected* and degrades to a plain recompile —
-a reason-coded ``warmstart.degraded.<reason>`` counter plus a flight
-event, never a wrong answer and never a crash.
+deserialization failure — or a replayed executable that fails when called
+— is *detected* and degrades to a plain recompile: a reason-coded
+``warmstart.degraded.<reason>`` counter plus a flight event, never a wrong
+answer and never a crash.
 
 Observability: ``warmstart.hits`` / ``misses`` / ``saves`` /
 ``save_failures`` / ``degraded`` (+ per-reason) / ``compile_skips`` /
@@ -54,8 +55,9 @@ __all__ = [
 ]
 
 #: bump when the pickled entry layout changes — old entries degrade to
-#: recompile instead of unpickling garbage
-ENTRY_FORMAT = 1
+#: recompile instead of unpickling garbage (2: entries record the ids of
+#: the devices the executable was compiled for)
+ENTRY_FORMAT = 2
 
 _LOCK = threading.Lock()
 _STORE: Optional["WarmstartStore"] = None
@@ -191,13 +193,24 @@ class WarmstartStore:
         digest = hashlib.sha256(key.encode()).hexdigest()[:20]
         return os.path.join(self._dir, digest + ".aot")
 
+    def note_degraded(self, reason: str, err: object) -> None:
+        """Count a degrade a CALLER of the store absorbed (a dispatch
+        surface that fell back to its plain jitted program) — the same
+        counter + flight event as the store's own, so no degrade is
+        silent."""
+        _degrade(reason, "", self.root, err)
+
     # -- load / save ----------------------------------------------------
 
-    def load(self, key: str):
-        """The deserialized executable for ``key``, or None (miss or
+    def load(self, key: str, recompile=None):
+        """The replayed executable for ``key``, or None (miss or
         detected-degrade — the caller compiles as if the store were
         absent; this function never raises and never returns a wrong
-        executable)."""
+        executable).  The executable is loaded onto exactly the devices
+        it was compiled for.  ``recompile()`` hands back the freshly
+        jitted program: a replayed executable that fails when CALLED
+        degrades to it (:class:`_Replayed`) instead of failing the
+        request."""
         from flink_ml_tpu import obs
         from flink_ml_tpu.fault import injection
         from flink_ml_tpu.serve.errors import ModelIntegrityError
@@ -227,10 +240,17 @@ class WarmstartStore:
                     f"{blob.get('fingerprint')!r}, this process is "
                     f"{self.fingerprint!r}"
                 )
+            import jax
             from jax.experimental import serialize_executable as se
 
+            # without execution_devices jax loads onto EVERY device of the
+            # backend, and a one-device executable then refuses its call
+            # in any process that owns more ("Expected args ... to have 8
+            # shards, got: [1, 1]")
+            by_id = {d.id: d for d in jax.devices()}
             loaded = se.deserialize_and_load(
-                blob["payload"], blob["in_tree"], blob["out_tree"]
+                blob["payload"], blob["in_tree"], blob["out_tree"],
+                execution_devices=[by_id[i] for i in blob["device_ids"]],
             )
         except injection.InjectedFault as e:
             _degrade("injected", key, path, e)
@@ -251,7 +271,7 @@ class WarmstartStore:
             _degrade("deserialize", key, path, e)
             return None
         obs.counter_add("warmstart.hits")
-        return loaded
+        return _Replayed(loaded, key, path, recompile)
 
     def save(self, key: str, compiled) -> bool:
         """Persist ``compiled`` (a ``jax.stages.Compiled``) under ``key``.
@@ -275,6 +295,10 @@ class WarmstartStore:
                 "payload": payload,
                 "in_tree": in_tree,
                 "out_tree": out_tree,
+                "device_ids": [
+                    d.id
+                    for d in compiled.runtime_executable().local_devices()
+                ],
             })
             with AtomicFile(path, unique_tmp=True) as f:
                 f.write(blob)
@@ -391,6 +415,42 @@ class WarmstartStore:
         if evicted:
             obs.counter_add("warmstart.gc_evictions", evicted)
         return evicted
+
+
+class _Replayed:
+    """A store-loaded executable whose CALL can degrade too.
+
+    Loading proves the bytes deserialize, not that the executable accepts
+    this process's arguments (a layout or device-set disagreement shows
+    only at call time).  The first failing call counts a
+    ``warmstart.degraded.call``, switches to ``recompile()``'s program for
+    good, and answers the request from it.  Allocator exhaustion is not
+    the artifact's fault and re-raises for the pressure layer, as does a
+    failure after donated arguments were consumed (nothing left to retry
+    with)."""
+
+    def __init__(self, loaded, key: str, path: str, recompile):
+        self._fn = loaded
+        self._key = key
+        self._path = path
+        self._recompile = recompile
+
+    def __call__(self, *args):
+        try:
+            return self._fn(*args)
+        except Exception as e:
+            import jax
+
+            from flink_ml_tpu.fault.pressure import is_oom
+
+            if self._recompile is None or is_oom(e) or any(
+                getattr(x, "is_deleted", lambda: False)()
+                for x in jax.tree_util.tree_leaves(args)
+            ):
+                raise
+            _degrade("call", self._key, self._path, e)
+            self._fn, self._recompile = self._recompile(), None
+            return self._fn(*args)
 
 
 class _Torn(RuntimeError):

@@ -1,9 +1,9 @@
 """Cross-fit device slab pool — the warm-fit placement cache (ISSUE 2).
 
-The round-5 bench showed a full ``fit()`` spends ~100 ms on host-side pack
-plus host->device placement that is repeated even when the SAME table is
-fit again (hyperparameter sweeps, warm restarts, CV folds) — the fused
-device program itself runs in under a millisecond per epoch.  The
+A full ``fit()`` pays host-side pack plus host->device placement, and
+repeats both even when the SAME table is fit again (hyperparameter sweeps,
+warm restarts, CV folds), while the fused device program itself is short
+per epoch (the split on the chip is to be re-measured, ROADMAP S0).  The
 reference design this repo reproduces (PAPER.md §4: broadcast-model bulk
 iteration) materializes the training set once and re-iterates; per-fit
 re-placement is overhead the architecture never intended.
@@ -289,6 +289,13 @@ class SlabPool:
         with self._lock:
             return self.hits, self.misses
 
+    def items(self) -> list:
+        """``[(key, placed value)]`` snapshot of the live entries — a read
+        for diagnostics (``chip_smoke.py`` checks which devices hold the
+        training slab); it neither touches LRU order nor counts a hit."""
+        with self._lock:
+            return [(k, e.value) for k, e in self._entries.items()]
+
     def _guarded_refs(self, key, refs) -> list:
         """Re-wrap the token pass's weakrefs with death callbacks that
         queue ``key`` for reaping — the callback only appends (atomic, no
@@ -463,8 +470,8 @@ class SlabPool:
 
         t0 = time.perf_counter()
         # outside the lock: placement is the slow part.  Cold placement is
-        # a transient-failure surface (device OOM blips, tunneled-backend
-        # hiccups, injected chaos) — retried with backoff; single-process
+        # a transient-failure surface (runtime UNAVAILABLE/ABORTED blips,
+        # injected chaos) — retried with backoff; single-process
         # only, because a multi-process builder's collectives must dispatch
         # exactly once per peer agreement round
         if jax.process_count() == 1:

@@ -1,60 +1,80 @@
 """Persistent XLA compilation cache — warm-process startup parity.
 
 The reference rides the JVM: a Flink job's operators are bytecode that
-starts in milliseconds, every run (`/root/reference/pom.xml:71-80` — plain
-Java 8, no AOT step).  The TPU framework's equivalent startup tax is XLA
-compilation: the first fit of a process pays ~10-20 s of HLO->LLO compile
-for the fused training program (measured `first_fit_s` in BENCH_r04.json:
-16.8 s).  JAX ships a persistent compilation cache that keys compiled
-executables by (HLO, compile options, backend) and replays them across
-processes; enabling it turns every warm process's compile into a disk
-read, which is the closest a compiled-accelerator framework gets to JVM
-startup.
+starts in milliseconds, every run.  The TPU framework's equivalent
+startup tax is XLA compilation: the first fit of a process pays the
+HLO->LLO compile of the fused training program, seconds against a
+sub-second steady fit.  JAX ships a persistent compilation cache that keys
+compiled executables by (HLO, compile options, backend) and replays them
+across processes; enabling it turns every warm process's compile into a
+disk read, which is the closest a compiled-accelerator framework gets to
+JVM startup.
 
-Enabled automatically for non-CPU backends — at package import when
-``jax_platforms`` names one explicitly, else deferred to the first mesh
-construction (where the backend initializes anyway):
+Where the cache lives is decided ONCE, at package import, from the
+environment alone (no backend is initialized):
 
-* cache directory: ``$FMT_COMPILE_CACHE`` if set (legacy name
-  ``FLINK_ML_TPU_COMPILE_CACHE`` honored as a fallback), else
-  ``~/.cache/flink_ml_tpu/xla`` (created on first use);
-* opt out with ``FMT_COMPILE_CACHE=off``; CPU backends are
-  opt-in only (set the env var to a directory) — see
-  :func:`enable_compilation_cache` for why;
-* thresholds are set to cache everything (min entry size / min compile
-  time both disabled) — a pipeline of small stages benefits exactly as
-  much as one big program.
+* ``JAX_COMPILATION_CACHE_DIR`` set — JAX reads its own variable; this
+  module never touches ``jax_compilation_cache_dir``.  A sealed machine
+  whose home directory dies with it keeps its cache wherever the operator
+  mounted one.
+* unset — one fixed path inside the checkout, ``<repo root>/.jax_cache``
+  (the path is part of JAX's cache key, so it must never move).  Skipped
+  when ``JAX_PLATFORMS`` names only ``cpu``: XLA:CPU AOT replay logs a
+  machine-feature mismatch error per loaded executable (jax 0.9.0's
+  ``+prefer-no-scatter`` pseudo-features), and the compile the cache
+  exists to skip is the TPU one.  A CPU run opts in by setting
+  ``JAX_COMPILATION_CACHE_DIR``.
+* ``FMT_COMPILE_CACHE=off`` — no persistent cache at all (tests, chaos
+  workers).
 
-``scripts/compile_cache_warmstart.py`` measures the effect: it runs the
-same fit in two fresh subprocesses against a fresh cache dir and reports
+Thresholds are set to cache everything (min entry size / min compile time
+both disabled) — a pipeline of small stages benefits exactly as much as
+one big program.  ``scripts/compile_cache_warmstart.py`` measures the
+effect: the same fit in two fresh subprocesses against a fresh cache dir,
 cold vs warm ``first_fit_s``.
 """
 
 from __future__ import annotations
 
-import contextlib
 import os
 import threading
 import warnings
-from pathlib import Path
 
+#: the directory this module pointed JAX at (None: JAX's own env var owns
+#: the choice, or the cache is off)
 _enabled_dir: str | None = None
 
+#: the default location: ``<repo root>/.jax_cache`` (gitignored)
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))),
+    ".jax_cache",
+)
 
-def _env_setting() -> str:
-    """The cache knob value: ``FMT_COMPILE_CACHE`` via the registry, with
-    the pre-registry ``FLINK_ML_TPU_COMPILE_CACHE`` name as a fallback so
-    existing deployments keep working through the rename."""
+
+def _off() -> bool:
     from flink_ml_tpu.utils import knobs
 
-    return (knobs.knob_str("FMT_COMPILE_CACHE")
-            or os.environ.get("FLINK_ML_TPU_COMPILE_CACHE", ""))
+    return knobs.knob_str("FMT_COMPILE_CACHE").strip().lower() == "off"
+
+
+def _cpu_only() -> bool:
+    """Does ``JAX_PLATFORMS`` (``jax_platforms``) name only ``cpu``?  Read
+    from the config string — no backend is initialized."""
+    import jax
+
+    names = [p.strip() for p in (jax.config.jax_platforms or "").split(",")
+             if p.strip()]
+    return bool(names) and all(p == "cpu" for p in names)
 
 
 def cache_dir() -> str | None:
-    """The directory the persistent cache is currently enabled at (None
-    when disabled/deferred) — what replica spawn propagates to children."""
-    return _enabled_dir
+    """The directory the persistent cache writes to (None when off) —
+    what replica spawn propagates to children and the chip smoke counts
+    entries in."""
+    if _off():
+        return None
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or _enabled_dir
 
 
 # -- batch-shape bucketing ----------------------------------------------------
@@ -126,29 +146,15 @@ def reset_bucket_stats() -> None:
         _BUCKETS_SEEN.clear()
 
 
-def enable_compilation_cache(directory: str | None = None, *,
-                             backend_known: bool = False) -> str | None:
-    """Point JAX's persistent compilation cache at ``directory`` (idempotent).
+def enable_compilation_cache() -> str | None:
+    """Resolve the persistent compilation cache (idempotent; called at
+    package import).  Returns the directory in use, or None when off.
 
-    Returns the cache directory in use, or ``None`` when disabled via
-    ``FMT_COMPILE_CACHE=off`` — or deferred: default-on applies
-    only off the CPU backend (XLA:CPU AOT replay checks host machine
-    features and logs SIGILL-risk errors when the compile-time feature set
-    disagrees, observed with jax 0.9.0's +prefer-no-scatter
-    pseudo-features; the compile the cache exists to skip is the TPU one
-    anyway).  At import time the backend must not be initialized, so the
-    decision reads ``jax_platforms`` only: an explicitly non-cpu platform
-    list enables now; unset/ambiguous defers to
-    :func:`ensure_compilation_cache_for_backend`, which the mesh layer
-    calls once the backend is actually being brought up
-    (``backend_known=True`` skips the platform-string heuristic).  CPU
-    users opt in by pointing ``FMT_COMPILE_CACHE`` at a directory.
-    """
+    See the module docstring for the resolution order.  Runs before any
+    backend exists, so every compile of the process — including a
+    ``ModelServer`` load that jits before any mesh is built — finds the
+    cache already configured."""
     global _enabled_dir
-    env = _env_setting()
-    if env.lower() in ("off", "0", "disable", "disabled"):
-        return None
-
     try:
         import jax
     except ImportError:
@@ -156,61 +162,33 @@ def enable_compilation_cache(directory: str | None = None, *,
         # package in images without JAX; no backend means no cache
         return None
 
-    if directory is None and not env and not backend_known:
-        platforms = (jax.config.jax_platforms or "").strip()
-        names = [p.strip() for p in platforms.split(",") if p.strip()]
-        if not names or all(p == "cpu" for p in names):
-            # backend unknown (auto-detect) or cpu-only: defer / skip
-            return None
-    if directory is None:
-        directory = env or str(Path.home() / ".cache" / "flink_ml_tpu" / "xla")
-    if _enabled_dir == directory:
+    if _off():
+        jax.config.update("jax_enable_compilation_cache", False)
+        return None
+    # cache every program regardless of size or compile time: the
+    # pipeline API compiles many small per-stage programs whose compiles
+    # add up; bound on-disk growth (JAX evicts LRU past the max size)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_compilation_cache_max_size", 2 * 1024**3)
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return cache_dir()
+    if _enabled_dir is not None:
         return _enabled_dir
-
+    if _cpu_only():
+        return None
     try:
-        Path(directory).mkdir(parents=True, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", directory)
-        # cache every program regardless of size or compile time: the
-        # pipeline API compiles many small per-stage programs whose
-        # compiles add up
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        # bound on-disk growth (JAX evicts LRU past this); older jax
-        # versions without the knob just run uncapped
-        with contextlib.suppress(AttributeError, ValueError):
-            jax.config.update(
-                "jax_compilation_cache_max_size", 2 * 1024**3
-            )
-    except OSError as e:  # pragma: no cover - needs an unwritable dir
-        # an unwritable cache dir (read-only $HOME, locked-down container)
-        # must never make the package unimportable — fall back to no cache
+        os.makedirs(DEFAULT_CACHE_DIR, exist_ok=True)
+    except OSError as e:
+        # a read-only checkout must never make the package unimportable
         warnings.warn(
-            f"persistent compilation cache disabled: cannot use "
-            f"{directory!r} ({e}); set FMT_COMPILE_CACHE to a "
-            "writable directory or to 'off' to silence this",
+            f"persistent compilation cache disabled: cannot create "
+            f"{DEFAULT_CACHE_DIR!r} ({e}); set JAX_COMPILATION_CACHE_DIR "
+            "to a writable directory or FMT_COMPILE_CACHE=off to silence "
+            "this",
             stacklevel=2,
         )
         return None
-    _enabled_dir = directory
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+    _enabled_dir = DEFAULT_CACHE_DIR
     return _enabled_dir
-
-
-def ensure_compilation_cache_for_backend() -> str | None:
-    """Finish the deferred default-on decision once the backend is known.
-
-    Called by the mesh layer right where ``jax.devices()`` initializes the
-    backend anyway — so querying ``jax.default_backend()`` here adds no
-    side effect.  Enables the cache for any non-CPU backend; no-op when
-    already enabled or opted out.
-    """
-    if _enabled_dir is not None:
-        return _enabled_dir
-    env = _env_setting()
-    if env.lower() in ("off", "0", "disable", "disabled"):
-        return None
-
-    import jax
-
-    if jax.default_backend() == "cpu":
-        return None
-    return enable_compilation_cache(backend_known=True)

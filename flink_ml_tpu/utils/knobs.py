@@ -256,8 +256,8 @@ DECLARATIONS: Tuple[Knob, ...] = (
          "Serving numeric precision: f32 (default), bf16, or int8."),
     # -- cold-start resilience --------------------------------------------
     Knob("FMT_COMPILE_CACHE", "", "str",
-         "Persistent XLA compile-cache dir, or 'off' (legacy name "
-         "FLINK_ML_TPU_COMPILE_CACHE still honored as a fallback)."),
+         "'off' disables the persistent XLA compile cache; the directory "
+         "is JAX_COMPILATION_CACHE_DIR (default <repo>/.jax_cache)."),
     Knob("FMT_WARMSTART", "1", "bool",
          "Warm-artifact layer: persist AOT-serialized fused executables "
          "next to the model and load them before compiling."),

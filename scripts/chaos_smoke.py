@@ -230,7 +230,7 @@ sys.path.insert(0, REPO)
 
 # environment before jax import: virtual mesh, x64 (match the test suite),
 # telemetry on so RunReports carry the fault accounting this smoke asserts
-os.environ.setdefault("FLINK_ML_TPU_COMPILE_CACHE", "off")
+os.environ.setdefault("FMT_COMPILE_CACHE", "off")
 os.environ["XLA_FLAGS"] = (
     os.environ.get("XLA_FLAGS", "")
     + " --xla_force_host_platform_device_count=8"

@@ -8,10 +8,11 @@ one JSON line:
   {"cold_first_fit_s": ..., "warm_first_fit_s": ..., "speedup": ...,
    "cache_entries": N, "cache_bytes": B}
 
-The reference's JVM equivalent starts in milliseconds every run
-(`/root/reference/pom.xml:71-80`); `first_fit_s` is this framework's
-startup tax, and the warm number is what every process after the first
-actually pays.
+The reference's JVM equivalent starts in milliseconds every run;
+`first_fit_s` is this framework's startup tax, and the warm number is what
+every process after the first actually pays.  The parent never imports
+JAX (a chip belongs to one process — the children need it), and places the
+cache through JAX's own ``JAX_COMPILATION_CACHE_DIR``.
 
 Usage: python scripts/compile_cache_warmstart.py [--cpu] [--rows N] [--dim D]
 """
@@ -33,7 +34,7 @@ if {cpu!r} == "cpu":
     jax.config.update("jax_platforms", "cpu")
 sys.path.insert(0, {repo!r})
 import numpy as np
-import flink_ml_tpu  # enables the compilation cache (env var points it here)
+import flink_ml_tpu  # sets the cache thresholds; JAX reads the directory
 from flink_ml_tpu.lib import LogisticRegression
 from flink_ml_tpu.table.schema import DataTypes, Schema
 from flink_ml_tpu.table.table import Table
@@ -57,7 +58,8 @@ print(json.dumps({{"first_fit_s": first_fit_s}}))
 
 def run_child(cache_dir: str, cpu: bool, rows: int, dim: int) -> float:
     env = dict(os.environ)
-    env["FLINK_ML_TPU_COMPILE_CACHE"] = cache_dir
+    env.pop("FMT_COMPILE_CACHE", None)
+    env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     if cpu:
         env["JAX_PLATFORMS"] = "cpu"
     code = CHILD.format(

@@ -22,7 +22,7 @@ import sys
 phase = sys.argv[1]
 candidate_dir = sys.argv[2]
 
-os.environ.setdefault("FLINK_ML_TPU_COMPILE_CACHE", "off")
+os.environ.setdefault("FMT_COMPILE_CACHE", "off")
 os.environ.pop("XLA_FLAGS", None)
 
 import jax

@@ -21,7 +21,7 @@ import sys
 phase = sys.argv[1]
 ckpt_dir = sys.argv[2]
 
-os.environ.setdefault("FLINK_ML_TPU_COMPILE_CACHE", "off")
+os.environ.setdefault("FMT_COMPILE_CACHE", "off")
 os.environ["XLA_FLAGS"] = (
     os.environ.get("XLA_FLAGS", "")
     + " --xla_force_host_platform_device_count=8"

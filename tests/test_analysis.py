@@ -321,7 +321,7 @@ class TestRepoSelfCheck:
 
 
 class TestAnalysisReportLine:
-    def test_check_report_follows_fmt_obs_reports(self, tmp_path,
+    def test_analysis_report_follows_fmt_obs_reports(self, tmp_path,
                                                   monkeypatch):
         # the analyzer's report must land where obs --check will look
         from flink_ml_tpu.analysis.__main__ import default_report_dir

@@ -244,3 +244,40 @@ class TestNativeChunkedReaders:
         schema = Schema.of(("a", "double"), ("b", "double"))
         for _ in range(50):
             assert sum(c.num_rows() for c in CsvSource(str(path), schema).read_chunks(4)) == 1
+
+
+class TestBuiltFromTheseSources:
+    """The .so is trusted by the digest of the sources it was built from,
+    recorded beside it — never by mtime, which a copied tree loses."""
+
+    def test_recorded_digest_matches_the_sources(self):
+        assert native.available()
+        with open(native._DIGEST) as f:
+            assert f.read().strip() == native._source_digest()
+
+    def test_a_foreign_so_is_rebuilt(self, monkeypatch):
+        import shutil
+
+        if not (shutil.which("make") and shutil.which("g++")):
+            pytest.skip("no compiler to rebuild with")
+        assert native.available()
+        # an .so whose recorded digest is not these sources' (e.g. an
+        # ignored file that rode along a copy, newer than everything)
+        with open(native._DIGEST, "w") as f:
+            f.write("built-from-something-else\n")
+        os.utime(native._SO, None)
+        builds = []
+        real_run = native.subprocess.run
+
+        def counting_run(cmd, **kw):
+            builds.append(cmd)
+            return real_run(cmd, **kw)
+
+        monkeypatch.setattr(native.subprocess, "run", counting_run)
+        # plain assignment, not monkeypatch: the handle to the rebuilt
+        # library is the one the rest of the suite must keep using
+        native._tried, native._lib = False, None
+        assert native._load() is not None
+        assert builds and "-B" in builds[0]
+        with open(native._DIGEST) as f:
+            assert f.read().strip() == native._source_digest()

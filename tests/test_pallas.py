@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from flink_ml_tpu.ops import pallas_kernels
 from flink_ml_tpu.ops.pallas_kernels import glm_grad, make_pallas_grad_fn
 
 
@@ -17,40 +18,6 @@ def data(n=300, d=28, seed=0):
     wts = jnp.asarray(rng.randn(d), jnp.float32)
     b = jnp.asarray(0.3, jnp.float32)
     return x, y, w, wts, b
-
-
-def _pallas_cpu_unavailable():
-    """Capability probe: can this environment lower the Pallas kernel in
-    interpret mode at all?  Legacy JAX builds reject kernel plumbing the
-    kernels rely on (e.g. ``ShapeDtypeStruct(..., vma=...)`` predates
-    the vma-aware API), which is an ENVIRONMENT limitation, not a
-    regression in this repo — those runs should read as named skips in
-    tier-1 output, not as 8 failures masking real breakage.  Returns the
-    diagnostic string (None when the lowering works).
-
-    Deliberately NARROW: only error signatures known to mean "this JAX
-    build lacks the capability" skip — anything else propagates and
-    fails collection loudly, because a regression in the kernel code
-    itself must never read as an environment skip."""
-    try:
-        glm_grad(*data(n=8, d=4), interpret=True)
-        return None
-    except TypeError as exc:
-        if "vma" in str(exc):  # pre-vma ShapeDtypeStruct/pallas_call API
-            return f"{type(exc).__name__}: {exc}"
-        raise
-    except (ImportError, NotImplementedError) as exc:
-        # no pallas package / no interpret lowering on this backend
-        return f"{type(exc).__name__}: {exc}"
-
-
-_PALLAS_UNAVAILABLE = _pallas_cpu_unavailable()
-
-pytestmark = pytest.mark.skipif(
-    _PALLAS_UNAVAILABLE is not None,
-    reason=("Pallas CPU lowering unavailable in this environment: "
-            f"{_PALLAS_UNAVAILABLE}"),
-)
 
 
 class TestGlmGradKernel:
@@ -103,8 +70,8 @@ class TestPallasGradFnIntegration:
     def test_trains_through_harness(self):
         """make_pallas_grad_fn drops into train_glm and converges — runs in
         the CPU CI suite via interpret mode (the grad fn declares
-        shard_map_check_vma=False there; strict vma on real TPU).  This was
-        the one skipped test through r3 (VERDICT r3 weak #5)."""
+        shard_map_check_vma=False there; strict vma with Mosaic, which
+        chip_smoke.py covers)."""
         from flink_ml_tpu.lib.common import pack_minibatches, train_glm
         from flink_ml_tpu.parallel.mesh import default_mesh
 
@@ -174,3 +141,60 @@ class TestPallasGradFnIntegration:
                                    rtol=5e-4, atol=5e-5)
         np.testing.assert_allclose(rp.params[1], rj.params[1],
                                    rtol=5e-4, atol=5e-5)
+
+
+class TestLoweringIsChosenByPlatform:
+    """tpu compiles with Mosaic or raises, cpu interprets, anything else
+    raises — nothing selects the interpreter silently."""
+
+    class _Device:
+        def __init__(self, platform):
+            self.platform = platform
+
+    def test_cpu_interprets_and_says_so(self):
+        assert pallas_kernels.launch_interpreted() is True
+        grad_fn = make_pallas_grad_fn("logistic", with_intercept=True)
+        assert grad_fn.pallas_interpret is True
+        assert grad_fn.shard_map_check_vma is False
+
+    def test_tpu_compiles_with_mosaic(self, monkeypatch):
+        monkeypatch.setattr(jax, "devices",
+                            lambda *a: [self._Device("tpu")])
+        assert pallas_kernels.launch_interpreted() is False
+
+    def test_unknown_platform_raises(self, monkeypatch):
+        monkeypatch.setattr(jax, "devices",
+                            lambda *a: [self._Device("some_plugin")])
+        with pytest.raises(RuntimeError, match="some_plugin"):
+            pallas_kernels.launch_interpreted()
+        with pytest.raises(RuntimeError, match="some_plugin"):
+            make_pallas_grad_fn("logistic", with_intercept=True)
+        with pytest.raises(RuntimeError, match="some_plugin"):
+            pallas_kernels.serve_chain(["glm_score"], [True], 4)
+
+    def test_interpreted_fit_is_counted(self):
+        from flink_ml_tpu import obs
+        from flink_ml_tpu.lib.common import pack_minibatches, train_glm
+        from flink_ml_tpu.parallel.mesh import default_mesh
+
+        x, y, *_ = data(n=64, d=4)
+        stack = pack_minibatches(np.asarray(x), np.asarray(y, np.float64),
+                                 jax.device_count())
+        obs.enable()
+        obs.reset()
+        try:
+            train_glm(
+                (jnp.zeros((4,), jnp.float32), jnp.zeros((), jnp.float32)),
+                stack, make_pallas_grad_fn("logistic", with_intercept=True),
+                default_mesh(), learning_rate=0.5, max_iter=2,
+            )
+            c = obs.registry().snapshot()["counters"]
+            assert c.get("train.pallas_interpreted", 0) == 1, c
+        finally:
+            obs.disable()
+            obs.reset()
+
+    def test_too_wide_for_vmem_is_a_clear_error(self):
+        # the (d_pad, 1) weight/gradient blocks alone overflow the budget
+        with pytest.raises(ValueError, match="VMEM"):
+            glm_grad(*data(n=8, d=8192), interpret=True)

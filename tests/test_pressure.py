@@ -96,6 +96,99 @@ class TestOomClassification:
         assert retry.is_transient(InjectedFault("place.h2d", 1))
 
 
+#: the two texts a kernel that overflows VMEM produced on a v5e (jax 0.9.0,
+#: libtpu 0.0.34 — chip run of PR 21), plus the short form ISSUE 21 quotes
+SCOPED_VMEM_MSG = (
+    "RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem while "
+    "allocating on stack for %tpu_custom_call.1 = f32[2048,2048]{1,0:T(8,128)}"
+    " custom-call(%args_0_.1), custom_call_target=\"tpu_custom_call\". Scoped "
+    "allocation with size 32.00M and limit 16.00M exceeded scoped vmem limit "
+    "by 16.00M. It should not be possible to run out of scoped vmem"
+)
+VMEM_WINDOW_MSG = (
+    "RESOURCE_EXHAUSTED: Allocation (size=268435456) would exceed memory "
+    "(size=134217728) :: #allocation2 [shape = 'u8[268435456]{0}', "
+    "space=vmem, size = 0x10000000, tag = 'input window allocation for "
+    "operator input 0.'] :: tpu_custom_call.1"
+)
+COMPILE_SHAPED = (
+    SCOPED_VMEM_MSG, VMEM_WINDOW_MSG,
+    "RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem",
+)
+#: ...and what the chip's HBM allocator says, which IS memory pressure
+HBM_MSG = (
+    "RESOURCE_EXHAUSTED: Error allocating device buffer: Attempting to "
+    "allocate 4.00G. That was not possible. There are 3.75G free.; "
+    "(0x0x0_HBM0)"
+)
+
+
+class TestCompileFailureIsNobodysToAbsorb:
+    """A kernel that does not fit VMEM carries the allocator's status code
+    but is a compile failure: not an OOM (bisecting the batch compiles the
+    same kernel), not transient (a retry compiles it again)."""
+
+    def test_compile_shaped_text_is_neither_oom_nor_transient(self):
+        for msg in COMPILE_SHAPED:
+            for exc in (RuntimeError(msg), ValueError(msg)):
+                assert pressure.is_compile_failure(exc), msg
+                assert not pressure.is_oom(exc), msg
+                assert not retry.is_transient(exc), msg
+
+    def test_hbm_exhaustion_is_still_an_oom(self):
+        for exc in (RuntimeError(HBM_MSG), ValueError(HBM_MSG)):
+            assert not pressure.is_compile_failure(exc)
+            assert pressure.is_oom(exc)
+            assert not retry.is_transient(exc)
+
+    def test_propagates_out_of_a_fused_serve_dispatch(self, monkeypatch):
+        from flink_ml_tpu.api.pipeline import Pipeline
+        from flink_ml_tpu.common.fused import FusedRun
+        from flink_ml_tpu.lib.feature import StandardScaler
+
+        t = _dense_table(n=512)
+        model = Pipeline([
+            StandardScaler().set_selected_col("features"), _logreg(),
+        ]).fit(t)
+        calls = []
+
+        def refuse(self, *a, **k):
+            calls.append(1)
+            raise RuntimeError(SCOPED_VMEM_MSG)
+
+        monkeypatch.setattr(FusedRun, "_device_batch", refuse)
+        obs.enable()
+        obs.reset()
+        with pytest.raises(RuntimeError, match="scoped vmem"):
+            model.transform(t)
+        assert calls == [1]  # not retried, not bisected
+        c = obs.registry().snapshot()["counters"]
+        for hidden in ("pressure.ooms", "pressure.bisections",
+                       "fault.retries", "serve.fallbacks",
+                       "serve.dispatch_failures",
+                       "pipeline.plan_fallback_batches"):
+            assert c.get(hidden, 0) == 0, (hidden, c)
+
+    def test_propagates_out_of_train_glm(self, monkeypatch):
+        from flink_ml_tpu.lib import common
+
+        calls = []
+
+        def refuse(*a, **k):
+            calls.append(1)
+            raise RuntimeError(VMEM_WINDOW_MSG)
+
+        monkeypatch.setattr(common, "_run_fused_train", refuse)
+        obs.enable()
+        obs.reset()
+        with pytest.raises(RuntimeError, match="space=vmem"):
+            _logreg(iters=2, global_batch_size=32).fit(_dense_table())
+        assert calls == [1]  # no micro-batch fallback
+        c = obs.registry().snapshot()["counters"]
+        assert c.get("pressure.ooms", 0) == 0, c
+        assert c.get("train.pressure_runs", 0) == 0, c
+
+
 class TestRetryDeclassification:
     def test_oom_not_retried_same_size(self):
         """The red test for the old behavior: fault/retry.py classified

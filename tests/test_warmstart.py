@@ -1,13 +1,14 @@
-"""Cold-start resilience: compile-cache knob + warm-artifact store (ISSUE 18).
+"""Cold-start resilience: compile-cache resolution + warm-artifact store
+(ISSUE 18, resolution rewritten in ISSUE 21).
 
-Covers the satellite-4 checklist for ``enable_compilation_cache`` /
-``ensure_compilation_cache_for_backend`` (idempotency, ``=off`` opt-out,
-CPU-defer heuristic, legacy-name fallback) and the tentpole warm-artifact
-layer: AOT save/load round trip, torn-write / corrupt-entry / fingerprint
-mismatch -> detected degrade to recompile (counter + flight event, never a
+Covers where the persistent compile cache lives (``JAX_COMPILATION_CACHE_DIR``
+owned by JAX itself, else ``<repo>/.jax_cache``, ``FMT_COMPILE_CACHE=off``)
+and the warm-artifact layer: AOT save/load round trip onto the executable's
+own devices, torn-write / corrupt-entry / fingerprint mismatch / call-time
+failure -> detected degrade to recompile (counter + flight event, never a
 raise), bounded GC, fault-injection points, the fused lookup-before-compile
-path, the ladder warmup in ``VersionManager.deploy``, and the replica spawn
-env propagation.
+path, the ladder warmup in ``VersionManager.deploy``, and what a spawned
+replica inherits.
 """
 
 import os
@@ -37,25 +38,23 @@ def _obs_on():
     yield
 
 
-# -- compile-cache knob migration (satellite 1 + 4) ---------------------------
+# -- compile-cache resolution -------------------------------------------------
 
 
 @pytest.fixture
 def cache_state(monkeypatch):
-    """Isolate the module-global idempotency latch and both env names."""
-    old = compile_cache._enabled_dir
-    compile_cache._enabled_dir = None
+    """Isolate the module latch and the environment, and RECORD every
+    ``jax.config.update`` the resolution makes instead of applying it."""
+    monkeypatch.setattr(compile_cache, "_enabled_dir", None)
     monkeypatch.delenv("FMT_COMPILE_CACHE", raising=False)
-    monkeypatch.delenv("FLINK_ML_TPU_COMPILE_CACHE", raising=False)
-    yield monkeypatch
-    compile_cache._enabled_dir = old
-    try:
-        jax.config.update("jax_compilation_cache_dir", None)
-    except Exception:
-        pass
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    updates = {}
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: updates.__setitem__(name, value))
+    return monkeypatch, updates
 
 
-class TestCompileCacheKnob:
+class TestCompileCacheResolution:
     def test_knob_declared(self):
         names = {k.name for k in knobs.DECLARATIONS}
         assert "FMT_COMPILE_CACHE" in names
@@ -64,49 +63,59 @@ class TestCompileCacheKnob:
         assert "FMT_WARM_DIR" in names
         assert "FMT_WARM_CACHE_MB" in names
 
-    def test_off_opt_out(self, cache_state):
-        cache_state.setenv("FMT_COMPILE_CACHE", "off")
-        assert compile_cache.enable_compilation_cache(backend_known=True) is None
-        assert compile_cache.cache_dir() is None
+    def test_jax_variable_owns_the_directory(self, cache_state, tmp_path):
+        env, updates = cache_state
+        d = str(tmp_path / "placed_from_outside")
+        env.setenv("JAX_COMPILATION_CACHE_DIR", d)
+        assert compile_cache.enable_compilation_cache() == d
+        assert compile_cache.cache_dir() == d
+        # JAX reads its own variable: the package never sets the directory
+        assert "jax_compilation_cache_dir" not in updates
+        # ...but still caches every program regardless of size/time
+        assert updates["jax_persistent_cache_min_compile_time_secs"] == 0
+        assert updates["jax_persistent_cache_min_entry_size_bytes"] == -1
 
-    def test_legacy_name_fallback(self, cache_state, tmp_path):
-        d = str(tmp_path / "xla_legacy")
-        cache_state.setenv("FLINK_ML_TPU_COMPILE_CACHE", d)
-        assert compile_cache.enable_compilation_cache(backend_known=True) == d
+    def test_default_is_fixed_path_in_the_checkout(self, cache_state,
+                                                   tmp_path):
+        env, updates = cache_state
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert compile_cache.DEFAULT_CACHE_DIR == os.path.join(
+            repo, ".jax_cache")
+        d = str(tmp_path / ".jax_cache")
+        env.setattr(compile_cache, "DEFAULT_CACHE_DIR", d)
+        env.setattr(compile_cache, "_cpu_only", lambda: False)
+        assert compile_cache.enable_compilation_cache() == d
+        assert updates["jax_compilation_cache_dir"] == d
+        assert compile_cache.cache_dir() == d and os.path.isdir(d)
+        # idempotent: a second call neither moves nor re-sets it
+        updates.clear()
+        assert compile_cache.enable_compilation_cache() == d
+        assert "jax_compilation_cache_dir" not in updates
 
-    def test_legacy_off_still_honored(self, cache_state):
-        cache_state.setenv("FLINK_ML_TPU_COMPILE_CACHE", "off")
-        assert compile_cache.enable_compilation_cache(backend_known=True) is None
-
-    def test_fmt_name_wins_over_legacy(self, cache_state, tmp_path):
-        a, b = str(tmp_path / "a"), str(tmp_path / "b")
-        cache_state.setenv("FMT_COMPILE_CACHE", a)
-        cache_state.setenv("FLINK_ML_TPU_COMPILE_CACHE", b)
-        assert compile_cache.enable_compilation_cache(backend_known=True) == a
-
-    def test_cpu_defer_without_env(self, cache_state):
-        # jax_platforms is cpu under the test harness: default-on defers
+    def test_explicit_cpu_run_has_no_default_cache(self, cache_state):
+        # jax_platforms is "cpu" under the test harness
+        _env, updates = cache_state
+        assert compile_cache._cpu_only()
         assert compile_cache.enable_compilation_cache() is None
         assert compile_cache.cache_dir() is None
+        assert "jax_compilation_cache_dir" not in updates
 
-    def test_env_dir_enables_even_on_cpu(self, cache_state, tmp_path):
-        d = str(tmp_path / "xla_cpu_optin")
-        cache_state.setenv("FMT_COMPILE_CACHE", d)
-        assert compile_cache.enable_compilation_cache() == d
+    def test_off_disables(self, cache_state, tmp_path):
+        env, updates = cache_state
+        env.setenv("FMT_COMPILE_CACHE", "off")
+        env.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "ignored"))
+        assert compile_cache.enable_compilation_cache() is None
+        assert compile_cache.cache_dir() is None
+        assert updates == {"jax_enable_compilation_cache": False}
 
-    def test_idempotent(self, cache_state, tmp_path):
-        d = str(tmp_path / "xla")
-        assert compile_cache.enable_compilation_cache(d, backend_known=True) == d
-        # second call with the same dir is a no-op returning the same dir
-        assert compile_cache.enable_compilation_cache(d, backend_known=True) == d
-        assert compile_cache.cache_dir() == d
-
-    def test_ensure_for_backend_cpu_noop(self, cache_state):
-        assert compile_cache.ensure_compilation_cache_for_backend() is None
-
-    def test_ensure_for_backend_off(self, cache_state):
-        cache_state.setenv("FMT_COMPILE_CACHE", "off")
-        assert compile_cache.ensure_compilation_cache_for_backend() is None
+    def test_directory_value_is_no_longer_a_meaning(self, cache_state,
+                                                    tmp_path):
+        # FMT_COMPILE_CACHE keeps one meaning, "off": a directory there
+        # places nothing (JAX_COMPILATION_CACHE_DIR does that now)
+        env, updates = cache_state
+        env.setenv("FMT_COMPILE_CACHE", str(tmp_path / "xla"))
+        assert compile_cache.enable_compilation_cache() is None
+        assert "jax_compilation_cache_dir" not in updates
 
 
 # -- warm-artifact store (tentpole) -------------------------------------------
@@ -142,6 +151,59 @@ class TestWarmstartStore:
         p = store.entry_path(key)
         assert os.path.exists(p)
         assert os.path.exists(integrity.commit_path(p))
+
+    def test_roundtrip_loads_onto_the_executables_own_devices(self, store):
+        # a one-device executable replayed in an 8-device process: without
+        # execution_devices jax would load it onto all 8 and refuse the call
+        assert jax.device_count() == 8
+        compiled, args = _tiny_compiled()
+        key = store.entry_key("one_device", 8, 1, "float32")
+        assert store.save(key, compiled)
+        with open(store.entry_path(key), "rb") as f:
+            assert pickle.loads(f.read())["device_ids"] == [
+                jax.devices()[0].id]
+        loaded = store.load(key)
+        np.testing.assert_array_equal(
+            np.asarray(loaded(*args)), np.asarray(compiled(*args)))
+
+    def test_call_time_failure_degrades_to_recompile(self, store):
+        """A replayed executable that loads but fails when CALLED is a
+        degrade like a load-time one — the request is answered by the
+        recompiled program, once and for all."""
+        compiled, args = _tiny_compiled()
+        key = store.entry_key("k", 8, 1, "float32")
+        assert store.save(key, compiled)
+        recompiles = []
+
+        def recompile():
+            recompiles.append(1)
+            return compiled
+
+        loaded = store.load(key, recompile=recompile)
+        loaded._fn = lambda *a: (_ for _ in ()).throw(RuntimeError(
+            "INVALID_ARGUMENT: Expected args to execute_sharded_on_local_"
+            "devices to have 8 shards, got: [1, 1]"))
+        np.testing.assert_array_equal(
+            np.asarray(loaded(*args)), np.asarray(compiled(*args)))
+        np.testing.assert_array_equal(
+            np.asarray(loaded(*args)), np.asarray(compiled(*args)))
+        assert recompiles == [1]  # switched for good, not per call
+        c = _counters()
+        assert c.get("warmstart.degraded.call", 0) == 1
+        assert c.get("warmstart.degraded", 0) == 1
+
+    def test_call_time_oom_is_not_the_artifacts_fault(self, store):
+        compiled, args = _tiny_compiled()
+        key = store.entry_key("k", 8, 1, "float32")
+        assert store.save(key, compiled)
+        loaded = store.load(key, recompile=lambda: compiled)
+        loaded._fn = lambda *a: (_ for _ in ()).throw(RuntimeError(
+            "RESOURCE_EXHAUSTED: Error allocating device buffer: "
+            "Attempting to allocate 4.00G. That was not possible. There "
+            "are 3.75G free.; (0x0x0_HBM0)"))
+        with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+            loaded(*args)  # re-raised for the pressure layer
+        assert _counters().get("warmstart.degraded", 0) == 0
 
     def test_missing_entry_is_miss(self, store):
         assert store.load(store.entry_key("nope", 1, 1, "float32")) is None
@@ -344,31 +406,50 @@ class TestLadderWarmup:
             warmstart.configure(None)
 
 
-# -- replica spawn env propagation (satellite 2) ------------------------------
+# -- what a spawned replica inherits -------------------------------------------
 
 
 class TestSpawnEnvPropagation:
-    def test_cache_dirs_ride_to_children(self, tmp_path, monkeypatch):
+    def test_platform_and_cache_dirs_ride_to_children(self, tmp_path,
+                                                      monkeypatch):
         from flink_ml_tpu.serving import replica as replica_mod
 
-        monkeypatch.setattr(
-            compile_cache, "_enabled_dir", str(tmp_path / "xla")
-        )
+        monkeypatch.delenv("FMT_COMPILE_CACHE", raising=False)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / "xla"))
         warmstart.configure(str(tmp_path / "warm_aot"))
         try:
             env = {}
-            replica_mod._cache_env(env)
-            assert env["FMT_COMPILE_CACHE"] == str(tmp_path / "xla")
+            replica_mod._child_env(env)
+            # pinned to the parent's backend: a child that cannot get it
+            # dies at boot instead of serving from another platform
+            assert env["JAX_PLATFORMS"] == jax.default_backend() == "cpu"
+            assert env["JAX_COMPILATION_CACHE_DIR"] == str(tmp_path / "xla")
             assert env["FMT_WARM_DIR"] == str(tmp_path / "warm_aot")
+            assert "FMT_COMPILE_CACHE" not in env
         finally:
             warmstart.configure(None)
 
-    def test_noop_when_nothing_enabled(self, monkeypatch):
+    def test_resolved_default_cache_dir_rides_too(self, tmp_path,
+                                                  monkeypatch):
+        from flink_ml_tpu.serving import replica as replica_mod
+
+        monkeypatch.delenv("FMT_COMPILE_CACHE", raising=False)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.setattr(compile_cache, "_enabled_dir",
+                            str(tmp_path / ".jax_cache"))
+        env = {}
+        replica_mod._child_env(env)
+        assert env["JAX_COMPILATION_CACHE_DIR"] == str(
+            tmp_path / ".jax_cache")
+
+    def test_no_cache_no_store_hands_over_neither(self, monkeypatch):
         from flink_ml_tpu.serving import replica as replica_mod
 
         monkeypatch.setattr(compile_cache, "_enabled_dir", None)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         assert warmstart.active() is None
         env = {}
-        replica_mod._cache_env(env)
-        assert "FMT_COMPILE_CACHE" not in env
+        replica_mod._child_env(env)
+        assert "JAX_COMPILATION_CACHE_DIR" not in env
         assert "FMT_WARM_DIR" not in env
