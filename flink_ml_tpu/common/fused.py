@@ -612,6 +612,9 @@ class FusedRun:
         else:
             fused = self._pallas_fused_fn(masked=pallas == "masked")
             n_out = len(self.fetch_layout) + (pallas == "masked")
+        # a name the program owns on every operation of the serve program
+        # (metadata: the compiled program does not change)
+        fused = jax.named_scope("fmt.serve")(fused)
         if width == 1:
             # a 1-wide data axis (or FMT_SERVE_MESH=0) degenerates to the
             # plain single-logical-device program

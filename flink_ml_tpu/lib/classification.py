@@ -83,15 +83,20 @@ def _log_loss_grads(with_intercept: bool):
 
     def grad_fn(params, x, y, w):
         wts, b = params
-        logits = x @ wts + b
-        p = jax.nn.sigmoid(logits)
-        err = (p - y) * w
-        g_w = x.T @ err
-        g_b = jnp.sum(err) * keep_b
-        # numerically-stable weighted log-loss sum
-        loss = jnp.sum(
-            w * (jnp.logaddexp(0.0, logits) - y * logits)
-        )
+        # scopes are names on the operations; their order stays as it was,
+        # so the program (and its cache key) is the one it was
+        with jax.named_scope("fmt.train.scores"):
+            logits = x @ wts + b
+        with jax.named_scope("fmt.train.grad"):
+            p = jax.nn.sigmoid(logits)
+            err = (p - y) * w
+            g_w = x.T @ err
+            g_b = jnp.sum(err) * keep_b
+        with jax.named_scope("fmt.train.scores"):
+            # numerically-stable weighted log-loss sum
+            loss = jnp.sum(
+                w * (jnp.logaddexp(0.0, logits) - y * logits)
+            )
         return (g_w, g_b), loss, jnp.sum(w)
 
     return grad_fn

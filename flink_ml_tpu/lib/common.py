@@ -514,9 +514,10 @@ def _combined_view(stack: MinibatchStack) -> np.ndarray:
     """x, y, w packed into one (n_dev*steps, mb, d+2) array — a single
     host->device transfer instead of three (one placement, one pooled slab,
     one scanned operand in the fused program)."""
-    return np.concatenate(
-        [stack.x, stack.y[..., None], stack.w[..., None]], axis=2
-    )
+    with obs.span("place.host_view"):
+        return np.concatenate(
+            [stack.x, stack.y[..., None], stack.w[..., None]], axis=2
+        )
 
 
 def _combined_view_memo(stack: MinibatchStack) -> np.ndarray:
@@ -581,14 +582,22 @@ def _build_fused_train_fn(key, mb_grad_step, mesh, learning_rate, reg,
     sgd_update = make_sgd_update(learning_rate, reg)
     tol_ = float(tol)
 
+    # named scopes are metadata on the operations (the compiled program
+    # and its cache key do not change): a profile finds the program's
+    # parts under names the program owns.  The dense grad fns scope their
+    # own scores/loss and gradient (fmt.train.scores, fmt.train.grad)
+    @jax.named_scope("fmt.train")
     def local_train(params, batch):
         def mb_step(p, xs):
             grads, loss_sum, w_sum = mb_grad_step(p, xs)
-            grads = jax.tree_util.tree_map(lambda g: psum(g, "data"), grads)
-            loss_sum = psum(loss_sum, "data")
-            w_sum = psum(w_sum, "data")
-            count = jnp.maximum(w_sum, 1.0)
-            new_p = sgd_update(p, grads, count)
+            with jax.named_scope("fmt.train.grad"):
+                grads = jax.tree_util.tree_map(
+                    lambda g: psum(g, "data"), grads)
+                loss_sum = psum(loss_sum, "data")
+                w_sum = psum(w_sum, "data")
+            with jax.named_scope("fmt.train.update"):
+                count = jnp.maximum(w_sum, 1.0)
+                new_p = sgd_update(p, grads, count)
             return new_p, (loss_sum / count, w_sum)
 
         def sgd_epoch(params):
@@ -661,16 +670,19 @@ def _build_fused_train_fn(key, mb_grad_step, mesh, learning_rate, reg,
     # host values.
     fetch_dtype = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
 
+    # the function's name is the program's on the device (``jit_bundled``:
+    # what a trace's module events are called); tests pin it
     def bundled(params, batch):
         params, loss_hist, epochs, delta = sharded(params, batch)
-        pieces = [
-            jnp.ravel(a).astype(fetch_dtype)
-            for a in jax.tree_util.tree_leaves(params)
-        ]
-        pieces.append(loss_hist.astype(fetch_dtype))
-        pieces.append(jnp.reshape(epochs, (1,)).astype(fetch_dtype))
-        pieces.append(jnp.reshape(delta, (1,)).astype(fetch_dtype))
-        return jnp.concatenate(pieces)
+        with jax.named_scope("fmt.train.bundle"):
+            pieces = [
+                jnp.ravel(a).astype(fetch_dtype)
+                for a in jax.tree_util.tree_leaves(params)
+            ]
+            pieces.append(loss_hist.astype(fetch_dtype))
+            pieces.append(jnp.reshape(epochs, (1,)).astype(fetch_dtype))
+            pieces.append(jnp.reshape(delta, (1,)).astype(fetch_dtype))
+            return jnp.concatenate(pieces)
 
     jitted = jax.jit(bundled)
 
@@ -702,52 +714,68 @@ def _run_fused_train(train_fn, init_params, batch, mesh,
     from flink_ml_tpu.parallel.mesh import replicate
     from flink_ml_tpu.table import slab_pool
 
-    import time as _time
+    global _RUN_BUILDS_SEEN
 
     metrics = StepMetrics("fused_train")
     metrics.start_step()
-    t_call0 = _time.perf_counter()
-    placed = (
-        place_params(init_params) if place_params is not None
-        else replicate(mesh, init_params)
-    )
-    # the train fn donates its params: when the caller passes already-placed
-    # device arrays, placement may alias their buffers (device_put returns a
-    # view-like Array for no-op placements) and donation would delete the
-    # CALLER's data — a second fit from the same initial params would crash.
-    # Copy any leaf whose origin is a device array (host-sourced leaves were
-    # freshly copied by placement already).
-    placed = jax.tree_util.tree_map(
-        lambda p, o: jnp.copy(p) if isinstance(o, jax.Array) else p,
-        placed, init_params,
-    )
-    global _RUN_BUILDS_SEEN
-
-    t_place = _time.perf_counter()
+    with obs.span("train.place_params"):
+        placed = (
+            place_params(init_params) if place_params is not None
+            else replicate(mesh, init_params)
+        )
+        # the train fn donates its params: when the caller passes
+        # already-placed device arrays, placement may alias their buffers
+        # (device_put returns a view-like Array for no-op placements) and
+        # donation would delete the CALLER's data — a second fit from the
+        # same initial params would crash.  Copy any leaf whose origin is
+        # a device array (host-sourced leaves were freshly copied by
+        # placement already).
+        placed = jax.tree_util.tree_map(
+            lambda p, o: jnp.copy(p) if isinstance(o, jax.Array) else p,
+            placed, init_params,
+        )
+    place = None
     if batch_preplaced:
         device_batch = batch
-        place_s = 0.0
     else:
         # pooled + double-buffered: a warm re-fit of the same host arrays
         # skips the transfer entirely (slab_pool hit); a cold placement
         # overlaps host staging with the async H2D DMA
-        device_batch = slab_pool.place_batch(mesh, batch)
-        place_s = _time.perf_counter() - t_place
+        with obs.span("train.place") as place:
+            device_batch = slab_pool.place_batch(mesh, batch)
     # pin the (possibly pooled) batch for the whole dispatch+fetch window:
     # budget eviction must never drop the pool's reference while a program
-    # is in flight over these buffers
+    # is in flight over these buffers.  The compile/steady split: dispatch
+    # absorbs trace+compile (cold program) or just the enqueue (warm); sync
+    # is device execution + readback
+    bundled = getattr(train_fn, "bundle_fetch", False)
     with slab_pool.pool().pinned(device_batch):
-        t_run = _time.perf_counter()
-        if getattr(train_fn, "bundle_fetch", False):
-            flat = train_fn(placed, device_batch)
-            dispatch_s = _time.perf_counter() - t_run
-            t_fetch = _time.perf_counter()
-            # ONE readback for the whole result: param leaves + loss
-            # history + epochs + delta ride a single flat buffer packed
-            # in-program.  Split by the placed leaves' shapes.
+        if bundled:
+            with obs.span("train.dispatch") as dispatch:
+                flat = train_fn(placed, device_batch)
+            with obs.span("train.sync") as sync:
+                # ONE readback for the whole result: param leaves + loss
+                # history + epochs + delta ride a single flat buffer packed
+                # in-program
+                buf = np.asarray(flat)
+        else:
+            with obs.span("train.dispatch") as dispatch:
+                params, loss_hist, epochs, delta = train_fn(
+                    placed, device_batch)
+            leaves, treedef = jax.tree_util.tree_flatten(params)
+            with obs.span("train.sync") as sync:
+                # fetch_flat is the single sync point: it absorbs transfer
+                # + program + readback (no extra block_until_ready
+                # round-trips)
+                fetched = fetch_flat(
+                    *leaves, loss_hist, jnp.asarray(epochs),
+                    jnp.asarray(delta)
+                )
+    with obs.span("train.demux"):
+        if bundled:
+            # split the flat buffer by the placed leaves' shapes
             leaves, treedef = jax.tree_util.tree_flatten(placed)
             hist_len = int(train_fn.loss_hist_len)
-            buf = np.asarray(flat)
             fetched = []
             off = 0
             for a in leaves:
@@ -757,74 +785,45 @@ def _run_fused_train(train_fn, init_params, batch, mesh,
             fetched.append(buf[off : off + hist_len])
             fetched.append(buf[off + hist_len])
             fetched.append(buf[off + hist_len + 1])
-            sync_s = _time.perf_counter() - t_fetch
-        else:
-            params, loss_hist, epochs, delta = train_fn(placed, device_batch)
-            dispatch_s = _time.perf_counter() - t_run
-            t_fetch = _time.perf_counter()
-            leaves, treedef = jax.tree_util.tree_flatten(params)
-            fetched = fetch_flat(
-                *leaves, loss_hist, jnp.asarray(epochs), jnp.asarray(delta)
-            )
-            # fetch_flat is the single sync point: it absorbs transfer +
-            # program + readback (no extra block_until_ready round-trips)
-            sync_s = _time.perf_counter() - t_fetch
-    n_epochs = int(fetched[-2])
-    losses = [float(x) for x in fetched[-3][:n_epochs]]
-    # call_latency_ms: the DRIVER's device-call window — param placement,
-    # any driver-internal batch placement, dispatch, sync.  Estimator
-    # paths place their batch via the slab pool BEFORE this driver runs;
-    # that cost lands in the slab_pool.build timing and in the fit-level
-    # fit_wall_ms (fit_pool_extra), which is what the warm-fit telemetry
-    # reads end-to-end.
-    metrics.end_step(
-        samples=n_rows * n_epochs, epochs=n_epochs,
-        loss=losses[-1] if losses else 0.0,
-        dispatch_seconds=dispatch_s, sync_seconds=sync_s,
-        place_seconds=place_s,
-        call_latency_ms=(_time.perf_counter() - t_call0) * 1e3,
-    )
-    # the compile/steady split: dispatch absorbs trace+compile (cold
-    # program) or just the enqueue (warm); sync is device execution +
-    # readback.  A run whose program was built since the previous fused
-    # run (the factory runs strictly before this driver) pays the XLA
-    # compile — count it so reports separate compile-bearing fits from
-    # cache-warm ones.
-    obs.observe("train.dispatch", dispatch_s)
-    obs.observe("train.sync", sync_s)
-    if not batch_preplaced:
-        obs.observe("train.place", place_s)
-    # the same split as spans under the fit's trace (FMT_TRACE): post-hoc
-    # records with the measured windows, so a guarded fit's waterfall
-    # shows place -> dispatch -> sync the way a served request shows
-    # place_h2d -> fused_dispatch -> device_sync
-    parents = obs.trace.current()
-    if parents:
-        obs.trace.record_span(parents, "train.sync", sync_s,
-                              {"epochs": n_epochs})
-        obs.trace.record_span(parents, "train.dispatch", dispatch_s,
-                              end_ts=_time.time() - sync_s)
-        if not batch_preplaced:
-            obs.trace.record_span(
-                parents, "train.place", place_s,
-                end_ts=_time.time() - sync_s - dispatch_s,
-            )
-    obs.counter_add("train.fused_runs")
-    obs.counter_add("train.epochs", n_epochs)
-    obs.counter_add("train.rows", n_rows * n_epochs)
-    if _FUSED_PROGRAM_BUILDS > _RUN_BUILDS_SEEN:
-        obs.counter_add("train.compile_runs")
-    _RUN_BUILDS_SEEN = _FUSED_PROGRAM_BUILDS
-    obs.record_hbm_gauges()
-    host_params = jax.tree_util.tree_unflatten(treedef, fetched[: len(leaves)])
-    # numeric-health sentinel on the values just fetched (free: no extra
-    # sync): a diverged fit raises here and the estimator-level guard
-    # rolls back / retries with a backed-off learning rate
-    fault.check_health(
-        losses, fetched[: len(leaves)],
-        float(fetched[-1]) if n_epochs else None,  # 0-epoch delta is inf
-        where="fused_train",
-    )
+        n_epochs = int(fetched[-2])
+        losses = [float(x) for x in fetched[-3][:n_epochs]]
+        # call_latency_ms: the DRIVER's device-call window — param
+        # placement, any driver-internal batch placement, dispatch, sync.
+        # Estimator paths place their batch via the slab pool BEFORE this
+        # driver runs; that cost lands in the slab_pool.build timing and
+        # in the fit-level fit_wall_ms (fit_pool_extra), which is what the
+        # warm-fit telemetry reads end-to-end.  The dispatch/sync/place
+        # split is the spans' (there when obs is on).
+        split = (("dispatch_seconds", dispatch), ("sync_seconds", sync),
+                 ("place_seconds", place))
+        step = metrics.end_step(
+            samples=n_rows * n_epochs, epochs=n_epochs,
+            loss=losses[-1] if losses else 0.0,
+            **{k: s.seconds for k, s in split if s is not None},
+        )
+        step["call_latency_ms"] = step["seconds"] * 1e3
+        obs.counter_add("train.fused_runs")
+        obs.counter_add("train.epochs", n_epochs)
+        obs.counter_add("train.rows", n_rows * n_epochs)
+        # a run whose program was built since the previous fused run (the
+        # factory runs strictly before this driver) pays the XLA compile —
+        # count it so reports separate compile-bearing fits from
+        # cache-warm ones
+        if _FUSED_PROGRAM_BUILDS > _RUN_BUILDS_SEEN:
+            obs.counter_add("train.compile_runs")
+        _RUN_BUILDS_SEEN = _FUSED_PROGRAM_BUILDS
+        host_params = jax.tree_util.tree_unflatten(
+            treedef, fetched[: len(leaves)])
+    with obs.span("train.health"):
+        obs.record_hbm_gauges()
+        # numeric-health sentinel on the values just fetched (free: no
+        # extra sync): a diverged fit raises here and the estimator-level
+        # guard rolls back / retries with a backed-off learning rate
+        fault.check_health(
+            losses, fetched[: len(leaves)],
+            float(fetched[-1]) if n_epochs else None,  # 0-epoch delta is inf
+            where="fused_train",
+        )
     return TrainResult(
         params=host_params,
         epochs=n_epochs,

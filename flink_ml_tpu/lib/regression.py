@@ -9,6 +9,7 @@ new parameters (withBroadcastSet:114) is the replicated params placement.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -53,12 +54,15 @@ def _squared_loss_grads(with_intercept: bool):
 
     def grad_fn(params, x, y, w):
         wts, b = params
-        pred = x @ wts + b
-        err = (pred - y) * w
-        # d/dw of 0.5*sum(w*(pred-y)^2)
-        g_w = x.T @ err
-        g_b = jnp.sum(err) * keep_b
-        loss_sum = 0.5 * jnp.sum(err * (pred - y))
+        with jax.named_scope("fmt.train.scores"):
+            pred = x @ wts + b
+        with jax.named_scope("fmt.train.grad"):
+            err = (pred - y) * w
+            # d/dw of 0.5*sum(w*(pred-y)^2)
+            g_w = x.T @ err
+            g_b = jnp.sum(err) * keep_b
+        with jax.named_scope("fmt.train.scores"):
+            loss_sum = 0.5 * jnp.sum(err * (pred - y))
         return (g_w, g_b), loss_sum, jnp.sum(w)
 
     return grad_fn

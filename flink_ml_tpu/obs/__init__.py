@@ -5,10 +5,13 @@ the only hook (see ``utils/metrics.py``).  This package is the repo's one
 measurement layer:
 
   * :mod:`flink_ml_tpu.obs.registry` — a process-wide registry of counters,
-    gauges, and timing histograms, plus nested ``phase("pack_csr")`` timers
-    that separate host-side packing, compile/dispatch, device step time,
-    and spill I/O.  **Off by default** and near-zero-cost when off: every
-    hook degrades to one module-level boolean check.  Enable with
+    gauges, and timing histograms, plus the program's ONE span API:
+    ``obs.span("train.dispatch")`` times a piece of host code into the
+    registry, writes it as ``fmt.train.dispatch`` on the profiler's clock
+    (``jax.profiler.TraceAnnotation``, imported there and nowhere else)
+    and into the thread's request trace; ``phase("pack_csr")`` is a span
+    whose name nests.  **Off by default** and near-zero-cost when off:
+    every hook degrades to one module-level boolean check.  Enable with
     ``obs.enable()`` or ``FMT_OBS=1``.
   * :mod:`flink_ml_tpu.obs.report` — structured JSONL :class:`RunReport`
     records (git SHA, device topology, registry snapshot, StepMetrics
@@ -17,10 +20,9 @@ measurement layer:
     latest bench reports against ``BASELINE.json`` and flags throughput
     regressions.
 
-``StepMetrics`` (per-step wall/loss/throughput) and ``utils.tracing``
-(jax.profiler hooks) remain the per-run primitives; this package is where
-their outputs — and everything else worth keeping — get aggregated and
-persisted per run instead of dying in stdout.
+``StepMetrics`` (per-step wall/loss/throughput) remains the per-run
+primitive; this package is where its output — and everything else worth
+keeping — gets aggregated and persisted per run instead of dying in stdout.
 
 ISSUE 8 added the per-request layer on top of the aggregates:
 
@@ -70,9 +72,11 @@ from flink_ml_tpu.obs.registry import (
     observe,
     phase,
     phased,
+    profiler_annotation,
     record_hbm_gauges,
     registry,
     reset,
+    span,
 )
 from flink_ml_tpu.obs.report import (
     RunReport,
@@ -101,12 +105,14 @@ __all__ = [
     "observe",
     "phase",
     "phased",
+    "profiler_annotation",
     "record_hbm_gauges",
     "registry",
     "reports_dir",
     "reset",
     "sketch",
     "slo",
+    "span",
     "telemetry",
     "trace",
     "write_run_report",

@@ -1,11 +1,11 @@
-"""Process-wide metrics registry + nested phase timers.
+"""Process-wide metrics registry + the program's one span API.
 
 Counters (monotonic totals: chunks parsed, spill blocks written, epochs
 run), gauges (last-value observations: HBM watermarks, the agreed hot-slab
 decision), and timing histograms (count/total/min/max per named phase).
 
 **Off by default.**  Every hook in a hot path reduces to one module-level
-boolean check when disabled — ``phase()`` returns a shared
+boolean check when disabled — ``span()`` / ``phase()`` return a shared
 ``contextlib.nullcontext`` and the record functions return immediately —
 so instrumented code pays nothing measurable (the bench contract:
 steady-state samples/sec within 2% of the uninstrumented value).  Enable
@@ -16,6 +16,11 @@ Phase timers nest: ``phase("fit")`` around ``phase("pack_csr")`` records
 packing, dispatch/compile, device sync, and spill I/O in one run's
 snapshot.  The stack is thread-local, so the out-of-core prefetch thread's
 phases land under their own root rather than a racing parent's.
+
+:func:`span` is the one way to time a piece of host code: a registry
+timing under its name, a ``fmt.<name>`` scope on the profiler's clock and
+a child span of the thread's request trace, from one pair of clock
+readings.  ``phase`` is a span whose name nests.
 """
 
 from __future__ import annotations
@@ -32,7 +37,7 @@ _ENABLED = knobs.knob_bool("FMT_OBS")
 
 
 def enabled() -> bool:
-    """Is telemetry recording on for this process?"""
+    """True when telemetry recording is on."""
     return _ENABLED
 
 
@@ -182,6 +187,21 @@ class MetricsRegistry:
             stat = self._timings.get(name)
             return stat.recent(k) if stat is not None else []
 
+    def totals(self) -> dict:
+        """Counters, gauges and each timing's ``{"count", "total_s"}``:
+        the monotonic part of :meth:`snapshot`, with no reservoir copied
+        or sorted — what a per-fit delta (``obs.report``) takes inside
+        every fit."""
+        with self._lock:
+            return {
+                "counters": dict(self._counters),
+                "gauges": dict(self._gauges),
+                "timings": {
+                    k: {"count": v.count, "total_s": v.total}
+                    for k, v in self._timings.items()
+                },
+            }
+
     def snapshot(self) -> dict:
         """Plain-dict view of everything recorded (JSON-serializable).
         The lock covers only shallow copies; the per-stat quantile
@@ -250,35 +270,104 @@ def observe(name: str, seconds: float) -> None:
 _PHASE_LOCAL = threading.local()
 _NULL_CTX = contextlib.nullcontext()
 
+_TRACE_ANNOTATION = None
+#: ``obs.trace``'s door while request tracing is on, else None:
+#: ``hook(name)`` gives None where no trace is active on the thread, else
+#: the ``close(seconds, status)`` that records the child span
+_TRACE_HOOK = None
 
-@contextlib.contextmanager
-def _phase_cm(name: str):
-    stack = getattr(_PHASE_LOCAL, "stack", None)
-    if stack is None:
-        stack = _PHASE_LOCAL.stack = []
-    stack.append(name)
-    key = "phase." + "/".join(stack)
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        dt = time.perf_counter() - t0
-        stack.pop()
-        # record even if recording was toggled off mid-phase: the open
-        # timer was paid for, and a lone partial record is harmless
-        _REGISTRY.observe(key, dt)
+
+def profiler_annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)`` — the program's ONE door to
+    the profiler.  With a profiler session running (an operator's, or a
+    benchmark's ``--trace 1``) the scope lands on the trace's clock,
+    beside the device's operations; with none it is a TraceMe that records
+    nothing.  ``jax.profiler`` is imported on first use, here and nowhere
+    else."""
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _TRACE_ANNOTATION = TraceAnnotation
+    return _TRACE_ANNOTATION(name)
+
+
+def set_trace_hook(hook) -> None:
+    """``obs.trace`` registers its child-span recorder here while request
+    tracing is on (None takes it away): the span API stays below it."""
+    global _TRACE_HOOK
+    _TRACE_HOOK = hook
+
+
+class _Span:
+    """One open span (see :func:`span`).  ``seconds`` holds the measured
+    duration once the block has ended."""
+
+    __slots__ = ("name", "seconds", "_nest", "_observe", "_t0",
+                 "_annotation", "_close_child")
+
+    def __init__(self, name: str, nest: bool = False):
+        self.name = name
+        self.seconds = None
+        self._nest = nest
+
+    def __enter__(self):
+        # decided here: a span open while recording is toggled off still
+        # records (the open timer was paid for, a lone record is harmless)
+        self._observe = _ENABLED
+        self._close_child = None
+        if self._nest:
+            stack = getattr(_PHASE_LOCAL, "stack", None)
+            if stack is None:
+                stack = _PHASE_LOCAL.stack = []
+            stack.append(self.name)
+            self.name = "phase." + "/".join(stack)
+        elif _TRACE_HOOK is not None:
+            self._close_child = _TRACE_HOOK(self.name)
+        self._annotation = profiler_annotation("fmt." + self.name)
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.seconds = dt = time.perf_counter() - self._t0
+        self._annotation.__exit__(exc_type, exc, tb)
+        if self._nest:
+            _PHASE_LOCAL.stack.pop()
+        if self._observe:
+            _REGISTRY.observe(self.name, dt)
+        if self._close_child is not None:
+            self._close_child(dt, "ok" if exc_type is None else "error")
+        return False
+
+
+def span(name: str):
+    """THE span of the program: ``with obs.span("train.dispatch"): ...``.
+
+    One pair of clock readings, three records: a timing stat in the
+    registry under ``name``; a ``fmt.<name>`` scope in the profiler's trace
+    (:func:`profiler_annotation`), so host work sits on the device trace's
+    clock; and, where a request trace is active on the thread
+    (``obs.trace.current()``), a child span there with its true start.
+    ``with ... as s`` gives an object whose ``s.seconds`` is the duration
+    after the block.  Returns the shared no-op context (``as`` gives
+    ``None``) when telemetry and request tracing are both off; with
+    tracing alone on, the registry gets nothing."""
+    if not _ENABLED and _TRACE_HOOK is None:
+        return _NULL_CTX
+    return _Span(name)
 
 
 def phase(name: str):
-    """Context manager timing a named (nestable) phase.
-
-    ``with obs.phase("pack_csr"): ...`` records a timing stat under
-    ``phase.pack_csr`` (``phase.outer/pack_csr`` when nested).  Returns a
-    shared no-op context when telemetry is off.
-    """
+    """A :func:`span` whose name nests: ``with obs.phase("pack_csr"): ...``
+    records under ``phase.pack_csr`` (``phase.outer/pack_csr`` inside
+    ``phase("outer")``; the stack is per thread) and is written to the
+    profiler as ``fmt.phase.<path>``.  Not copied into a request trace:
+    the serving paths that carry phases have their own trace spans.
+    Returns the shared no-op context when telemetry is off."""
     if not _ENABLED:
         return _NULL_CTX
-    return _phase_cm(name)
+    return _Span(name, nest=True)
 
 
 def phased(name: str):
@@ -291,7 +380,7 @@ def phased(name: str):
         def wrapper(*args, **kwargs):
             if not _ENABLED:
                 return fn(*args, **kwargs)
-            with _phase_cm(name):
+            with _Span(name, nest=True):
                 return fn(*args, **kwargs)
 
         return wrapper

@@ -47,6 +47,7 @@ from typing import Dict, List, Optional
 from flink_ml_tpu.obs.registry import enabled as _obs_enabled
 from flink_ml_tpu.obs.registry import registry as _obs_registry
 from flink_ml_tpu.obs.registry import reset_generation as _obs_reset_gen
+from flink_ml_tpu.obs.registry import sample_quantile
 from flink_ml_tpu.utils import knobs
 
 _REPO_ROOT = os.path.dirname(
@@ -105,7 +106,10 @@ class RunReport:
     extra: Optional[dict] = None   # per-kind payload (bench record, epochs, ...)
 
     def to_dict(self) -> dict:
-        return dataclasses.asdict(self)
+        # shallow: the one caller serialises it at once, and asdict's deep
+        # copy of every timing's dict ran inside every fit
+        return {f.name: getattr(self, f.name)
+                for f in dataclasses.fields(self)}
 
 
 def reports_dir() -> str:
@@ -140,13 +144,18 @@ def _fit_delta_snapshot() -> dict:
     """Registry snapshot scoped to work since the last fit report.
 
     Counters subtract the previously-attributed totals; timings subtract
-    count/total (mean derived), dropping stats with no new observations.
+    count/total (mean derived), dropping stats with no new observations,
+    and take their tail quantiles over the new observations themselves
+    (the newest ``TimingStat.RESERVOIR`` of them).  It runs inside every
+    fit, so it reads the registry's totals and sorts no reservoir: a key
+    a warm fit observes once costs one sample, not a 512-sample sort.
     An ``obs.reset()`` in between invalidates the previous totals — the
     reset generation detects that even when post-reset totals happen to
     equal pre-reset ones (a shrunken-total guard alone misses equality).
     Gauges are last-value by nature and pass through."""
     global _PREV_FIT_SNAPSHOT, _PREV_FIT_RESET_GEN
-    snap = _obs_registry().snapshot()
+    registry = _obs_registry()
+    snap = registry.totals()
     gen = _obs_reset_gen()
     if gen != _PREV_FIT_RESET_GEN:
         _PREV_FIT_SNAPSHOT = {"counters": {}, "timings": {}}
@@ -171,23 +180,17 @@ def _fit_delta_snapshot() -> dict:
             # the count guard alone; see the r5 line 23 artifact)
             dc, dt = t["count"], t["total_s"]
         if dc > 0:
+            new = sorted(registry.timing_recent(k, dc))
             timings[k] = {
                 "count": dc,
                 "total_s": dt,
                 "mean_s": dt / dc,
-                # tail quantiles over the stat's RECENT reservoir window
-                # (TimingStat.RESERVOIR newest samples) — not delta-exact
-                # like count/total, but the window is dominated by this
-                # fit's own observations, and a p99 is a tail signal, not
-                # an accounting identity
-                "p50_s": t.get("p50_s", 0.0),
-                "p90_s": t.get("p90_s", 0.0),
-                "p99_s": t.get("p99_s", 0.0),
+                "p50_s": sample_quantile(new, 0.50),
+                "p90_s": sample_quantile(new, 0.90),
+                "p99_s": sample_quantile(new, 0.99),
             }
-    _PREV_FIT_SNAPSHOT = {
-        "counters": dict(snap["counters"]),
-        "timings": {k: dict(v) for k, v in snap["timings"].items()},
-    }
+    _PREV_FIT_SNAPSHOT = {"counters": snap["counters"],
+                          "timings": snap["timings"]}
     return {"counters": counters, "gauges": snap["gauges"],
             "timings": timings}
 

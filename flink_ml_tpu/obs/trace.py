@@ -125,6 +125,7 @@ __all__ = [
 ]
 
 
+from flink_ml_tpu.obs.registry import set_trace_hook
 from flink_ml_tpu.utils import knobs
 
 _ENABLED = knobs.knob_bool("FMT_TRACE")
@@ -162,6 +163,8 @@ def enable(on: bool = True, sample: Optional[float] = None) -> None:
     """Turn tracing on/off; optionally set the head-sampling rate."""
     global _ENABLED, _SAMPLE
     _ENABLED = bool(on)
+    # obs.span is live under a request trace too
+    set_trace_hook(_open_child if _ENABLED else None)
     if sample is not None:
         _SAMPLE = float(sample)
 
@@ -653,6 +656,22 @@ def record_span(parents: Sequence[SpanContext], name: str, dur_s: float,
     ts = (end_ts if end_ts is not None else time.time()) - max(dur_s, 0.0)
     _record(tuple(parents), _mint_id(), name, ts, max(dur_s, 0.0),
             attrs, status)
+
+
+def _open_child(name: str):
+    """``obs.span``'s hook, registered while tracing is on: None where no
+    trace is active on the thread, else the ``close(seconds, status)``
+    that records the span as a child, with its true start."""
+    parents = current()
+    if not parents:
+        return None
+    ts = time.time()
+    return lambda dur_s, status: record_span(
+        parents, name, dur_s, status=status, end_ts=ts + dur_s)
+
+
+if _ENABLED:  # FMT_TRACE=1 in the environment
+    set_trace_hook(_open_child)
 
 
 class RequestTrace:

@@ -17,6 +17,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from flink_ml_tpu import obs
 from flink_ml_tpu.fault.injection import maybe_fail
 from flink_ml_tpu.fault.watchdog import with_timeout
 from flink_ml_tpu.utils import knobs
@@ -238,12 +239,13 @@ def _concat_placed_fn(mesh: Mesh, spec: P, n_parts: int):
     return jax.jit(concat, out_shardings=sharding)
 
 
-def _put_chunked(mesh: Mesh, x: np.ndarray, spec: P, chunk_bytes: int):
+def _put_slices(mesh: Mesh, x: np.ndarray, spec: P, chunk_bytes: int):
     """Double-buffered H2D placement of one host array: dim 0 splits into
-    shard-aligned slices, a background thread enqueues each slice's async
-    device_put (the ``_prefetch`` idiom from lib/out_of_core.py — host
-    staging of slice N+1 overlaps the DMA of slice N), and a jitted concat
-    reassembles the placed slices under the final sharding."""
+    shard-aligned slices and a background thread enqueues each slice's
+    async device_put (the ``_prefetch`` idiom from lib/out_of_core.py —
+    host staging of slice N+1 overlaps the DMA of slice N).  Returns the
+    placed slices, for :func:`_concat_placed_fn` to reassemble under the
+    final sharding."""
     from flink_ml_tpu.utils.prefetch import prefetch_iter
 
     sharding = NamedSharding(mesh, spec)
@@ -253,7 +255,7 @@ def _put_chunked(mesh: Mesh, x: np.ndarray, spec: P, chunk_bytes: int):
     rows_per_chunk = max(unit, (chunk_bytes // (row_bytes * unit)) * unit)
     bounds = list(range(0, x.shape[0], rows_per_chunk))
     if len(bounds) < 2:
-        return jax.device_put(x, sharding)
+        return [jax.device_put(x, sharding)]
 
     def pieces():
         for lo in bounds:
@@ -261,8 +263,7 @@ def _put_chunked(mesh: Mesh, x: np.ndarray, spec: P, chunk_bytes: int):
             # the producer thread pipelines staging against the transfer
             yield jax.device_put(x[lo : lo + rows_per_chunk], sharding)
 
-    parts = list(prefetch_iter(pieces(), depth=2, name="h2d-prefetch"))
-    return _concat_placed_fn(mesh, spec, len(parts))(*parts)
+    return list(prefetch_iter(pieces(), depth=2, name="h2d-prefetch"))
 
 
 def shard_batch_prefetched(mesh: Mesh, batch, axis: str = "data",
@@ -288,13 +289,21 @@ def shard_batch_prefetched(mesh: Mesh, batch, axis: str = "data",
         min_bytes = _CHUNKED_MIN_BYTES_DEFAULT
 
     def _put(x):
-        ndim = getattr(x, "ndim", 0)
-        if ndim < 1:
-            return jax.device_put(x, NamedSharding(mesh, P()))
-        x = np.asarray(x)
-        if x.nbytes < max(min_bytes, 2 * chunk_bytes):
-            return jax.device_put(x, NamedSharding(mesh, P(axis)))
-        return _put_chunked(mesh, x, P(axis), chunk_bytes)
+        # the span is the host's side of a leaf's copy: device_put is
+        # asynchronous and nothing waits here, so it ends when the last
+        # slice is ENQUEUED, not when it has arrived
+        with obs.span("place.h2d"):
+            if getattr(x, "ndim", 0) < 1:
+                return jax.device_put(x, NamedSharding(mesh, P()))
+            x = np.asarray(x)
+            if x.nbytes < max(min_bytes, 2 * chunk_bytes):
+                return jax.device_put(x, NamedSharding(mesh, P(axis)))
+            parts = _put_slices(mesh, x, P(axis), chunk_bytes)
+        # outside it: the reassembling program, which a cold compile cache
+        # compiles here
+        if len(parts) == 1:
+            return parts[0]
+        return _concat_placed_fn(mesh, P(axis), len(parts))(*parts)
 
     return jax.tree_util.tree_map(_put, batch)
 

@@ -466,22 +466,19 @@ class SlabPool:
                 self.hits += 1
             obs.counter_add("slab_pool.hits")
             return entry.value
-        import time
-
-        t0 = time.perf_counter()
         # outside the lock: placement is the slow part.  Cold placement is
         # a transient-failure surface (runtime UNAVAILABLE/ABORTED blips,
         # injected chaos) — retried with backoff; single-process
         # only, because a multi-process builder's collectives must dispatch
-        # exactly once per peer agreement round
-        if jax.process_count() == 1:
-            value = with_retry(builder, "slab.build")
-        else:
-            value = builder()
-        # the pack+place cost a warm fit skips — recorded HERE because
+        # exactly once per peer agreement round.  The span is the
+        # pack+place cost a warm fit skips — recorded HERE because
         # estimator paths resolve placement before the fused driver runs
         # (its own train.place covers only driver-internal placement)
-        obs.observe("slab_pool.build", time.perf_counter() - t0)
+        with obs.span("slab_pool.build"):
+            if jax.process_count() == 1:
+                value = with_retry(builder, "slab.build")
+            else:
+                value = builder()
         if nbytes is None:
             nbytes = pytree_nbytes(value)
         with self._lock:
@@ -654,10 +651,13 @@ def get_or_place(table, layout_key, mesh, builder: Callable, cols=None):
     :func:`table_token`)."""
     if not pool_active():
         return builder()
-    token, refs = table_token(table, cols=cols)
-    return pool().get_or_build(
-        ("table", token, mesh, layout_key), builder, refs=refs
-    )
+    # the token pass (the CRC canaries) and the locked lookup, hit or
+    # miss; on a miss it encloses slab_pool.build
+    with obs.span("slab_pool.lookup"):
+        token, refs = table_token(table, cols=cols)
+        return pool().get_or_build(
+            ("table", token, mesh, layout_key), builder, refs=refs
+        )
 
 
 def place_batch(mesh, batch, axis: str = "data"):

@@ -45,3 +45,20 @@ assert jax.device_count() == 8, (
     f"expected 8 virtual CPU devices, got {jax.device_count()} on "
     f"{jax.default_backend()}; backend was initialized before conftest"
 )
+
+
+import sys  # noqa: E402
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _no_warm_store_leaks_into_the_next_file():
+    """A path deploy activates the process-wide warm-artifact store and
+    nothing deactivates it: the next file on the same worker then got its
+    fused dispatches off that store (no compile span, no jitted function),
+    which failed whichever test expected a first compile, by worker order."""
+    yield
+    warmstart = sys.modules.get("flink_ml_tpu.serving.warmstart")
+    if warmstart is not None:
+        warmstart.configure(None)

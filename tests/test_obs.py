@@ -57,8 +57,28 @@ class TestRegistry:
         obs.observe("t.off", 1.0)
         with obs.phase("p.off"):
             pass
+        with obs.span("s.off") as s:
+            pass
+        assert s is None and obs.span("a") is obs.phase("b")
+        # the span's call sites along a fit: pack, place, train, report
+        self._tiny_fit()
         snap = obs.registry().snapshot()
         assert snap == {"counters": {}, "gauges": {}, "timings": {}}
+
+    @staticmethod
+    def _tiny_fit():
+        from flink_ml_tpu.lib import LogisticRegression
+        from flink_ml_tpu.table.schema import DataTypes, Schema
+        from flink_ml_tpu.table.table import Table
+
+        X = np.random.RandomState(0).randn(64, 4).astype(np.float32)
+        table = Table.from_columns(
+            Schema.of(("features", DataTypes.DENSE_VECTOR),
+                      ("label", "double")),
+            {"features": X, "label": (X[:, 0] > 0).astype(np.float64)})
+        (LogisticRegression().set_vector_col("features")
+         .set_label_col("label").set_prediction_col("pred")
+         .set_max_iter(1).fit(table))
 
     def test_phase_nesting_builds_paths(self):
         obs.enable()
@@ -169,10 +189,10 @@ class TestRunReports:
         assert b["metrics"]["counters"]["train.epochs"] == 2
         assert b["metrics"]["timings"]["train.dispatch"] == {
             "count": 1, "total_s": 0.25, "mean_s": 0.25,
-            # tail quantiles ride along (ISSUE 8, p90 since ISSUE 10):
-            # window quantiles over the stat's recent reservoir, not
-            # delta-exact accounting
-            "p50_s": 0.25, "p90_s": 1.0, "p99_s": 1.0,
+            # tail quantiles ride along (ISSUE 8, p90 since ISSUE 10),
+            # over this fit's own observations (PR 24: the report sorts
+            # no whole reservoir inside a fit)
+            "p50_s": 0.25, "p90_s": 0.25, "p99_s": 0.25,
         }
         assert c["metrics"]["counters"] == {}
         assert c["metrics"]["timings"] == {}

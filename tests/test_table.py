@@ -240,13 +240,22 @@ def test_output_cols_reserved_case_insensitive():
 
 
 def test_tracing_helpers():
-    from flink_ml_tpu.utils.tracing import annotate, timed
+    """The span is what ``utils.tracing``'s ``annotate`` and ``timed`` were:
+    a named region in the profiler's timeline and a wall-clock timing."""
+    from flink_ml_tpu import obs
 
-    calls = []
-    with timed("phase", sink=lambda l, s: calls.append((l, s))):
-        with annotate("step"):
-            pass
-    assert calls and calls[0][0] == "phase" and calls[0][1] >= 0
+    assert obs.span("phase") is obs.span("step")  # off: the shared no-op
+    obs.enable()
+    try:
+        with obs.span("phase") as timed:
+            with obs.span("step"):
+                pass
+        stat = obs.registry().timing("phase")
+        assert stat["count"] == 1 and stat["total_s"] == timed.seconds >= 0
+        assert obs.registry().timing("step")["count"] == 1
+    finally:
+        obs.disable()
+        obs.reset()
 
 
 class TestMatrixBackedColumn:

@@ -99,6 +99,18 @@ class TestTraceCore:
         assert trace.current() == ()
         trace.record_span((), "x", 0.1)  # no parents: records nothing
         assert trace.recent_spans() == []
+        # the program's span (obs.span) with obs off is the same kind of
+        # no-op, and with obs on but tracing off it leaves no trace record
+        assert not obs.enabled()
+        assert obs.span("fit.wall") is obs.span("train.sync")
+        obs.enable()
+        try:
+            with obs.span("train.sync"):
+                pass
+        finally:
+            obs.disable()
+            obs.reset()
+        assert trace.recent_spans() == []
 
     def test_enabled_but_no_active_trace_records_nothing(self, traced):
         with trace.span("orphan"):
