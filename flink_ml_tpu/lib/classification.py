@@ -99,6 +99,11 @@ def _log_loss_grads(with_intercept: bool):
             )
         return (g_w, g_b), loss, jnp.sum(w)
 
+    #: what the fused dense fit reads to give this gradient to the one-pass
+    #: kernel (lib/common.py:_onepass_rows); a grad fn without them keeps
+    #: the XLA step
+    grad_fn.glm_kind = "logistic"
+    grad_fn.with_intercept = with_intercept
     return grad_fn
 
 

@@ -456,7 +456,6 @@ def make_glm_epoch_step(
     ``loss`` is the epoch's mean training loss and ``delta`` the L2 norm of
     the epoch's total parameter update (the convergence criterion).
     """
-    check_vma = getattr(grad_fn, "shard_map_check_vma", True)
     key = (grad_fn, mesh, float(learning_rate), float(reg))
     cached = _cache_get(key)
     if cached is not None:
@@ -492,9 +491,7 @@ def make_glm_epoch_step(
         )
         return params, (loss, delta)
 
-    return _cache_put(
-        key, make_data_parallel_step(local_epoch, mesh, check_vma=check_vma)
-    )
+    return _cache_put(key, make_data_parallel_step(local_epoch, mesh))
 
 
 @dataclass
@@ -537,7 +534,7 @@ def _combined_view_memo(stack: MinibatchStack) -> np.ndarray:
 def _build_fused_train_fn(key, mb_grad_step, mesh, learning_rate, reg,
                           max_iter, tol, in_specs=None, out_specs=None,
                           delta_fn=None, epoch_fn=None, check_vma=True,
-                          bundle=False):
+                          bundle=False, whole_batch_step=None):
     """The WHOLE training run as one compiled device program.
 
     Epochs are a ``lax.while_loop`` around the minibatch ``lax.scan``; the
@@ -557,6 +554,11 @@ def _build_fused_train_fn(key, mb_grad_step, mesh, learning_rate, reg,
     are sharded.  Non-SGD algorithms (KMeans' Lloyd step) pass ``epoch_fn
     (params, batch) -> (params, loss, delta)`` instead of ``mb_grad_step`` to
     reuse the identical while_loop/termination/history scaffolding.
+    ``whole_batch_step(params, batch, step) -> (grads, loss_sum, w_sum)``
+    takes ``mb_grad_step``'s place for a step that reads its minibatch out of
+    the whole batch itself (the dense one-pass kernel): the scan then runs
+    over step numbers and slices nothing, since a slice the scan makes is a
+    copy of the minibatch before its first use.
 
     ``bundle`` folds the result packing INTO the training program: the four
     outputs (params pytree, loss history, epochs, delta) ravel and
@@ -588,8 +590,17 @@ def _build_fused_train_fn(key, mb_grad_step, mesh, learning_rate, reg,
     # own scores/loss and gradient (fmt.train.scores, fmt.train.grad)
     @jax.named_scope("fmt.train")
     def local_train(params, batch):
+        if whole_batch_step is None:
+            xs, grad_step = batch, mb_grad_step
+        else:
+            xs = jnp.arange(jax.tree_util.tree_leaves(batch)[0].shape[0],
+                            dtype=jnp.int32)
+
+            def grad_step(p, step):
+                return whole_batch_step(p, batch, step)
+
         def mb_step(p, xs):
-            grads, loss_sum, w_sum = mb_grad_step(p, xs)
+            grads, loss_sum, w_sum = grad_step(p, xs)
             with jax.named_scope("fmt.train.grad"):
                 grads = jax.tree_util.tree_map(
                     lambda g: psum(g, "data"), grads)
@@ -602,7 +613,7 @@ def _build_fused_train_fn(key, mb_grad_step, mesh, learning_rate, reg,
 
         def sgd_epoch(params):
             start = params
-            params, (losses, counts) = jax.lax.scan(mb_step, params, batch)
+            params, (losses, counts) = jax.lax.scan(mb_step, params, xs)
             total = jnp.maximum(jnp.sum(counts), 1.0)
             loss = jnp.sum(losses * counts) / total
             if delta_fn is not None:
@@ -655,8 +666,8 @@ def _build_fused_train_fn(key, mb_grad_step, mesh, learning_rate, reg,
         out_specs=(
             out_specs if out_specs is not None else (P(), P(), P(), P())
         ),
-        # relaxed only for grad fns that declare it (interpret-mode pallas,
-        # see make_pallas_grad_fn) — every other path stays strict
+        # relaxed only for the one-pass kernel on the interpreter (see
+        # make_glm_train_fn) — every other path stays strict
         check_vma=check_vma,
     )
     if not bundle:
@@ -803,6 +814,14 @@ def _run_fused_train(train_fn, init_params, batch, mesh,
         )
         step["call_latency_ms"] = step["seconds"] * 1e3
         obs.counter_add("train.fused_runs")
+        # of those, the fits whose program holds the one-pass kernel (0
+        # keeps the counter there for a reader to find)
+        obs.counter_add("train.onepass_fits",
+                        int(getattr(train_fn, "onepass", False)))
+        if getattr(train_fn, "pallas_interpret", False):
+            # the kernel on the interpreter (the CPU parity harness); a
+            # chip run asserts this is zero
+            obs.counter_add("train.pallas_interpreted")
         obs.counter_add("train.epochs", n_epochs)
         obs.counter_add("train.rows", n_rows * n_epochs)
         # a run whose program was built since the previous fused run (the
@@ -841,23 +860,118 @@ def make_glm_train_fn(
     max_iter: int,
     tol: float,
     bundle: bool = False,
+    onepass_rows: int = 0,
 ):
     """Fused training over the dense combined layout
     (see :func:`_build_fused_train_fn` for the program structure;
     ``bundle`` selects the single-buffer-fetch program variant driven by
     :func:`_run_fused_train` — direct callers that unpack the 4-tuple keep
-    the default)."""
-    check_vma = getattr(grad_fn, "shard_map_check_vma", True)
+    the default).
+
+    ``onepass_rows`` > 0 (what :func:`_onepass_rows` found for the fit's
+    slab) makes the minibatch step ONE Pallas call that reads its slice of
+    the resident slab in place, once (``ops/pallas_kernels.py:glm_grad``,
+    row tile ``onepass_rows``), where the XLA step copies the slice out of
+    the slab and then reads it twice.  The psums, the update and the bundle
+    stay where they are; only the gradient's sums come from the kernel."""
     key = ("train", grad_fn, mesh, float(learning_rate), float(reg),
-           int(max_iter), float(tol), check_vma)
+           int(max_iter), float(tol), int(onepass_rows))
+    if not onepass_rows:
+        def mb_grad_step(p, mb):
+            return grad_fn(p, mb[..., :-2], mb[..., -2], mb[..., -1])
 
-    def mb_grad_step(p, mb):
-        return grad_fn(p, mb[..., :-2], mb[..., -2], mb[..., -1])
+        return _build_fused_train_fn(
+            key, mb_grad_step, mesh, learning_rate, reg, max_iter, tol,
+            bundle=bundle,
+        )
 
-    return _build_fused_train_fn(
-        key, mb_grad_step, mesh, learning_rate, reg, max_iter, tol,
-        check_vma=check_vma, bundle=bundle,
+    from flink_ml_tpu.ops import pallas_kernels
+
+    interpret = pallas_kernels.launch_interpreted()
+    kind = grad_fn.glm_kind
+    keep_b = 1.0 if grad_fn.with_intercept else 0.0
+
+    def onepass_step(p, slab, step):
+        wts, b = p
+        with jax.named_scope("fmt.train.onepass"):
+            g_w, g_b, loss_sum, w_sum = pallas_kernels.glm_grad(
+                slab, step, wts, b, kind=kind, tile_rows=int(onepass_rows),
+                interpret=interpret,
+            )
+        return (g_w.astype(wts.dtype), g_b * keep_b), loss_sum, w_sum
+
+    train_fn = _build_fused_train_fn(
+        key, None, mesh, learning_rate, reg, max_iter, tol,
+        # interpret-mode pallas_call mixes data-varying and unvarying
+        # operands in a dynamic_slice, which strict-vma shard_map rejects
+        # (a JAX-internal limit; the Mosaic lowering passes strict — seen on
+        # 1- and 4-chip v5e meshes), so only the CPU parity harness relaxes
+        check_vma=not interpret, bundle=bundle,
+        whole_batch_step=onepass_step,
     )
+    if bundle:
+        #: read by _run_fused_train, which counts the fits that hold the
+        #: kernel and, apart, those that ran it on the interpreter
+        train_fn.onepass = True
+        train_fn.pallas_interpret = interpret
+    return train_fn
+
+
+_KERNELS_MODULE = "flink_ml_tpu.ops.pallas_kernels"
+
+
+def _onepass_eligible(grad_fn: GradFn, mesh) -> bool:
+    """Could this fit take the one-pass kernel, whatever its slab: a dense
+    GLM (a grad fn that names its ``glm_kind``) on a 1-D mesh of TPUs."""
+    return getattr(grad_fn, "glm_kind", None) is not None \
+        and len(mesh.axis_names) == 1 \
+        and mesh.devices.flat[0].platform == "tpu"
+
+
+def _import_kernels_early(grad_fn: GradFn, mesh) -> None:
+    """Pallas and Mosaic take about a second of host to import, in every
+    process.  A fit that may take the kernel starts that import on a thread
+    BEFORE it places its slab (seconds of host copies that hold no GIL), so
+    that the first fit of a process does not pay it after the placement."""
+    import sys
+
+    if _KERNELS_MODULE in sys.modules or not _onepass_eligible(grad_fn, mesh):
+        return
+    import importlib
+    import threading
+
+    threading.Thread(
+        target=importlib.import_module, args=(_KERNELS_MODULE,),
+        name="fmt-kernels-import", daemon=True,
+    ).start()
+
+
+def _onepass_rows(grad_fn: GradFn, mesh, slab) -> int:
+    """The one-pass kernel's row tile for this fit, or 0 for the XLA step:
+    what the code can observe, no knob.  The kernel takes an eligible fit
+    (:func:`_onepass_eligible`) whose float32 slab lies rows-minor on the
+    device, features next, steps major (``{1,2,0}``; the chip picks a
+    slab's layout from its shape alone, and on any other the kernel's view
+    of it would be a transposing copy of the whole slab), at a width and
+    minibatch for which a row tile fits VMEM
+    (``pallas_kernels.glm_grad_tile``: arithmetic on the shape).  An
+    eligible fit that keeps the XLA step for its slab is counted
+    (``train.onepass_declined``)."""
+    if not _onepass_eligible(grad_fn, mesh):
+        return 0
+    rows = 0
+    layout = getattr(getattr(slab, "format", None), "layout", None)
+    if getattr(slab, "ndim", 0) == 3 and slab.dtype == jnp.float32 \
+            and layout is not None \
+            and tuple(layout.major_to_minor) == (0, 2, 1) \
+            and tuple(layout.tiling or ())[:1] == ((8, 128),):
+        from flink_ml_tpu.ops import pallas_kernels
+
+        rows = pallas_kernels.glm_grad_tile(
+            int(slab.shape[1]), int(slab.shape[2]) - 2)
+    if not rows:
+        obs.counter_add("train.onepass_declined")
+    return rows
 
 
 def _sparse_loss(kind: str, logits, y, w):
@@ -2348,9 +2462,8 @@ def _pressure_window_fn(grad_fn: GradFn, mesh, learning_rate: float,
     same loss bookkeeping), so streaming a run through windows of ANY
     size replays the identical per-step floating-point computation:
     final params match the whole-batch fused run exactly."""
-    check_vma = getattr(grad_fn, "shard_map_check_vma", True)
     key = ("pressure_win", grad_fn, mesh, float(learning_rate),
-           float(reg), int(w), check_vma)
+           float(reg), int(w))
     cached = _cache_get(key)
     if cached is not None:
         return cached
@@ -2376,7 +2489,6 @@ def _pressure_window_fn(grad_fn: GradFn, mesh, learning_rate: float,
         local_window, mesh=mesh,
         in_specs=(P(), P("data")),
         out_specs=(P(), P(), P()),
-        check_vma=check_vma,
     )
     return _cache_put(key, jax.jit(sharded))
 
@@ -2385,8 +2497,7 @@ def _pressure_grad_fn(grad_fn: GradFn, mesh, c: int):
     """psum'd gradient SUMS over one ``c``-row micro-chunk per device (no
     update) — the accumulation half of micro-batch gradient accumulation
     for a single SGD step that exceeds device capacity on its own."""
-    check_vma = getattr(grad_fn, "shard_map_check_vma", True)
-    key = ("pressure_grad", grad_fn, mesh, int(c), check_vma)
+    key = ("pressure_grad", grad_fn, mesh, int(c))
     cached = _cache_get(key)
     if cached is not None:
         return cached
@@ -2405,7 +2516,6 @@ def _pressure_grad_fn(grad_fn: GradFn, mesh, c: int):
         local_grad, mesh=mesh,
         in_specs=(P(), P("data")),
         out_specs=(P(), P(), P()),
-        check_vma=check_vma,
     )
     return _cache_put(key, jax.jit(sharded))
 
@@ -2645,10 +2755,6 @@ def train_glm(
     """
     from flink_ml_tpu.parallel.mesh import replicate, shard_batch
 
-    if getattr(grad_fn, "pallas_interpret", False):
-        # a Pallas grad fn on the interpreter (the CPU parity harness); a
-        # chip run asserts this is zero
-        obs.counter_add("train.pallas_interpreted")
     if not listeners and checkpoint is None:
         from flink_ml_tpu.fault import pressure
         from flink_ml_tpu.parallel.mesh import data_parallel_size
@@ -2668,17 +2774,21 @@ def train_glm(
                 init_params, stack, grad_fn, mesh, learning_rate, reg,
                 max_iter, tol,
             )
-        # dispatch diet (ISSUE 17): the fast path always bundles the
-        # result fetch into the training program
-        train_fn = make_glm_train_fn(
-            grad_fn, mesh, learning_rate, reg, max_iter, tol, bundle=True,
-        )
+        _import_kernels_early(grad_fn, mesh)
         try:
             fault.maybe_oom(row_slots)
             # device_batch may be a thunk (lib/glm.py passes one so no
             # caller frame pins the placed slab): resolve it HERE, inside
             # the pressure scope, so a placement OOM recovers too
             device_batch = _resolve_thunk(device_batch)
+            # dispatch diet (ISSUE 17): the fast path always bundles the
+            # result fetch into the training program.  Built once the slab
+            # is placed: how it lies on the device decides the step
+            train_fn = make_glm_train_fn(
+                grad_fn, mesh, learning_rate, reg, max_iter, tol,
+                bundle=True,
+                onepass_rows=_onepass_rows(grad_fn, mesh, device_batch),
+            )
             return _run_fused_train(
                 train_fn, init_params,
                 device_batch if device_batch is not None

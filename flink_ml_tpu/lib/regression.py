@@ -65,6 +65,11 @@ def _squared_loss_grads(with_intercept: bool):
             loss_sum = 0.5 * jnp.sum(err * (pred - y))
         return (g_w, g_b), loss_sum, jnp.sum(w)
 
+    #: what the fused dense fit reads to give this gradient to the one-pass
+    #: kernel (lib/common.py:_onepass_rows); a grad fn without them keeps
+    #: the XLA step
+    grad_fn.glm_kind = "squared"
+    grad_fn.with_intercept = with_intercept
     return grad_fn
 
 

@@ -1,31 +1,31 @@
 """Pallas TPU kernels for the hot ops XLA fusion leaves on the table.
 
-Kernel inventory.  The rule for every entry: XLA stays the default until
-a chip benchmark shows the kernel winning (ROADMAP S4/S5 decide that on
-the ledger); what is recorded here is only what has been SEEN:
+Kernel inventory.  What is recorded here is only what has been SEEN on the
+chip, and when; a statement about speed is a line of ``PERF_LEDGER.jsonl``:
 
   ==================  ==========================  =========================
   kernel              hot path                    seen on the chip
   ==================  ==========================  =========================
-  :func:`glm_grad`    training minibatch grad     2026-09-26, TPU v5 lite,
-                      (forward matvec + rank-1    jax 0.9.0 / libtpu 0.0.34:
-                      accumulate, one HBM pass)   compiles with Mosaic at
-                                                  (16384, 512) and
-                                                  (32768, 28) f32, matches
-                                                  the XLA grad to 1e-6
-                                                  relative, under strict
-                                                  ``check_vma`` on 1- and
-                                                  4-chip meshes.  Speed
-                                                  against XLA: not measured
-                                                  (``chip_smoke.py`` prints a
-                                                  smoke timing only).  No
-                                                  estimator selects it; it
-                                                  is the opt-in drop-in
-                                                  (make_pallas_grad_fn)
-  :func:`serve_chain` fused serving hot path      same date and stack:
-                      (quarantine NaN/Inf scan    compiles with Mosaic at
-                      + affine scalers + GLM      4096 x 512 and x 28, raw
-                      score in one launch)        and masked, f32 and bf16
+  :func:`glm_grad`    the dense fused fit's       2026-10-01, TPU v5 lite,
+                      minibatch step: scores,     jax 0.9.0: compiles with
+                      loss, error and the         Mosaic at slabs (13,
+                      gradient's accumulate in    32768, 2002), (62, 32768,
+                      ONE pass over the           786) and (13, 32768, 39),
+                      minibatch, read from the    row tiles 512 / 1024 /
+                      resident slab in place      2048; a fit's coefficients
+                      (rows on lanes).  Chosen    within 6e-8 of the XLA
+                      by what the program         step's and of the plain
+                      observes, no knob           reference's, a repeated
+                      (lib/common.py:             fit the same bytes; reads
+                      _onepass_rows)              a minibatch at 739-748
+                                                  GB/s (PERF.md 5); under
+                                                  strict check_vma on 1 and
+                                                  4 chips (chip_smoke.py)
+  :func:`serve_chain` fused serving hot path      2026-09-26, TPU v5 lite,
+                      (quarantine NaN/Inf scan    jax 0.9.0 / libtpu 0.0.34:
+                      + affine scalers + GLM      compiles with Mosaic at
+                      score in one launch)        4096 x 512 and x 28, raw
+                                                  and masked, f32 and bf16
                                                   placement, buckets 1 and
                                                   8 included; scores match
                                                   fused XLA to 1e-5.  Opt-in
@@ -43,21 +43,28 @@ the ledger); what is recorded here is only what has been SEEN:
                                                   ships.
   ==================  ==========================  =========================
 
-:func:`glm_grad` tiles rows, keeps each X tile VMEM-resident for both the
-forward matvec and the gradient rank-1 accumulate, and accumulates ``g_w``
-in VMEM across the sequential grid.  :func:`serve_chain` is embarrassingly
-parallel over row tiles (no cross-tile accumulators): each tile is scanned
-for NaN/Inf, scaled through the affine stages, and scored without leaving
-VMEM — the three serving HBM passes collapse into one.
+:func:`glm_grad` supersedes the row-tiled, features-on-lanes kernel of the
+same name (and its ``make_pallas_grad_fn`` drop-in) that PRs 1–24 carried
+and no estimator selected: that one took a minibatch XLA had already sliced
+out of the slab and padded it into a fresh array, an HBM pass of its own.
+This one takes the slab as the chip lays it (rows minor: a minibatch's rows
+on the lanes, its features, label and weight on the sublanes), so a row tile
+arrives in VMEM by one strided DMA, both products are float32 multiplies on
+the VPU (a matrix-vector product at precision ``highest`` would bind the MXU
+before HBM), and the accumulators stay in VMEM across the sequential grid.
+:func:`serve_chain` is embarrassingly parallel over row tiles (no cross-tile
+accumulators): each tile is scanned for NaN/Inf, scaled through the affine
+stages, and scored without leaving VMEM — the three serving HBM passes
+collapse into one.
 
 Which lowering runs is decided by the device platform, three ways
 (:func:`launch_interpreted`): ``tpu`` compiles with Mosaic or raises;
 ``cpu`` runs ``interpret=True`` (the tier-1 parity harness — same kernel
 body, numerically identical); any other platform raises.  Nothing degrades
-to interpret mode silently: the factories stamp ``pallas_interpret`` on
-what they return, and the drivers count every interpreted dispatch
-(``train.pallas_interpreted`` / ``fused.pallas_interpreted``) so a chip
-run can assert zero.
+to interpret mode silently: what is built on the interpreter says so
+(``pallas_interpret``), and the drivers count every interpreted fit and
+dispatch (``train.pallas_interpreted`` / ``fused.pallas_interpreted``) so a
+chip run can assert zero.
 
 Sparse-grad kernel (measured before PR 1, record removed — XLA retained)
 ------------------------------------------------------------------------
@@ -102,6 +109,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -127,181 +135,240 @@ def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
-def _glm_grad_kernel(kind: str, x_ref, yw_ref, w_ref, b_ref,
-                     gw_ref, stats_ref):
-    """One row tile: forward matvec + loss stats + gradient accumulate.
+#: what one launch may take of v5e's 16 MiB default scoped-VMEM limit
+#: (the rest is headroom for Mosaic's own temporaries)
+_VMEM_BUDGET_BYTES = 12 << 20
 
-    Refs (all VMEM):
-      x_ref     (TM, D)   row tile of features
-      yw_ref    (TM, 2)   [label, sample weight] per row
-      w_ref     (D, 1)    weights (same block every step)
-      b_ref     (1, 1)    intercept
-      gw_ref    (D, 1)    accumulated weight gradient (same block every step)
-      stats_ref (1, 128)  [g_b, loss_sum, w_sum, 0...] accumulators
+_LANES = 128
+_SUBLANES = 8
+#: feature groups (eight sublanes each) a trip of the kernel's two loops
+#: takes: a constant of the kernel, whatever the shape
+_GROUPS_PER_TRIP = 4
+#: a block index, as an int32 spelled out: with x64 on a bare 0 is an int64,
+#: which Mosaic refuses beside the grid's i32
+_I32_ZERO = np.int32(0)
+#: beyond this a longer row tile buys nothing (seen on the chip: 512 to 2048
+#: rows read within 1% of each other) and its first copy, which no compute
+#: hides, grows
+_MAX_TILE_ROWS = 2048
+
+
+def _glm_grad_kernel(kind: str, d: int, tile_rows: int, step_ref, slab_ref,
+                     w_ref, b_ref, gw_ref, stats_ref):
+    """One row tile of one minibatch, ROWS ON LANES: scores, loss, error
+    and the gradient's accumulate from the one tile in VMEM.
+
+    Refs:
+      step_ref  (1,) SMEM    the minibatch's index in the slab (it picked
+                             the block; the body does not read it)
+      slab_ref  (d+2, TM)    features, then label, then weight, on
+                             sublanes; TM rows on lanes
+      w_ref     (D8, 128)    weights, each repeated along the lanes
+      b_ref     (1, 1) SMEM  intercept
+      gw_ref    (D8, 128)    the gradient, still spread over the lanes
+                             (same block every step: the accumulator)
+      stats_ref (8, 128)     rows 0..2: error, loss and weight sums,
+                             spread over the lanes likewise
+
+    Every product is a float32 multiply on the VPU and every sum a float32
+    add in one fixed order, so a repeated call returns the same bytes.  The
+    two loops run over feature groups of eight sublanes; their trip count is
+    a number in the program, not a length of it.
     """
-    # zero the cross-tile accumulators on the first sequential grid step
+    del step_ref
+
     @pl.when(pl.program_id(0) == 0)
     def _():
         gw_ref[...] = jnp.zeros_like(gw_ref)
         stats_ref[...] = jnp.zeros_like(stats_ref)
 
-    x = x_ref[...]
-    y = yw_ref[..., 0:1]
-    w = yw_ref[..., 1:2]
-    logits = jax.lax.dot_general(
-        x, w_ref[...], (((1,), (0,)), ((), ())),
-        precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32,
-    )
-    logits = logits + b_ref[0, 0]
+    chunks = tile_rows // _LANES
+    trip_rows = _SUBLANES * _GROUPS_PER_TRIP
+    trips, left = divmod(d, trip_rows)
+
+    def along_lanes(a):  # (r, 128) -> (r, TM), the same lanes again
+        return jnp.concatenate([a] * chunks, axis=1) if chunks > 1 else a
+
+    def fold_lanes(a):  # (r, TM) -> (r, 128), lane chunk on lane chunk
+        out = a[:, :_LANES]
+        for c in range(1, chunks):
+            out = out + a[:, c * _LANES:(c + 1) * _LANES]
+        return out
+
+    # the rows the loops leave: whole groups, then a part of one
+    rest = [(trips * trip_rows + g * _SUBLANES, _SUBLANES)
+            for g in range(left // _SUBLANES)]
+    if left % _SUBLANES:
+        rest.append((d - left % _SUBLANES, left % _SUBLANES))
+
+    def products(lo, rows):
+        return slab_ref[pl.ds(lo, rows), :] * along_lanes(
+            w_ref[pl.ds(lo, rows), :])
+
+    # a loop carries its own first row as an int32 and steps it: with x64
+    # on, the trip counter fori_loop hands out is an int64, which Mosaic
+    # neither multiplies with its own i32 counter nor converts
+    first_row, step_rows = _I32_ZERO, np.int32(trip_rows)
+
+    def trip_groups(lo):
+        lo = pl.multiple_of(lo, trip_rows)
+        return [pl.multiple_of(lo + np.int32(g * _SUBLANES), _SUBLANES)
+                for g in range(_GROUPS_PER_TRIP)]
+
+    def score_trip(_, carry):
+        lo, acc = carry
+        for row in trip_groups(lo):
+            acc = acc + products(row, _SUBLANES)
+        return lo + step_rows, acc
+
+    acc = jnp.zeros((_SUBLANES, tile_rows), jnp.float32)
+    if trips:
+        _, acc = jax.lax.fori_loop(0, trips, score_trip, (first_row, acc))
+    for lo, rows in rest:
+        if rows == _SUBLANES:
+            acc = acc + products(lo, rows)
+    logits = jnp.sum(acc, axis=0, keepdims=True) + b_ref[0, 0]
+    for lo, rows in rest:
+        if rows < _SUBLANES:
+            logits = logits + jnp.sum(products(lo, rows), axis=0,
+                                      keepdims=True)
+
+    y = slab_ref[d:d + 1, :]
+    sw = slab_ref[d + 1:d + 2, :]
     if kind == "logistic":
-        p = jax.nn.sigmoid(logits)
-        err = (p - y) * w
-        loss = jnp.sum(w * (jnp.logaddexp(0.0, logits) - y * logits))
+        err = (jax.nn.sigmoid(logits) - y) * sw
+        loss = sw * (jnp.logaddexp(0.0, logits) - y * logits)
     else:
-        err = (logits - y) * w
-        loss = 0.5 * jnp.sum(err * (logits - y))
-    # rank-1 accumulate: X tile reused from VMEM — the second HBM pass
-    # the two-matmul formulation would have paid
-    gw_ref[...] += jax.lax.dot_general(
-        x.T, err, (((1,), (0,)), ((), ())),
-        precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32,
-    )
-    # build the [g_b, loss, w_sum, 0...] row with an iota mask (dynamic
-    # scatter does not lower in Pallas TPU kernels)
-    col = jax.lax.broadcasted_iota(jnp.int32, (1, 128), dimension=1)
-    stats = (
-        jnp.where(col == 0, jnp.sum(err), 0.0)
-        + jnp.where(col == 1, loss, 0.0)
-        + jnp.where(col == 2, jnp.sum(w), 0.0)
-    )
-    stats_ref[...] += stats
+        err = (logits - y) * sw
+        loss = 0.5 * err * (logits - y)
+    err_rows = jnp.broadcast_to(err, (_SUBLANES, tile_rows))
+
+    def grad_of(lo, rows):
+        gw_ref[pl.ds(lo, rows), :] += fold_lanes(
+            slab_ref[pl.ds(lo, rows), :] * err_rows[:rows, :])
+
+    def grad_trip(_, lo):
+        for row in trip_groups(lo):
+            grad_of(row, _SUBLANES)
+        return lo + step_rows
+
+    if trips:
+        jax.lax.fori_loop(0, trips, grad_trip, first_row)
+    for lo, rows in rest:
+        grad_of(lo, rows)
+    stats_ref[0:1, :] += fold_lanes(err)
+    stats_ref[1:2, :] += fold_lanes(loss)
+    stats_ref[2:3, :] += fold_lanes(sw)
 
 
-#: what one launch may take of v5e's 16 MiB default scoped-VMEM limit
-#: (the rest is headroom for Mosaic's own temporaries)
-_VMEM_BUDGET_BYTES = 12 << 20
+def glm_grad_tile(rows: int, d: int) -> int:
+    """The kernel's row tile for minibatches of ``rows`` rows and ``d``
+    features, by arithmetic on the shape alone (nothing is compiled or timed
+    to find it): the longest run of whole 128-lane chunks that divides
+    ``rows``, fits :data:`_VMEM_BUDGET_BYTES` and is no longer than
+    :data:`_MAX_TILE_ROWS`.  0 where no tile does: rows that do not fill
+    whole lane chunks, or a width whose shortest tile is over the budget;
+    such a fit keeps the XLA step."""
+    if rows <= 0 or d <= 0 or rows % _LANES:
+        return 0
+    d8 = _round_up(d, _SUBLANES)
+    # the weights and the gradient accumulator, (D8, 128) each, two buffers
+    fixed = 2 * 2 * d8 * _LANES * 4
+    # per row: its column of the slab block in two buffers, and the
+    # kernel's own row vectors (scores, error, loss: eight sublanes each)
+    per_row = (2 * _round_up(d + 2, _SUBLANES) + 8 * _SUBLANES) * 4
+    fit = (_VMEM_BUDGET_BYTES - fixed) // per_row
+    for tile in range(min(rows, _MAX_TILE_ROWS, fit) // _LANES * _LANES, 0,
+                      -_LANES):
+        if rows % tile == 0:
+            return tile
+    return 0
 
 
 @functools.partial(
     jax.jit, static_argnames=("kind", "tile_rows", "interpret")
 )
-def glm_grad(x, y, w, wts, b, kind: str = "logistic",
-             tile_rows: int = 512, interpret: bool = False):
-    """Fused GLM minibatch gradient: one HBM pass over ``x``.
+def glm_grad(slab, step, wts, b, kind: str = "logistic",
+             tile_rows: int = 0, interpret: bool = False):
+    """The dense GLM minibatch gradient in ONE pass over HBM, read from the
+    resident slab where it lies.
 
-    Args: x (n, d), y (n,), w (n,) sample weights, wts (d,), b scalar.
-    Returns (g_w (d,), g_b, loss_sum, w_sum) — identical semantics to the
-    jnp grad fns in lib/regression.py / lib/classification.py.
+    Args: ``slab`` (steps, rows, d+2) float32, the dense combined layout
+    (features, label, sample weight a row); ``step`` the minibatch's index;
+    ``wts`` (d,), ``b`` scalar.  Returns ``(g_w (d,), g_b, loss_sum,
+    w_sum)``, the sums over minibatch ``step`` that the jnp grad fns of
+    lib/regression.py / lib/classification.py return.
+
+    The kernel wants the rows on the lanes.  On the chip a slab of the
+    benchmark's shapes lies so already (device layout ``{1,2,0}``: rows
+    minor, features next, steps major), and the ``swapaxes`` below is a
+    bitcast: no copy of the slab or of a minibatch is made.  Whoever
+    selects the kernel checks that layout first
+    (``lib/common.py:_onepass_rows``); on any other the same line is a
+    transposing copy of the whole slab, correct and slow.
     """
-    n, d = x.shape
-    d_pad = _round_up(max(d, 1), 128)
-    # size the row tile to the VMEM the launch really takes: the (d_pad, 1)
-    # weight and gradient blocks pad to 128 lanes and are double-buffered;
-    # per row, the X block (two buffers), the in-kernel x.T temporary, and
-    # the lane-padded (tm, 2) label/weight block (two buffers)
-    fixed = 2 * 2 * d_pad * 128 * 4
-    per_row = (3 * d_pad + 2 * 128) * 4
-    vmem_rows = (_VMEM_BUDGET_BYTES - fixed) // per_row // 8 * 8
-    if vmem_rows < 8:
+    _, rows, width = slab.shape
+    d = width - 2
+    tile_rows = tile_rows or glm_grad_tile(rows, d)
+    if not tile_rows or rows % tile_rows or tile_rows % _LANES:
         raise ValueError(
-            f"glm_grad: {d} features need {fixed + 8 * per_row} bytes of "
-            f"VMEM for the smallest row tile, over the "
-            f"{_VMEM_BUDGET_BYTES}-byte budget"
+            f"glm_grad: no row tile for minibatches of {rows} rows x {d} "
+            f"features (tile {tile_rows}; whole {_LANES}-lane chunks within "
+            f"{_VMEM_BUDGET_BYTES} bytes of VMEM)"
         )
-    tm = min(tile_rows, _round_up(max(n, 8), 8), vmem_rows)
-    n_pad = _round_up(max(n, 1), tm)
-
-    xp = jnp.zeros((n_pad, d_pad), jnp.float32).at[:n, :d].set(x)
-    yw = jnp.zeros((n_pad, 2), jnp.float32)
-    yw = yw.at[:n, 0].set(y.astype(jnp.float32))
-    yw = yw.at[:n, 1].set(w.astype(jnp.float32))  # pad rows weight 0
-    wp = jnp.zeros((d_pad, 1), jnp.float32).at[:d, 0].set(
-        wts.astype(jnp.float32)
-    )
-    bp = jnp.asarray(b, jnp.float32).reshape(1, 1)
+    d8 = _round_up(d, _SUBLANES)
+    rows_on_lanes = jnp.swapaxes(slab, 1, 2)
+    w_lanes = jnp.broadcast_to(
+        jnp.pad(wts.astype(jnp.float32), (0, d8 - d))[:, None], (d8, _LANES))
+    operands = [
+        jnp.reshape(step, (1,)).astype(jnp.int32), rows_on_lanes, w_lanes,
+        jnp.reshape(b, (1, 1)).astype(jnp.float32),
+    ]
 
     # under shard_map(check_vma=True) outputs must declare how they vary
     # across mesh axes: they vary wherever any input does.  Operands are
-    # promoted to the same vma so in-kernel dots see matching axes.
+    # promoted to the same vma so the kernel sees matching axes.
     vma = frozenset()
-    for operand in (xp, yw, wp, bp):
+    for operand in operands:
         vma = vma | jax.typeof(operand).vma
 
     def _promote(a):
         need = vma - jax.typeof(a).vma
         return jax.lax.pcast(a, tuple(need), to="varying") if need else a
 
-    xp, yw, wp, bp = (_promote(a) for a in (xp, yw, wp, bp))
+    def same_block(i, step):  # weights, intercept, both accumulators
+        return _I32_ZERO, _I32_ZERO
 
-    grid = (n_pad // tm,)
     gw, stats = pl.pallas_call(
-        functools.partial(_glm_grad_kernel, kind),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tm, d_pad), lambda i: (i, 0)),
-            pl.BlockSpec((tm, 2), lambda i: (i, 0)),
-            pl.BlockSpec((d_pad, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((d_pad, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 128), lambda i: (0, 0)),
-        ],
+        functools.partial(_glm_grad_kernel, kind, d, tile_rows),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(rows // tile_rows,),
+            in_specs=[
+                # minibatch `step`, all d+2 sublane rows, row tile i
+                pl.BlockSpec((None, width, tile_rows),
+                             lambda i, step: (step[0], _I32_ZERO, i)),
+                pl.BlockSpec((d8, _LANES), same_block),
+                pl.BlockSpec((1, 1), same_block, memory_space=pltpu.SMEM),
+            ],
+            out_specs=[
+                pl.BlockSpec((d8, _LANES), same_block),
+                pl.BlockSpec((_SUBLANES, _LANES), same_block),
+            ],
+        ),
         out_shape=[
-            jax.ShapeDtypeStruct((d_pad, 1), jnp.float32, vma=vma),
-            jax.ShapeDtypeStruct((1, 128), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((d8, _LANES), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((_SUBLANES, _LANES), jnp.float32, vma=vma),
         ],
-        # the grid axis carries the g_w / stats accumulators: sequential
+        # the grid axis carries the accumulators: sequential
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)
         ),
         interpret=interpret,
-    )(xp, yw, wp, bp)
-    return gw[:d, 0], stats[0, 0], stats[0, 1], stats[0, 2]
-
-
-def make_pallas_grad_fn(kind: str, with_intercept: bool, tile_rows: int = 512):
-    """A drop-in GradFn (lib/common.py contract) backed by :func:`glm_grad`.
-
-    Signature matches the jnp grad factories: (params, x, y, w) ->
-    ((g_w, g_b), loss_sum, w_sum).  On CPU the kernel runs interpreted —
-    numerically identical, just slower — so tests cover one code path.
-
-    Memoized on the hyper-flags AND the lowering (like the jnp grad
-    factories): downstream compiled-step caches key on grad-fn identity,
-    so a fresh closure per call would force a recompile of the whole fused
-    training program every fit.
-    """
-    return _make_pallas_grad_fn(kind, with_intercept, tile_rows,
-                                launch_interpreted())
-
-
-@functools.lru_cache(maxsize=None)
-def _make_pallas_grad_fn(kind: str, with_intercept: bool, tile_rows: int,
-                         interpret: bool):
-    keep_b = 1.0 if with_intercept else 0.0
-
-    def grad_fn(params, x, y, w):
-        wts, b = params
-        g_w, g_b, loss_sum, w_sum = glm_grad(
-            x, y, w, wts, b, kind=kind, tile_rows=tile_rows,
-            interpret=interpret,
-        )
-        return (g_w.astype(wts.dtype), (g_b * keep_b).astype(jnp.float32)), \
-            loss_sum, w_sum
-
-    #: read by train_glm, which counts every interpreted fit
-    grad_fn.pallas_interpret = interpret
-    # interpret-mode pallas_call internally mixes data-varying and unvarying
-    # operands in a dynamic_slice, which strict-vma shard_map rejects
-    # (JAX-internal limit; the Mosaic lowering passes strict — seen on 1-
-    # and 4-chip v5e meshes, PR 21).  Training builders (fused + epoch-step)
-    # read this to relax check_vma ONLY for the interpreted path, so the
-    # CPU suite exercises the kernel through the full harness.
-    grad_fn.shard_map_check_vma = not interpret
-    return grad_fn
+        name="glm_grad",
+    )(*(_promote(a) for a in operands))
+    sums = jnp.sum(stats[:3], axis=1)
+    return jnp.sum(gw[:d], axis=1), sums[0], sums[1], sums[2]
 
 
 # -- fused serving chain ------------------------------------------------------

@@ -53,7 +53,6 @@ def make_data_parallel_step(
     axis: str = "data",
     donate_state: bool = True,
     max_inflight: int = None,
-    check_vma: bool = True,
 ) -> Callable:
     """Lift ``local_step(state, batch) -> (state, aux)`` to the mesh.
 
@@ -70,7 +69,7 @@ def make_data_parallel_step(
     executions queue up on few host cores.  On TPU it defaults to 64, which
     keeps the dispatch pipeline full without unbounded queuing.
     """
-    # check_vma=True makes shard_map verify that outputs declared replicated
+    # strict check_vma makes shard_map verify that outputs declared replicated
     # really are (i.e. the user ran the collective); a local_step that forgets
     # its pmean fails loudly instead of silently returning one shard's value.
     sharded = shard_map(
@@ -79,7 +78,6 @@ def make_data_parallel_step(
         # pytree-prefix specs: state replicated, batch sharded on dim 0
         in_specs=(P(), P(axis)),
         out_specs=(P(), P()),
-        check_vma=check_vma,
     )
     donate = (0,) if donate_state else ()
     fn = jax.jit(sharded, donate_argnums=donate)

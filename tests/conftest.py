@@ -6,8 +6,9 @@ running parallel subtasks in Flink's in-JVM mini-cluster (SURVEY.md §4).
 
 Everything here is plain environment + jax config set BEFORE the first
 backend use.  The suite never touches a TPU: the Pallas tests pass
-``interpret=True``, and the Mosaic lowering is covered on the chip by
-``chip_smoke.py``.
+``interpret=True``; ``test_pallas_aot.py`` compiles with Mosaic for a chip
+that is described, not attached; what only a run can show is covered on the
+chip by ``chip_smoke.py``.
 """
 
 import os

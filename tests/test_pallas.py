@@ -1,200 +1,360 @@
-"""Pallas kernel numerics (interpret mode on the CPU test mesh) and
-integration as a drop-in GradFn in the training harness."""
+"""The dense GLM gradient kernel (``ops/pallas_kernels.py:glm_grad``): its
+numbers on the interpreter against the XLA grad fns, the fused fit that holds
+it against the fused fit that does not, the rule that selects it, and what
+building it costs.  The estimator's own CPU path keeps the XLA step, so the
+kernel and the step are called directly here."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from flink_ml_tpu import obs
+from flink_ml_tpu.lib import common
+from flink_ml_tpu.lib.classification import _log_loss_grads
+from flink_ml_tpu.lib.regression import _squared_loss_grads
 from flink_ml_tpu.ops import pallas_kernels
-from flink_ml_tpu.ops.pallas_kernels import glm_grad, make_pallas_grad_fn
+from flink_ml_tpu.ops.pallas_kernels import glm_grad, glm_grad_tile
+from flink_ml_tpu.parallel.mesh import default_mesh
+
+GRAD_FNS = {"logistic": _log_loss_grads, "squared": _squared_loss_grads}
 
 
-def data(n=300, d=28, seed=0):
+def slab_of(steps=3, rows=256, d=28, seed=0, padded=0):
+    """A dense combined slab (features, label, weight a row) as the pack
+    makes it; the last ``padded`` rows of the last minibatch are padding:
+    zeros at weight 0."""
     rng = np.random.RandomState(seed)
-    x = jnp.asarray(rng.randn(n, d), jnp.float32)
-    y = jnp.asarray((rng.randn(n) > 0), jnp.float32)
-    w = jnp.asarray((rng.rand(n) > 0.1), jnp.float32)  # some zero weights
-    wts = jnp.asarray(rng.randn(d), jnp.float32)
-    b = jnp.asarray(0.3, jnp.float32)
-    return x, y, w, wts, b
+    slab = rng.randn(steps, rows, d + 2).astype(np.float32)
+    slab[..., -2] = slab[..., -2] > 0
+    slab[..., -1] = rng.rand(steps, rows) > 0.1  # some zero weights
+    if padded:
+        slab[-1, -padded:, :] = 0.0
+    wts = (rng.randn(d) / np.sqrt(d)).astype(np.float32)
+    return jnp.asarray(slab), jnp.asarray(wts), jnp.float32(0.3)
+
+
+def xla_sums(kind, slab, step, wts, b):
+    mb = slab[step]
+    (g_w, g_b), loss, w_sum = GRAD_FNS[kind](True)(
+        (wts, b), mb[:, :-2], mb[:, -2], mb[:, -1])
+    return g_w, g_b, loss, w_sum
 
 
 class TestGlmGradKernel:
     @pytest.mark.parametrize("kind", ["logistic", "squared"])
-    def test_matches_jnp_reference(self, kind):
-        x, y, w, wts, b = data()
-        gw, gb, loss, wsum = glm_grad(x, y, w, wts, b, kind=kind, interpret=True)
-        logits = x @ wts + b
-        if kind == "logistic":
-            err = (jax.nn.sigmoid(logits) - y) * w
-            ref_loss = jnp.sum(w * (jnp.logaddexp(0.0, logits) - y * logits))
-        else:
-            err = (logits - y) * w
-            ref_loss = 0.5 * jnp.sum(err * (logits - y))
-        np.testing.assert_allclose(gw, x.T @ err, rtol=2e-4, atol=2e-4)
-        np.testing.assert_allclose(gb, err.sum(), rtol=1e-4, atol=1e-4)
-        np.testing.assert_allclose(loss, ref_loss, rtol=1e-4)
-        np.testing.assert_allclose(wsum, w.sum(), rtol=1e-6)
+    @pytest.mark.parametrize("d", [2000, 784, 28, 37])
+    def test_matches_the_xla_grad_fn(self, kind, d):
+        """The cells' widths, a narrow one, and one that fills neither
+        sublane groups nor lane chunks."""
+        slab, wts, b = slab_of(rows=128 if d > 100 else 256, d=d)
+        for step in (0, 2):
+            gw, gb, loss, wsum = glm_grad(slab, jnp.int32(step), wts, b,
+                                          kind=kind, interpret=True)
+            rgw, rgb, rloss, rwsum = xla_sums(kind, slab, step, wts, b)
+            scale = float(jnp.max(jnp.abs(rgw)))
+            assert float(jnp.max(jnp.abs(gw - rgw))) <= 2e-6 * scale
+            np.testing.assert_allclose(gb, rgb, rtol=1e-5, atol=2e-5)
+            np.testing.assert_allclose(loss, rloss, rtol=1e-5)
+            assert float(wsum) == float(rwsum)
 
-    def test_row_padding_is_neutral(self):
-        """n not a multiple of the tile: padded rows must contribute nothing."""
-        x, y, w, wts, b = data(n=130)
-        gw_a, *_ = glm_grad(x, y, w, wts, b, interpret=True, tile_rows=64)
-        gw_b, *_ = glm_grad(x, y, w, wts, b, interpret=True, tile_rows=512)
-        np.testing.assert_allclose(gw_a, gw_b, rtol=1e-5, atol=1e-5)
+    def test_padded_rows_of_the_last_minibatch_are_neutral(self):
+        slab, wts, b = slab_of(padded=100)
+        got = glm_grad(slab, jnp.int32(2), wts, b, interpret=True)
+        # the same minibatch with its padding filled with rows at weight 0
+        filled = slab.at[2, -100:, :-1].set(7.0)
+        again = glm_grad(filled, jnp.int32(2), wts, b, interpret=True)
+        for a, c in zip(got, again):
+            np.testing.assert_array_equal(a, c)
+        assert float(got[3]) == float(jnp.sum(slab[2, :, -1]))
 
-    def test_wide_d_tile_shrinks_to_vmem_budget(self):
-        x, y, w, wts, b = data(n=64, d=3000)
-        gw, *_ = glm_grad(x, y, w, wts, b, interpret=True)
-        logits = x @ wts + b
-        err = (jax.nn.sigmoid(logits) - y) * w
-        np.testing.assert_allclose(gw, x.T @ err, rtol=2e-3, atol=2e-3)
+    def test_two_calls_return_the_same_bytes(self):
+        slab, wts, b = slab_of(d=37)
+        one = glm_grad(slab, jnp.int32(1), wts, b, interpret=True)
+        two = glm_grad(slab, jnp.int32(1), wts, b, interpret=True)
+        for a, c in zip(one, two):
+            assert np.asarray(a).tobytes() == np.asarray(c).tobytes()
+
+    def test_row_tiles_agree(self):
+        """Several row tiles a minibatch against one: the accumulators
+        carry across the grid."""
+        slab, wts, b = slab_of(rows=512)
+        a = glm_grad(slab, jnp.int32(0), wts, b, tile_rows=128,
+                     interpret=True)
+        c = glm_grad(slab, jnp.int32(0), wts, b, tile_rows=512,
+                     interpret=True)
+        np.testing.assert_allclose(a[0], c[0], rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(a[2], c[2], rtol=1e-6)
+
+    def test_no_tile_is_a_clear_error(self):
+        # rows that do not fill lane chunks; a width over the VMEM budget
+        with pytest.raises(ValueError, match="no row tile"):
+            slab, wts, b = slab_of(rows=100)
+            glm_grad(slab, jnp.int32(0), wts, b, interpret=True)
+        wide = jnp.zeros((1, 128, 8194), jnp.float32)
+        with pytest.raises(ValueError, match="VMEM"):
+            glm_grad(wide, jnp.int32(0), jnp.zeros((8192,), jnp.float32),
+                     jnp.float32(0.0), interpret=True)
 
 
-class TestPallasGradFnIntegration:
-    def test_grad_fn_contract(self):
-        """make_pallas_grad_fn satisfies the GradFn contract numerically."""
-        x, y, w, wts, b = data()
-        grad_fn = make_pallas_grad_fn("logistic", with_intercept=True)
-        (g_w, g_b), loss, wsum = grad_fn((wts, b), x, y, w)
-        logits = x @ wts + b
-        err = (jax.nn.sigmoid(logits) - y) * w
-        np.testing.assert_allclose(g_w, x.T @ err, rtol=2e-4, atol=2e-4)
-        np.testing.assert_allclose(g_b, err.sum(), rtol=1e-4, atol=1e-4)
+class TestRowTileArithmetic:
+    @pytest.mark.parametrize("rows,d,tile", [
+        (32768, 2000, 512),    # epsilon_lr
+        (32768, 784, 1024),    # mnist8m_lr
+        (32768, 28, 2048),     # narrow: the cap
+        (4096, 2000, 512),
+        (1280, 784, 1280),     # the longest divisor, not a power of two
+        (32768, 4000, 128),    # the shortest tile still fits
+        (32768, 4096, 0),      # too wide for it: the XLA step
+        (1000, 28, 0),         # rows that do not fill lane chunks
+        (0, 28, 0),
+    ])
+    def test_tile_by_shape(self, rows, d, tile):
+        assert glm_grad_tile(rows, d) == tile
+        if tile:
+            assert rows % tile == 0 and tile % 128 == 0
 
-        no_b = make_pallas_grad_fn("logistic", with_intercept=False)
-        (_, g_b0), *_ = no_b((wts, b), x, y, w)
-        assert float(g_b0) == 0.0
 
-    def test_trains_through_harness(self):
-        """make_pallas_grad_fn drops into train_glm and converges — runs in
-        the CPU CI suite via interpret mode (the grad fn declares
-        shard_map_check_vma=False there; strict vma with Mosaic, which
-        chip_smoke.py covers)."""
-        from flink_ml_tpu.lib.common import pack_minibatches, train_glm
-        from flink_ml_tpu.parallel.mesh import default_mesh
+class _Device:
+    def __init__(self, platform):
+        self.platform = platform
+
+
+class _Mesh:
+    def __init__(self, platform="tpu", axis_names=("data",)):
+        self.axis_names = axis_names
+        self.devices = np.array([_Device(platform)], dtype=object)
+
+
+class _Layout:
+    def __init__(self, major_to_minor, tiling=((8, 128),)):
+        self.major_to_minor, self.tiling = major_to_minor, tiling
+
+
+class _Slab:
+    """What the rule reads of a placed slab."""
+
+    def __init__(self, shape=(13, 32768, 2002), dtype=jnp.float32,
+                 major_to_minor=(0, 2, 1), tiling=((8, 128),)):
+        self.shape, self.ndim, self.dtype = shape, len(shape), dtype
+        self.format = type("Format", (), {})()
+        self.format.layout = (None if major_to_minor is None
+                              else _Layout(major_to_minor, tiling))
+
+
+@pytest.fixture
+def counters():
+    obs.enable()
+    obs.reset()
+    try:
+        yield lambda: obs.registry().snapshot()["counters"]
+    finally:
+        obs.disable()
+        obs.reset()
+
+
+class TestSelectionRule:
+    """What takes the kernel, what keeps the XLA step, and which of those
+    are counted: all from what the code observes."""
+
+    GRAD = _log_loss_grads(True)
+
+    @pytest.mark.parametrize("shape,rows", [
+        ((13, 32768, 2002), 512), ((62, 32768, 786), 1024),
+        ((7, 4096, 30), 2048)])
+    def test_a_rows_minor_slab_on_a_tpu_takes_the_kernel(
+            self, counters, shape, rows):
+        assert common._onepass_rows(self.GRAD, _Mesh(), _Slab(shape)) == rows
+        assert "train.onepass_declined" not in counters()
+
+    @pytest.mark.parametrize("mesh,grad_fn", [
+        (_Mesh("cpu"), GRAD),
+        (_Mesh("tpu", ("data", "model")), GRAD),
+        (_Mesh(), lambda p, x, y, w: None),  # no glm_kind: not a dense GLM
+    ], ids=["cpu", "2-D mesh", "foreign grad fn"])
+    def test_what_is_not_eligible_is_not_counted(self, counters, mesh,
+                                                 grad_fn):
+        assert common._onepass_rows(grad_fn, mesh, _Slab()) == 0
+        assert "train.onepass_declined" not in counters()
+
+    @pytest.mark.parametrize("slab", [
+        _Slab(major_to_minor=(0, 1, 2)),           # features minor
+        _Slab((8, 32768, 2002), major_to_minor=(2, 0, 1)),  # steps next
+        _Slab(major_to_minor=None),                # layout not reported
+        _Slab(tiling=((4, 128),)),
+        _Slab(dtype=jnp.float64),
+        _Slab((13, 32768, 4098)),                  # no tile fits VMEM
+        _Slab((13, 1000, 2002)),                   # rows off the lanes
+        np.zeros((3, 128, 6), np.float32),         # a host array: no layout
+    ], ids=["features-minor", "steps-on-sublanes", "no-layout", "tiling",
+            "float64", "too-wide", "ragged-rows", "host-array"])
+    def test_an_eligible_fit_that_keeps_xla_is_counted(self, counters, slab):
+        assert common._onepass_rows(self.GRAD, _Mesh(), slab) == 0
+        assert counters()["train.onepass_declined"] == 1
+
+    def test_the_kernels_module_is_imported_early_only_where_it_may_run(
+            self, monkeypatch):
+        """About a second of host, started on a thread before the slab's
+        placement: only for an eligible fit, only once a process."""
+        import sys
+        import threading
+
+        started = []
+        monkeypatch.setattr(threading.Thread, "start",
+                            lambda self: started.append(self.name))
+        # as in a process that has not used a kernel yet
+        monkeypatch.delitem(sys.modules, common._KERNELS_MODULE)
+        common._import_kernels_early(self.GRAD, _Mesh("cpu"))
+        common._import_kernels_early(lambda p, x, y, w: None, _Mesh())
+        assert started == []
+        common._import_kernels_early(self.GRAD, _Mesh())
+        assert started == ["fmt-kernels-import"]
+        monkeypatch.setitem(sys.modules, common._KERNELS_MODULE,
+                            pallas_kernels)
+        common._import_kernels_early(self.GRAD, _Mesh())
+        assert len(started) == 1
+
+    def test_the_estimator_on_the_cpu_keeps_the_xla_step(self, counters):
+        from flink_ml_tpu.lib import LogisticRegression
+        from flink_ml_tpu.table.schema import DataTypes, Schema
+        from flink_ml_tpu.table.table import Table
 
         rng = np.random.RandomState(1)
-        X = rng.randn(160, 4)
-        true_w = np.array([1.0, -2.0, 0.5, 0.0])
-        y = ((X @ true_w) > 0).astype(np.float64)
+        X = rng.randn(1024, 6).astype(np.float32)
+        y = (X @ rng.randn(6) > 0).astype(np.float64)
+        table = Table.from_columns(
+            Schema.of(("features", DataTypes.DENSE_VECTOR),
+                      ("label", "double")), {"features": X, "label": y})
+        (LogisticRegression().set_vector_col("features")
+         .set_label_col("label").set_prediction_col("pred")
+         .set_global_batch_size(1024).set_max_iter(3).fit(table))
+        c = counters()
+        assert c["train.fused_runs"] == 1
+        assert c["train.onepass_fits"] == 0  # there, for a reader to find
+        assert "train.pallas_interpreted" not in c
+        assert "train.onepass_declined" not in c
+
+
+def fused_fit(grad_fn, onepass_rows, slab_host, n_rows, d, max_iter=5):
+    mesh = default_mesh()
+    fn = common.make_glm_train_fn(grad_fn, mesh, 0.2, 0.01, max_iter, 0.0,
+                                  bundle=True, onepass_rows=onepass_rows)
+    p0 = (jnp.zeros((d,), jnp.float32), jnp.zeros((), jnp.float32))
+    return common._run_fused_train(fn, p0, slab_host, mesh, n_rows=n_rows)
+
+
+class TestTheStepInsideTheFusedFit:
+    """``make_glm_train_fn(onepass_rows=...)``: the scan over step numbers,
+    the kernel a step, the psums, update and bundle where they were."""
+
+    @staticmethod
+    def stack(d=37, seed=2):
+        rng = np.random.RandomState(seed)
+        n_dev = jax.device_count()
+        n = 128 * n_dev * 3 - 40  # the last minibatches end in padding
+        X = rng.randn(n, d).astype(np.float32)
+        y = (X @ rng.randn(d) > 0).astype(np.float64)
+        stack = common.pack_minibatches(X, y, n_dev, 128 * n_dev)
+        return common._combined_view_memo(stack), n
+
+    @pytest.mark.parametrize("kind", ["logistic", "squared"])
+    @pytest.mark.parametrize("with_intercept", [True, False])
+    def test_matches_the_xla_fit(self, kind, with_intercept):
+        slab, n = self.stack()
+        grad_fn = GRAD_FNS[kind](with_intercept)
+        xla = fused_fit(grad_fn, 0, slab, n, 37)
+        one = fused_fit(grad_fn, 128, slab, n, 37)
+        np.testing.assert_allclose(one.params[0], xla.params[0],
+                                   rtol=0, atol=2e-6)
+        np.testing.assert_allclose(one.params[1], xla.params[1],
+                                   rtol=0, atol=2e-6)
+        np.testing.assert_allclose(one.losses, xla.losses, rtol=2e-6)
+        assert one.epochs == xla.epochs == 5
+        if not with_intercept:
+            assert float(one.params[1]) == 0.0
+
+    def test_a_repeated_fit_returns_the_same_bytes(self):
+        slab, n = self.stack(d=28)
+        a = fused_fit(_log_loss_grads(True), 128, slab, n, 28)
+        b = fused_fit(_log_loss_grads(True), 128, slab, n, 28)
+        assert a.params[0].tobytes() == b.params[0].tobytes()
+        assert a.losses == b.losses
+
+    def test_the_fit_is_counted_and_so_is_the_interpreter(self, counters):
+        slab, n = self.stack(d=28)
+        fused_fit(_log_loss_grads(True), 128, slab, n, 28, max_iter=2)
+        fused_fit(_log_loss_grads(True), 0, slab, n, 28, max_iter=2)
+        c = counters()
+        assert c["train.fused_runs"] == 2
+        assert c["train.onepass_fits"] == 1
+        assert c["train.pallas_interpreted"] == 1
+
+    def test_the_program_keeps_its_name(self):
+        """``jit_bundled``: three readers of the benchmark match on it."""
         mesh = default_mesh()
-        stack = pack_minibatches(X, y, jax.device_count())
-        grad_fn = make_pallas_grad_fn("logistic", with_intercept=True)
-        result = train_glm(
-            (jnp.zeros((4,), jnp.float32), jnp.zeros((), jnp.float32)),
-            stack, grad_fn, mesh, learning_rate=0.5, max_iter=60,
-        )
-        w, b = result.params
-        preds = (X @ w + b) > 0
-        assert np.mean(preds == y) > 0.9
+        slab, _n = self.stack(d=28)
+        fn = common.make_glm_train_fn(_log_loss_grads(True), mesh, 0.3, 0.0,
+                                      2, 0.0, bundle=True, onepass_rows=128)
+        p0 = (jnp.zeros((28,), jnp.float32), jnp.zeros((), jnp.float32))
+        jitted = fn.__closure__[0].cell_contents
+        assert "jit_bundled" in jitted.lower(
+            p0, jnp.asarray(slab)).as_text()[:200]
 
-    def test_trains_through_listener_path(self):
-        """The listener/checkpoint epoch path (make_glm_epoch_step ->
-        make_data_parallel_step) must also honor the grad fn's vma
-        declaration (r4 review finding)."""
-        from flink_ml_tpu.iteration.listener import IterationListener
-        from flink_ml_tpu.lib.common import pack_minibatches, train_glm
-        from flink_ml_tpu.parallel.mesh import default_mesh
 
-        class Counter(IterationListener):
-            epochs = 0
+class TestBuildCost:
+    """The set-up budget as a test: what is built for a fit is the same size
+    whatever the steps an epoch and the rows a minibatch, so tracing,
+    lowering, the executable and its cache entry are too."""
 
-            def on_epoch_watermark_incremented(self, epoch, context):
-                self.epochs += 1
+    @staticmethod
+    def lowered_text(steps, rows, d):
+        def epoch(slab, wts, b):
+            def step(params, i):
+                gw, gb, loss, _w = glm_grad(slab, i, *params)
+                return (params[0] - gw, params[1] - gb), loss
 
-        rng = np.random.RandomState(3)
-        X = rng.randn(128, 4)
-        y = ((X @ np.array([1.0, -2.0, 0.5, 0.0])) > 0).astype(np.float64)
-        listener = Counter()
-        result = train_glm(
-            (jnp.zeros((4,), jnp.float32), jnp.zeros((), jnp.float32)),
-            pack_minibatches(X, y, jax.device_count()),
-            make_pallas_grad_fn("logistic", with_intercept=True),
-            default_mesh(), learning_rate=0.5, max_iter=15,
-            listeners=[listener],
-        )
-        assert listener.epochs == result.epochs == 15
-        w, b = result.params
-        assert np.mean(((X @ w + b) > 0) == y) > 0.9
+            return jax.lax.scan(step, (wts, b),
+                                jnp.arange(steps, dtype=jnp.int32))
 
-    def test_matches_jnp_grad_fn_through_harness(self):
-        """The pallas-backed fused fit matches the jnp grad fn's fit."""
-        from flink_ml_tpu.lib.classification import _log_loss_grads
-        from flink_ml_tpu.lib.common import pack_minibatches, train_glm
-        from flink_ml_tpu.parallel.mesh import default_mesh
+        args = (jax.ShapeDtypeStruct((steps, rows, d + 2), jnp.float32),
+                jax.ShapeDtypeStruct((d,), jnp.float32),
+                jax.ShapeDtypeStruct((), jnp.float32))
+        # lowered for the chip (Mosaic), with no chip and no TPU client
+        return jax.jit(epoch).trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
 
-        rng = np.random.RandomState(2)
-        X = rng.randn(128, 6)
-        y = ((X @ rng.randn(6)) > 0).astype(np.float64)
-        mesh = default_mesh()
-        stack = pack_minibatches(X, y, jax.device_count(), global_batch_size=32)
-        p0 = (jnp.zeros((6,), jnp.float32), jnp.zeros((), jnp.float32))
-        rp = train_glm((jnp.copy(p0[0]), jnp.copy(p0[1])), stack,
-                       make_pallas_grad_fn("logistic", with_intercept=True),
-                       mesh, learning_rate=0.5, max_iter=10)
-        rj = train_glm((jnp.copy(p0[0]), jnp.copy(p0[1])), stack,
-                       _log_loss_grads(True), mesh,
-                       learning_rate=0.5, max_iter=10)
-        np.testing.assert_allclose(rp.params[0], rj.params[0],
-                                   rtol=5e-4, atol=5e-5)
-        np.testing.assert_allclose(rp.params[1], rj.params[1],
-                                   rtol=5e-4, atol=5e-5)
+    @pytest.mark.parametrize("d", [2000, 784])
+    def test_the_lowered_program_does_not_grow_with_steps_or_rows(self, d):
+        base = self.lowered_text(13, 32768, d)
+        assert "tpu_custom_call" in base
+        assert len(self.lowered_text(62, 32768, d)) <= 1.03 * len(base)
+        assert len(base) <= 1.03 * len(self.lowered_text(13, 4096, d))
 
 
 class TestLoweringIsChosenByPlatform:
     """tpu compiles with Mosaic or raises, cpu interprets, anything else
     raises — nothing selects the interpreter silently."""
 
-    class _Device:
-        def __init__(self, platform):
-            self.platform = platform
-
     def test_cpu_interprets_and_says_so(self):
         assert pallas_kernels.launch_interpreted() is True
-        grad_fn = make_pallas_grad_fn("logistic", with_intercept=True)
-        assert grad_fn.pallas_interpret is True
-        assert grad_fn.shard_map_check_vma is False
+        fn = common.make_glm_train_fn(_log_loss_grads(True), default_mesh(),
+                                      0.4, 0.0, 2, 0.0, bundle=True,
+                                      onepass_rows=128)
+        assert fn.onepass is True
+        assert fn.pallas_interpret is True
 
     def test_tpu_compiles_with_mosaic(self, monkeypatch):
-        monkeypatch.setattr(jax, "devices",
-                            lambda *a: [self._Device("tpu")])
+        monkeypatch.setattr(jax, "devices", lambda *a: [_Device("tpu")])
         assert pallas_kernels.launch_interpreted() is False
 
     def test_unknown_platform_raises(self, monkeypatch):
         monkeypatch.setattr(jax, "devices",
-                            lambda *a: [self._Device("some_plugin")])
+                            lambda *a: [_Device("some_plugin")])
         with pytest.raises(RuntimeError, match="some_plugin"):
             pallas_kernels.launch_interpreted()
         with pytest.raises(RuntimeError, match="some_plugin"):
-            make_pallas_grad_fn("logistic", with_intercept=True)
+            common.make_glm_train_fn(_log_loss_grads(True), default_mesh(),
+                                     0.5, 0.0, 2, 0.0, bundle=True,
+                                     onepass_rows=128)
         with pytest.raises(RuntimeError, match="some_plugin"):
             pallas_kernels.serve_chain(["glm_score"], [True], 4)
-
-    def test_interpreted_fit_is_counted(self):
-        from flink_ml_tpu import obs
-        from flink_ml_tpu.lib.common import pack_minibatches, train_glm
-        from flink_ml_tpu.parallel.mesh import default_mesh
-
-        x, y, *_ = data(n=64, d=4)
-        stack = pack_minibatches(np.asarray(x), np.asarray(y, np.float64),
-                                 jax.device_count())
-        obs.enable()
-        obs.reset()
-        try:
-            train_glm(
-                (jnp.zeros((4,), jnp.float32), jnp.zeros((), jnp.float32)),
-                stack, make_pallas_grad_fn("logistic", with_intercept=True),
-                default_mesh(), learning_rate=0.5, max_iter=2,
-            )
-            c = obs.registry().snapshot()["counters"]
-            assert c.get("train.pallas_interpreted", 0) == 1, c
-        finally:
-            obs.disable()
-            obs.reset()
-
-    def test_too_wide_for_vmem_is_a_clear_error(self):
-        # the (d_pad, 1) weight/gradient blocks alone overflow the budget
-        with pytest.raises(ValueError, match="VMEM"):
-            glm_grad(*data(n=8, d=8192), interpret=True)

@@ -1,0 +1,123 @@
+"""The one-pass GLM kernel compiled for a TPU v5e that is described, not
+attached: what the chip's compiler (Mosaic, XLA:TPU) accepts, which layout it
+gives the slab, and that the kernel's view of the slab copies nothing.  No
+chip time, about two seconds a compile.  Nothing runs, so nothing here is a
+time or a result; the chip runs are ``chip_smoke.py`` and the benchmark.
+
+The topology is described inside a fixture, never while a module is imported
+(one process at a time may load libtpu: under several test workers only the
+one that is given this file does), and the file skips where it cannot be.
+"""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from flink_ml_tpu.lib import common
+from flink_ml_tpu.lib.classification import _log_loss_grads
+from flink_ml_tpu.ops import pallas_kernels
+
+ROWS = 32768
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps libtpu away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def quiet_cache():
+    """A compile for a described chip is written to the persistent cache and
+    cannot be read back without a chip: keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def compiled_fit(topo, n_dev, steps, rows, d, onepass, monkeypatch):
+    """The fused fit's program (unbundled: the bundle is float64 under the
+    suite's x64) for ``n_dev`` described chips, with or without the kernel."""
+    # the program asks which lowering the launch takes; here it is Mosaic
+    monkeypatch.setattr(pallas_kernels, "launch_interpreted", lambda: False)
+    mesh = Mesh(np.array(topo.devices[:n_dev]), ("data",))
+    fn = common.make_glm_train_fn(
+        _log_loss_grads(True), mesh, 0.1, 0.0, 10, 0.0,
+        onepass_rows=pallas_kernels.glm_grad_tile(rows, d) if onepass else 0)
+    replicated = NamedSharding(mesh, P())
+    args = ((jax.ShapeDtypeStruct((d,), jnp.float32, sharding=replicated),
+             jax.ShapeDtypeStruct((), jnp.float32, sharding=replicated)),
+            jax.ShapeDtypeStruct((n_dev * steps, rows, d + 2), jnp.float32,
+                                 sharding=NamedSharding(mesh, P("data"))))
+    return fn.lower(*args).compile()
+
+
+def slab_layout(text):
+    return re.search(r"entry_computation_layout=\{\(.*?f32\[\d+,\d+,\d+\]"
+                     r"(\{[^}]*\})", text).group(1)
+
+
+@pytest.mark.parametrize("steps,d", [(13, 2000), (62, 784), (7, 28), (7, 37)],
+                         ids=["epsilon", "mnist8m", "narrow", "odd-width"])
+def test_the_kernel_compiles_and_reads_the_slab_in_place(
+        topo, quiet_cache, monkeypatch, steps, d):
+    compiled = compiled_fit(topo, 1, steps, ROWS, d, True, monkeypatch)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    # rows minor, features next, steps major: the kernel's view is a bitcast
+    assert slab_layout(text) == "{1,2,0:T(8,128)}"
+    minibatch = ROWS * (d + 2) * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < minibatch // 8
+    assert "dynamic-slice_bitcast_fusion" not in text
+
+
+def test_the_xla_step_it_replaces_copies_a_minibatch(topo, quiet_cache,
+                                                     monkeypatch):
+    """The parent's program at epsilon's shape, for the comparison: the
+    scan's slice is a copy of the minibatch (PERF.md §5, bottleneck 1)."""
+    compiled = compiled_fit(topo, 1, 13, ROWS, 2000, False, monkeypatch)
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes >= ROWS * 2002 * 4
+
+
+def test_four_chips_compile_under_strict_vma(topo, quiet_cache, monkeypatch):
+    """Each chip runs the kernel on its own rows, the psum after it."""
+    compiled = compiled_fit(topo, 4, 13, 8192, 2000, True, monkeypatch)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert len(re.findall(r"= .* all-reduce\(", text)) >= 1
+    assert slab_layout(text) == "{1,2,0:T(8,128)}"
+
+
+@pytest.mark.parametrize("shape,layout", [
+    ((8, ROWS, 2002), "{1,0,2:T(8,128)}"),    # steps take the sublanes
+    ((62, ROWS, 30), "{1,0,2:T(8,128)}"),     # chip_smoke's HIGGS shape
+    ((13, ROWS, 128), "{2,1,0:T(8,128)}"),    # features fill the lanes
+    ((13, 1000, 2002), "{2,1,0:T(8,128)}"),   # rows do not
+])
+def test_the_chip_lays_other_shapes_otherwise(topo, quiet_cache, shape,
+                                              layout):
+    """Why the selection rule reads the placed slab's layout and does not
+    reckon it: the chip picks it from the shape alone, by padding."""
+    from jax.sharding import SingleDeviceSharding
+
+    x = jax.ShapeDtypeStruct(shape, jnp.float32,
+                             sharding=SingleDeviceSharding(topo.devices[0]))
+    text = jax.jit(lambda a: a * 2.0).lower(x).compile().as_text()
+    assert slab_layout(text) == layout
