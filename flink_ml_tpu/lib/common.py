@@ -174,6 +174,7 @@ class SparseMinibatchStack:
     nnz_pad: int
     dim: int
     n_rows: int = 0  # true (un-padded) row count, for throughput metrics
+    n_entries: int = 0  # stored entries (pads not counted), likewise
 
 
 @obs.phased("pack_sparse")
@@ -256,7 +257,7 @@ def pack_sparse_minibatches(
             floats[g, nnz_pad + mb + j] = 1.0
     return SparseMinibatchStack(
         ints=ints, floats=floats, steps=steps, mb=mb, nnz_pad=nnz_pad, dim=dim,
-        n_rows=n,
+        n_rows=n, n_entries=sum(len(v.indices) for v in vectors),
     )
 
 
@@ -310,6 +311,32 @@ def sparse_layout_floors(counts: np.ndarray, n_dev: int,
     return -(-nnz_max // pad_multiple) * pad_multiple, steps
 
 
+#: entries a block of the CSR order check looks at (its temporaries are a
+#: few bytes an entry: a whole-column check of a 447 M-entry click log took
+#: an int64 copy and its differences, 7 GB, for one boolean)
+_ORDER_CHECK_BLOCK = 1 << 24
+
+
+def _csr_rows_out_of_order(indptr, indices, nnz_total: int) -> bool:
+    """Does any row hold an index BELOW the one stored before it?  Equal
+    neighbours (a hashed column's collisions inside a row) are in order:
+    downstream needs non-decreasing ids, and a stable sort would leave them
+    where they are.  Checked block by block, in the indices' own dtype."""
+    boundary = indptr[1:-1]  # entry b starts a row: the pair (b-1, b) crosses
+    boundary = boundary[(boundary > 0) & (boundary < nnz_total)]
+    for lo in range(0, nnz_total - 1, _ORDER_CHECK_BLOCK):
+        hi = min(lo + _ORDER_CHECK_BLOCK, nnz_total - 1)
+        falls = indices[lo + 1 : hi + 1] < indices[lo:hi]  # pair (i, i+1)
+        if not falls.any():
+            continue
+        crossing = boundary[np.searchsorted(boundary, lo + 1):
+                            np.searchsorted(boundary, hi + 1)]
+        falls[crossing - 1 - lo] = False
+        if falls.any():
+            return True
+    return False
+
+
 @obs.phased("pack_csr")
 def _pack_sparse_minibatches_csr(
     rows, y, n_dev: int, global_batch_size: int, dim, pad_multiple: int,
@@ -333,16 +360,7 @@ def _pack_sparse_minibatches_csr(
         # the native loader carry file order verbatim — sort here when a
         # file violates it (one vectorized pass detects; per-row argsort
         # only runs on violation)
-        adjacent_same_row = np.ones(nnz_total - 1, dtype=bool)
-        row_ends = indptr[1:-1] - 1  # pair (i, i+1) crosses a row boundary
-        # empty leading rows repeat indptr[i]=0 (row_ends -1) and empty
-        # trailing rows repeat indptr[i]=nnz_total (row_ends nnz_total-1,
-        # past the last PAIR) — both carry no adjacent pair to mask
-        adjacent_same_row[
-            row_ends[(row_ends >= 0) & (row_ends < nnz_total - 1)]
-        ] = False
-        if np.any((np.diff(indices.astype(np.int64)) <= 0)
-                  & adjacent_same_row):
+        if _csr_rows_out_of_order(indptr, indices, nnz_total):
             order = np.argsort(
                 indices + (np.repeat(
                     np.arange(n, dtype=np.int64), np.diff(indptr)
@@ -392,7 +410,7 @@ def _pack_sparse_minibatches_csr(
         floats[g, nnz_pad + mb : nnz_pad + mb + (hi - lo)] = 1.0
     return SparseMinibatchStack(
         ints=ints, floats=floats, steps=steps, mb=mb, nnz_pad=nnz_pad, dim=dim,
-        n_rows=n,
+        n_rows=n, n_entries=nnz_total,
     )
 
 
@@ -1003,9 +1021,13 @@ def make_sparse_mb_grad_step(kind: str, mb: int, nnz_pad: int, dim: int,
         ints, floats = xs  # (2, nnz_pad), (nnz_pad + 2*mb,)
         idx, rid, vals, y, w = _segment_csr_unpack(ints, floats, nnz_pad, mb)
         wts, b = params
-        logits = _segment_csr_forward(wts, idx, rid, vals, mb) + b
+        # the step's two random-access halves, named for a profile: take,
+        # multiply and the sorted segment sum; the scatter into ``dim``
+        with jax.named_scope("fmt.train.sparse.forward"):
+            logits = _segment_csr_forward(wts, idx, rid, vals, mb) + b
         err, loss_sum = _sparse_loss(kind, logits, y, w)
-        g_w = _segment_csr_backward(err, idx, rid, vals, dim)
+        with jax.named_scope("fmt.train.sparse.backward"):
+            g_w = _segment_csr_backward(err, idx, rid, vals, dim)
         g_b = jnp.sum(err) * keep_b
         return (g_w, g_b), loss_sum, jnp.sum(w)
 
@@ -1061,7 +1083,9 @@ def make_sparse_glm_train_fn(
 
     ``kind`` picks the loss ('logistic' | 'squared'); the minibatch math is
     :func:`make_sparse_mb_grad_step`.  Program structure is shared with the
-    dense path via :func:`_build_fused_train_fn`.
+    dense path via :func:`_build_fused_train_fn`, bundled as the dense
+    estimator fit is (one program named ``jit_bundled``, one buffer to
+    fetch): :func:`_run_fused_train` is its one caller's driver.
     """
     if kind not in ("logistic", "squared"):
         raise ValueError(f"unknown loss kind {kind!r}")
@@ -1071,7 +1095,8 @@ def make_sparse_glm_train_fn(
     mb_grad_step = make_sparse_mb_grad_step(kind, mb, nnz_pad, dim, with_intercept)
 
     return _build_fused_train_fn(
-        key, mb_grad_step, mesh, learning_rate, reg, max_iter, tol
+        key, mb_grad_step, mesh, learning_rate, reg, max_iter, tol,
+        bundle=True,
     )
 
 
@@ -2246,6 +2271,13 @@ def train_glm_sparse(
             place_params=place, batch_preplaced=dev_batch is not None,
             n_rows=sstack.n_rows,
         )
+        # beside train.fused_runs: what the segment-CSR step consumed, in
+        # stored entries and in the slots it walked for them (pads too: one
+        # block of nnz_pad a device a step, len(ints) = n_dev * steps)
+        obs.counter_add("train.sparse_fits")
+        obs.counter_add("train.sparse_entries", sstack.n_entries * r.epochs)
+        obs.counter_add("train.sparse_slots",
+                        sstack.nnz_pad * len(sstack.ints) * r.epochs)
         return TrainResult(params=trim(r.params), epochs=r.epochs,
                            losses=r.losses, final_delta=r.final_delta,
                            metrics=r.metrics)

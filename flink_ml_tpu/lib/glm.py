@@ -347,8 +347,7 @@ class GlmEstimatorBase(Estimator, GlmTrainParams):
         if (vector_col is None) == (self.get_feature_cols() is None):
             raise ValueError("set exactly one of vectorCol / featureCols")
         if vector_col is not None and _col_is_sparse(table, vector_col):
-            return functools.partial(self._fit_sparse, table, y, mesh, n_dev,
-                                     batch_share)
+            return self._prepare_sparse(table, y, mesh, n_dev, batch_share)
 
         if int(self.get_num_hot_features() or 0) > 0:
             raise ValueError(
@@ -474,10 +473,12 @@ class GlmEstimatorBase(Estimator, GlmTrainParams):
         )
         return self._finish(result)
 
-    def _fit_sparse(
-        self, table: Table, y, mesh, n_dev: int, batch_share: int
-    ) -> GlmModelBase:
-        """Sparse-feature training: segment-CSR minibatches, fused device loop."""
+    def _prepare_sparse(self, table: Table, y, mesh, n_dev: int,
+                        batch_share: int):
+        """The sparse layout's part of :meth:`_prepare` (inside
+        ``fit.prepare``): the layout's agreement, the (cached) segment-CSR
+        pack and the zero start.  Returns the layout's fit, bound to its
+        arguments."""
         if not self.LOSS_KIND:
             raise NotImplementedError(
                 f"{type(self).__name__} has no sparse loss kind"
@@ -539,13 +540,21 @@ class GlmEstimatorBase(Estimator, GlmTrainParams):
                 f"steps={steps}) but the pack chose "
                 f"({sstack.nnz_pad}, {sstack.steps})"
             )
+        hot_k = int(self.get_num_hot_features() or 0)
+        if hot_k > 0:
+            return functools.partial(self._fit_sparse_hotcold, table, mesh,
+                                     layout_key, sstack, hot_k)
+        w0 = jnp.zeros((sstack.dim,), dtype=jnp.float32)
+        b0 = jnp.zeros((), dtype=jnp.float32)
+        return functools.partial(self._fit_sparse, table, sstack, mesh,
+                                 layout_key, (w0, b0))
+
+    def _fit_sparse(self, table: Table, sstack, mesh, layout_key,
+                    init_params) -> GlmModelBase:
+        """Sparse-feature training: segment-CSR minibatches, fused device loop."""
         from flink_ml_tpu.parallel.mesh import shard_batch_prefetched
         from flink_ml_tpu.table import slab_pool
 
-        hot_k = int(self.get_num_hot_features() or 0)
-        if hot_k > 0:
-            return self._fit_sparse_hotcold(table, mesh, layout_key, sstack,
-                                            hot_k)
         # thunk: resolved lazily so a no-op checkpoint resume skips the hop
         sparse_cols = [self.get_vector_col(), self.get_label_col()]
         device_batch = lambda: slab_pool.get_or_place(  # noqa: E731
@@ -555,12 +564,10 @@ class GlmEstimatorBase(Estimator, GlmTrainParams):
             ),
             cols=sparse_cols,
         )
-        w0 = jnp.zeros((sstack.dim,), dtype=jnp.float32)
-        b0 = jnp.zeros((), dtype=jnp.float32)
         lr = self.get_learning_rate()
         result = fault.run_guarded(
             lambda lr_scale: train_glm_sparse(
-                (w0, b0),
+                init_params,
                 sstack,
                 self.LOSS_KIND,
                 mesh,
