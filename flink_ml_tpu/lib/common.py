@@ -153,7 +153,10 @@ def make_sgd_update(learning_rate: float, l2: float):
 
 @dataclass
 class SparseMinibatchStack:
-    """Device-major sparse minibatches in padded segment-CSR layout.
+    """Device-major sparse minibatches in padded segment-CSR layout: one of
+    the sparse route's two step layouts, the other being the row-regular
+    :class:`EllMinibatchStack` that the pack lays where the rows' widths
+    allow it (:data:`_ELL_MAX_SLOT_RATIO`).
 
     The Criteo-scale replacement for per-record SparseVector math
     (BLAS.java:205-233, SURVEY.md §7.3 'sparse features at Criteo scale'):
@@ -175,6 +178,83 @@ class SparseMinibatchStack:
     dim: int
     n_rows: int = 0  # true (un-padded) row count, for throughput metrics
     n_entries: int = 0  # stored entries (pads not counted), likewise
+    #: the pack was asked for the row-regular layout and the rows' widths
+    #: failed its rule (``train.sparse_ell_declined`` counts such fits)
+    ell_declined: bool = False
+
+    row_regular = False  # the layout, for ``train.sparse_ell_fits``
+
+    @property
+    def step_slots(self) -> int:
+        """Slots a device's step walks, pads included."""
+        return self.nnz_pad
+
+    def grad_step(self, kind: str, with_intercept: bool = True):
+        """This layout's minibatch gradient step, behind the part of a
+        program's cache key that names it: what
+        :func:`make_sparse_glm_train_fn` builds on."""
+        return (("sparse", self.mb, self.nnz_pad, self.dim),
+                make_sparse_mb_grad_step(kind, self.mb, self.nnz_pad,
+                                         self.dim, with_intercept))
+
+
+@dataclass
+class EllMinibatchStack:
+    """Device-major sparse minibatches in the row-regular (ELL) layout: the
+    rows of a step side by side at ONE width (the table's largest stored
+    entry count a row), entries-major, so that a row's score is a sum over
+    an axis and its error reaches its entries by a broadcast — no row ids,
+    one gather and one scatter a step where segment-CSR makes two of each.
+
+      ints   (n_dev*steps, width, mb) int32 — feature ids; a shorter row
+             pads with id 0 at value 0.0 (adds 0 to its score and 0 to
+             slot 0's gradient).  Entries-major: a minor axis of ``width``
+             would be padded to the chip's 128 lanes, the sublane axis pads
+             to a multiple of 8.
+      floats (n_dev*steps, width + 2, mb) — values at ``ints``' shape, then
+             one row of labels and one of row weights (0 past the table's
+             end): two leaves, as segment-CSR has.
+    """
+
+    ints: np.ndarray
+    floats: np.ndarray
+    steps: int
+    mb: int
+    width: int
+    dim: int
+    n_rows: int = 0  # true (un-padded) row count, for throughput metrics
+    n_entries: int = 0  # stored entries (pads not counted), likewise
+
+    row_regular = True
+    ell_declined = False
+
+    @property
+    def step_slots(self) -> int:
+        """Slots a device's step walks, pads included."""
+        return self.width * self.mb
+
+    def grad_step(self, kind: str, with_intercept: bool = True):
+        """As :meth:`SparseMinibatchStack.grad_step`, for this layout."""
+        return (("sparse-ell", self.mb, self.width, self.dim),
+                make_ell_mb_grad_step(kind, self.mb, self.width, self.dim,
+                                      with_intercept))
+
+
+#: the most slots a row-regular step may walk for ONE slot of the
+#: segment-CSR step it replaces (``mb * width <= 1.75 * nnz_pad``).  By the
+#: chip's per-slot costs (PERF.md §5; my chip runs, PR 28) a segment-CSR
+#: slot costs 7.1 (take of the weights) + 8.7 (sorted scatter-add into the
+#: rows) + 7.1 (take of the error by row id) + 6.6 (scatter into ``dim``) =
+#: 29.5 ns and a row-regular slot 7.1 + 6.6 = 13.7 ns: the layouts break
+#: even at 29.5 / 13.7 = 2.15 slots for one (ragged tables read 2.0-2.05:
+#: row-regular x1.40 faster at 1.45 slots for one, 12% slower at 2.30).
+#: 1.75 leaves room on both measured sides: the row-regular step is then
+#: at least 2.0 / 1.75 = 1.14 times as fast, for leaves at most 1.17 times
+#: segment-CSR's bytes (the int leaf, no row-id plane, 0.875 of it; the
+#: float leaf 1.75).  (ISSUE 28 reckoned 26.2 / 10.4 = 2.5, and so asked
+#: for 2, from a scatter at 3.3 ns: half its cost, lost by the benchmark's
+#: breakdown where two programs name an operation alike)
+_ELL_MAX_SLOT_RATIO = 1.75
 
 
 @obs.phased("pack_sparse")
@@ -187,7 +267,8 @@ def pack_sparse_minibatches(
     pad_multiple: int = 512,
     min_nnz_pad: int = 0,
     min_steps: int = 0,
-) -> SparseMinibatchStack:
+    row_regular: bool = False,
+):
     """Pack sparse rows into the device-major sparse layout.
 
     ``vectors`` is a sequence of SparseVector (per-row Python loop) or a
@@ -197,13 +278,22 @@ def pack_sparse_minibatches(
     them, which would silently train a corrupted model.  ``min_nnz_pad``
     floors the padded nnz width — the out-of-core feed uses it to keep one
     static shape (one compiled program) across chunks.
+
+    ``row_regular`` is the caller saying that its step may be either
+    layout (the plain route on one process and a 1-D mesh; hot/cold, the
+    2-D mesh, multi-process and out-of-core fits read segment-CSR and leave
+    it off).  A CSR column then packs as an :class:`EllMinibatchStack`
+    where ``mb * width <= _ELL_MAX_SLOT_RATIO * nnz_pad`` — a choice made
+    from the row widths the pack observes — and as segment-CSR, byte for
+    byte what it is without the flag and marked ``ell_declined``, where
+    they fail that rule.  A per-object column keeps segment-CSR.
     """
     from flink_ml_tpu.ops.batch import CsrRows
 
     if isinstance(vectors, CsrRows):
         return _pack_sparse_minibatches_csr(
             vectors, y, n_dev, global_batch_size, dim, pad_multiple,
-            min_nnz_pad, min_steps,
+            min_nnz_pad, min_steps, row_regular,
         )
     n = len(vectors)
     max_idx = -1
@@ -340,11 +430,13 @@ def _csr_rows_out_of_order(indptr, indices, nnz_total: int) -> bool:
 @obs.phased("pack_csr")
 def _pack_sparse_minibatches_csr(
     rows, y, n_dev: int, global_batch_size: int, dim, pad_multiple: int,
-    min_nnz_pad: int, min_steps: int,
-) -> SparseMinibatchStack:
+    min_nnz_pad: int, min_steps: int, row_regular: bool = False,
+):
     """Vectorized packing from a CSR column: identical layout and validation
     to the per-row path (shared tests assert bit-equality), but the inner
-    work is numpy slice copies — O(groups) Python instead of O(rows)."""
+    work is numpy slice copies — O(groups) Python instead of O(rows).  With
+    ``row_regular`` the row widths decide between this layout and
+    :func:`_pack_ell` (see :func:`pack_sparse_minibatches`)."""
     n = len(rows)
     indptr, indices, values = rows.indptr, rows.indices, rows.values
     nnz_total = int(indptr[-1]) if n else 0
@@ -353,22 +445,6 @@ def _pack_sparse_minibatches_csr(
         first_bad = int(np.argmax(indices < 0))
         row = int(np.searchsorted(indptr, first_bad, side="right")) - 1
         raise ValueError(f"row {row}: negative feature index")
-    if nnz_total:
-        # per-row ascending ids are a layout invariant downstream (the
-        # hot-slab scatter declares its (rid, pos) tuples sorted); the
-        # SparseVector path sorts at construction, but CSR columns from
-        # the native loader carry file order verbatim — sort here when a
-        # file violates it (one vectorized pass detects; per-row argsort
-        # only runs on violation)
-        if _csr_rows_out_of_order(indptr, indices, nnz_total):
-            order = np.argsort(
-                indices + (np.repeat(
-                    np.arange(n, dtype=np.int64), np.diff(indptr)
-                ) << 32),
-                kind="stable",
-            )
-            indices = indices[order]
-            values = values[order]
     if dim is None:
         dim = max(max_idx + 1, rows.dim)
     elif max_idx >= dim:
@@ -394,6 +470,30 @@ def _pack_sparse_minibatches_csr(
         nnz_max = max(nnz_max, e1 - e0)
     nnz_pad = max(-(-nnz_max // pad_multiple) * pad_multiple, int(min_nnz_pad))
 
+    if row_regular:
+        # the slots either layout would walk a step, from the widths alone
+        width = max(1, int(counts.max(initial=0)))
+        if mb * width <= _ELL_MAX_SLOT_RATIO * nnz_pad:
+            return _pack_ell(rows, y, bounds, counts, width, mb, steps, dim)
+
+    if nnz_total:
+        # per-row ascending ids are a layout invariant downstream (the
+        # hot-slab scatter declares its (rid, pos) tuples sorted); the
+        # SparseVector path sorts at construction, but CSR columns from
+        # the native loader carry file order verbatim — sort here when a
+        # file violates it (one vectorized pass detects; per-row argsort
+        # only runs on violation).  The row-regular layout above sums a
+        # row's entries whatever their order, and skips this
+        if _csr_rows_out_of_order(indptr, indices, nnz_total):
+            order = np.argsort(
+                indices + (np.repeat(
+                    np.arange(n, dtype=np.int64), np.diff(indptr)
+                ) << 32),
+                kind="stable",
+            )
+            indices = indices[order]
+            values = values[order]
+
     ints = np.zeros((n_groups, 2, nnz_pad), dtype=np.int32)
     ints[:, 1, :] = mb  # pad row id -> dropped segment
     floats = np.zeros((n_groups, nnz_pad + 2 * mb), dtype=np.float32)
@@ -410,7 +510,39 @@ def _pack_sparse_minibatches_csr(
         floats[g, nnz_pad + mb : nnz_pad + mb + (hi - lo)] = 1.0
     return SparseMinibatchStack(
         ints=ints, floats=floats, steps=steps, mb=mb, nnz_pad=nnz_pad, dim=dim,
-        n_rows=n, n_entries=nnz_total,
+        n_rows=n, n_entries=nnz_total, ell_declined=row_regular,
+    )
+
+
+def _pack_ell(rows, y, bounds, counts, width: int, mb: int, steps: int,
+              dim: int) -> EllMinibatchStack:
+    """Lay a validated CSR column out row-regular, a device's step (one
+    entry of ``bounds``: rows ``[lo, hi)``, entries ``[e0, e1)``) at a
+    time, so that the temporaries are a step's: a step whose rows are all
+    ``width`` long is one transposing copy, a ragged one scatters its
+    entries by (position in the row, row).  Rows keep their stored order of
+    entries: the step sums a row whatever its order."""
+    indptr, indices, values = rows.indptr, rows.indices, rows.values
+    ints = np.zeros((len(bounds), width, mb), dtype=np.int32)
+    floats = np.zeros((len(bounds), width + 2, mb), dtype=np.float32)
+    for g, (lo, hi, e0, e1) in enumerate(bounds):
+        m = hi - lo
+        if not m:
+            continue
+        if e1 - e0 == m * width:
+            ints[g, :, :m] = indices[e0:e1].reshape(m, width).T
+            floats[g, :width, :m] = values[e0:e1].reshape(m, width).T
+        elif e1 > e0:
+            rid = np.repeat(np.arange(m, dtype=np.int32), counts[lo:hi])
+            pos = np.arange(e1 - e0, dtype=np.int32) - np.repeat(
+                (indptr[lo:hi] - e0).astype(np.int32), counts[lo:hi])
+            ints[g, pos, rid] = indices[e0:e1]
+            floats[g, pos, rid] = values[e0:e1]
+        floats[g, width, :m] = y[lo:hi]
+        floats[g, width + 1, :m] = 1.0
+    return EllMinibatchStack(
+        ints=ints, floats=floats, steps=steps, mb=mb, width=width, dim=dim,
+        n_rows=len(rows), n_entries=int(indptr[-1]) if len(rows) else 0,
     )
 
 
@@ -1034,6 +1166,34 @@ def make_sparse_mb_grad_step(kind: str, mb: int, nnz_pad: int, dim: int,
     return mb_grad_step
 
 
+def make_ell_mb_grad_step(kind: str, mb: int, width: int, dim: int,
+                          with_intercept: bool = True):
+    """:func:`make_sparse_mb_grad_step`'s gradient over one step of an
+    :class:`EllMinibatchStack`: the same loss, the same scopes' names, the
+    same update downstream.  With the step's rows side by side the score is
+    a sum over the entries' axis and the error reaches a row's entries by a
+    broadcast: ONE gather (the weights) and ONE scatter (the gradient),
+    float32 throughout, a row's products summed in its stored order."""
+    keep_b = 1.0 if with_intercept else 0.0
+
+    def mb_grad_step(params, xs):
+        idx, floats = xs  # (width, mb), (width + 2, mb)
+        vals, y, w = floats[:width], floats[width], floats[width + 1]
+        wts, b = params
+        with jax.named_scope("fmt.train.sparse.forward"):
+            logits = jnp.sum(vals * jnp.take(wts, idx, axis=0), axis=0) + b
+        err, loss_sum = _sparse_loss(kind, logits, y, w)
+        with jax.named_scope("fmt.train.sparse.backward"):
+            g_w = jax.ops.segment_sum(
+                (err[None, :] * vals).reshape(width * mb),
+                idx.reshape(width * mb), num_segments=dim,
+            )
+        g_b = jnp.sum(err) * keep_b
+        return (g_w, g_b), loss_sum, jnp.sum(w)
+
+    return mb_grad_step
+
+
 def _segment_csr_unpack(ints, floats, nnz_pad: int, mb: int):
     """Unpack one packed sparse minibatch slice into (idx, rid, vals, y, w)
     — the ONE copy of the [values | y | w] layout decode (sparse, 2-D, and
@@ -1070,29 +1230,30 @@ def _segment_csr_backward(err, idx, rid, vals, dim: int):
 def make_sparse_glm_train_fn(
     kind: str,
     mesh,
-    mb: int,
-    nnz_pad: int,
-    dim: int,
+    sstack,
     learning_rate: float,
     reg: float,
     max_iter: int,
     tol: float,
     with_intercept: bool = True,
 ):
-    """Fused training over :class:`SparseMinibatchStack` batches.
+    """Fused training over the batches of ``sstack``, a
+    :class:`SparseMinibatchStack` or an :class:`EllMinibatchStack` (read for
+    its shapes only): the stack names its step (``grad_step``).
 
     ``kind`` picks the loss ('logistic' | 'squared'); the minibatch math is
-    :func:`make_sparse_mb_grad_step`.  Program structure is shared with the
-    dense path via :func:`_build_fused_train_fn`, bundled as the dense
-    estimator fit is (one program named ``jit_bundled``, one buffer to
-    fetch): :func:`_run_fused_train` is its one caller's driver.
+    :func:`make_sparse_mb_grad_step` or :func:`make_ell_mb_grad_step`.
+    Program structure is shared with the dense path via
+    :func:`_build_fused_train_fn`, bundled as the dense estimator fit is
+    (one program named ``jit_bundled``, one buffer to fetch):
+    :func:`_run_fused_train` is its one caller's driver.
     """
     if kind not in ("logistic", "squared"):
         raise ValueError(f"unknown loss kind {kind!r}")
-    key = ("sparse", kind, mesh, mb, nnz_pad, dim,
+    step_key, mb_grad_step = sstack.grad_step(kind, with_intercept)
+    key = (*step_key, kind, mesh,
            float(learning_rate), float(reg), int(max_iter), float(tol),
            bool(with_intercept))
-    mb_grad_step = make_sparse_mb_grad_step(kind, mb, nnz_pad, dim, with_intercept)
 
     return _build_fused_train_fn(
         key, mb_grad_step, mesh, learning_rate, reg, max_iter, tol,
@@ -2255,7 +2416,7 @@ def train_glm_sparse(
 
         def factory(n_epochs):
             return make_sparse_glm_train_fn(
-                kind, mesh, sstack.mb, sstack.nnz_pad, dim,
+                kind, mesh, sstack,
                 learning_rate, reg, n_epochs, tol, with_intercept,
             )
 
@@ -2271,13 +2432,18 @@ def train_glm_sparse(
             place_params=place, batch_preplaced=dev_batch is not None,
             n_rows=sstack.n_rows,
         )
-        # beside train.fused_runs: what the segment-CSR step consumed, in
-        # stored entries and in the slots it walked for them (pads too: one
-        # block of nnz_pad a device a step, len(ints) = n_dev * steps)
+        # beside train.fused_runs: what the sparse step consumed, in
+        # stored entries and in the slots it walked for them (pads too:
+        # step_slots a device a step, len(ints) = n_dev * steps), and which
+        # of the two layouts it walked (0 keeps the counter there from the
+        # first sparse fit)
         obs.counter_add("train.sparse_fits")
+        obs.counter_add("train.sparse_ell_fits", int(sstack.row_regular))
+        if sstack.ell_declined:
+            obs.counter_add("train.sparse_ell_declined")
         obs.counter_add("train.sparse_entries", sstack.n_entries * r.epochs)
         obs.counter_add("train.sparse_slots",
-                        sstack.nnz_pad * len(sstack.ints) * r.epochs)
+                        sstack.step_slots * len(sstack.ints) * r.epochs)
         return TrainResult(params=trim(r.params), epochs=r.epochs,
                            losses=r.losses, final_delta=r.final_delta,
                            metrics=r.metrics)

@@ -476,9 +476,10 @@ class GlmEstimatorBase(Estimator, GlmTrainParams):
     def _prepare_sparse(self, table: Table, y, mesh, n_dev: int,
                         batch_share: int):
         """The sparse layout's part of :meth:`_prepare` (inside
-        ``fit.prepare``): the layout's agreement, the (cached) segment-CSR
-        pack and the zero start.  Returns the layout's fit, bound to its
-        arguments."""
+        ``fit.prepare``): the layout's agreement, the (cached) pack
+        (segment-CSR, or row-regular where the plain route's row widths
+        allow it) and the zero start.  Returns the layout's fit, bound to
+        its arguments."""
         if not self.LOSS_KIND:
             raise NotImplementedError(
                 f"{type(self).__name__} has no sparse loss kind"
@@ -522,14 +523,22 @@ class GlmEstimatorBase(Estimator, GlmTrainParams):
             )
         else:
             nnz_pad, steps = 0, 0  # pack's own natural layout
+        hot_k = int(self.get_num_hot_features() or 0)
+        # the plain step takes either layout, and the pack picks by the row
+        # widths it observes; hot/cold's split and the 2-D step read
+        # segment-CSR, and processes agree on nnz_pad and steps only
+        row_regular = (hot_k == 0 and jax.process_count() == 1
+                       and dict(mesh.shape).get("model", 1) == 1)
         layout_key = ("sparse", self.get_vector_col(), self.get_label_col(),
-                      n_dev, batch_share, num_features, nnz_pad, steps)
+                      n_dev, batch_share, num_features, nnz_pad, steps,
+                      row_regular)
         sstack = table.cached_pack(
             layout_key,
             lambda: pack_sparse_minibatches(
                 table.col(self.get_vector_col()), y, n_dev,
                 batch_share, dim=num_features,
                 min_nnz_pad=nnz_pad, min_steps=steps,
+                row_regular=row_regular,
             ),
         )
         if nnz_pad and (sstack.nnz_pad, sstack.steps) != (nnz_pad, steps):
@@ -540,7 +549,6 @@ class GlmEstimatorBase(Estimator, GlmTrainParams):
                 f"steps={steps}) but the pack chose "
                 f"({sstack.nnz_pad}, {sstack.steps})"
             )
-        hot_k = int(self.get_num_hot_features() or 0)
         if hot_k > 0:
             return functools.partial(self._fit_sparse_hotcold, table, mesh,
                                      layout_key, sstack, hot_k)
@@ -551,7 +559,8 @@ class GlmEstimatorBase(Estimator, GlmTrainParams):
 
     def _fit_sparse(self, table: Table, sstack, mesh, layout_key,
                     init_params) -> GlmModelBase:
-        """Sparse-feature training: segment-CSR minibatches, fused device loop."""
+        """Sparse-feature training: segment-CSR or row-regular (ELL)
+        minibatches, as ``sstack`` is laid; fused device loop."""
         from flink_ml_tpu.parallel.mesh import shard_batch_prefetched
         from flink_ml_tpu.table import slab_pool
 
@@ -711,8 +720,12 @@ class GlmEstimatorBase(Estimator, GlmTrainParams):
         The dataset is never materialized: chunks stream through the fused
         per-chunk program (lib/out_of_core.py) with host->device prefetch.
         Step-major packing makes the result bit-identical to the in-memory
-        fit of the same rows.  Requires an explicit ``globalBatchSize``
-        (full-batch SGD needs the entire dataset resident by definition).
+        fit of the same rows on the same step layout; the chunk program's
+        is segment-CSR, so an in-memory fit that took the row-regular step
+        (:meth:`_prepare_sparse`) agrees to float32 rounding (a row's
+        products summed in another order).  Requires an explicit
+        ``globalBatchSize`` (full-batch SGD needs the entire dataset
+        resident by definition).
 
         Configurations with a full layout pre-pass (hot/cold frequency
         scan, multi-process shape/count scans) run under a

@@ -1,21 +1,31 @@
 #!/usr/bin/env python
-"""The three sparse formulations of ``LogisticRegression.fit`` on ONE table,
-by hand on the chip (ROADMAP D1 waits for these numbers; no cell):
+"""The sparse formulations of ``LogisticRegression.fit`` on ONE table, by
+hand on the chip (ROADMAP D1 waits for these numbers; no cell):
 
-    python scripts/sparse_routes.py --seed <n> [--hot 4096]
+    python scripts/sparse_routes.py --seed <n> [--hot 4096] [--keep 0.67 ...]
 
 Makes ``criteo_sparse_lr``'s table from the seed with the benchmark's own
 generator, then fits it (first fit: pack, split, place, compile; then
 ``--fits`` warm fits, timed on the host clock from the call to the model)
 through
 
-* the plain segment-CSR route (``numHotFeatures`` unset: the default),
+* the plain route as the estimator takes it (``numHotFeatures`` unset: the
+  default; the pack lays this table row-regular since PR 28),
+* plain segment-CSR, which the estimator no longer takes for this table:
+  packed and trained by the builders themselves (``pack_sparse_minibatches``
+  without ``row_regular``, ``train_glm_sparse``), no switch in the program,
 * hot/cold with ``hotSlabMode`` ``stream`` (the hot columns densified inside
   the program, a step at a time),
 * hot/cold with ``hotSlabMode`` ``resident`` (the hot columns as bf16 slabs
   on the device) where the slabs fit the chip: ``rows x hot x 2`` bytes, by
   the program's own ``hotcold_slab_bytes``; a slab that cannot fit is
-  reported, not tried.
+  reported, not tried.  ``--hot 0`` leaves both hot/cold routes out.
+
+Each ``--keep p`` then makes a RAGGED table (every stored entry of the
+cell's table kept with probability ``p``) and fits it through both step
+layouts by the builders, the row-regular one forced past the pack's rule
+inside this script where the rule declines it: one reading on each side of
+``mb x width <= _ELL_MAX_SLOT_RATIO x nnz_pad``.
 
 One JSON line a route: warm fit seconds (median), stored entries a second,
 the device's peak memory, the loss, and the must-be-zero counters.  Refuses
@@ -33,11 +43,65 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
+def by_builders(name, column, y, config, row_regular, fits):
+    """One layout of one CSR column, packed and trained by the builders:
+    the JSON line's fields.  ``row_regular`` lifts the pack's rule for this
+    one pack (the row-regular layout even where the rule declines it)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from chipbench import program
+    from flink_ml_tpu.lib import common
+    from flink_ml_tpu.parallel.mesh import shard_batch_prefetched
+    from flink_ml_tpu.utils.environment import MLEnvironmentFactory
+
+    mesh = MLEnvironmentFactory.get_default().get_mesh()
+    dim, batch = int(config["numFeatures"]), int(config["globalBatchSize"])
+    program.release()
+    rule = common._ELL_MAX_SLOT_RATIO
+    t0 = time.perf_counter()
+    try:
+        if row_regular:
+            common._ELL_MAX_SLOT_RATIO = float("inf")
+        stack = common.pack_sparse_minibatches(
+            column, y, 1, batch, dim=dim, row_regular=row_regular)
+    finally:
+        common._ELL_MAX_SLOT_RATIO = rule
+    pack_s = time.perf_counter() - t0
+    placed = shard_batch_prefetched(mesh, (stack.ints, stack.floats))
+
+    def fit():
+        start = (jnp.zeros((dim,), jnp.float32), jnp.zeros((), jnp.float32))
+        t = time.perf_counter()
+        result = common.train_glm_sparse(
+            start, stack, "logistic", mesh, 0.1, int(config["maxIter"]),
+            with_intercept=True, device_batch=placed)
+        return time.perf_counter() - t, result
+
+    first_s, first = fit()
+    warm = [fit() for _ in range(fits)]
+    seconds = statistics.median(s for s, _r in warm)
+    entries = stack.n_entries * int(config["maxIter"])
+    return {
+        "route": name, "layout": "row_regular" if stack.row_regular else "segment_csr",
+        "ran": True, "pack_s": pack_s, "first_fit_s": first_s,
+        "warm_fit_s": seconds, "entries_per_s": entries / seconds,
+        "slots_a_step": stack.step_slots,
+        "ns_a_slot": 1e9 * seconds / (stack.step_slots * len(stack.ints)),
+        "same_bytes": all(np.array_equal(r.params[0], first.params[0])
+                          for _s, r in warm),
+        "loss": float(first.losses[-1]),
+        "coef_norm": float(np.linalg.norm(np.asarray(first.params[0]))),
+        "resident_bytes": int(sum(a.nbytes for a in placed)),
+    }
+
+
 def main() -> int:
     parser = argparse.ArgumentParser()
     parser.add_argument("--seed", type=int, required=True)
     parser.add_argument("--hot", type=int, default=4096)
     parser.add_argument("--fits", type=int, default=2)
+    parser.add_argument("--keep", type=float, action="append", default=[])
     args = parser.parse_args()
 
     import jax
@@ -45,7 +109,9 @@ def main() -> int:
 
     from chipbench import data_sparse, program, program_sparse, run
     from flink_ml_tpu import obs
+    from flink_ml_tpu.lib import common
     from flink_ml_tpu.lib.common import hotcold_slab_bytes
+    from flink_ml_tpu.ops.batch import CsrRows
 
     device = jax.devices()[0]
     if device.platform != "tpu":
@@ -64,15 +130,18 @@ def main() -> int:
     limit = (device.memory_stats() or {}).get("bytes_limit", 0)
     padded_rows = -(-len(y) // batch) * batch
     slab = hotcold_slab_bytes(padded_rows, args.hot)
-    routes = [("plain", None, None), ("hotcold_stream", args.hot, "stream")]
-    if limit and slab > 0.8 * limit:
+    routes = [("plain", None, None)]
+    if args.hot:
+        routes.append(("hotcold_stream", args.hot, "stream"))
+    if args.hot and limit and slab > 0.8 * limit:
         print(json.dumps({"route": "hotcold_resident", "hot": args.hot,
                           "slab_bytes": slab, "device_bytes": limit,
                           "ran": False, "why": "the slab does not fit"}),
               flush=True)
-    else:
+    elif args.hot:
         routes.append(("hotcold_resident", args.hot, "resident"))
-    # one table: the routes share its segment-CSR pack (and nothing placed)
+    # one table: the hot/cold routes share its segment-CSR pack, the plain
+    # route packs its own (row-regular), and nothing placed is shared
     table = program_sparse.table(dim, indptr, indices, values, y)
     for name, hot, mode in routes:
         program.release()
@@ -106,6 +175,8 @@ def main() -> int:
             coef_norm=float(np.linalg.norm(first["coef"])),
             peak_bytes=(device.memory_stats() or {}).get(
                 "peak_bytes_in_use", 0),
+            ell_fits=after.get("train.sparse_ell_fits", 0)
+            - before.get("train.sparse_ell_fits", 0),
             hidden={k: after[k] - before.get(k, 0)
                     for k in program.MUST_BE_ZERO
                     if after.get(k, 0) - before.get(k, 0)},
@@ -113,6 +184,32 @@ def main() -> int:
                      obs.registry().snapshot()["timings"].items()
                      if k.startswith("phase.")})
         print(json.dumps(line), flush=True)
+    table = None
+    column = CsrRows(dim, indptr, indices, values)
+    print(json.dumps(by_builders("plain_segment_csr", column, y, config,
+                                 False, args.fits)), flush=True)
+    # ragged tables on the two sides of the pack's rule
+    rng = np.random.default_rng(args.seed)
+    for keep in args.keep:
+        kept = rng.random(len(indices), dtype=np.float32) < keep
+        counts = np.add.reduceat(kept, indptr[:-1])
+        ragged = CsrRows(dim, np.concatenate([[0], np.cumsum(counts)]),
+                         indices[kept], values[kept])
+        del kept
+        pair = [by_builders(f"ragged_{keep:g}_{layout}", ragged, y, config,
+                            row_regular, args.fits)
+                for layout, row_regular in (("segment_csr", False),
+                                            ("row_regular", True))]
+        ratio = pair[1]["slots_a_step"] / pair[0]["slots_a_step"]
+        for line in pair:
+            line.update(
+                keep=keep, width=int(counts.max()),
+                mean_width=float(counts.mean()), slot_ratio=ratio,
+                rule_takes="row_regular"
+                if ratio <= common._ELL_MAX_SLOT_RATIO else "segment_csr",
+                row_regular_speedup=pair[0]["warm_fit_s"]
+                / pair[1]["warm_fit_s"])
+            print(json.dumps(line), flush=True)
     return 0
 
 

@@ -926,3 +926,367 @@ class TestCsrEmptyRowPack:
                  .set_label_col("label").set_prediction_col("p")
                  .set_num_features(dim).set_max_iter(3).fit(t))
         assert model.train_epochs_ >= 1
+
+
+# -- the row-regular (ELL) step layout (PR 28) ---------------------------------
+#
+# The plain route on one process and a 1-D mesh packs a CSR column row-regular
+# where ``mb * width <= _ELL_MAX_SLOT_RATIO * nnz_pad`` (what the pack observes
+# of the rows' widths) and segment-CSR where not; every other route reads
+# segment-CSR, byte for byte what it read before.
+
+
+def _csr_table_parts(counts, dim, seed, duplicate_in_row=None, sort=True):
+    """A CSR column of ``len(counts)`` rows with the given stored-entry
+    counts; ``duplicate_in_row`` stores that row's first id twice."""
+    rng = np.random.RandomState(seed)
+    parts = []
+    for r, c in enumerate(counts):
+        ids = rng.choice(dim, c, replace=False)
+        if sort:
+            ids = np.sort(ids)
+        if duplicate_in_row == r and c >= 2:
+            ids[1] = ids[0]
+        parts.append(ids)
+    indptr = np.concatenate([[0], np.cumsum(counts)]).astype(np.int64)
+    indices = (np.concatenate(parts) if len(parts) else
+               np.zeros(0)).astype(np.int32)
+    values = rng.randn(len(indices)).astype(np.float32)
+    y = (rng.rand(len(counts)) < 0.4).astype(np.float64)
+    return indptr, indices, values, y
+
+
+def _csr_rows(dim, indptr, indices, values):
+    from flink_ml_tpu.ops.batch import CsrRows
+
+    return CsrRows(dim, indptr, indices, values)
+
+
+_ELL_STEP_TABLES = {
+    # name: (counts, duplicate_in_row); 16 rows a global batch over 2 devices
+    "uniform": (np.full(48, 5), None),
+    "ragged": (np.random.RandomState(5).randint(3, 6, 48), None),
+    "duplicate_id": (np.full(48, 5), 7),
+    "empty_row": (np.where(np.arange(48) % 11 == 3, 0, 4), None),
+    "padded_last_step": (np.random.RandomState(6).randint(2, 5, 41), None),
+}
+
+
+@pytest.mark.parametrize("with_intercept", [True, False],
+                         ids=["intercept", "no_intercept"])
+@pytest.mark.parametrize("kind", ["logistic", "squared"])
+@pytest.mark.parametrize("name", sorted(_ELL_STEP_TABLES))
+def test_the_row_regular_step_equals_the_segment_csr_step(
+        name, kind, with_intercept):
+    """One minibatch gradient, both layouts of the same rows, every step of
+    the table: equal to float32 rounding (a row's products are summed in
+    another order), pads and weight-0 rows adding nothing."""
+    import jax.numpy as jnp
+
+    from flink_ml_tpu.lib import common
+
+    counts, dup = _ELL_STEP_TABLES[name]
+    dim = 40
+    indptr, indices, values, y = _csr_table_parts(counts, dim, 17, dup)
+    rows = _csr_rows(dim, indptr, indices, values)
+    csr = pack_sparse_minibatches(rows, y, 2, 16, dim=dim)
+    ell = pack_sparse_minibatches(rows, y, 2, 16, dim=dim, row_regular=True)
+    assert isinstance(csr, common.SparseMinibatchStack)
+    assert isinstance(ell, common.EllMinibatchStack)
+    assert ell.width == counts.max() and ell.mb == csr.mb == 8
+    assert ell.ints.shape == (len(csr.ints), ell.width, 8)
+    assert ell.floats.shape == (len(csr.ints), ell.width + 2, 8)
+    assert ell.n_entries == csr.n_entries == counts.sum()
+    # the weights row marks exactly the table's rows
+    assert ell.floats[:, ell.width + 1].sum() == len(counts)
+    rng = np.random.RandomState(3)
+    params = (jnp.asarray(rng.randn(dim), jnp.float32),
+              jnp.asarray(0.3, jnp.float32))
+    step_csr = common.make_sparse_mb_grad_step(
+        kind, csr.mb, csr.nnz_pad, dim, with_intercept)
+    step_ell = common.make_ell_mb_grad_step(
+        kind, ell.mb, ell.width, dim, with_intercept)
+    for g in range(len(csr.ints)):
+        (gw_c, gb_c), loss_c, w_c = step_csr(
+            params, (jnp.asarray(csr.ints[g]), jnp.asarray(csr.floats[g])))
+        (gw_e, gb_e), loss_e, w_e = step_ell(
+            params, (jnp.asarray(ell.ints[g]), jnp.asarray(ell.floats[g])))
+        assert gw_e.dtype == gw_c.dtype == jnp.float32
+        np.testing.assert_allclose(gw_e, gw_c, rtol=2e-5, atol=2e-6)
+        np.testing.assert_allclose(gb_e, gb_c, rtol=2e-5, atol=2e-6)
+        np.testing.assert_allclose(loss_e, loss_c, rtol=2e-5, atol=2e-6)
+        assert float(w_e) == float(w_c)
+        if not with_intercept:
+            assert float(gb_e) == 0.0
+
+
+def _sparse_est(dim, batch, epochs=2, lr=0.5):
+    return (LogisticRegression().set_vector_col("features")
+            .set_label_col("label").set_prediction_col("pred")
+            .set_num_features(dim).set_global_batch_size(batch)
+            .set_learning_rate(lr).set_max_iter(epochs))
+
+
+def _sparse_table(dim, indptr, indices, values, y):
+    return Table.from_columns(SCHEMA, {
+        "features": _csr_rows(dim, indptr, indices, values), "label": y})
+
+
+def _packed(table):
+    """The stacks a fit left in the table's pack cache."""
+    return list(table._pack_cache.values())
+
+
+@pytest.fixture
+def sparse_counters(tmp_path, monkeypatch):
+    from flink_ml_tpu import obs
+
+    monkeypatch.setenv("FMT_OBS_REPORTS", str(tmp_path / "reports"))
+    obs.reset()
+    obs.enable()
+    yield lambda: obs.registry().snapshot()["counters"]
+    obs.disable()
+    obs.reset()
+
+
+def _mesh_devices():
+    from flink_ml_tpu.utils.environment import MLEnvironmentFactory
+
+    return len(MLEnvironmentFactory.get_default().get_mesh().devices.flat)
+
+
+def test_a_fit_at_the_rehearsal_shape_lands_within_the_cells_limits():
+    """``LogisticRegression.fit`` of a ``CsrRows`` column on the row-regular
+    step against the benchmark's plain reference, at the sparse cell's
+    rehearsal shape and under the cell's own limits."""
+    import json
+    import os
+    import sys
+
+    from flink_ml_tpu.lib import common
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from chipbench import data_sparse, program, program_sparse, references
+
+    with open(os.path.join(root, "chipbench", "configs",
+                           "criteo_sparse_lr.json")) as f:
+        config = json.load(f)
+    config.update(config["rehearsal"])
+    with open(os.path.join(root, "chipbench", "limits",
+                           "criteo_sparse_lr.sweep.json")) as f:
+        limits = json.load(f)
+    dim, batch = config["numFeatures"], config["globalBatchSize"]
+    indptr, indices, values, y = data_sparse.make_rows(
+        config["data"], config["rows"], dim, 2**31 + 28)
+    table = program_sparse.table(dim, indptr, indices, values, y)
+    reference = references.load(config["reference"])
+    width = config["nnz_per_row"]
+    ref_table = reference.Table(indices.reshape(-1, width),
+                                values.reshape(-1, width), y, dim, batch)
+    for lr, reg in ((0.1, 0.0), (0.5, 1e-4)):
+        answer = program.fit_answer(
+            program_sparse.logreg(config, lr, reg).fit(table))
+        gaps = reference.gaps(
+            answer, ref_table.fit(lr, reg, config["maxIter"]))
+        assert gaps["coef_gap"] <= limits["coef_gap"]["limit"], gaps
+        assert gaps["loss_gap"] <= limits["loss_gap"]["limit"], gaps
+    (stack,) = _packed(table)
+    assert isinstance(stack, common.EllMinibatchStack) and stack.width == 39
+
+
+def test_a_repeated_row_regular_fit_returns_the_same_bytes():
+    from flink_ml_tpu.lib import common
+
+    counts = np.random.RandomState(8).randint(2, 7, 700)
+    table = _sparse_table(60, *_csr_table_parts(counts, 60, 21))
+    a = _sparse_est(60, 128).fit(table)
+    b = _sparse_est(60, 128).fit(table)
+    (stack,) = _packed(table)
+    assert isinstance(stack, common.EllMinibatchStack)
+    assert np.asarray(a.coefficients()).tobytes() == \
+        np.asarray(b.coefficients()).tobytes()
+    assert a.intercept() == b.intercept()
+    assert list(a.train_losses_) == list(b.train_losses_)
+
+
+def test_rows_out_of_ascending_order_give_the_sorted_rows_answer():
+    """The row-regular pack does NOT sort a row's entries (its step sums a
+    row whatever the order: the order check and its argsort do not run), so
+    a file-order column gives the sorted column's answer to float32
+    rounding, not its bytes."""
+    from flink_ml_tpu.lib import common
+
+    counts = np.random.RandomState(9).randint(3, 7, 500)
+    indptr, indices, values, y = _csr_table_parts(counts, 80, 23, sort=False)
+    order = np.concatenate([
+        lo + np.argsort(indices[lo:hi], kind="stable")
+        for lo, hi in zip(indptr[:-1], indptr[1:])])
+    assert not np.array_equal(order, np.arange(len(indices)))
+    shuffled = _sparse_table(80, indptr, indices, values, y)
+    ordered = _sparse_table(80, indptr, indices[order], values[order], y)
+    a = _sparse_est(80, 128).fit(shuffled)
+    b = _sparse_est(80, 128).fit(ordered)
+    for table in (shuffled, ordered):
+        (stack,) = _packed(table)
+        assert isinstance(stack, common.EllMinibatchStack)
+    # the pack kept the stored order: the first row's ids, as stored
+    (stack,) = _packed(shuffled)
+    assert np.array_equal(stack.ints[0, :counts[0], 0], indices[:counts[0]])
+    np.testing.assert_allclose(a.coefficients(), b.coefficients(),
+                               rtol=1e-5, atol=1e-7)
+    np.testing.assert_allclose(a.intercept(), b.intercept(), atol=1e-7)
+
+
+# -- the choice and its counters -----------------------------------------------
+
+
+def test_uniform_rows_take_the_row_regular_step_and_count_it(sparse_counters):
+    n_dev, width, epochs = _mesh_devices(), 6, 3
+    rows, batch = 1000, 32 * n_dev
+    table = _sparse_table(
+        90, *_csr_table_parts(np.full(rows, width), 90, 31))
+    _sparse_est(90, batch, epochs=epochs).fit(table)
+    counted = sparse_counters()
+    blocks = n_dev * -(-rows // batch)
+    assert counted["train.sparse_fits"] == 1
+    assert counted["train.sparse_ell_fits"] == 1
+    assert "train.sparse_ell_declined" not in counted
+    assert counted["train.sparse_entries"] == rows * width * epochs
+    assert counted["train.sparse_slots"] == width * 32 * blocks * epochs
+
+
+def _one_long_row(n_dev, long_width):
+    """``256 * n_dev`` rows in one global batch: every row stores 4 entries
+    but the first, so one device's step holds ``1020 + long_width`` entries
+    (``nnz_pad`` 1536 for a long row of 5 to 516) and the row-regular
+    layout would walk ``256 * long_width`` slots a device."""
+    counts = np.full(256 * n_dev, 4)
+    counts[0] = long_width
+    return _csr_table_parts(counts, 2000, 37)
+
+
+@pytest.mark.parametrize("long_width, takes_ell", [(10, True), (11, False)],
+                         ids=["just_inside", "just_outside"])
+def test_the_rule_splits_tables_by_the_slots_they_would_walk(
+        long_width, takes_ell, sparse_counters):
+    from flink_ml_tpu.lib import common
+
+    n_dev = _mesh_devices()
+    assert common._ELL_MAX_SLOT_RATIO == 1.75
+    # 256 * 10 = 2560 <= 1.75 * 1536 = 2688 < 256 * 11 = 2816
+    parts = _one_long_row(n_dev, long_width)
+    table = _sparse_table(2000, *parts)
+    _sparse_est(2000, 256 * n_dev, epochs=1).fit(table)
+    (stack,) = _packed(table)
+    counted = sparse_counters()
+    assert counted["train.sparse_fits"] == 1
+    assert counted["train.sparse_ell_fits"] == int(takes_ell)
+    assert counted.get("train.sparse_ell_declined", 0) == int(not takes_ell)
+    if takes_ell:
+        assert isinstance(stack, common.EllMinibatchStack)
+        assert (stack.mb, stack.width) == (256, long_width)
+        assert counted["train.sparse_slots"] == 256 * long_width * n_dev
+    else:
+        # segment-CSR, byte for byte what the pack lays without the flag
+        plain = pack_sparse_minibatches(
+            _csr_rows(2000, *parts[:3]), parts[3], n_dev, 256 * n_dev,
+            dim=2000)
+        assert isinstance(stack, common.SparseMinibatchStack)
+        assert stack.ell_declined and not plain.ell_declined
+        assert stack.nnz_pad == plain.nnz_pad == 1536
+        assert stack.ints.tobytes() == plain.ints.tobytes()
+        assert stack.floats.tobytes() == plain.floats.tobytes()
+        assert counted["train.sparse_slots"] == 1536 * n_dev
+
+
+def _parents_segment_csr(parts, dim, n_dev, batch):
+    """The parent commit's stack for these rows: the per-object pack, which
+    this PR does not touch (and which the CSR pack equals byte for byte)."""
+    indptr, indices, values, y = parts
+    vecs = [SparseVector(dim, indices[a:b].astype(np.int64),
+                         values[a:b].astype(np.float64))
+            for a, b in zip(indptr[:-1], indptr[1:])]
+    return pack_sparse_minibatches(vecs, y, n_dev, batch, dim=dim)
+
+
+def _assert_segment_csr_as_the_parent_packs(stack, parts, dim, n_dev, batch):
+    from flink_ml_tpu.lib import common
+
+    want = _parents_segment_csr(parts, dim, n_dev, batch)
+    assert isinstance(stack, common.SparseMinibatchStack)
+    assert not stack.ell_declined
+    assert (stack.steps, stack.mb, stack.nnz_pad, stack.dim) == \
+        (want.steps, want.mb, want.nnz_pad, want.dim)
+    assert stack.ints.tobytes() == want.ints.tobytes()
+    assert stack.floats.tobytes() == want.floats.tobytes()
+
+
+def test_hot_cold_still_reads_a_segment_csr_stack(sparse_counters):
+    from flink_ml_tpu.lib import common
+
+    n_dev = _mesh_devices()
+    parts = _csr_table_parts(np.full(600, 5), 70, 41)  # uniform: ELL's case
+    table = _sparse_table(70, *parts)
+    _sparse_est(70, 16 * n_dev).set_num_hot_features(8).fit(table)
+    stacks = [s for s in _packed(table)
+              if isinstance(s, (common.SparseMinibatchStack,
+                                common.EllMinibatchStack))]
+    (stack,) = stacks
+    _assert_segment_csr_as_the_parent_packs(stack, parts, 70, n_dev,
+                                            16 * n_dev)
+    assert "train.sparse_ell_fits" not in sparse_counters()
+
+
+def test_a_two_d_mesh_still_reads_a_segment_csr_stack(sparse_counters):
+    import jax
+
+    from flink_ml_tpu.parallel.mesh import create_mesh
+    from flink_ml_tpu.utils.environment import MLEnvironmentFactory
+
+    if len(jax.devices()) < 8:
+        pytest.skip("needs 8 devices for a (2, 4) mesh")
+    parts = _csr_table_parts(np.full(600, 5), 72, 43)
+    table = _sparse_table(72, *parts)
+    env = MLEnvironmentFactory.get_default()
+    old = env.get_mesh()
+    env.set_mesh(create_mesh({"data": 2, "model": 4}))
+    try:
+        _sparse_est(72, 32).fit(table)
+    finally:
+        env.set_mesh(old)
+    (stack,) = _packed(table)
+    _assert_segment_csr_as_the_parent_packs(stack, parts, 72, 2, 32)
+    counted = sparse_counters()
+    assert counted["train.sparse_fits"] == 1
+    assert counted["train.sparse_ell_fits"] == 0
+    assert "train.sparse_ell_declined" not in counted
+
+
+def test_the_out_of_core_path_still_packs_segment_csr(monkeypatch):
+    from flink_ml_tpu.lib import common
+    from flink_ml_tpu.lib import out_of_core as oc
+    from flink_ml_tpu.table.sources import ChunkedTable, CollectionSource
+
+    n_dev = _mesh_devices()
+    indptr, indices, values, y = _csr_table_parts(np.full(400, 5), 64, 47)
+    vecs = [SparseVector(64, indices[a:b].astype(np.int64),
+                         values[a:b].astype(np.float64))
+            for a, b in zip(indptr[:-1], indptr[1:])]
+    seen = []
+
+    def recording(*args, **kwargs):
+        stack = common.pack_sparse_minibatches(*args, **kwargs)
+        seen.append((kwargs.get("row_regular", False), stack))
+        return stack
+
+    monkeypatch.setattr(oc, "pack_sparse_minibatches", recording)
+    _sparse_est(64, 8 * n_dev).fit(
+        ChunkedTable(CollectionSource(list(zip(vecs, y)), SCHEMA),
+                     chunk_rows=96))
+    assert seen
+    for asked, stack in seen:
+        assert asked is False
+        assert isinstance(stack, common.SparseMinibatchStack)
+        assert not stack.ell_declined

@@ -202,6 +202,23 @@ def sparse_data(n=3000, dim=500, nnz=8, seed=5):
     return table, vectors, np.asarray(labels), dim
 
 
+def _per_object(table):
+    """``table`` with its CSR column as one ``SparseVector`` a row.  A
+    per-object column packs segment-CSR, byte for byte what the CSR column
+    packed before PR 28 and what the out-of-core chunk program still reads,
+    where the CSR column itself may now take the row-regular step."""
+    return Table.from_rows(table.to_rows(), table.schema)
+
+
+def _packed_row_regular(table):
+    """Whether the fit of ``table`` left a row-regular stack in its pack
+    cache."""
+    from flink_ml_tpu.lib.common import EllMinibatchStack
+
+    return any(isinstance(stack, EllMinibatchStack)
+               for stack in table._pack_cache.values())
+
+
 class TestSparseOutOfCore:
     def make_est(self, dim, iters=4):
         return (
@@ -237,12 +254,45 @@ class TestSparseOutOfCore:
                 )
                 f.write(f"{label:g} {feats}\n")
         source = LibSvmSource(str(path), n_features=dim)
-        in_mem = self.make_est(dim, iters=3).fit(source.read())
         streamed = self.make_est(dim, iters=3).fit(
             ChunkedTable(source, chunk_rows=400)
         )
+        # the stream's step is segment-CSR; the in-memory fit of the same
+        # rows on that step (a per-object column packs it: _per_object)
+        # returns the same bytes
+        in_mem = self.make_est(dim, iters=3).fit(_per_object(source.read()))
         np.testing.assert_array_equal(
             streamed.coefficients(), in_mem.coefficients()
+        )
+        assert streamed.intercept() == in_mem.intercept()
+
+    def test_libsvm_stream_matches_the_row_regular_fit_to_rounding(
+            self, tmp_path):
+        """The materialized CSR column itself takes the row-regular step
+        (PR 28) where the stream keeps segment-CSR: the same update, a
+        row's products summed in another order, so float32 rounding and not
+        the bytes."""
+        table, vectors, labels, dim = sparse_data(n=1500)
+        path = tmp_path / "data.svm"
+        with open(path, "w") as f:
+            for label, v in zip(labels, vectors):
+                feats = " ".join(
+                    f"{int(i) + 1}:{val:.17g}" for i, val in zip(v.indices, v.vals)
+                )
+                f.write(f"{label:g} {feats}\n")
+        source = LibSvmSource(str(path), n_features=dim)
+        materialized = source.read()
+        in_mem = self.make_est(dim, iters=3).fit(materialized)
+        assert _packed_row_regular(materialized)
+        streamed = self.make_est(dim, iters=3).fit(
+            ChunkedTable(source, chunk_rows=400)
+        )
+        np.testing.assert_allclose(
+            streamed.coefficients(), in_mem.coefficients(),
+            rtol=2e-5, atol=1e-7,
+        )
+        np.testing.assert_allclose(
+            streamed.intercept(), in_mem.intercept(), rtol=2e-5, atol=1e-7
         )
 
     def test_chunked_libsvm_requires_dim(self, tmp_path):
@@ -634,11 +684,12 @@ class TestFeatureInteractions:
             .set_label_col("label").set_prediction_col("p")
             .set_num_features(dim).set_learning_rate(0.1)
             .set_global_batch_size(256).set_max_iter(3)
-            .fit(sharded.read())
+            .fit(_per_object(sharded.read()))  # the stream's step: the bytes
         )
         np.testing.assert_array_equal(
             streamed.coefficients(), in_mem.coefficients()
         )
+        assert streamed.intercept() == in_mem.intercept()
 
     def test_2d_mesh_with_spill(self, tmp_path):
         table, vectors, labels, dim = sparse_data(n=1200, dim=500)

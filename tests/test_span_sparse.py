@@ -7,9 +7,13 @@
   ``fit.wall``'s children leave no more unnamed than on the dense route;
 * ``train.sparse_fits``, ``train.sparse_entries`` (stored entries x epochs)
   and ``train.sparse_slots`` (padded width x steps x epochs) count beside
-  ``train.fused_runs``;
-* the sparse train program is ``jit_bundled`` too, and its step carries
-  ``fmt.train.sparse.forward`` and ``fmt.train.sparse.backward``;
+  ``train.fused_runs``; since PR 28 the table's uniform rows take the
+  row-regular step (``train.sparse_ell_fits``; a step's padded width is
+  ``width x mb``), and a warm fit observes the spans the parent's did and
+  no other;
+* the sparse train program is ``jit_bundled`` too, on either step layout,
+  and its step carries ``fmt.train.sparse.forward`` and
+  ``fmt.train.sparse.backward``;
 * the pack's order check, block by block, says what the whole-column check
   said, and lets equal neighbours inside a row stand.
 """
@@ -91,10 +95,13 @@ def test_a_warm_sparse_fit_observes_every_span_once_and_counts_its_entries():
         first["phase.pack_sparse"]["total_s"] > 0
     n_dev = len(MLEnvironmentFactory.get_default().get_mesh().devices.flat)
     steps = -(-ROWS // BATCH)
-    nnz_pad = -(-(BATCH // n_dev) * WIDTH // 512) * 512
+    # rows of one width: the row-regular step, WIDTH x mb slots a block
+    block = WIDTH * (BATCH // n_dev)
     assert counted["train.sparse_fits"] == counted["train.fused_runs"] == 1
+    assert counted["train.sparse_ell_fits"] == 1
+    assert "train.sparse_ell_declined" not in counted
     assert counted["train.sparse_entries"] == ROWS * WIDTH * EPOCHS
-    assert counted["train.sparse_slots"] == nnz_pad * n_dev * steps * EPOCHS
+    assert counted["train.sparse_slots"] == block * n_dev * steps * EPOCHS
     assert counted["train.onepass_fits"] == 0
 
     _logreg().fit(table)  # warm: the cached pack, a pool hit
@@ -106,13 +113,17 @@ def test_a_warm_sparse_fit_observes_every_span_once_and_counts_its_entries():
         assert delta[name][0] == 1, name
     for name in MISS_ONLY:
         assert delta[name][0] == 0, name
+    # the spans the parent's warm fit observed, and no other: the layout's
+    # choice lies inside the cached pack, so a warm fit scans no row
+    assert {k for k, (count, _s) in delta.items() if count} == \
+        set(CHILDREN + ("fit.wall",))
     wall, children = delta["fit.wall"][1], sum(delta[c][1] for c in CHILDREN)
     assert wall >= children > 0
     # what no child names: dispatch, sync and the rest are spans, so the
     # fit's own share is small beside them
     assert wall - children < 0.25 * wall
     assert counted["slab_pool.hits"] == counted["slab_pool.misses"] == 1
-    assert counted["train.sparse_fits"] == 2
+    assert counted["train.sparse_fits"] == counted["train.sparse_ell_fits"] == 2
     assert counted["train.sparse_entries"] == 2 * ROWS * WIDTH * EPOCHS
 
 
@@ -125,16 +136,26 @@ def test_a_repeated_sparse_fit_returns_the_same_bytes():
     assert list(a.train_losses_) == list(b.train_losses_)
 
 
-def test_the_sparse_train_program_carries_its_scopes_and_its_name():
+@pytest.mark.parametrize("layout", ["segment_csr", "row_regular"])
+def test_the_sparse_train_program_carries_its_scopes_and_its_name(layout):
     mesh = MLEnvironmentFactory.get_default().get_mesh()
     n_dev = len(mesh.devices.flat)
     mb, nnz_pad = 64, 512
+    if layout == "segment_csr":
+        stack = common.SparseMinibatchStack(
+            ints=np.zeros((2 * n_dev, 2, nnz_pad), np.int32),
+            floats=np.zeros((2 * n_dev, nnz_pad + 2 * mb), np.float32),
+            steps=2, mb=mb, nnz_pad=nnz_pad, dim=DIM)
+    else:
+        stack = common.EllMinibatchStack(
+            ints=np.zeros((2 * n_dev, WIDTH, mb), np.int32),
+            floats=np.zeros((2 * n_dev, WIDTH + 2, mb), np.float32),
+            steps=2, mb=mb, width=WIDTH, dim=DIM)
     fn = common.make_sparse_glm_train_fn(
-        "logistic", mesh, mb, nnz_pad, DIM, 0.125, 0.0, 3, 0.0)
+        "logistic", mesh, stack, 0.125, 0.0, 3, 0.0)
+    batch = (jnp.asarray(stack.ints), jnp.asarray(stack.floats))
     assert fn.bundle_fetch and fn.loss_hist_len == 3
     params = (jnp.zeros((DIM,), jnp.float32), jnp.zeros((), jnp.float32))
-    batch = (jnp.zeros((2 * n_dev, 2, nnz_pad), jnp.int32),
-             jnp.zeros((2 * n_dev, nnz_pad + 2 * mb), jnp.float32))
     (program,) = [c.cell_contents for c in fn.__closure__
                   if hasattr(c.cell_contents, "lower")]
     lowered = program.lower(params, batch)
