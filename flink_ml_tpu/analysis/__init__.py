@@ -12,7 +12,7 @@ enforced mechanically until now:
   bare in another (dispatcher/prefetch/monitor threads share these
   objects) — :mod:`~flink_ml_tpu.analysis.checkers.lock_discipline`;
 * every ``FMT_*`` environment knob is declared exactly once in
-  :mod:`flink_ml_tpu.utils.knobs` and documented in README/BASELINE.md
+  :mod:`flink_ml_tpu.utils.knobs` and documented in README.md
   — :mod:`~flink_ml_tpu.analysis.checkers.knob_registry`;
 * thread-ambient scopes (``trace.use``, ``quarantine.capture``, drift
   taps) are used only as context managers, and metric names stay

@@ -1,11 +1,10 @@
 """KNOB* — every ``FMT_*`` environment knob is declared once, read through
-:mod:`flink_ml_tpu.utils.knobs`, and documented in README/BASELINE.md.
+:mod:`flink_ml_tpu.utils.knobs`, and documented in README.md.
 
 The declaration table is read *statically* (the literal ``Knob(...)``
 calls in ``utils/knobs.py``), so this checker needs no imports from the
-package under analysis — and it is exactly the code-vs-docs drift gate
-the repo lacked when round 14's BASELINE.md documented 45 of the 50
-knobs the code read.
+package under analysis — and it is the code-vs-docs drift gate: a round
+once documented 45 of the 50 knobs the code read.
 """
 
 from __future__ import annotations
@@ -26,7 +25,7 @@ RULES = {
                "os.getenv) instead of through utils/knobs.py",
     "KNOB002": "knobs getter called with an undeclared FMT_* name",
     "KNOB003": "knob declared in utils/knobs.py but never read (dead knob)",
-    "KNOB004": "knob declared but not documented in README.md/BASELINE.md",
+    "KNOB004": "knob declared but not documented in README.md",
     "KNOB005": "FMT_* name referenced in docs but not declared (doc drift)",
     "KNOB006": "knob declared more than once in utils/knobs.py",
 }
@@ -150,8 +149,7 @@ def check(project: Project) -> Iterator[Finding]:
         if name not in doc_names:
             yield Finding(
                 "KNOB004", KNOBS_REL, line,
-                f"knob {name!r} is declared but documented in neither "
-                f"README.md nor BASELINE.md")
+                f"knob {name!r} is declared but not documented in README.md")
 
     for name, doc_name in sorted(doc_names.items()):
         if name not in declared:

@@ -5,7 +5,7 @@ A checker is a callable ``check(project) -> iterable[Finding]`` plus a
 Checkers get the whole parsed :class:`Project`, not one file at a time,
 because the repo's invariants are cross-file by nature (a knob declared
 in ``utils/knobs.py`` is read in ``serve/breaker.py`` and documented in
-``BASELINE.md``; a metric-name collision is two call sites in two
+``README.md``; a metric-name collision is two call sites in two
 modules).
 
 Suppressions live in the committed ``analysis/baseline.json``::
@@ -38,7 +38,7 @@ REPO_ROOT = os.path.dirname(
 )
 
 #: documentation files the knob checker cross-references
-DOC_FILES = ("README.md", "BASELINE.md")
+DOC_FILES = ("README.md",)
 
 
 @dataclasses.dataclass(frozen=True)

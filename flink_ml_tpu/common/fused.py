@@ -64,7 +64,7 @@ run), ``pipeline.fused_rows``, ``pipeline.plan_fallback_batches``, the
 ``pipeline.fusion_ratio`` gauge (fused stages / total stages), the
 ``pipeline.fused_call_ms`` timing histogram, and the mesh plane:
 ``fused.mesh_devices`` gauge, ``fused.shard_map_dispatches`` counter
-(the proof the sharded path ran — the bench gate's bypass detector),
+(the proof the sharded path ran — the bypass detector),
 ``fused.padded_rows`` per-batch pad accounting, and the per-device
 row-share breakdown ``/statusz`` renders (:func:`mesh_status`).
 
@@ -221,7 +221,7 @@ def _mark_dispatch_warm(plan: str, b: int, width: int,
     """A dispatch whose executable came off the warm-artifact store paid
     no compile: claim its (plan, bucket, mesh, dtype) key WITHOUT a
     ledger line, so the compile-ledger delta of a warm process stays
-    empty — the coldstart bench's core assert."""
+    empty (``tests/test_warmstart.py`` holds it)."""
     name = ("pallas:" + plan) if pallas else plan
     with _COMPILE_LOCK:
         _COMPILE_SEEN.add((name, b, width, dtype))

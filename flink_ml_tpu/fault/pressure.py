@@ -44,7 +44,7 @@ variants), the ``pressure.cap.<surface>`` gauge, flight-recorder events
 for every OOM/shrink/recovery, and a post-hoc ``pressure.recovery``
 trace span on sampled traces.
 
-Knobs (BASELINE.md round-12 table): ``FMT_PRESSURE`` (default on; off
+Knobs (README.md, "Memory-pressure resilience"): ``FMT_PRESSURE`` (default on; off
 restores fail-fast OOM), ``FMT_PRESSURE_PROBE_S`` (default 30).
 Off-path overhead is one state lookup and a try/except per dispatch —
 within the existing <= 2% disabled-overhead contract.

@@ -118,7 +118,7 @@ class RetryPolicy:
 
 def default_policy() -> RetryPolicy:
     """The process default, env-tunable: ``FMT_RETRY_ATTEMPTS`` /
-    ``FMT_RETRY_BASE_S`` (see BASELINE.md's fault-tolerance knob table)."""
+    ``FMT_RETRY_BASE_S`` (README.md, "Fault tolerance")."""
     return RetryPolicy(
         attempts=knobs.knob_int("FMT_RETRY_ATTEMPTS"),
         base_delay_s=knobs.knob_float("FMT_RETRY_BASE_S"),

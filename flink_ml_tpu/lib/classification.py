@@ -1,5 +1,5 @@
-"""LogisticRegression — binary log-loss GLM (BASELINE configs[0], the
-flagship workload of the north star: LogisticRegression.fit samples/sec/chip).
+"""LogisticRegression — binary log-loss GLM (the first configuration
+ROADMAP.md's Reach lists, and the north star's: a click log fitted on a chip).
 
 Labels are {0, 1}. Training is the same data-parallel SGD harness as
 LinearRegression with the logistic gradient; prediction emits the argmax

@@ -1,6 +1,6 @@
-"""KMeans — Lloyd iterations on the device mesh (BASELINE configs[1], k=100).
+"""KMeans — Lloyd iterations on the device mesh (ROADMAP.md, Reach: k=100).
 
-The reference has no KMeans; this is the workload BASELINE.json names, built
+The reference has no KMeans; this is the workload the roadmap names, built
 on the same bounded-iteration + in-step-psum pattern as the GLMs: centroids
 replicated, rows sharded over the ``data`` axis, one epoch = one device call
 computing assignments (argmin over an MXU-friendly x·cᵀ distance matrix) and

@@ -1370,8 +1370,8 @@ class HotColdStack:
     ``(mb, hot_k)`` in bf16 — built once on device — and the forward/
     backward over them are two MXU GEMMs reading the slab at HBM stream
     bandwidth; only the cold tail (a few nnz/row) still pays random access.
-    Measured on v5e: 1.75x the segment-CSR step, 1.3x the strengthened CSR
-    CPU baseline at the bench shape.
+    What each formulation costs on the chip is measured, not reckoned
+    here: ``PERF.md`` §6.
 
     Features are permuted so hot ids occupy [0, hot_k) (slab position =
     feature id) and cold ids [hot_k, dim); ``perm``/``inv_perm`` map

@@ -453,7 +453,7 @@ EVAL_SCHEMA = Schema.of(
 
 def binary_auc(labels: np.ndarray, scores: np.ndarray) -> float:
     """Tie-aware rank AUC: P(score+ > score-) + 0.5 P(tie) — the same
-    statistic the bench harness asserts parity on."""
+    statistic the AUC parity tests compare."""
     labels = np.asarray(labels, dtype=np.float64)
     scores = np.asarray(scores, dtype=np.float64)
     pos = labels > 0.5

@@ -1,4 +1,4 @@
-"""Knn — brute-force k-nearest-neighbors classification (BASELINE configs[3]).
+"""Knn — brute-force k-nearest-neighbors classification (ROADMAP.md, Reach: MNIST Knn).
 
 Model data is the training set itself (vectors + labels), following the
 model-as-table convention.  ``transform`` is the benchmark workload: each

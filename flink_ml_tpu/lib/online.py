@@ -1,5 +1,5 @@
 """Online LogisticRegression — unbounded streaming mini-batch training
-(BASELINE configs[4]).
+(ROADMAP.md, Reach: online LR).
 
 The reference defines this topology but never implements it: the unbounded
 iteration entry point returns null (Iterations.java:87-90) and the

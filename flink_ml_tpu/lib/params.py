@@ -2,7 +2,7 @@
 
 Extends the shared column mixins (params/shared.py, cf.
 flink-ml-lib/.../params/shared/) with the training hyper-parameters the
-BASELINE workloads need.  Same mixin pattern as the reference
+estimators need.  Same mixin pattern as the reference
 (HasSelectedCol.java:33-47): one ParamInfo class attribute + typed accessors
 per interface, composable by inheritance.
 """

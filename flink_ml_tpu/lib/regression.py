@@ -1,4 +1,4 @@
-"""LinearRegression — squared-loss GLM (BASELINE configs[2]).
+"""LinearRegression — squared-loss GLM (ROADMAP.md, Reach: YearPredictionMSD linreg).
 
 The productized form of the reference's only trainer
 (examples-batch/.../LinearRegression.java): the per-record gradient step

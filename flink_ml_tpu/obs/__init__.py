@@ -15,10 +15,10 @@ measurement layer:
     ``obs.enable()`` or ``FMT_OBS=1``.
   * :mod:`flink_ml_tpu.obs.report` — structured JSONL :class:`RunReport`
     records (git SHA, device topology, registry snapshot, StepMetrics
-    summary) written by every ``fit``/bench invocation while obs is on,
-    and the ``python -m flink_ml_tpu.obs`` CLI that diffs the
-    latest bench reports against ``BASELINE.json`` and flags throughput
-    regressions.
+    summary) written by every ``fit`` / ``transform`` / serving run
+    while obs is on, and the ``python -m flink_ml_tpu.obs`` CLI that
+    summarises them (degraded and fault-assisted runs, drift, timing
+    tails).
 
 ``StepMetrics`` (per-step wall/loss/throughput) remains the per-run
 primitive; this package is where its output — and everything else worth
@@ -80,7 +80,6 @@ from flink_ml_tpu.obs.registry import (
 )
 from flink_ml_tpu.obs.report import (
     RunReport,
-    bench_report,
     fit_report,
     git_sha,
     load_reports,
@@ -91,7 +90,6 @@ from flink_ml_tpu.obs.report import (
 __all__ = [
     "MetricsRegistry",
     "RunReport",
-    "bench_report",
     "counter_add",
     "disable",
     "drift",

@@ -1,4 +1,4 @@
-"""``python -m flink_ml_tpu.obs`` — the report diff CLI (+ ``trace``).
+"""``python -m flink_ml_tpu.obs`` — the report summary CLI (+ ``trace``).
 
 The package ``__init__`` imports :mod:`flink_ml_tpu.obs.report`, so running
 ``python -m flink_ml_tpu.obs.report`` makes runpy re-execute an
@@ -14,7 +14,7 @@ dir into one clock-corrected multi-process waterfall with a per-phase
 cost rollup; ``python -m flink_ml_tpu.obs drift`` renders the
 per-column reference-vs-live drift comparison
 (:mod:`flink_ml_tpu.obs.drift`); everything else goes to the report
-differ (``--check`` / ``--json`` / ``--reports`` / ``--baseline``).
+summary (``--check`` / ``--json`` / ``--reports`` / ``--last``).
 """
 
 import sys

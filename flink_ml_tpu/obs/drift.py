@@ -41,7 +41,7 @@ reference-vs-live comparison table from the latest serving/transform
 RunReport; ``obs --check`` prints one ``DRIFT`` line per report whose
 worst column crosses the threshold.
 
-Knobs (BASELINE.md round-14 table): ``FMT_DRIFT``,
+Knobs (README.md, "Data drift observability"): ``FMT_DRIFT``,
 ``FMT_DRIFT_REF_ROWS``, ``FMT_DRIFT_PSI``, ``FMT_DRIFT_WINDOW_S``,
 ``FMT_DRIFT_MIN_ROWS``, ``FMT_DRIFT_MAX_COLS``.
 """

@@ -7,8 +7,7 @@ decision), and timing histograms (count/total/min/max per named phase).
 **Off by default.**  Every hook in a hot path reduces to one module-level
 boolean check when disabled — ``span()`` / ``phase()`` return a shared
 ``contextlib.nullcontext`` and the record functions return immediately —
-so instrumented code pays nothing measurable (the bench contract:
-steady-state samples/sec within 2% of the uninstrumented value).  Enable
+so instrumented code pays nothing measurable.  Enable
 with :func:`enable` or ``FMT_OBS=1`` in the environment.
 
 Phase timers nest: ``phase("fit")`` around ``phase("pack_csr")`` records

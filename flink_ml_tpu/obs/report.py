@@ -1,31 +1,26 @@
-"""Structured JSONL run reports + the BASELINE.json diff CLI.
+"""Structured JSONL run reports + the CLI that summarises them.
 
-Every ``fit``/bench invocation with obs enabled appends one
+Every ``fit``, ``transform`` and serving run with obs enabled appends one
 :class:`RunReport` line to ``<reports dir>/runs.jsonl``: git SHA, device
 topology, the metrics-registry snapshot, the driver's StepMetrics summary,
-and free-form extras.  Round 5's VERDICT found the repo's headline numbers
-"live in commit messages and stray /tmp logs" — this file is where they
-live instead, durable and diffable.
+and free-form extras.
 
 The CLI::
 
-    python -m flink_ml_tpu.obs [--check] [--json] [--last N]
-                               [--reports DIR] [--baseline BASELINE.json]
+    python -m flink_ml_tpu.obs [--check] [--json] [--last N] [--reports DIR]
 
 (``python -m flink_ml_tpu.obs.report`` also works, at the cost of a runpy
 re-execution warning — the package __init__ already imports this module).
 
-diffs the LATEST bench report per metric against the ``measured`` section
-of ``BASELINE.json`` and prints per-metric status; throughput metrics
-(unit contains ``/sec``) that dropped >= ``--threshold`` (default 10%)
-are flagged as regressions, and ``--check`` exits non-zero on any.
-Comparisons are backend-scoped: a CPU-backend run is never diffed against
-a TPU-measured baseline (that delta is the hardware, not the code).
-``--json`` swaps the human text for one machine-readable object
-(per-metric pass/fail, gate direction, margin to the boundary, the
-FAULT-ASSISTED/SERVE-DEGRADED flags, timing tail quantiles) for CI
-annotations; ``python -m flink_ml_tpu.obs trace`` renders a request
-waterfall from the span sink (:mod:`flink_ml_tpu.obs.trace`).
+prints what the reports say went wrong or deserves a look: the
+FAULT-ASSISTED / SERVE-DEGRADED / PALLAS-DEGRADED / WARMSTART-DEGRADED
+flags, DRIFT rows, fmtlint's ANALYSIS line and the TIMING tail
+quantiles.  It is no benchmark and compares no number: speed is measured
+on the chip (``BENCHMARK.json``, ``PERF.md``).  ``--check`` exits
+non-zero when there are no reports to read.  ``--json`` swaps the human
+text for one machine-readable object; ``python -m flink_ml_tpu.obs
+trace`` renders a request waterfall from the span sink
+(:mod:`flink_ml_tpu.obs.trace`).
 """
 
 from __future__ import annotations
@@ -95,15 +90,15 @@ def device_topology() -> dict:
 class RunReport:
     """One telemetry record: everything a run measured, self-describing."""
 
-    kind: str                      # "fit" | "bench" | "import"
-    name: str                      # estimator class or bench metric name
+    kind: str                      # "fit" | "transform" | "serving"
+    name: str                      # estimator, model or server class
     ts: float                      # unix seconds at write time
     git_sha: str
     device: dict                   # device_topology()
     shape: Optional[str] = None    # workload shape, free-form
     metrics: Optional[dict] = None  # registry snapshot (counters/gauges/timings)
     step_summary: Optional[dict] = None  # StepMetrics.summary()
-    extra: Optional[dict] = None   # per-kind payload (bench record, epochs, ...)
+    extra: Optional[dict] = None   # per-kind payload (epochs, pool delta, ...)
 
     def to_dict(self) -> dict:
         # shallow: the one caller serialises it at once, and asdict's deep
@@ -134,7 +129,7 @@ def write_run_report(report: RunReport, directory: Optional[str] = None) -> str:
 
 #: registry state already attributed to an earlier fit RunReport — fit
 #: reports carry the DELTA since the previous fit, so a process running
-#: several fits (every bench workload does) never misattributes earlier
+#: several fits (a hyper-parameter sweep does) never misattributes earlier
 #: fits' counters to a later one
 _PREV_FIT_SNAPSHOT: dict = {"counters": {}, "timings": {}}
 _PREV_FIT_RESET_GEN = 0
@@ -212,8 +207,8 @@ def _build_report(kind: str, name: str, shape=None, step_metrics=None,
                     summary[k] = last[k]
         except Exception:  # noqa: BLE001 - never fail a fit over telemetry
             summary = None
-    # fit reports scope metrics to the fit itself; bench reports keep the
-    # whole workload's since-reset snapshot (bench_all resets per workload)
+    # fit reports scope metrics to the fit itself; the other kinds keep
+    # the registry's whole since-reset snapshot
     metrics = (
         _fit_delta_snapshot() if kind == "fit"
         else _obs_registry().snapshot()
@@ -560,22 +555,6 @@ def _timing_lines(summary: Dict[str, dict]) -> List[str]:
     return lines
 
 
-def bench_report(record: dict, directory: Optional[str] = None) -> Optional[str]:
-    """Write a ``bench`` RunReport from one bench_all result record."""
-    if not _obs_enabled():
-        return None
-    try:
-        return write_run_report(
-            _build_report(
-                "bench", str(record.get("metric", "unknown")),
-                shape=record.get("shape"), extra=record,
-            ),
-            directory,
-        )
-    except Exception:  # noqa: BLE001
-        return None
-
-
 def load_reports(directory: Optional[str] = None) -> List[dict]:
     """All RunReport dicts from ``runs.jsonl`` (empty list when absent)."""
     path = _runs_path(directory)
@@ -590,101 +569,8 @@ def load_reports(directory: Optional[str] = None) -> List[dict]:
     return out
 
 
-def latest_bench_by_name(reports: List[dict]) -> Dict[str, dict]:
-    """Last bench-kind report per metric name (file order == time order)."""
-    latest: Dict[str, dict] = {}
-    for r in reports:
-        if r.get("kind") == "bench":
-            latest[r.get("name", "")] = r
-    return latest
-
-
-def _bench_value(report: dict):
-    extra = report.get("extra") or {}
-    return extra.get("value"), extra.get("unit", "")
-
-
-def diff_against_baseline(reports: List[dict], baseline: dict,
-                          threshold: float = 0.10) -> List[dict]:
-    """Compare latest bench reports to ``baseline["measured"]``.
-
-    Returns one row per baseline metric: ``status`` is ``regression`` when
-    a throughput metric (unit contains ``/sec``) dropped more than
-    ``threshold`` relative to baseline, ``improved`` when it rose that
-    much, ``ok`` within the band, ``no-report`` / ``backend-mismatch``
-    when not comparable.
-
-    A baseline entry may carry ``"direction": "lower"`` for
-    lower-is-better metrics (latencies, the warm-fit ``warm_over_cold``
-    ratio): there a RISE beyond ``threshold`` is the regression and a drop
-    the improvement — the warm-fit CI gate (ISSUE 2) rides this."""
-    measured = baseline.get("measured", {})
-    latest = latest_bench_by_name(reports)
-    rows = []
-    for name, base in sorted(measured.items()):
-        row = {
-            "metric": name,
-            "baseline": base.get("value"),
-            "unit": base.get("unit", ""),
-            "backend": base.get("backend", ""),
-        }
-        rep = latest.get(name)
-        if rep is None:
-            row.update(status="no-report", latest=None, ratio=None)
-            rows.append(row)
-            continue
-        rep_backend = (rep.get("device") or {}).get("backend")
-        if base.get("backend") and rep_backend != base.get("backend"):
-            row.update(status="backend-mismatch", latest=None, ratio=None,
-                       report_backend=rep_backend)
-            rows.append(row)
-            continue
-        value, unit = _bench_value(rep)
-        base_value = base.get("value")
-        # only a missing latest value or an unusable (zero/absent) baseline
-        # denominator skips the comparison — a latest value of 0.0 against
-        # a nonzero baseline is the WORST regression, not "no value"
-        if value is None or not base_value:
-            row.update(status="no-value", latest=value, ratio=None)
-            rows.append(row)
-            continue
-        ratio = float(value) / float(base_value)
-        lower_better = base.get("direction") == "lower"
-        throughput = "/sec" in (unit or base.get("unit", ""))
-        # direction + margin make the row machine-consumable (--json):
-        # margin is the slack (in ratio units) before the row would flag
-        # as a regression — positive means inside the band, negative by
-        # how much the gate was blown
-        if lower_better:
-            direction = "lower"
-            margin = (1.0 + threshold) - ratio
-        elif throughput:
-            direction = "higher"
-            margin = ratio - (1.0 - threshold)
-        else:
-            direction = None
-            margin = None
-        if lower_better and ratio > 1.0 + threshold:
-            status = "regression"
-        elif lower_better and ratio < 1.0 - threshold:
-            status = "improved"
-        elif throughput and ratio < 1.0 - threshold:
-            status = "regression"
-        elif throughput and ratio > 1.0 + threshold:
-            status = "improved"
-        else:
-            status = "ok"
-        row.update(status=status, latest=value, ratio=round(ratio, 3),
-                   direction=direction,
-                   margin=round(margin, 4) if margin is not None else None,
-                   git_sha=rep.get("git_sha"))
-        rows.append(row)
-    return rows
-
-
 #: per-fit counters that mean the run leaned on the fault layer to pass —
-#: surfaced by ``--check`` so a chronically-retrying deployment is visible
-#: in the same place as a throughput regression
+#: surfaced by the CLI so a chronically-retrying deployment is visible
 _FAULT_COUNTER_PREFIXES = (
     "fault.retries", "fault.rollbacks", "fault.fallbacks",
     "fault.emergency_checkpoints", "fault.spill_rebuilds", "fault.giveups",
@@ -697,14 +583,13 @@ def fault_assisted_runs(reports: List[dict]) -> List[dict]:
     PASSED, but only because something recovered — a fleet where these
     trend up is degrading before it starts failing.
 
-    Only the LATEST fit report per name is judged (mirroring
-    :func:`latest_bench_by_name`): runs.jsonl is append-only, and
-    re-printing every historical fault-assisted fit forever would bury
-    the current signal under runs long since fixed.  Runs whose delta
-    also carries ``fault.injected`` are marked ``injected: True``: those
-    faults were deliberate chaos (a chaos-smoke or test run), not
-    environment degradation, and the CLI labels them so they never bury
-    the real signal."""
+    Only the LATEST fit report per name is judged: runs.jsonl is
+    append-only, and re-printing every historical fault-assisted fit
+    forever would bury the current signal under runs long since fixed.
+    Runs whose delta also carries ``fault.injected`` are marked
+    ``injected: True``: those faults were deliberate chaos (a chaos-smoke
+    or test run), not environment degradation, and the CLI labels them so
+    they never bury the real signal."""
     latest_fit: Dict[str, dict] = {}
     for r in reports:
         if r.get("kind") == "fit":
@@ -729,43 +614,35 @@ def fault_assisted_runs(reports: List[dict]) -> List[dict]:
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(
         prog="python -m flink_ml_tpu.obs",
-        description="Diff the latest committed bench reports against "
-                    "BASELINE.json and flag throughput regressions.",
+        description="Summarise the RunReports: degraded and "
+                    "fault-assisted runs, drift, timing tails.",
     )
     parser.add_argument("--reports", default=None,
                         help="reports directory (default: repo reports/)")
-    parser.add_argument("--baseline",
-                        default=os.path.join(_REPO_ROOT, "BASELINE.json"))
-    parser.add_argument("--threshold", type=float, default=0.10,
-                        help="relative drop that counts as a regression")
     parser.add_argument("--check", action="store_true",
-                        help="exit 1 when any regression is flagged")
+                        help="exit 1 when there are no reports to read")
     parser.add_argument("--last", type=int, default=0, metavar="N",
-                        help="diff only the newest N RunReports (0 = all) "
+                        help="read only the newest N RunReports (0 = all) "
                              "— bounds the cost of an append-only "
                              "runs.jsonl that has grown for months")
     parser.add_argument("--json", action="store_true",
                         help="emit ONE machine-readable JSON object "
-                             "(per-metric pass/fail, direction, margin) "
-                             "instead of the human text — for CI "
-                             "annotations; exit semantics unchanged")
+                             "instead of the human text; exit semantics "
+                             "unchanged")
     args = parser.parse_args(argv)
 
-    with open(args.baseline) as f:
-        baseline = json.load(f)
     reports = load_reports(args.reports)
     if not reports:
         # a missing/empty reports dir is an operator mistake (wrong path,
-        # FMT_OBS never enabled), not a clean diff: one diagnostic line,
-        # never a traceback, and --check fails on it
+        # FMT_OBS never enabled), not a clean summary: one diagnostic
+        # line, never a traceback, and --check fails on it
         where = args.reports or reports_dir()
         msg = (f"obs --check: no RunReports under {where} (runs.jsonl "
-               "missing or empty) — run a fit or bench with FMT_OBS=1, "
-               "or point --reports at the right directory")
+               "missing or empty) — run a fit or a transform with "
+               "FMT_OBS=1, or point --reports at the right directory")
         if args.json:
             print(json.dumps({"ok": not args.check, "check": bool(args.check),
-                              "error": msg, "baselined": 0, "comparable": 0,
-                              "regressions": 0, "metrics": []},
+                              "error": msg},
                              sort_keys=True, indent=1))
         else:
             print(msg)
@@ -779,26 +656,12 @@ def main(argv=None) -> int:
     drift_rows = drift_runs(reports)
     analysis = analysis_summary(args.reports)
     timing_summary = timing_quantile_summary(reports)
-    rows = diff_against_baseline(reports, baseline, args.threshold)
-    regressions = sum(r["status"] == "regression" for r in rows)
-    n_cmp = sum(r["status"] in ("ok", "improved", "regression") for r in rows)
-    # a gate that silently compares nothing stays green forever — when
-    # baselines exist but NOTHING was diffed (renamed metrics, missing
-    # reports, backend drift), --check fails loudly instead
-    nothing_comparable = bool(rows) and n_cmp == 0
-    failed = bool(args.check and (regressions or nothing_comparable))
 
     if args.json:
         print(json.dumps({
-            "ok": not failed,
+            "ok": True,
             "check": bool(args.check),
-            "threshold": args.threshold,
-            "baseline": args.baseline,
-            "regressions": regressions,
-            "comparable": n_cmp,
-            "baselined": len(rows),
-            "nothing_comparable": nothing_comparable,
-            "metrics": rows,
+            "reports": len(reports),
             "fault_assisted": fault_assisted,
             "serve_degraded": serve_degraded,
             "pallas_degraded": pallas_degraded,
@@ -807,7 +670,7 @@ def main(argv=None) -> int:
             "analysis": analysis,
             "timings": timing_summary,
         }, sort_keys=True, indent=1))
-        return 1 if failed else 0
+        return 0
 
     # static-analysis state, when fmtlint's --check has left a report —
     # same visibility rule as the FAULT-ASSISTED/SERVE-DEGRADED/DRIFT
@@ -823,8 +686,8 @@ def main(argv=None) -> int:
               f"{analysis.get('suppressed', 0)} suppressed, "
               f"{analysis.get('files_scanned', 0)} files{detail}")
 
-    # fault-assisted fits are flagged alongside the perf diff: a run that
-    # only passed by retrying is one environment blip from not passing
+    # a run that only passed by retrying is one environment blip from
+    # not passing
     for fr in fault_assisted:
         counters = ", ".join(
             f"{k}={v:g}" for k, v in sorted(fr["fault_counters"].items())
@@ -876,30 +739,11 @@ def main(argv=None) -> int:
                   f"{dr['worst_column']} psi={dr['psi']:g} "
                   f"ks={dr['ks']:g} (threshold {dr['threshold']:g}) "
                   f"{verdict}")
-    # tail-quantile lines for the latest fit/transform per name: the p99
-    # lives next to the throughput gate it explains
+    # tail-quantile lines for the latest fit/transform per name
     for line in _timing_lines(timing_summary):
         print(line)
-    if not rows:
-        print("no measured baselines in"
-              f" {args.baseline} — nothing to diff (record bench runs via"
-              " bench_all.py, then add them to BASELINE.json 'measured')")
-        return 0
-    width = max(len(r["metric"]) for r in rows)
-    for r in rows:
-        ratio = f"{r['ratio']:.3f}x" if r.get("ratio") is not None else "-"
-        latest = (f"{r['latest']:.6g}" if r.get("latest") is not None
-                  else "-")
-        base = (f"{r['baseline']:.6g}" if r.get("baseline") is not None
-                else "-")
-        print(f"{r['metric']:<{width}}  base={base:<12} latest={latest:<12} "
-              f"{ratio:<8} [{r['backend'] or 'any'}] {r['status']}")
-    print(f"\n{len(rows)} baselined metric(s), {n_cmp} comparable, "
-          f"{regressions} regression(s) at >{args.threshold:.0%} drop")
-    if nothing_comparable and args.check:
-        print("check FAILED: baselined metrics exist but none were "
-              "comparable — metric names, reports/, or backend drifted")
-    return 1 if failed else 0
+    print(f"{len(reports)} RunReport(s) read")
+    return 0
 
 
 if __name__ == "__main__":

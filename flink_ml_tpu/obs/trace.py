@@ -40,8 +40,7 @@ Design rules, in the obs-registry tradition:
 * **Off by default, one-bool hooks.**  ``span()`` returns a shared
   ``nullcontext`` after a single module-bool check when tracing is off,
   and again when no trace is active on the calling thread — instrumented
-  hot paths pay nothing measurable (the serving bench asserts the <= 2%
-  disabled-overhead contract, BASELINE.json round 11).  Enable with
+  hot paths pay nothing measurable.  Enable with
   ``FMT_TRACE=1`` or :func:`enable`.
 * **Head sampling.**  ``FMT_TRACE_SAMPLE`` (0..1, default 1.0) decides at
   trace-mint time; an unsampled request carries no context and every
@@ -71,7 +70,7 @@ Design rules, in the obs-registry tradition:
   ``reports/compile_ledger.jsonl`` — the per-rung cost table ROADMAP
   item 2's AOT warm-start needs as its before/after evidence.
 
-Knobs (BASELINE.md round-11 and round-19 tables): ``FMT_TRACE``,
+Knobs (README.md, "Tracing & flight recorder"): ``FMT_TRACE``,
 ``FMT_TRACE_SAMPLE``, ``FMT_TRACE_DIR``, ``FMT_TRACE_TAIL``,
 ``FMT_TRACE_SLOW_MS``, ``FMT_TRACE_MAX_MB``.
 """

@@ -3,7 +3,7 @@
 The reference's training round is: per-subtask gradient map, network-shuffle
 ``reduce`` to one node, divide by count, re-broadcast
 (LinearRegression.java:113-121, UpdateAccumulator:235-246).  The TPU-native
-replacement (BASELINE.json north star) keeps everything inside one jitted
+replacement (ROADMAP.md's north star) keeps everything inside one jitted
 step: local grads on each mesh slice, ``pmean`` over the ``data`` axis riding
 ICI, parameters updated replicated — no host round-trip, no reduce node.
 """

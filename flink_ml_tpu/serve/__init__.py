@@ -26,7 +26,7 @@ prints ``SERVE-DEGRADED`` for transforms that only completed via
 fallback.  Chaos entry point: ``python scripts/chaos_smoke.py --serve``
 (CI job ``chaos-smoke``).
 
-Knobs (BASELINE.md round-8 table): ``FMT_SERVE_QUARANTINE``,
+Knobs (README.md, "Serving robustness"): ``FMT_SERVE_QUARANTINE``,
 ``FMT_SERVE_QUARANTINE_CAP``, ``FMT_SERVE_DEADLINE_MS``,
 ``FMT_SERVE_BREAKER_THRESHOLD``, ``FMT_SERVE_BREAKER_COOLDOWN_S``.
 """

@@ -47,10 +47,7 @@ hysteresis + cooldown flap protection, and a preemption-aware
 warm-spares mode (``FMT_SCALE_WARM_SPARES``) so SIGTERM storms never
 drop serving capacity below target.
 
-Entry points: ``bench_all.py serving`` (the >=3x dynamic-batching gate),
-``bench_all.py router`` (the <=1.25x router-overhead gate),
-``bench_all.py autoscale`` (the <=1.05x idle-controller gate),
-``python scripts/chaos_smoke.py --serving`` / ``--router`` /
+Entry points: ``python scripts/chaos_smoke.py --serving`` / ``--router`` /
 ``--autoscale`` (shed / hot-swap / corrupt-deploy / replica-kill /
 elastic-ramp legs), ``examples/online_serving.py``,
 ``examples/router_serving.py``.

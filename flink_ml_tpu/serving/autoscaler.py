@@ -48,7 +48,7 @@ carrying the triggering signal snapshot, an ``autoscaler`` section on
 ``/statusz``, and a decision span on the fleet trace timeline per
 scale action.
 
-Knobs (BASELINE.md round-22 table): ``FMT_SCALE_MIN``,
+Knobs (README.md, "Elastic fleet"): ``FMT_SCALE_MIN``,
 ``FMT_SCALE_MAX``, ``FMT_SCALE_UP_BURN``, ``FMT_SCALE_DOWN_BURN``,
 ``FMT_SCALE_WINDOW_S``, ``FMT_SCALE_IDLE_WINDOWS``,
 ``FMT_SCALE_COOLDOWN_S``, ``FMT_SCALE_WARM_SPARES``.

@@ -62,14 +62,13 @@ bit-identically (subprocess-tested).
 Counters: ``lifecycle.candidates`` / ``lifecycle.swaps`` /
 ``lifecycle.blocked`` (+ ``.{reason}``) / ``lifecycle.rollbacks`` /
 ``lifecycle.trainer_resets`` / ``lifecycle.emergency_candidates``.
-Knobs (BASELINE.md round-17): ``FMT_LIFECYCLE_EVERY_WINDOWS``,
+Knobs (README.md, "Continuous learning"): ``FMT_LIFECYCLE_EVERY_WINDOWS``,
 ``FMT_LIFECYCLE_REGRESSION_TOL``, ``FMT_LIFECYCLE_SCORE_PSI``,
 ``FMT_LIFECYCLE_PROBATION_S``, ``FMT_LIFECYCLE_HISTORY``,
 ``FMT_LIFECYCLE_DIR``.
 
 Entry points: ``scripts/chaos_smoke.py --online`` (poisoned burst /
-drift-burn rollback / multi-swap loop legs), ``bench_all.py
-online_loop`` (the <= 1.05 controller-attached overhead gate),
+drift-burn rollback / multi-swap loop legs),
 ``tests/test_lifecycle.py``.
 """
 

@@ -71,7 +71,7 @@ a slot whose replica dies ``FMT_ROUTER_CRASHLOOP_MAX`` times inside
 backoff (a ``router.crashloop`` flight dump names the slot and exit
 status) instead of hot-loop respawning.
 
-Knobs (BASELINE.md round-16 table): ``FMT_ROUTER_REPLICAS``,
+Knobs (README.md, "Replica router"): ``FMT_ROUTER_REPLICAS``,
 ``FMT_ROUTER_POLL_MS``, ``FMT_ROUTER_QUEUE_CAP``,
 ``FMT_ROUTER_DISPATCH_THREADS``, ``FMT_ROUTER_RETRIES``,
 ``FMT_ROUTER_SPAWN_TIMEOUT_S``, ``FMT_ROUTER_DRAIN_TIMEOUT_S``; the

@@ -74,7 +74,7 @@ quarantine boundary and output scores at demux, and feeds the third
 ``/readyz`` entry, per-column ``/statusz``, and ``drift_breach``
 black boxes.
 
-Knobs (BASELINE.md round-10/12/13/14 tables): ``FMT_SERVING_MAX_BATCH``,
+Knobs (README.md, "Online serving" and after): ``FMT_SERVING_MAX_BATCH``,
 ``FMT_SERVING_MAX_WAIT_MS``, ``FMT_SERVING_QUEUE_CAP``,
 ``FMT_SERVING_QUEUE_CAP_MB``, ``FMT_SERVING_DEADLINE_MS``,
 ``FMT_SERVING_SHED_ON_BREAKER``, ``FMT_TELEMETRY_PORT``,

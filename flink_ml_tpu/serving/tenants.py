@@ -31,7 +31,7 @@ module is the control-plane half of multi-tenant serving:
   per tenant, a top-N-by-traffic table for ``/statusz``, and the
   ``FMT_TENANT_QUOTA_ROWS`` quota the server's admission door enforces.
 
-Knobs (BASELINE.md round-23 table): ``FMT_TENANT_MAX_RESIDENT``,
+Knobs (README.md, "Multi-tenant serving"): ``FMT_TENANT_MAX_RESIDENT``,
 ``FMT_TENANT_QUOTA_ROWS``, ``FMT_TENANT_MUX``.
 """
 

@@ -1,8 +1,8 @@
 """Central declaration table for every ``FMT_*`` environment knob.
 
 Eleven PRs grew ~50 ``FMT_*`` environment variables, each parsed ad hoc
-at its point of use — and the documentation drifted (BASELINE.md round
-14 documented 45 of the 50 the code actually read).  This module is the
+at its point of use — and the documentation drifted (round 14's notes
+documented 45 of the 50 the code actually read).  This module is the
 single source of truth the static analyzer (``flink_ml_tpu.analysis``,
 rule family KNOB*) enforces:
 
@@ -11,8 +11,8 @@ rule family KNOB*) enforces:
 * every runtime read goes through the typed getters below (this module
   owns the only ``os.environ`` read of an ``FMT_*`` name in the
   package);
-* the analyzer cross-references the declarations against README.md and
-  BASELINE.md, so an undocumented knob — or a documented-but-deleted
+* the analyzer cross-references the declarations against README.md,
+  so an undocumented knob — or a documented-but-deleted
   one — is a CI failure, not a silent drift.
 
 Parsing semantics (shared by every knob so no two call sites can

@@ -20,7 +20,7 @@ import ast
 import sys
 from pathlib import Path
 
-ROOTS = ["flink_ml_tpu", "tests", "examples", "scripts", "bench_all.py", "bench.py", "__graft_entry__.py"]
+ROOTS = ["flink_ml_tpu", "tests", "examples", "scripts", "__graft_entry__.py"]
 
 # Names intentionally imported for re-export or side effects.
 REEXPORT_FILES = {"__init__.py", "conftest.py"}

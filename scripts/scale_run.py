@@ -5,7 +5,7 @@ out-of-core sparse LogisticRegression fit with spill on, on the LOCAL CPU
 backend (transfer is a memcpy, RSS is meaningful).
 Reports one JSON line: steady-epoch throughput (two-point method), first
 epoch (parse+spill) wall, peak RSS, spill volume, and the engine's
-live-block bound.  Replaces BASELINE's 317 MB smoke as the measured point
+live-block bound.  It is the point
 between "fits in RAM" and "larger than any host" — the engine streams
 blocks whose count per epoch scales with the file, while host residency
 stays bounded by the prefetch/in-flight caps regardless of file size.

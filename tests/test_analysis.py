@@ -44,7 +44,7 @@ def synth_project(sources, docs=None):
     modules = [Module(path="/" + rel, rel=rel, tree=ast.parse(src),
                       source=src)
                for rel, src in sources.items()]
-    return Project("/", modules, docs or {"README.md": "", "BASELINE.md": ""})
+    return Project("/", modules, docs or {"README.md": ""})
 
 
 class TestKnobsModule:
@@ -168,8 +168,7 @@ class TestKnobChecker:
         project = synth_project(
             {"flink_ml_tpu/utils/knobs.py": knobs_src,
              "flink_ml_tpu/reader.py": reader},
-            docs={"README.md": "`FMT_ALPHA` and `FMT_GONE`",
-                  "BASELINE.md": ""})
+            docs={"README.md": "`FMT_ALPHA` and `FMT_GONE`"})
         findings = run_checkers(project, CHECKERS)
         rules = {(f.rule, f.message.split("'")[1]) for f in findings
                  if f.rule.startswith("KNOB")}

@@ -161,7 +161,7 @@ class TestLogisticRegression:
     def test_auc_parity_with_numpy_reference(self):
         """AUC of the device-trained model matches a plain-numpy full-batch GD
         implementation of the same optimization (the 'identical AUC' criterion
-        of the north star, BASELINE.md)."""
+        of the north star, ROADMAP.md)."""
         t = logreg_data(300, seed=7)
         lr, iters = 0.5, 120
         model = (

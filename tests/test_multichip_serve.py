@@ -13,8 +13,7 @@ device_count={8,1}``) because the device count pins at backend init:
 the parent fits + saves the models once (model files are the
 cross-process contract — both workers load identical bytes) and each
 worker transforms identical deterministic tables; this module compares
-their emitted results.  Until this PR, multi-chip correctness was only
-exercised by scripts/scale_run.py dry-runs outside tier-1.
+their emitted results.
 """
 
 import json
@@ -138,6 +137,8 @@ class TestMultichipServeParity:
         """The 8-device worker must have dispatched through shard_map
         (the CSR bypass is gone); the 1-device worker must not have."""
         assert results[8]["shard_map_dispatches"] > 0, results[8]
+        assert (results[8]["shard_map_dispatches"]
+                == results[8]["fused_dispatches"]), results[8]
         assert results[1]["shard_map_dispatches"] == 0, results[1]
         assert results[8]["fused_dispatches"] > 0
         assert results[8]["plan_fallbacks"] == 0, (

@@ -1,6 +1,6 @@
 """Unified run-telemetry subsystem tests (ISSUE 1): registry semantics,
 off-by-default zero-cost hooks, RunReport JSONL persistence, and the
-BASELINE.json diff CLI — plus the hot-path wiring (a tiny fit with obs on
+report summary CLI — plus the hot-path wiring (a tiny fit with obs on
 must leave a parseable report with the compile/steady split recorded)."""
 
 import json
@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from flink_ml_tpu import obs
-from flink_ml_tpu.obs.report import diff_against_baseline, main as report_main
+from flink_ml_tpu.obs.report import main as report_main
 
 
 @pytest.fixture(autouse=True)
@@ -210,10 +210,9 @@ class TestRunReports:
         assert b["metrics"]["counters"]["c"] == 3
 
     def test_fit_delta_detects_reset_even_at_equal_totals(self, tmp_path):
-        """bench_all's per-workload obs.reset() must not make a later
-        workload's fit report drop counters whose post-reset totals land
-        exactly on the pre-reset ones (one fused fit per workload is the
-        COMMON case)."""
+        """An obs.reset() between two jobs must not make the later job's
+        fit report drop counters whose post-reset totals land exactly on
+        the pre-reset ones (one fused fit per job is the COMMON case)."""
         obs.enable()
         obs.counter_add("train.fused_runs")
         obs.fit_report("A", directory=str(tmp_path))
@@ -226,18 +225,6 @@ class TestRunReports:
     def test_fit_report_noop_when_disabled(self, tmp_path):
         assert obs.fit_report("X", directory=str(tmp_path)) is None
         assert obs.load_reports(str(tmp_path)) == []
-
-    def test_bench_report_records_the_record(self, tmp_path):
-        obs.enable()
-        obs.bench_report(
-            {"metric": "m1", "value": 10.0, "unit": "rows/sec",
-             "shape": "tiny"},
-            directory=str(tmp_path),
-        )
-        (r,) = obs.load_reports(str(tmp_path))
-        assert r["kind"] == "bench"
-        assert r["name"] == "m1"
-        assert r["extra"]["value"] == 10.0
 
     def test_tiny_fit_emits_parseable_report(self, tmp_path, monkeypatch):
         """The CI smoke contract: a fit with obs enabled writes one JSONL
@@ -274,207 +261,62 @@ class TestRunReports:
         assert r["step_summary"] is not None
 
 
-def _baseline(tmp_path, measured):
-    p = tmp_path / "BASELINE.json"
-    p.write_text(json.dumps({"measured": measured}))
-    return str(p)
-
-
-def _reports(tmp_path, records):
+def _fit_reports(tmp_path, names, retried=()):
+    """One ``fit`` RunReport per name, in order; those in ``retried``
+    carry a retry on their account (a FAULT-ASSISTED fit)."""
     obs.enable()
-    d = tmp_path / "reports"
-    for rec in records:
-        obs.bench_report(rec, directory=str(d))
-    return str(d)
+    d = str(tmp_path / "reports")
+    for name in names:
+        if name in retried:
+            obs.counter_add("fault.retries")
+        obs.fit_report(name, directory=d)
+    return d
 
 
-class TestBaselineDiff:
-    def test_regression_improved_ok_and_missing(self, tmp_path):
-        import jax
-
-        backend = jax.default_backend()
-        d = _reports(tmp_path, [
-            {"metric": "a", "value": 80.0, "unit": "rows/sec"},
-            {"metric": "b", "value": 100.0, "unit": "rows/sec"},
-            {"metric": "c", "value": 130.0, "unit": "rows/sec"},
-        ])
-        rows = diff_against_baseline(
-            obs.load_reports(d),
-            {"measured": {
-                "a": {"value": 100.0, "unit": "rows/sec", "backend": backend},
-                "b": {"value": 100.0, "unit": "rows/sec", "backend": backend},
-                "c": {"value": 100.0, "unit": "rows/sec", "backend": backend},
-                "d": {"value": 1.0, "unit": "rows/sec", "backend": backend},
-            }},
-        )
-        status = {r["metric"]: r["status"] for r in rows}
-        assert status == {"a": "regression", "b": "ok", "c": "improved",
-                          "d": "no-report"}
-
-    def test_lower_is_better_direction_gates_latency_metrics(self, tmp_path):
-        """The warm-fit gate (ISSUE 2): a baseline entry with
-        direction='lower' flags a RISE as the regression — warm_over_cold
-        drifting toward 1.0 must fail --check even though no '/sec' unit
-        is involved."""
-        import jax
-
-        backend = jax.default_backend()
-        d = _reports(tmp_path, [
-            {"metric": "warm", "value": 0.7, "unit": "ratio"},
-            {"metric": "fast", "value": 0.2, "unit": "ratio"},
-            {"metric": "steady", "value": 0.52, "unit": "ratio"},
-        ])
-        base = {
-            "value": 0.5, "unit": "ratio", "direction": "lower",
-            "backend": backend,
-        }
-        rows = diff_against_baseline(
-            obs.load_reports(d),
-            {"measured": {"warm": dict(base), "fast": dict(base),
-                          "steady": dict(base)}},
-        )
-        status = {r["metric"]: r["status"] for r in rows}
-        assert status == {"warm": "regression", "fast": "improved",
-                          "steady": "ok"}
-
-    def test_zero_throughput_is_a_regression_not_no_value(self, tmp_path):
-        import jax
-
-        d = _reports(tmp_path, [
-            {"metric": "a", "value": 0.0, "unit": "rows/sec"},
-        ])
-        (row,) = diff_against_baseline(
-            obs.load_reports(d),
-            {"measured": {"a": {"value": 100.0, "unit": "rows/sec",
-                                "backend": jax.default_backend()}}},
-        )
-        # a collapse to zero is the worst regression; it must not slip
-        # through the --check gate as "no-value"
-        assert row["status"] == "regression" and row["ratio"] == 0.0
-
-    def test_backend_scoping_skips_foreign_measurements(self, tmp_path):
-        d = _reports(tmp_path, [
-            {"metric": "a", "value": 1.0, "unit": "rows/sec"},
-        ])
-        (row,) = diff_against_baseline(
-            obs.load_reports(d),
-            {"measured": {"a": {"value": 1e9, "unit": "rows/sec",
-                                "backend": "tpu"}}},
-        )
-        # a CPU-backend run never diffs against a TPU baseline
-        assert row["status"] == "backend-mismatch"
-
-    def test_latest_report_wins(self, tmp_path):
-        import jax
-
-        d = _reports(tmp_path, [
-            {"metric": "a", "value": 10.0, "unit": "rows/sec"},
-            {"metric": "a", "value": 100.0, "unit": "rows/sec"},
-        ])
-        (row,) = diff_against_baseline(
-            obs.load_reports(d),
-            {"measured": {"a": {"value": 100.0, "unit": "rows/sec",
-                                "backend": jax.default_backend()}}},
-        )
-        assert row["status"] == "ok" and row["latest"] == 100.0
-
-    def test_cli_check_exit_codes(self, tmp_path, capsys):
-        import jax
-
-        backend = jax.default_backend()
-        d = _reports(tmp_path, [
-            {"metric": "a", "value": 50.0, "unit": "rows/sec"},
-        ])
-        base_bad = _baseline(
-            tmp_path, {"a": {"value": 100.0, "unit": "rows/sec",
-                             "backend": backend}}
-        )
-        assert report_main(["--reports", d, "--baseline", base_bad,
-                            "--check"]) == 1
-        assert "regression" in capsys.readouterr().out
-        # within the band -> exit 0
-        base_ok = str(tmp_path / "ok.json")
-        with open(base_ok, "w") as f:
-            json.dump({"measured": {"a": {"value": 52.0, "unit": "rows/sec",
-                                          "backend": backend}}}, f)
-        assert report_main(["--reports", d, "--baseline", base_ok,
-                            "--check"]) == 0
-
-    def test_cli_check_fails_when_nothing_comparable(self, tmp_path, capsys):
-        # baselines exist but no report matches (renamed metric / backend
-        # drift): the gate must fail loudly, not stay green on nothing
-        d = _reports(tmp_path, [
-            {"metric": "renamed", "value": 5.0, "unit": "rows/sec"},
-        ])
-        base = _baseline(
-            tmp_path, {"old-name": {"value": 5.0, "unit": "rows/sec",
-                                    "backend": "cpu"}}
-        )
-        assert report_main(["--reports", d, "--baseline", base,
-                            "--check"]) == 1
-        assert "none were comparable" in capsys.readouterr().out
-        # without --check it stays informational
-        assert report_main(["--reports", d, "--baseline", base]) == 0
-
-    def test_cli_empty_baseline_is_not_an_error(self, tmp_path, capsys):
-        # reports exist; the baseline just has no measured section yet
-        d = _reports(tmp_path, [
-            {"metric": "m", "value": 1.0, "unit": "rows/sec"},
-        ])
-        base = _baseline(tmp_path, {})
-        assert report_main(["--reports", d, "--baseline",
-                            base, "--check"]) == 0
-        assert "nothing to diff" in capsys.readouterr().out
+class TestReportCli:
+    def test_cli_reports_present_is_not_an_error(self, tmp_path, capsys):
+        # reports exist and nothing in them is degraded: the summary
+        # prints and --check passes
+        d = _fit_reports(tmp_path, ["A"])
+        assert report_main(["--reports", d, "--check"]) == 0
+        out = capsys.readouterr().out
+        assert "1 RunReport(s) read" in out
+        assert "FAULT-ASSISTED" not in out
 
     def test_cli_missing_reports_is_one_line_diagnostic(self, tmp_path,
                                                         capsys):
         """ISSUE 10 satellite: a missing or empty reports dir is an
         operator mistake — --check fails with ONE diagnostic line (no
-        traceback, no silently-green diff)."""
-        base = _baseline(tmp_path, {"a": {"value": 1.0,
-                                          "unit": "rows/sec",
-                                          "backend": "cpu"}})
+        traceback, no silently-green summary)."""
         missing = str(tmp_path / "never_written")
-        assert report_main(["--reports", missing, "--baseline", base,
-                            "--check"]) == 1
+        assert report_main(["--reports", missing, "--check"]) == 1
         out = capsys.readouterr().out.strip()
         assert len(out.splitlines()) == 1
         assert "no RunReports" in out and missing in out
-        # informational mode stays exit 0 (matching the empty-baseline
-        # convention), but still prints the diagnostic
-        assert report_main(["--reports", missing,
-                            "--baseline", base]) == 0
+        # informational mode stays exit 0, but still prints the diagnostic
+        assert report_main(["--reports", missing]) == 0
         assert "no RunReports" in capsys.readouterr().out
         # --json keeps the machine-readable shape
-        assert report_main(["--reports", missing, "--baseline", base,
-                            "--check", "--json"]) == 1
+        assert report_main(["--reports", missing, "--check", "--json"]) == 1
         payload = json.loads(capsys.readouterr().out)
         assert payload["ok"] is False and "no RunReports" in payload["error"]
 
-    def test_cli_last_bounds_the_diffed_reports(self, tmp_path, capsys):
-        """--last N diffs only the newest N RunReports — the bound for
+    def test_cli_last_bounds_the_summarised_reports(self, tmp_path, capsys):
+        """--last N reads only the newest N RunReports — the bound for
         an append-only runs.jsonl that has grown for months."""
-        import jax
-
-        backend = jax.default_backend()
-        d = _reports(tmp_path, [
-            {"metric": "a", "value": 100.0, "unit": "rows/sec"},
-            {"metric": "b", "value": 100.0, "unit": "rows/sec"},
-        ])
-        base = _baseline(tmp_path, {
-            "a": {"value": 100.0, "unit": "rows/sec", "backend": backend},
-            "b": {"value": 100.0, "unit": "rows/sec", "backend": backend},
-        })
-        assert report_main(["--reports", d, "--baseline", base,
-                            "--check"]) == 0
-        capsys.readouterr()  # drain the unbounded run's output
-        # bounded to the newest single report, metric a drops out
-        report_main(["--reports", d, "--baseline", base, "--last", "1"])
+        d = _fit_reports(tmp_path, ["Old", "New"], retried=("Old",))
+        assert report_main(["--reports", d, "--check"]) == 0
         out = capsys.readouterr().out
-        assert "no-report" in out
-        rows = [line for line in out.splitlines()
-                if line.startswith("a ")]
-        assert rows and "no-report" in rows[0]
+        assert "FAULT-ASSISTED fit Old" in out
+        assert "2 RunReport(s) read" in out
+        # bounded to the newest single report, the old fit drops out
+        assert report_main(["--reports", d, "--last", "1"]) == 0
+        out = capsys.readouterr().out
+        assert "Old" not in out
+        assert "1 RunReport(s) read" in out
+        report_main(["--reports", d, "--last", "1", "--json"])
+        payload = json.loads(capsys.readouterr().out)
+        assert payload["reports"] == 1 and payload["fault_assisted"] == []
 
 
 class _FakeDevice:

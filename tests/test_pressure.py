@@ -405,6 +405,36 @@ class TestFusedBisectionParity:
         # under pressure the plan dispatches MORE, never fewer, rows
         assert c.get("pipeline.fused_rows", 0) >= t.num_rows()
 
+    def test_transform_returns_to_unsplit_batches_once_the_ceiling_lifts(
+            self, monkeypatch):
+        """Recovery end to end on the fused serving surface: the caps a
+        ceiling taught are probed back up by later transforms (AIMD, the
+        probe interval at zero), and after that a transform bisects
+        nothing and predicts the same bits."""
+        model, t = self._pipeline_and_table(n=192)
+        (ref,) = model.transform(t)
+        obs.enable()
+        injection.configure("fault.oom>64")
+        try:
+            model.transform(t)
+        finally:
+            injection.configure(None)
+        assert pressure.current_caps(), "the ceiling taught no cap"
+        monkeypatch.setenv("FMT_PRESSURE_PROBE_S", "0")
+        for _ in range(40):
+            if not pressure.current_caps():
+                break
+            model.transform(t)
+        assert not pressure.current_caps()
+        obs.reset()
+        (out,) = model.transform(t)
+        c = obs.registry().snapshot()["counters"]
+        assert c.get("pressure.bisections", 0) == 0, c
+        assert c.get("pressure.ooms", 0) == 0, c
+        np.testing.assert_array_equal(
+            np.asarray(out.col("p")), np.asarray(ref.col("p"))
+        )
+
     def test_quarantine_offsets_survive_bisection(self):
         from flink_ml_tpu.serve import quarantine
         from flink_ml_tpu.table.table import Table

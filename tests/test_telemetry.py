@@ -346,6 +346,57 @@ class TestModelServerWiring:
         with pytest.raises((URLError, OSError)):
             urllib.request.urlopen(url, timeout=2)
 
+    def test_scrape_under_load_sits_within_the_registry_snapshots(self):
+        """The exporter publishes the registry, not an approximation of
+        it: while requests are being served, every counter of one scrape
+        lies between the registry snapshots taken around it, and the
+        scrape passes the strict parser."""
+        from flink_ml_tpu.serving import ModelServer
+
+        model, table = _tiny_model()
+        server = ModelServer(model, max_wait_ms=1.0, telemetry_port=0)
+        stop = threading.Event()
+
+        def load():
+            i = 0
+            while not stop.is_set():
+                lo = (i * 8) % (table.num_rows() - 8)
+                server.predict(table.slice_rows(lo, lo + 8), timeout=60)
+                i += 1
+
+        loader = threading.Thread(target=load, daemon=True)
+        try:
+            server.predict(table.slice_rows(0, 8), timeout=60)
+            loader.start()
+            checked = []
+            for _ in range(5):
+                before = obs.registry().snapshot()["counters"]
+                status, body = _get(server.telemetry, "/metrics")
+                after = obs.registry().snapshot()["counters"]
+                assert status == 200
+                checked.append(telemetry.counters_within_bounds(
+                    before, parse_openmetrics(body), after))
+        finally:
+            stop.set()
+            loader.join(60)
+            server.shutdown()
+        assert not loader.is_alive()
+        assert min(checked) >= 5, checked
+
+    def test_a_counter_outside_the_scrape_window_is_a_violation(self):
+        key = family_name("serving.requests") + "_total"
+        assert telemetry.counters_within_bounds(
+            {"serving.requests": 3}, {key: 4}, {"serving.requests": 4}) == 1
+        # a counter the scrape or the later snapshot lacks is not judged
+        assert telemetry.counters_within_bounds(
+            {"serving.requests": 3, "a.b": 1}, {key: 3},
+            {"serving.requests": 3}) == 1
+        for exported in (2, 5):
+            with pytest.raises(ValueError, match="outside the scrape"):
+                telemetry.counters_within_bounds(
+                    {"serving.requests": 3}, {key: exported},
+                    {"serving.requests": 4})
+
     def test_env_port_arms_the_server(self, monkeypatch):
         from flink_ml_tpu.serving import ModelServer
 

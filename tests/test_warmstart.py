@@ -406,6 +406,56 @@ class TestLadderWarmup:
             warmstart.configure(None)
 
 
+    def test_a_warm_path_deploy_compiles_nothing_and_serves_the_same_bits(
+            self, tmp_path, monkeypatch):
+        """What a respawned or rolling-deployed replica relies on: a
+        path deploy over the store an earlier deploy sealed replays every
+        rung, so its compile-ledger delta is EMPTY, nothing degrades, and
+        its first response is the cold deploy's bit for bit.  Each deploy
+        starts as a fresh process does: no ledgered shape, no active
+        store, a model loaded from the directory."""
+        from flink_ml_tpu.common import fused
+        from flink_ml_tpu.obs import trace
+        from flink_ml_tpu.serving.versioning import VersionManager
+
+        monkeypatch.setenv("FMT_OBS_REPORTS", str(tmp_path / "reports"))
+        monkeypatch.delenv("FMT_WARM_DIR", raising=False)
+        model, t = _fit_scaler_model(tmp_path)
+        d = str(tmp_path / "m")
+        model.save(d)
+
+        def ledger_lines():
+            try:
+                with open(trace.compile_ledger_path()) as f:
+                    return sum(1 for line in f if line.strip())
+            except OSError:
+                return 0
+
+        def deploy_and_serve():
+            fused.reset_compile_keys()
+            trace.reset()
+            warmstart.configure(None)
+            obs.reset()
+            before = ledger_lines()
+            vm = VersionManager()
+            vm.deploy(d, "v1", warmup=t.slice_rows(0, 8))
+            out = vm.active().transform(t.slice_rows(8, 24))
+            return (_counters(), ledger_lines() - before,
+                    np.asarray(out.col("features")).tobytes())
+
+        try:
+            cold, cold_lines, cold_bytes = deploy_and_serve()
+            warm, warm_lines, warm_bytes = deploy_and_serve()
+        finally:
+            warmstart.configure(None)
+        assert cold.get("warmstart.saves", 0) > 0    # the cold deploy sealed it
+        assert cold_lines > 0
+        assert warm.get("warmstart.hits", 0) > 0     # ...and the warm one hit
+        assert warm.get("warmstart.degraded", 0) == 0
+        assert warm_lines == 0, "the warm deploy compiled something"
+        assert warm_bytes == cold_bytes
+
+
 # -- what a spawned replica inherits -------------------------------------------
 
 
