@@ -507,6 +507,8 @@ def phase_nothing_hid(ctx):
     ctx["counters"] = {k: snap["counters"].get(k, 0) for k in MUST_BE_ZERO + (
         "train.fused_runs", "train.onepass_fits", "train.onepass_declined",
         "train.sparse_ell_fits", "train.sparse_ell_declined",
+        "train.sparse_hot_fits", "train.sparse_hot_entries",
+        "train.sparse_hot_declined",
         "slab_pool.hits", "pipeline.fused_dispatches",
         "fused.shard_map_dispatches", "fused.pallas_dispatches",
         "warmstart.hits", "warmstart.saves", "serving.requests")}

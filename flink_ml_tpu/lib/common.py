@@ -183,6 +183,14 @@ class SparseMinibatchStack:
     ell_declined: bool = False
 
     row_regular = False  # the layout, for ``train.sparse_ell_fits``
+    hot_ids = None  # no frequency split: ``train.sparse_hot_fits`` reads it
+    hot_declined = False
+
+    @property
+    def batch(self):
+        """The leaves the train program reads, each sharded on its first
+        axis."""
+        return self.ints, self.floats
 
     @property
     def step_slots(self) -> int:
@@ -214,6 +222,28 @@ class EllMinibatchStack:
       floats (n_dev*steps, width + 2, mb) — values at ``ints``' shape, then
              one row of labels and one of row weights (0 past the table's
              end): two leaves, as segment-CSR has.
+
+    **The frequency split** (``hot_ids`` set; the pack lays it where the
+    feature counts it observes pass :func:`_hot_split_wins`).  The
+    ``len(hot_ids)`` most frequent features are looked up by comparison,
+    not by address: in ``ints`` a hot entry holds its CODE, its feature's
+    place in ``hot_ids``, and the step finds the weight of a code by a
+    one-hot product with the hot weights laid ``(codes / 128, 128)``
+    (``ops/pallas_kernels.py:hot_scores``), which costs nothing a slot that
+    depends on ``dim``.  Every other entry leaves the two leaves (code 0 at value 0.0
+    in its place, as a pad) for a compact segment-COO list of the step,
+    row-major as :class:`SparseMinibatchStack` lays it, in the table's own
+    feature ids:
+
+      cold_ints (n_dev*steps, 2, cold_pad) int32 — [feature id, row id];
+                pads carry row id ``mb`` at value 0.0.
+      cold_vals (n_dev*steps, cold_pad) float32.
+      hot_ids   (n_dev, K) int32 — the same ids for every device (a leaf is
+                sharded on its first axis); past ``dim`` features, id 0,
+                whose codes no entry holds.
+
+    Every stored entry is in exactly one of the two parts, and the weights
+    stay in the table's own id space: no permutation, float32 throughout.
     """
 
     ints: np.ndarray
@@ -224,20 +254,44 @@ class EllMinibatchStack:
     dim: int
     n_rows: int = 0  # true (un-padded) row count, for throughput metrics
     n_entries: int = 0  # stored entries (pads not counted), likewise
+    cold_ints: Optional[np.ndarray] = None
+    cold_vals: Optional[np.ndarray] = None
+    hot_ids: Optional[np.ndarray] = None
+    n_hot_entries: int = 0  # stored entries that hold a code
+    #: the pack counted the features' entries and kept the unsplit step
+    #: (``train.sparse_hot_declined`` counts such fits)
+    hot_declined: bool = False
 
     row_regular = True
     ell_declined = False
 
     @property
+    def batch(self):
+        """As :attr:`SparseMinibatchStack.batch`."""
+        if self.hot_ids is None:
+            return self.ints, self.floats
+        return (self.ints, self.floats, self.cold_ints, self.cold_vals,
+                self.hot_ids)
+
+    @property
+    def cold_pad(self) -> int:
+        return 0 if self.hot_ids is None else self.cold_ints.shape[-1]
+
+    @property
     def step_slots(self) -> int:
         """Slots a device's step walks, pads included."""
-        return self.width * self.mb
+        return self.width * self.mb + self.cold_pad
 
     def grad_step(self, kind: str, with_intercept: bool = True):
         """As :meth:`SparseMinibatchStack.grad_step`, for this layout."""
-        return (("sparse-ell", self.mb, self.width, self.dim),
-                make_ell_mb_grad_step(kind, self.mb, self.width, self.dim,
-                                      with_intercept))
+        if self.hot_ids is None:
+            return (("sparse-ell", self.mb, self.width, self.dim),
+                    make_ell_mb_grad_step(kind, self.mb, self.width,
+                                          self.dim, with_intercept))
+        return (("sparse-ell-hot", self.mb, self.width, self.dim,
+                 self.cold_pad, self.hot_ids.shape[-1]),
+                make_hot_ell_grad_step(kind, self.mb, self.width, self.dim,
+                                       with_intercept))
 
 
 #: the most slots a row-regular step may walk for ONE slot of the
@@ -255,6 +309,60 @@ class EllMinibatchStack:
 #: for 2, from a scatter at 3.3 ns: half its cost, lost by the benchmark's
 #: breakdown where two programs name an operation alike)
 _ELL_MAX_SLOT_RATIO = 1.75
+
+#: features the frequency split looks up by comparison: 128 x 128, one MXU
+#: tile squared (a code is a row of the hot weights' table and a lane).  On
+#: the cell's table 16384 features hold 90.9% of the stored entries, 4096
+#: hold 84.8%, and a fit lasts a third longer at 4096 (my chip run, PR 30)
+_HOT_K = 16384
+#: what a slot costs on a TPU v5e, in ns, on the cell's table (PERF.md §5;
+#: my chip runs, PR 30): a row-regular slot 3.108 s / (175 x 1,277,952) =
+#: 13.9 (take 7.1 + scatter 6.6 + the rest); a cold slot, segment-CSR's
+#: four random accesses, 3.54 ms / 116,736 = 30.3; the hot lookup, both
+#: kernels and the hot weights' take and scatter, 1.87 ms a step of
+#: 1,277,952 slots = 1.5, whatever the slot holds.  They predicted the
+#: fits of three thinner skews (hot shares 0.85, 0.61, 0.31) to 2%
+_ELL_SLOT_NS = 13.9
+_COLD_SLOT_NS = 30.3
+_HOT_SLOT_NS = 1.5
+#: the split engages where those costs say it takes at most this share of
+#: the unsplit step's time: break-even is a hot share of 0.59, the rule
+#: asks for 0.68 on a table of one width (at 0.61 the chip read the split
+#: x1.06 faster, at 0.31 x0.64)
+_HOT_SPLIT_ROOM = 0.8
+
+
+def _hot_split_wins(hot_share: float, slots: int, entries: int) -> bool:
+    """Does a row-regular step of ``slots`` slots, ``hot_share`` of whose
+    ``entries`` stored entries fall on the :data:`_HOT_K` most frequent
+    features, run faster split?  By the per-slot costs above: every slot
+    pays the hot lookup, the cold entries pay segment-CSR's four random
+    accesses, and the unsplit step pays two a slot."""
+    split = slots * _HOT_SLOT_NS + (1.0 - hot_share) * entries * _COLD_SLOT_NS
+    return split <= _HOT_SPLIT_ROOM * slots * _ELL_SLOT_NS
+
+
+def _hot_split_measured() -> bool:
+    """Were those costs measured on what this process computes on?  They
+    are a TPU's: a CPU gathers cheaply and multiplies one-hots dearly, and
+    its pack neither counts nor splits."""
+    return jax.devices()[0].platform == "tpu"
+
+
+def _hot_features(indices, dim: int):
+    """``(hot_ids (_HOT_K,) int32, their share of the stored entries)``:
+    the most frequent features of a validated CSR column's ``indices``,
+    ties to the lower id; past ``dim`` features, id 0.  Counted block by
+    block, in the indices' own dtype (``np.bincount`` makes an int64 copy
+    of what it is given)."""
+    counts = np.zeros(dim, np.int64)
+    for lo in range(0, len(indices), _ORDER_CHECK_BLOCK):
+        counts += np.bincount(indices[lo : lo + _ORDER_CHECK_BLOCK],
+                              minlength=dim)
+    top = np.argsort(-counts, kind="stable")[:_HOT_K]
+    hot_ids = np.zeros(_HOT_K, np.int32)
+    hot_ids[: len(top)] = top
+    return hot_ids, float(counts[top].sum()) / max(1, len(indices))
 
 
 @obs.phased("pack_sparse")
@@ -286,7 +394,12 @@ def pack_sparse_minibatches(
     where ``mb * width <= _ELL_MAX_SLOT_RATIO * nnz_pad`` — a choice made
     from the row widths the pack observes — and as segment-CSR, byte for
     byte what it is without the flag and marked ``ell_declined``, where
-    they fail that rule.  A per-object column keeps segment-CSR.
+    they fail that rule.  A per-object column keeps segment-CSR.  Where
+    the row-regular layout is taken, on a TPU, the pack also counts the
+    stored entries a feature and lays the frequency split
+    (:class:`EllMinibatchStack`) where :func:`_hot_split_wins` says it
+    pays; a table that fails that keeps the unsplit leaves byte for byte
+    and is marked ``hot_declined``.
     """
     from flink_ml_tpu.ops.batch import CsrRows
 
@@ -474,7 +587,18 @@ def _pack_sparse_minibatches_csr(
         # the slots either layout would walk a step, from the widths alone
         width = max(1, int(counts.max(initial=0)))
         if mb * width <= _ELL_MAX_SLOT_RATIO * nnz_pad:
-            return _pack_ell(rows, y, bounds, counts, width, mb, steps, dim)
+            # and the feature counts decide whether the hot features leave
+            # the gather and the scatter (EllMinibatchStack's split)
+            hot_ids = None
+            counted = _hot_split_measured()
+            if counted:
+                hot_ids, hot_share = _hot_features(indices[:nnz_total], dim)
+                if not _hot_split_wins(hot_share, mb * width, nnz_max):
+                    hot_ids = None
+            stack = _pack_ell(rows, y, bounds, counts, width, mb, steps, dim,
+                              n_dev, pad_multiple, hot_ids)
+            stack.hot_declined = counted and hot_ids is None
+            return stack
 
     if nnz_total:
         # per-row ascending ids are a layout invariant downstream (the
@@ -515,35 +639,73 @@ def _pack_sparse_minibatches_csr(
 
 
 def _pack_ell(rows, y, bounds, counts, width: int, mb: int, steps: int,
-              dim: int) -> EllMinibatchStack:
+              dim: int, n_dev: int, pad_multiple: int,
+              hot_ids=None) -> EllMinibatchStack:
     """Lay a validated CSR column out row-regular, a device's step (one
     entry of ``bounds``: rows ``[lo, hi)``, entries ``[e0, e1)``) at a
     time, so that the temporaries are a step's: a step whose rows are all
     ``width`` long is one transposing copy, a ragged one scatters its
     entries by (position in the row, row).  Rows keep their stored order of
-    entries: the step sums a row whatever its order."""
+    entries: the step sums a row whatever its order.
+
+    With ``hot_ids`` (the frequency split) an entry of one of those
+    features is laid as its code, and every other entry goes to its step's
+    cold list in the order it is stored (row-major: the cold list's row ids
+    do not fall), leaving code 0 at value 0.0 behind."""
     indptr, indices, values = rows.indptr, rows.indices, rows.values
     ints = np.zeros((len(bounds), width, mb), dtype=np.int32)
     floats = np.zeros((len(bounds), width + 2, mb), dtype=np.float32)
+    split = hot_ids is not None
+    if split:
+        code_of = np.full(dim, -1, np.int32)
+        n_hot = min(len(hot_ids), dim)
+        code_of[hot_ids[:n_hot]] = np.arange(n_hot, dtype=np.int32)
+        cold = [None] * len(bounds)  # a step's (feature ids, row ids, values)
     for g, (lo, hi, e0, e1) in enumerate(bounds):
         m = hi - lo
         if not m:
             continue
-        if e1 - e0 == m * width:
-            ints[g, :, :m] = indices[e0:e1].reshape(m, width).T
-            floats[g, :width, :m] = values[e0:e1].reshape(m, width).T
-        elif e1 > e0:
+        ids, vals = indices[e0:e1], values[e0:e1]
+        regular = e1 - e0 == m * width
+        if not regular:
             rid = np.repeat(np.arange(m, dtype=np.int32), counts[lo:hi])
+        if split:
+            ids = code_of[ids]
+            at = np.flatnonzero(ids < 0)
+            cold[g] = (indices[e0:e1][at],
+                       at // width if regular else rid[at], vals[at])
+            ids = np.maximum(ids, 0)
+            vals = vals.copy()
+            vals[at] = 0.0
+        if regular:
+            ints[g, :, :m] = ids.reshape(m, width).T
+            floats[g, :width, :m] = vals.reshape(m, width).T
+        elif e1 > e0:
             pos = np.arange(e1 - e0, dtype=np.int32) - np.repeat(
                 (indptr[lo:hi] - e0).astype(np.int32), counts[lo:hi])
-            ints[g, pos, rid] = indices[e0:e1]
-            floats[g, pos, rid] = values[e0:e1]
+            ints[g, pos, rid] = ids
+            floats[g, pos, rid] = vals
         floats[g, width, :m] = y[lo:hi]
         floats[g, width + 1, :m] = 1.0
-    return EllMinibatchStack(
+    stack = EllMinibatchStack(
         ints=ints, floats=floats, steps=steps, mb=mb, width=width, dim=dim,
         n_rows=len(rows), n_entries=int(indptr[-1]) if len(rows) else 0,
     )
+    if not split:
+        return stack
+    n_cold = [0 if c is None else len(c[0]) for c in cold]
+    cold_pad = max(1, -(-max(n_cold) // pad_multiple)) * pad_multiple
+    stack.cold_ints = np.zeros((len(bounds), 2, cold_pad), dtype=np.int32)
+    stack.cold_ints[:, 1, :] = mb  # pad row id -> dropped segment
+    stack.cold_vals = np.zeros((len(bounds), cold_pad), dtype=np.float32)
+    for g, c in enumerate(cold):
+        if c is not None:
+            stack.cold_ints[g, 0, : n_cold[g]] = c[0]
+            stack.cold_ints[g, 1, : n_cold[g]] = c[1]
+            stack.cold_vals[g, : n_cold[g]] = c[2]
+    stack.hot_ids = np.tile(hot_ids, (n_dev, 1))
+    stack.n_hot_entries = stack.n_entries - sum(n_cold)
+    return stack
 
 
 # Compiled epoch steps are reused across fit() calls: rebuilding the jitted
@@ -1083,9 +1245,15 @@ def _import_kernels_early(grad_fn: GradFn, mesh) -> None:
     process.  A fit that may take the kernel starts that import on a thread
     BEFORE it places its slab (seconds of host copies that hold no GIL), so
     that the first fit of a process does not pay it after the placement."""
+    if _onepass_eligible(grad_fn, mesh):
+        _start_kernels_import()
+
+
+def _start_kernels_import() -> None:
+    """The import of :data:`_KERNELS_MODULE` on a thread, once a process."""
     import sys
 
-    if _KERNELS_MODULE in sys.modules or not _onepass_eligible(grad_fn, mesh):
+    if _KERNELS_MODULE in sys.modules:
         return
     import importlib
     import threading
@@ -1194,6 +1362,57 @@ def make_ell_mb_grad_step(kind: str, mb: int, width: int, dim: int,
     return mb_grad_step
 
 
+def make_hot_ell_grad_step(kind: str, mb: int, width: int, dim: int,
+                           with_intercept: bool = True,
+                           interpret: Optional[bool] = None):
+    """:func:`make_ell_mb_grad_step`'s gradient over one step of a SPLIT
+    :class:`EllMinibatchStack`: ``(params, the device's whole batch, step
+    number) -> (grads, weighted loss sum, weight sum)`` (the step slices
+    its own leaves: ``hot_ids`` is not a step's).  The coded slots are
+    looked up by comparison, one Pallas call a direction
+    (``ops/pallas_kernels.py:hot_scores`` / ``hot_grad``, under
+    ``fmt.train.sparse.hot``; ``interpret`` as
+    ``pallas_kernels.launch_interpreted`` says unless given, and the step
+    says which as ``pallas_interpret``); the step's cold list runs
+    segment-CSR's gather and scatter in the table's own ids; the same
+    loss, the same scopes, float32 throughout."""
+    from flink_ml_tpu.ops import pallas_kernels
+
+    if interpret is None:
+        interpret = pallas_kernels.launch_interpreted()
+    keep_b = 1.0 if with_intercept else 0.0
+
+    def grad_step(params, batch, step):
+        codes, floats, cold_ints, cold_vals = (
+            jax.lax.dynamic_index_in_dim(leaf, step, keepdims=False)
+            for leaf in batch[:4])
+        hot_ids = batch[4][0]
+        vals, y, w = floats[:width], floats[width], floats[width + 1]
+        cold_idx, cold_rid = cold_ints[0], cold_ints[1]
+        wts, b = params
+        with jax.named_scope("fmt.train.sparse.forward"):
+            with jax.named_scope("fmt.train.sparse.hot"):
+                logits = pallas_kernels.hot_scores(
+                    jnp.take(wts, hot_ids, axis=0), codes, vals,
+                    interpret=interpret)
+            logits = logits + _segment_csr_forward(
+                wts, cold_idx, cold_rid, cold_vals, mb) + b
+        err, loss_sum = _sparse_loss(kind, logits, y, w)
+        with jax.named_scope("fmt.train.sparse.backward"):
+            with jax.named_scope("fmt.train.sparse.hot"):
+                g_hot = pallas_kernels.hot_grad(
+                    err, codes, vals, k=hot_ids.shape[0],
+                    interpret=interpret)
+            g_w = _segment_csr_backward(
+                err, cold_idx, cold_rid, cold_vals, dim
+            ).at[hot_ids].add(g_hot)
+        g_b = jnp.sum(err) * keep_b
+        return (g_w, g_b), loss_sum, jnp.sum(w)
+
+    grad_step.pallas_interpret = interpret
+    return grad_step
+
+
 def _segment_csr_unpack(ints, floats, nnz_pad: int, mb: int):
     """Unpack one packed sparse minibatch slice into (idx, rid, vals, y, w)
     — the ONE copy of the [values | y | w] layout decode (sparse, 2-D, and
@@ -1242,7 +1461,8 @@ def make_sparse_glm_train_fn(
     its shapes only): the stack names its step (``grad_step``).
 
     ``kind`` picks the loss ('logistic' | 'squared'); the minibatch math is
-    :func:`make_sparse_mb_grad_step` or :func:`make_ell_mb_grad_step`.
+    :func:`make_sparse_mb_grad_step`, :func:`make_ell_mb_grad_step` or, on
+    a stack split by frequency, :func:`make_hot_ell_grad_step`.
     Program structure is shared with the dense path via
     :func:`_build_fused_train_fn`, bundled as the dense estimator fit is
     (one program named ``jit_bundled``, one buffer to fetch):
@@ -1250,15 +1470,24 @@ def make_sparse_glm_train_fn(
     """
     if kind not in ("logistic", "squared"):
         raise ValueError(f"unknown loss kind {kind!r}")
-    step_key, mb_grad_step = sstack.grad_step(kind, with_intercept)
+    step_key, grad_step = sstack.grad_step(kind, with_intercept)
     key = (*step_key, kind, mesh,
            float(learning_rate), float(reg), int(max_iter), float(tol),
            bool(with_intercept))
-
-    return _build_fused_train_fn(
-        key, mb_grad_step, mesh, learning_rate, reg, max_iter, tol,
-        bundle=True,
+    if sstack.hot_ids is None:
+        return _build_fused_train_fn(
+            key, grad_step, mesh, learning_rate, reg, max_iter, tol,
+            bundle=True,
+        )
+    # the split step reads its slices out of the whole batch itself, and
+    # holds two Pallas calls: as make_glm_train_fn's one-pass step
+    interpret = grad_step.pallas_interpret
+    train_fn = _build_fused_train_fn(
+        key, None, mesh, learning_rate, reg, max_iter, tol,
+        check_vma=not interpret, bundle=True, whole_batch_step=grad_step,
     )
+    train_fn.pallas_interpret = interpret
+    return train_fn
 
 
 def make_sparse_mb_grad_step_2d(kind: str, mb: int, nnz_pad: int,
@@ -1370,8 +1599,15 @@ class HotColdStack:
     ``(mb, hot_k)`` in bf16 — built once on device — and the forward/
     backward over them are two MXU GEMMs reading the slab at HBM stream
     bandwidth; only the cold tail (a few nnz/row) still pays random access.
-    What each formulation costs on the chip is measured, not reckoned
-    here: ``PERF.md`` §6.
+    That holds for the RESIDENT slab.  The ``stream`` form densifies a
+    step's slab with one scatter a hot entry
+    (:func:`make_hotcold_stream_mb_grad_step`), so it still pays a random
+    access a slot: on the chip a fit of the Criteo-shaped table lasts
+    3.234 s at ``hot_k`` 4096 against the plain route's 3.112 s unsplit
+    (``PERF.md`` §6, PR 27 and 28), and the resident slab of that table is
+    46.98 GB.  The plain route's own frequency split
+    (:class:`EllMinibatchStack`, PR 30) looks the hot features up by
+    comparison instead, in float32 and in the table's own ids: 0.93 s.
 
     Features are permuted so hot ids occupy [0, hot_k) (slab position =
     feature id) and cold ids [hot_k, dim); ``perm``/``inv_perm`` map
@@ -2423,7 +2659,9 @@ def train_glm_sparse(
         def trim(params):
             return params
 
-    batch = (sstack.ints, sstack.floats)
+    batch = sstack.batch
+    if sstack.hot_ids is not None:
+        _start_kernels_import()  # before the placement, which hides it
 
     def run(n_epochs, params, dev_batch=None):
         r = _run_fused_train(
@@ -2441,6 +2679,14 @@ def train_glm_sparse(
         obs.counter_add("train.sparse_ell_fits", int(sstack.row_regular))
         if sstack.ell_declined:
             obs.counter_add("train.sparse_ell_declined")
+        # and whether its hot features were looked up by comparison
+        obs.counter_add("train.sparse_hot_fits",
+                        int(sstack.hot_ids is not None))
+        if sstack.hot_declined:
+            obs.counter_add("train.sparse_hot_declined")
+        if sstack.hot_ids is not None:
+            obs.counter_add("train.sparse_hot_entries",
+                            sstack.n_hot_entries * r.epochs)
         obs.counter_add("train.sparse_entries", sstack.n_entries * r.epochs)
         obs.counter_add("train.sparse_slots",
                         sstack.step_slots * len(sstack.ints) * r.epochs)

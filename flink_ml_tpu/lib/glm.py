@@ -568,9 +568,7 @@ class GlmEstimatorBase(Estimator, GlmTrainParams):
         sparse_cols = [self.get_vector_col(), self.get_label_col()]
         device_batch = lambda: slab_pool.get_or_place(  # noqa: E731
             table, layout_key + ("dev",), mesh,
-            lambda: shard_batch_prefetched(
-                mesh, (sstack.ints, sstack.floats)
-            ),
+            lambda: shard_batch_prefetched(mesh, sstack.batch),
             cols=sparse_cols,
         )
         lr = self.get_learning_rate()

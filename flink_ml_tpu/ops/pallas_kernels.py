@@ -35,12 +35,27 @@ chip, and when; a statement about speed is a line of ``PERF_LEDGER.jsonl``:
                                                   (interpret mode on CPU is
                                                   ~5x slower, which says
                                                   nothing about Mosaic)
-  (sparse grad)       segment-CSR minibatch grad  REJECTED — every
-                                                  programmable path lost
-                                                  to XLA's scatter
+  :func:`hot_scores`  the plain sparse fit's      2026-10-02, TPU v5 lite,
+  :func:`hot_grad`    step where the pack split   jax 0.9.0: compile with
+                      the table by frequency      Mosaic at planes (39,
+                      (lib/common.py:             32768), K 16384 and 4096;
+                      make_hot_ell_grad_step):    0.72 + 0.74 ms a step of
+                      the weight of a hot         1.28 M slots where XLA's
+                      feature's code found by a   take and scatter-add take
+                      one-hot product and a       9.2 + 9.6 ms; a fit's
+                      lane mask, not by its       coefficients within 1e-6
+                      address; the gradient the   of the unsplit step's, a
+                      transposed product.         repeated fit the same
+                      float32 in three bfloat16   bytes (PERF.md 5, 6)
+                      pieces; no knob
+                      (lib/common.py:
+                      _hot_split_wins)
+  (sparse grad)       segment-CSR minibatch grad  REJECTED — every path
+                                                  that ADDRESSES a slot
+                                                  lost to XLA's scatter
                                                   lowering; note below.
-                                                  No sparse Pallas kernel
-                                                  ships.
+                                                  The two kernels above
+                                                  address nothing.
   ==================  ==========================  =========================
 
 :func:`glm_grad` supersedes the row-tiled, features-on-lanes kernel of the
@@ -97,9 +112,12 @@ Three Pallas replacements were built and measured:
 Conclusion: on v5e (no SparseCore) every programmable path — XLA scatter,
 Mosaic scalar loop, lane-masked vector RMW — is bound by the same ~10
 cycles/random-access wall, and XLA's lowering is already at it.  The
-segment-CSR XLA formulation therefore remains the default and no sparse
-Pallas kernel ships.  To be re-measured with the chip benchmark
-(ROADMAP S0) before anyone retries it.
+segment-CSR XLA formulation therefore remained the default and no Pallas
+kernel that addresses a slot ships.  Re-measured with the chip benchmark
+(PERF.md 5, PR 27 to 30): a slot still costs 7.1 ns to gather and 6.6 ns to
+scatter whatever the table's size, 10^6 or 4096 entries.  What PR 30 ships
+leaves the address out: :func:`hot_scores` and :func:`hot_grad` find the
+hot features' weights by comparison, on the MXU.
 """
 
 from __future__ import annotations
@@ -147,6 +165,7 @@ _GROUPS_PER_TRIP = 4
 #: a block index, as an int32 spelled out: with x64 on a bare 0 is an int64,
 #: which Mosaic refuses beside the grid's i32
 _I32_ZERO = np.int32(0)
+_I32_ONE = np.int32(1)
 #: beyond this a longer row tile buys nothing (seen on the chip: 512 to 2048
 #: rows read within 1% of each other) and its first copy, which no compute
 #: hides, grows
@@ -261,6 +280,22 @@ def _glm_grad_kernel(kind: str, d: int, tile_rows: int, step_ref, slab_ref,
     stats_ref[2:3, :] += fold_lanes(sw)
 
 
+def _vma_of(*operands):
+    """``(vma, promote)``: under ``shard_map(check_vma=True)`` a kernel's
+    outputs must declare how they vary across mesh axes, and they vary
+    wherever any input does; ``promote`` gives an operand that same vma so
+    the kernel sees matching axes."""
+    vma = frozenset()
+    for operand in operands:
+        vma = vma | jax.typeof(operand).vma
+
+    def promote(a):
+        need = vma - jax.typeof(a).vma
+        return jax.lax.pcast(a, tuple(need), to="varying") if need else a
+
+    return vma, promote
+
+
 def glm_grad_tile(rows: int, d: int) -> int:
     """The kernel's row tile for minibatches of ``rows`` rows and ``d``
     features, by arithmetic on the shape alone (nothing is compiled or timed
@@ -325,16 +360,7 @@ def glm_grad(slab, step, wts, b, kind: str = "logistic",
         jnp.reshape(b, (1, 1)).astype(jnp.float32),
     ]
 
-    # under shard_map(check_vma=True) outputs must declare how they vary
-    # across mesh axes: they vary wherever any input does.  Operands are
-    # promoted to the same vma so the kernel sees matching axes.
-    vma = frozenset()
-    for operand in operands:
-        vma = vma | jax.typeof(operand).vma
-
-    def _promote(a):
-        need = vma - jax.typeof(a).vma
-        return jax.lax.pcast(a, tuple(need), to="varying") if need else a
+    vma, _promote = _vma_of(*operands)
 
     def same_block(i, step):  # weights, intercept, both accumulators
         return _I32_ZERO, _I32_ZERO
@@ -369,6 +395,204 @@ def glm_grad(slab, step, wts, b, kind: str = "logistic",
     )(*(_promote(a) for a in operands))
     sums = jnp.sum(stats[:3], axis=1)
     return jnp.sum(gw[:d], axis=1), sums[0], sums[1], sums[2]
+
+
+# -- the sparse step's hot lookup --------------------------------------------
+
+#: slots of a plane a grid step of the two kernels takes, at most, and the
+#: planes a trip of their loop takes.  Seen on the chip at planes (39,
+#: 32768), K 16384, forward + backward ms a step; seconds to the first
+#: call's return (PERF.md 5; my chip run, PR 30): 512 slots a plane a trip
+#: 0.99 + 1.09; 1.1 s.  1024 slots, 3 planes: 0.72 + 0.74; 1.3 s.  2048
+#: slots, 3 planes: 0.69 + 0.71; 2.0 s.  1024 slots, all 39 planes
+#: unrolled: 0.67 + 0.68 and no lower anywhere; 10.2 s
+_HOT_TILE = 1024
+_HOT_UNROLL = 3
+#: the float32 bits a bfloat16 keeps: sign, exponent, seven of mantissa
+_TOP_HALF = np.int32(-65536)
+
+
+def _f32_pieces(x):
+    """``x`` (float32) as three float32 arrays, each a bfloat16 value,
+    that sum to it exactly: 24 bits of mantissa cut 8 + 8 + 8 by masking
+    (a convert and its way back are a pair a compiler may drop as excess
+    precision).  A one-hot times each piece, summed in float32, is the
+    float32 itself: the MXU picks a float32 in three bfloat16 passes."""
+    pieces = []
+    for _ in range(3):
+        top = jax.lax.bitcast_convert_type(
+            jax.lax.bitcast_convert_type(x, jnp.int32) & _TOP_HALF,
+            jnp.float32)
+        pieces.append(top)
+        x = x - top
+    return pieces
+
+
+def _hot_masks(code, rows: int):
+    """A plane of codes ``(1, T)`` as its one-hot masks, slots on the
+    lanes: ``(rows, T)`` for the code's row of the hot table as bfloat16,
+    ``(128, T)`` for its lane as booleans."""
+    tile = code.shape[1]
+    row_of = jax.lax.broadcasted_iota(jnp.int32, (rows, tile), 0) == (
+        code >> 7)
+    lane_of = jax.lax.broadcasted_iota(
+        jnp.int32, (_LANES, tile), 0) == (code & 127)
+    return row_of.astype(jnp.float32).astype(jnp.bfloat16), lane_of
+
+
+def _hot_scores_kernel(width: int, rows: int, table_ref, codes_ref, vals_ref,
+                       out_ref):
+    """A tile of slots of every plane: ``sum over planes of vals *
+    w_hot[codes]``.
+
+    Refs: table_ref (3*128, rows) bf16, the hot weights' three pieces,
+    each transposed (lane, row); codes_ref / vals_ref (width, T); out_ref
+    (1, T)."""
+    table = table_ref[...]
+
+    def plane(carry):
+        j, acc = carry
+        code, val = codes_ref[pl.ds(j, 1), :], vals_ref[pl.ds(j, 1), :]
+        row_of, lane_of = _hot_masks(code, rows)
+        picked = jnp.dot(table, row_of, preferred_element_type=jnp.float32)
+        picked = (picked[:_LANES] + picked[_LANES:2 * _LANES]
+                  + picked[2 * _LANES:])
+        return j + _I32_ONE, acc + val * jnp.sum(
+            jnp.where(lane_of, picked, 0.0), axis=0, keepdims=True)
+
+    out_ref[...] = _over_planes(
+        width, plane, jnp.zeros(out_ref.shape, jnp.float32))
+
+
+def _over_planes(width: int, plane, acc):
+    """``plane`` applied to ``(plane number, accumulator)`` for every plane,
+    :data:`_HOT_UNROLL` planes a trip of one loop (Mosaic unrolls a
+    ``fori_loop`` whole or not at all), the planes left over after it.  The
+    loop carries its own int32 plane number: see :func:`_glm_grad_kernel`."""
+    trips, left = divmod(width, _HOT_UNROLL)
+
+    def trip(_, carry):
+        for _i in range(_HOT_UNROLL):
+            carry = plane(carry)
+        return carry
+
+    carry = (_I32_ZERO, acc)
+    if trips:
+        carry = jax.lax.fori_loop(0, trips, trip, carry)
+    for _i in range(left):
+        carry = plane(carry)
+    return carry[1]
+
+
+def _hot_grad_kernel(width: int, rows: int, codes_ref, vals_ref, err_ref,
+                     g_ref):
+    """The transposed product: ``g[row, piece * 128 + lane] += sum over
+    the tile's slots of onehot_row * piece(err * val) * onehot_lane``.
+
+    Refs: codes_ref / vals_ref (width, T); err_ref (1, T); g_ref (rows,
+    3*128), the same block every step (the accumulator)."""
+
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        g_ref[...] = jnp.zeros_like(g_ref)
+
+    err = err_ref[...]
+
+    def plane(carry):
+        j, acc = carry
+        row_of, lane_of = _hot_masks(codes_ref[pl.ds(j, 1), :], rows)
+        spread = jnp.concatenate(
+            [jnp.where(lane_of, p, 0.0).astype(jnp.bfloat16)
+             for p in _f32_pieces(err * vals_ref[pl.ds(j, 1), :])])
+        return j + _I32_ONE, acc + jax.lax.dot_general(
+            row_of, spread, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    g_ref[...] += _over_planes(
+        width, plane, jnp.zeros(g_ref.shape, jnp.float32))
+
+
+def _hot_tile(mb: int) -> int:
+    """The longest run of whole lane chunks, at most :data:`_HOT_TILE`
+    slots, that divides ``mb`` (a multiple of 128)."""
+    return next(t for t in range(_HOT_TILE, 0, -_LANES) if mb % t == 0)
+
+
+def _hot_operands(codes, vals):
+    """The planes with their slots padded to whole lane chunks (code 0 at
+    value 0.0 adds nothing either way)."""
+    pad = -codes.shape[1] % _LANES
+    if pad:
+        codes = jnp.pad(codes, ((0, 0), (0, pad)))
+        vals = jnp.pad(vals, ((0, 0), (0, pad)))
+    return codes.astype(jnp.int32), vals.astype(jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def hot_scores(w_hot, codes, vals, interpret: bool = False):
+    """``sum over the planes of vals * w_hot[codes]``, ``(mb,)``, with the
+    weight of a code found by comparison: ``w_hot`` (K,) float32, K a
+    multiple of 128; ``codes`` / ``vals`` (width, mb), codes in [0, K).
+    Exact: each product is ``vals * w_hot[codes]`` in float32."""
+    width, mb = codes.shape
+    rows = w_hot.shape[0] // _LANES
+    codes, vals = _hot_operands(codes, vals)
+    tile = _hot_tile(codes.shape[1])
+    table = jnp.concatenate(
+        [p.reshape(rows, _LANES).T
+         for p in _f32_pieces(w_hot.astype(jnp.float32))]
+    ).astype(jnp.bfloat16)
+    vma, promote = _vma_of(table, codes, vals)
+    out = pl.pallas_call(
+        functools.partial(_hot_scores_kernel, width, rows),
+        grid=(codes.shape[1] // tile,),
+        in_specs=[
+            pl.BlockSpec(table.shape, lambda i: (_I32_ZERO, _I32_ZERO)),
+            pl.BlockSpec((width, tile), lambda i: (_I32_ZERO, i)),
+            pl.BlockSpec((width, tile), lambda i: (_I32_ZERO, i)),
+        ],
+        out_specs=pl.BlockSpec((1, tile), lambda i: (_I32_ZERO, i)),
+        out_shape=jax.ShapeDtypeStruct((1, codes.shape[1]), jnp.float32,
+                                       vma=vma),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name="hot_scores",
+    )(promote(table), promote(codes), promote(vals))
+    return out[0, :mb]
+
+
+@functools.partial(jax.jit, static_argnames=("k", "interpret"))
+def hot_grad(err, codes, vals, k: int, interpret: bool = False):
+    """:func:`hot_scores` transposed: the gradient ``(k,)`` of the hot
+    weights, ``g[c] = sum over the slots with code c of err * vals``
+    (``err`` (mb,), a row's; float32 sums in the MXU's accumulator)."""
+    width, mb = codes.shape
+    rows = k // _LANES
+    codes, vals = _hot_operands(codes, vals)
+    err = jnp.pad(err.astype(jnp.float32), (0, codes.shape[1] - mb))[None]
+    tile = _hot_tile(codes.shape[1])
+    vma, promote = _vma_of(codes, vals, err)
+    g = pl.pallas_call(
+        functools.partial(_hot_grad_kernel, width, rows),
+        grid=(codes.shape[1] // tile,),
+        in_specs=[
+            pl.BlockSpec((width, tile), lambda i: (_I32_ZERO, i)),
+            pl.BlockSpec((width, tile), lambda i: (_I32_ZERO, i)),
+            pl.BlockSpec((1, tile), lambda i: (_I32_ZERO, i)),
+        ],
+        out_specs=pl.BlockSpec((rows, 3 * _LANES),
+                               lambda i: (_I32_ZERO, _I32_ZERO)),
+        out_shape=jax.ShapeDtypeStruct((rows, 3 * _LANES), jnp.float32,
+                                       vma=vma),
+        # the grid axis carries the accumulator: sequential
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="hot_grad",
+    )(promote(codes), promote(vals), promote(err))
+    g = g.reshape(rows, 3, _LANES)
+    return (g[:, 0] + g[:, 1] + g[:, 2]).reshape(k)
 
 
 # -- fused serving chain ------------------------------------------------------

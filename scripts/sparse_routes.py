@@ -3,6 +3,7 @@
 hand on the chip (ROADMAP D1 waits for these numbers; no cell):
 
     python scripts/sparse_routes.py --seed <n> [--hot 4096] [--keep 0.67 ...]
+                                    [--thin 0.33 ...]
 
 Makes ``criteo_sparse_lr``'s table from the seed with the benchmark's own
 generator, then fits it (first fit: pack, split, place, compile; then
@@ -10,10 +11,14 @@ generator, then fits it (first fit: pack, split, place, compile; then
 through
 
 * the plain route as the estimator takes it (``numHotFeatures`` unset: the
-  default; the pack lays this table row-regular since PR 28),
-* plain segment-CSR, which the estimator no longer takes for this table:
-  packed and trained by the builders themselves (``pack_sparse_minibatches``
-  without ``row_regular``, ``train_glm_sparse``), no switch in the program,
+  default; the pack lays this table row-regular since PR 28, and split by
+  frequency since PR 30: the 16384 most frequent features looked up by
+  comparison, the rest in a cold list),
+* the plain route's unsplit row-regular step (``plain_unsplit``) and plain
+  segment-CSR, which the estimator no longer takes for this table: packed
+  and trained by the builders themselves (``pack_sparse_minibatches``,
+  ``train_glm_sparse``), the pack's rules lifted inside this script, no
+  switch in the program,
 * hot/cold with ``hotSlabMode`` ``stream`` (the hot columns densified inside
   the program, a step at a time),
 * hot/cold with ``hotSlabMode`` ``resident`` (the hot columns as bf16 slabs
@@ -25,7 +30,11 @@ Each ``--keep p`` then makes a RAGGED table (every stored entry of the
 cell's table kept with probability ``p``) and fits it through both step
 layouts by the builders, the row-regular one forced past the pack's rule
 inside this script where the rule declines it: one reading on each side of
-``mb x width <= _ELL_MAX_SLOT_RATIO x nnz_pad``.
+``mb x width <= _ELL_MAX_SLOT_RATIO x nnz_pad`` (both unsplit).  Each
+``--thin q`` makes a table of the cell's shape with a THINNER skew (every
+stored entry's feature redrawn uniformly with probability ``q``) and fits
+it split and unsplit, each forced: one reading on each side of the split's
+rule (``lib/common.py:_hot_split_wins``), with the hot share the pack counts.
 
 One JSON line a route: warm fit seconds (median), stored entries a second,
 the device's peak memory, the loss, and the must-be-zero counters.  Refuses
@@ -43,10 +52,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
-def by_builders(name, column, y, config, row_regular, fits):
+def by_builders(name, column, y, config, row_regular, fits, split=False):
     """One layout of one CSR column, packed and trained by the builders:
-    the JSON line's fields.  ``row_regular`` lifts the pack's rule for this
-    one pack (the row-regular layout even where the rule declines it)."""
+    the JSON line's fields.  ``row_regular`` lifts the pack's rule on the
+    row widths for this one pack (the row-regular layout even where the
+    rule declines it) and ``split`` decides the frequency split in its
+    rule's place."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -58,17 +69,24 @@ def by_builders(name, column, y, config, row_regular, fits):
     mesh = MLEnvironmentFactory.get_default().get_mesh()
     dim, batch = int(config["numFeatures"]), int(config["globalBatchSize"])
     program.release()
-    rule = common._ELL_MAX_SLOT_RATIO
+    rules = common._ELL_MAX_SLOT_RATIO, common._hot_split_wins
+    shares = []
+
+    def decide(hot_share, slots, nnz_pad):
+        shares.append((hot_share, rules[1](hot_share, slots, nnz_pad)))
+        return split
+
     t0 = time.perf_counter()
     try:
         if row_regular:
             common._ELL_MAX_SLOT_RATIO = float("inf")
+        common._hot_split_wins = decide
         stack = common.pack_sparse_minibatches(
             column, y, 1, batch, dim=dim, row_regular=row_regular)
     finally:
-        common._ELL_MAX_SLOT_RATIO = rule
+        common._ELL_MAX_SLOT_RATIO, common._hot_split_wins = rules
     pack_s = time.perf_counter() - t0
-    placed = shard_batch_prefetched(mesh, (stack.ints, stack.floats))
+    placed = shard_batch_prefetched(mesh, stack.batch)
 
     def fit():
         start = (jnp.zeros((dim,), jnp.float32), jnp.zeros((), jnp.float32))
@@ -82,8 +100,9 @@ def by_builders(name, column, y, config, row_regular, fits):
     warm = [fit() for _ in range(fits)]
     seconds = statistics.median(s for s, _r in warm)
     entries = stack.n_entries * int(config["maxIter"])
-    return {
+    line = {
         "route": name, "layout": "row_regular" if stack.row_regular else "segment_csr",
+        "split": stack.hot_ids is not None,
         "ran": True, "pack_s": pack_s, "first_fit_s": first_s,
         "warm_fit_s": seconds, "entries_per_s": entries / seconds,
         "slots_a_step": stack.step_slots,
@@ -94,6 +113,10 @@ def by_builders(name, column, y, config, row_regular, fits):
         "coef_norm": float(np.linalg.norm(np.asarray(first.params[0]))),
         "resident_bytes": int(sum(a.nbytes for a in placed)),
     }
+    if shares:  # what the pack counted, and what its rule would have done
+        line.update(hot_share=shares[0][0], rule_splits=shares[0][1],
+                    cold_pad=stack.cold_pad)
+    return line, np.asarray(first.params[0])
 
 
 def main() -> int:
@@ -102,6 +125,7 @@ def main() -> int:
     parser.add_argument("--hot", type=int, default=4096)
     parser.add_argument("--fits", type=int, default=2)
     parser.add_argument("--keep", type=float, action="append", default=[])
+    parser.add_argument("--thin", type=float, action="append", default=[])
     args = parser.parse_args()
 
     import jax
@@ -175,8 +199,10 @@ def main() -> int:
             coef_norm=float(np.linalg.norm(first["coef"])),
             peak_bytes=(device.memory_stats() or {}).get(
                 "peak_bytes_in_use", 0),
-            ell_fits=after.get("train.sparse_ell_fits", 0)
-            - before.get("train.sparse_ell_fits", 0),
+            **{short: after.get(f"train.sparse_{short}", 0)
+               - before.get(f"train.sparse_{short}", 0)
+               for short in ("ell_fits", "hot_fits", "hot_declined",
+                             "hot_entries", "entries")},
             hidden={k: after[k] - before.get(k, 0)
                     for k in program.MUST_BE_ZERO
                     if after.get(k, 0) - before.get(k, 0)},
@@ -186,10 +212,31 @@ def main() -> int:
         print(json.dumps(line), flush=True)
     table = None
     column = CsrRows(dim, indptr, indices, values)
-    print(json.dumps(by_builders("plain_segment_csr", column, y, config,
-                                 False, args.fits)), flush=True)
-    # ragged tables on the two sides of the pack's rule
+    for name, row_regular in (("plain_unsplit", True),
+                              ("plain_segment_csr", False)):
+        print(json.dumps(by_builders(name, column, y, config, row_regular,
+                                     args.fits)[0]), flush=True)
     rng = np.random.default_rng(args.seed)
+    # thinner skews on the two sides of the split's rule
+    for thin in args.thin:
+        redrawn = rng.random(len(indices), dtype=np.float32) < thin
+        ids = indices.copy()
+        ids[redrawn] = rng.integers(0, dim, int(redrawn.sum()), np.int32)
+        del redrawn
+        thinned = CsrRows(dim, indptr, ids, values)
+        (unsplit, ref), (split, coef) = [
+            by_builders(f"thin_{thin:g}_{name}", thinned, y, config, True,
+                        args.fits, split=on)
+            for name, on in (("unsplit", False), ("split", True))]
+        for line in (unsplit, split):
+            line.update(
+                thin=thin,
+                split_speedup=unsplit["warm_fit_s"] / split["warm_fit_s"],
+                coef_gap=float(np.linalg.norm(coef - ref)
+                               / np.linalg.norm(ref)))
+            print(json.dumps(line), flush=True)
+        del thinned, ids
+    # ragged tables on the two sides of the pack's rule on the row widths
     for keep in args.keep:
         kept = rng.random(len(indices), dtype=np.float32) < keep
         counts = np.add.reduceat(kept, indptr[:-1])
@@ -197,7 +244,7 @@ def main() -> int:
                          indices[kept], values[kept])
         del kept
         pair = [by_builders(f"ragged_{keep:g}_{layout}", ragged, y, config,
-                            row_regular, args.fits)
+                            row_regular, args.fits)[0]
                 for layout, row_regular in (("segment_csr", False),
                                             ("row_regular", True))]
         ratio = pair[1]["slots_a_step"] / pair[0]["slots_a_step"]

@@ -2,16 +2,19 @@
 """One traced warm fit of the sparse cell's table, every device operation of
 its program listed: the per-operation split of PERF.md §5's sparse cell.
 
-    python scripts/sparse_step_trace.py --seed <n> [--rows N] [--segment-csr]
+    python scripts/sparse_step_trace.py --seed <n> [--rows N]
+                                        [--segment-csr | --unsplit]
 
 The benchmark's breakdown (``chipbench/trace_reduce.py``) keeps one of two
 programs' operations where both name one alike (PERF.md §7 (e)), so half the
 sparse cell's scatter is missing from its ``device_ops``.  This script holds
 its own profiler session (``scripts/fit_gaps.py:traced``) around ONE fit of
 ONE program, by the builders (``pack_sparse_minibatches``,
-``train_glm_sparse``; the row-regular layout as the pack picks it, or
-segment-CSR with ``--segment-csr``: the pack without ``row_regular``), and
-reads from the profile
+``train_glm_sparse``; the row-regular layout as the pack picks it: split by
+frequency on this table since PR 30; its unsplit step with ``--unsplit``:
+the split's rule lifted inside this script; or segment-CSR with
+``--segment-csr``: the pack without ``row_regular``), and reads from the
+profile
 
 * ``module_s``       the ``jit_bundled`` program's device time, and how much
                      of it the listed operations cover;
@@ -104,6 +107,7 @@ def main(argv=None) -> int:
     parser.add_argument("--seed", type=int, required=True)
     parser.add_argument("--rows", type=int, default=0)
     parser.add_argument("--segment-csr", action="store_true")
+    parser.add_argument("--unsplit", action="store_true")
     parser.add_argument("--out", default=os.path.join(
         ROOT, "chiprun_out", "sparse_step_trace"))
     args = parser.parse_args(argv)
@@ -122,11 +126,15 @@ def main(argv=None) -> int:
     indptr, indices, values, y = data_sparse.make_rows(
         config["data"], args.rows or int(config["rows"]), dim, args.seed)
     mesh = MLEnvironmentFactory.get_default().get_mesh()
+    if args.unsplit:
+        common._hot_split_wins = lambda *a: False
     stack = common.pack_sparse_minibatches(
         CsrRows(dim, indptr, indices, values), y, len(mesh.devices.flat),
         batch, dim=dim, row_regular=not args.segment_csr)
     layout = "row_regular" if stack.row_regular else "segment_csr"
-    placed = shard_batch_prefetched(mesh, (stack.ints, stack.floats))
+    if stack.hot_ids is not None:
+        layout += "_split"
+    placed = shard_batch_prefetched(mesh, stack.batch)
 
     def fit():
         start = (jnp.zeros((dim,), jnp.float32), jnp.zeros((), jnp.float32))
@@ -137,6 +145,7 @@ def main(argv=None) -> int:
         return time.perf_counter() - t
 
     report = {"layout": layout, "step_slots": stack.step_slots,
+              "cold_pad": getattr(stack, "cold_pad", 0),
               "steps": len(stack.ints), "first_fit_s": fit(),
               "warm_fit_s": fit()}
     os.makedirs(args.out, exist_ok=True)

@@ -1,5 +1,5 @@
-"""The one-pass GLM kernel compiled for a TPU v5e that is described, not
-attached: what the chip's compiler (Mosaic, XLA:TPU) accepts, which layout it
+"""The one-pass GLM kernel, and the sparse step's hot lookup (PR 30),
+compiled for a TPU v5e that is described, not attached: what the chip's compiler (Mosaic, XLA:TPU) accepts, which layout it
 gives the slab, and that the kernel's view of the slab copies nothing.  No
 chip time, about two seconds a compile.  Nothing runs, so nothing here is a
 time or a result; the chip runs are ``chip_smoke.py`` and the benchmark.
@@ -121,3 +121,49 @@ def test_the_chip_lays_other_shapes_otherwise(topo, quiet_cache, shape,
                              sharding=SingleDeviceSharding(topo.devices[0]))
     text = jax.jit(lambda a: a * 2.0).lower(x).compile().as_text()
     assert slab_layout(text) == layout
+
+
+# -- the sparse step's frequency split (PR 30) ---------------------------------
+
+
+def compiled_split_fit(topo, n_dev, steps, mb, width, dim, cold_pad, k,
+                       monkeypatch):
+    """The split sparse fit's program (unbundled, as above) at the sparse
+    cell's shapes for ``n_dev`` described chips."""
+    monkeypatch.setattr(pallas_kernels, "launch_interpreted", lambda: False)
+    mesh = Mesh(np.array(topo.devices[:n_dev]), ("data",))
+    step = common.make_hot_ell_grad_step("logistic", mb, width, dim, True,
+                                         interpret=False)
+    fn = common._build_fused_train_fn(
+        ("aot-sparse-ell-hot", n_dev, steps, mb, width, dim, cold_pad, k,
+         mesh), None, mesh, 0.1, 0.0, 1, 0.0, whole_batch_step=step)
+    replicated = NamedSharding(mesh, P())
+    sharded = NamedSharding(mesh, P("data"))
+
+    def leaf(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharded)
+
+    blocks = n_dev * steps
+    args = ((jax.ShapeDtypeStruct((dim,), jnp.float32, sharding=replicated),
+             jax.ShapeDtypeStruct((), jnp.float32, sharding=replicated)),
+            (leaf((blocks, width, mb), jnp.int32),
+             leaf((blocks, width + 2, mb), jnp.float32),
+             leaf((blocks, 2, cold_pad), jnp.int32),
+             leaf((blocks, cold_pad), jnp.float32),
+             leaf((n_dev, k), jnp.int32)))
+    return fn.lower(*args).compile()
+
+
+@pytest.mark.parametrize("n_dev,mb,k", [(1, ROWS, 16384), (1, ROWS, 4096),
+                                        (4, ROWS // 4, 16384)],
+                         ids=["criteo", "criteo-4096", "four-chips"])
+def test_the_split_sparse_step_compiles_with_both_hot_kernels(
+        topo, quiet_cache, monkeypatch, n_dev, mb, k):
+    compiled = compiled_split_fit(topo, n_dev, 8, mb, 39, 1_000_000,
+                                  116736, k, monkeypatch)
+    text = compiled.as_text()
+    # hot_scores and hot_grad, under strict check_vma on four chips too
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "hot_scores" in text and "hot_grad" in text
+    if n_dev > 1:
+        assert len(re.findall(r"= .* all-reduce\(", text)) >= 1
