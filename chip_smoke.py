@@ -509,6 +509,7 @@ def phase_nothing_hid(ctx):
         "train.sparse_ell_fits", "train.sparse_ell_declined",
         "train.sparse_hot_fits", "train.sparse_hot_entries",
         "train.sparse_hot_declined",
+        "train.kmeans_fits", "train.kmeans_row_iters",
         "slab_pool.hits", "pipeline.fused_dispatches",
         "fused.shard_map_dispatches", "fused.pallas_dispatches",
         "warmstart.hits", "warmstart.saves", "serving.requests")}

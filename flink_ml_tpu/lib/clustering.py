@@ -6,12 +6,16 @@ replicated, rows sharded over the ``data`` axis, one epoch = one device call
 computing assignments (argmin over an MXU-friendly x·cᵀ distance matrix) and
 the psum'd per-cluster sums/counts that yield the next centroids.
 
-Init is k-means++ on a host sample (seeded, reproducible); empty clusters
-keep their previous centroid.
+Init is k-means++ over a seeded sample of at most ``INIT_SAMPLE_CAP`` rows,
+run on the device (:func:`kmeans_plus_plus`); empty clusters keep their
+previous centroid.  Every product is computed in float32
+(``Precision.HIGHEST``): the distance product, and the per-cluster sums as a
+one-hot product (:func:`_onehot_sums`).
 """
 
 from __future__ import annotations
 
+from functools import lru_cache
 from typing import Optional
 
 import jax
@@ -33,7 +37,7 @@ from flink_ml_tpu.lib.params import (
     HasVectorColDefaultAsNull,
 )
 from flink_ml_tpu.ops.vector import DenseVector
-from flink_ml_tpu.parallel.collectives import psum
+from flink_ml_tpu.parallel.collectives import psum, pvary
 from flink_ml_tpu.params.shared import (
     HasPredictionCol,
     HasPredictionDetailCol,
@@ -59,11 +63,18 @@ class KMeansParams(
     """Shared column/k vocabulary for estimator and model."""
 
 
-def _pairwise_sq_dists(x, c):
-    """(n, k) squared distances; the x·cᵀ term is the MXU matmul."""
-    x2 = jnp.sum(x * x, axis=1, keepdims=True)
+def _pairwise_sq_dists(x, c, x2=None):
+    """(n, k) squared distances; the x·cᵀ term is the MXU matmul, at a
+    STATED float32 precision: left to JAX's default a float32 product on a
+    TPU is one bfloat16 pass, and ``x2 - 2 x·c + c2`` cancels, so that the
+    nearest centroid of a row would be decided by the rounding.  HIGHEST is
+    six bfloat16 passes (both operands in three pieces).  ``x2`` (n,) is the
+    rows' squared norms where the caller holds them."""
+    if x2 is None:
+        x2 = jnp.sum(x * x, axis=1)
     c2 = jnp.sum(c * c, axis=1)
-    return jnp.maximum(x2 - 2.0 * (x @ c.T) + c2, 0.0)
+    xc = jnp.dot(x, c.T, precision=jax.lax.Precision.HIGHEST)
+    return jnp.maximum(x2[:, None] - 2.0 * xc + c2, 0.0)
 
 
 # module-level + memoized so the jit cache survives across mapper instances
@@ -76,9 +87,6 @@ def _assign_fn(x, c):
     )
 
 
-from functools import lru_cache
-
-
 @lru_cache(maxsize=32)
 def _assign_apply(mesh):
     """Mesh-sharded assignment: rows over 'data', centroids replicated
@@ -88,43 +96,137 @@ def _assign_apply(mesh):
     return make_data_parallel_apply(_assign_fn, mesh, n_args=2)
 
 
-def make_kmeans_train_fn(mesh, k: int, max_iter: int, tol: float):
+def _onehot_sums(member, x):
+    """``memberᵀ @ x``: (k, d) per-cluster sums of the rows of ``x`` (rows, d)
+    by the membership ``member`` (rows, k) of zeros and ones.
+
+    A row scatter (``segment_sum``) is serial per row on a TPU (66 ms a pass
+    over 2,025,000 x 784 rows); the same sums as a product run on the MXU
+    (13.7 ms).  0 and 1 are exact in bfloat16
+    and ``HIGHEST`` multiplies a float32 value as its three bfloat16 pieces,
+    so the product gives the scatter's sums to the order of a sum."""
+    return jnp.dot(member.T, x, precision=jax.lax.Precision.HIGHEST)
+
+
+#: rows a Lloyd iteration takes at a time.  The iteration is written over
+#: row tiles so that no temporary is the table's size: the (tile, k) distances
+#: and the one-hot membership are what it holds beside the resident rows
+#: (48 MB).  On the chip 16384 / 32768 / 65536 rows read 29.14 / 28.43 /
+#: 28.32 ms an iteration at 2,025,000 x 896 (PERF.md §6, PR 31).
+_LLOYD_TILE_ROWS = 32768
+#: a TPU's lanes: rows at least ``_LANE_PAD_FROM`` wide are packed to a
+#: multiple of it (zero columns, at most a quarter more bytes), see
+#: :func:`packed_width`
+_LANES, _LANE_PAD_FROM = 128, 512
+
+
+def packed_width(dim: int) -> int:
+    """The width the fit's pack gives rows ``dim`` wide.  The distance
+    product streams rows through the MXU features-minor, and the chip lays a
+    float32 table whose width is no multiple of its 128 lanes rows-minor
+    instead (784 wide: no padding that way), so that the compiler opens the
+    program with a copy of the WHOLE table into the padded features-minor
+    layout: a temporary larger than the table (7.26 GB beside 6.35 at
+    2,025,000 x 784) and 20 ms a fit.  Packed lane-aligned the table lies as
+    the program reads it.  Zero columns change no distance and no sum; a
+    narrow table is left as it is (its padding would be most of it)."""
+    if dim < _LANE_PAD_FROM:
+        return dim
+    return -(-dim // _LANES) * _LANES
+
+
+def _lloyd_pass(x, w, x2, c, k: int, tile: int):
+    """One pass over the local rows, a tile at a time: (cost, per-cluster
+    sums (k, d), counts (k,)) of the rows under their nearest centroid of
+    ``c``.  ``w`` is the pack's mask (1 a row of the table, 0 a pad row),
+    ``x2`` the rows' squared norms."""
+    n = x.shape[0]
+    tile = max(1, min(int(tile), n))
+    n_full = n // tile
+    clusters = jnp.arange(k, dtype=jnp.int32)
+
+    def part(xt, wt, x2t):
+        with jax.named_scope("fmt.train.kmeans.assign"):
+            d = _pairwise_sq_dists(xt, c, x2t)
+            assign = jnp.argmin(d, axis=1).astype(jnp.int32)
+            cost = jnp.sum(jnp.min(d, axis=1) * wt)
+        with jax.named_scope("fmt.train.kmeans.update"):
+            member = jnp.where(
+                (assign[:, None] == clusters[None, :]) & (wt[:, None] > 0),
+                1.0, 0.0).astype(jnp.float32)
+            sums = _onehot_sums(member, xt)
+            counts = jnp.sum(member, axis=0)
+        return cost, sums, counts
+
+    def body(i, acc):
+        at = [jax.lax.dynamic_slice_in_dim(a, i * tile, tile, axis=0)
+              for a in (x, w, x2)]
+        return jax.tree_util.tree_map(jnp.add, acc, part(*at))
+
+    # the sums of a shard vary over the data axis from the first tile on
+    acc = tuple(pvary(jnp.zeros(shape, jnp.float32)) for shape in
+                ((), (k, x.shape[1]), (k,)))
+    acc = jax.lax.fori_loop(0, n_full, body, acc)
+    if n_full * tile < n:  # the rows a whole number of tiles leaves
+        acc = jax.tree_util.tree_map(
+            jnp.add, acc, part(*(a[n_full * tile:] for a in (x, w, x2))))
+    return acc
+
+
+def make_kmeans_train_fn(mesh, k: int, max_iter: int, tol: float,
+                         bundle: bool = True):
     """The WHOLE Lloyd run as one compiled device program.
 
     Reuses the GLM fused-loop scaffolding (lib/common.py
     ``_build_fused_train_fn``) with a Lloyd ``epoch_fn``: epochs are a
     ``lax.while_loop`` with the convergence test (centroid-shift norm vs
-    tol) evaluated on device, so training runs start-to-finish with zero
-    host round-trips — one transfer in (rows + weights), one out (centroids
-    + cost history + epochs).  Rows shard over ``data``; the per-cluster
-    sums/counts/cost ``psum`` over it (the reference's reduce-average round,
-    SURVEY.md §3.3, fused on-chip); empty clusters keep their previous
-    centroid.
+    tol) evaluated on device, so the iterations run start-to-finish with no
+    host round-trip.  A fit makes two device calls: the k-means++ init over
+    the resident rows (:func:`kmeans_plus_plus_rows`, whose centroids come
+    back to the host once), then this program, bundled as the GLM fits are
+    (``jit_bundled``: centroids + cost history + epochs + shift in ONE
+    buffer, one fetch).  The rows go up once a table, through the slab pool.
+    Rows shard over ``data``; the per-cluster sums/counts/cost ``psum`` over
+    it (the reference's reduce-average round, SURVEY.md §3.3, fused
+    on-chip); empty clusters keep their previous centroid.  An iteration
+    reads its rows tile by tile (:func:`_lloyd_pass`): distance product,
+    argmin and cost under ``fmt.train.kmeans.assign``, the one-hot sums and
+    counts under ``fmt.train.kmeans.update``.  The program's state is
+    ``(centroids (k, d), trail (max_iter, k, d))``: the trail keeps the
+    centroids every iteration started from, which come back with the result
+    (``KMeansModel.train_centroids_``).
     """
     from flink_ml_tpu.lib.common import _build_fused_train_fn
 
-    key = ("kmeans", mesh, int(k), int(max_iter), float(tol))
+    tile = int(_LLOYD_TILE_ROWS)
+    key = ("kmeans", mesh, int(k), int(max_iter), float(tol), tile)
 
-    def lloyd_epoch(c, batch):
+    def lloyd_epoch(params, batch):
+        # the trail holds the centroids each of the last ``max_iter``
+        # iterations started from, oldest first: every iteration drops the
+        # oldest and appends its own (7 MB moved at k 100 x 896, 20 deep)
+        c, trail = params
         x, w = batch  # local shards: (rows, d), (rows,)
-        d = _pairwise_sq_dists(x, c)
-        assign = jnp.argmin(d, axis=1)
-        cost = psum(jnp.sum(jnp.min(d, axis=1) * w), "data")
-        sums = psum(
-            jax.ops.segment_sum(x * w[:, None], assign, num_segments=k),
-            "data",
-        )
-        counts = psum(jax.ops.segment_sum(w, assign, num_segments=k), "data")
-        new_c = jnp.where(
-            counts[:, None] > 0,
-            sums / jnp.maximum(counts[:, None], 1.0),
-            c,
-        )
-        delta = jnp.sqrt(jnp.sum((new_c - c) ** 2))
-        return new_c, cost, delta
+        with jax.named_scope("fmt.train.kmeans.assign"):
+            # the rows' squared norms do not change with the centroids: the
+            # compiler moves this pass out of the loop over iterations, one
+            # a fit (8 ms) where a tile's own would be one an iteration
+            x2 = jnp.sum(x * x, axis=1)
+        cost, sums, counts = (
+            psum(a, "data") for a in _lloyd_pass(x, w, x2, c, k, tile))
+        with jax.named_scope("fmt.train.kmeans.update"):
+            new_c = jnp.where(
+                counts[:, None] > 0,
+                sums / jnp.maximum(counts[:, None], 1.0),
+                c,
+            )
+            delta = jnp.sqrt(jnp.sum((new_c - c) ** 2))
+            trail = jnp.concatenate([trail[1:], c[None]])
+        return (new_c, trail), cost, delta
 
     return _build_fused_train_fn(
-        key, None, mesh, 0.0, 0.0, max_iter, tol, epoch_fn=lloyd_epoch
+        key, None, mesh, 0.0, 0.0, max_iter, tol, epoch_fn=lloyd_epoch,
+        bundle=bundle,
     )
 
 
@@ -142,7 +244,7 @@ def train_kmeans(
 ):
     """Drive fused Lloyd iterations to termination (TrainResult contract).
 
-    ``init_centroids`` may be a thunk (the k-means++ host pass): it is only
+    ``init_centroids`` may be a thunk (the k-means++ pass): it is only
     resolved on a fresh start — a checkpoint resume (or a finished-run no-op
     re-fit) never pays for it.  With a CheckpointConfig the run executes as
     fused chunks with centroid snapshots between them, through the same
@@ -156,23 +258,43 @@ def train_kmeans(
 
     batch = (Xp, wp)
 
+    trails = []  # of the fused runs this call makes, in order
+
     def run(n_epochs, cents, dev_batch=None):
-        return _run_fused_train(
+        cents = jnp.asarray(cents, dtype=jnp.float32)
+        result = _run_fused_train(
             make_kmeans_train_fn(mesh, k, n_epochs, tol),
-            jnp.asarray(cents, dtype=jnp.float32),
+            (cents, jnp.zeros((n_epochs,) + cents.shape, jnp.float32)),
             batch if dev_batch is None else dev_batch, mesh,
             batch_preplaced=dev_batch is not None, n_rows=n_rows,
         )
+        # the state's trail goes its own way: the centroids are the params
+        # (what a snapshot holds, what the next chunk starts from)
+        result.params, trail = result.params
+        trails.append(trail[n_epochs - result.epochs:])
+        # beside train.fused_runs: the Lloyd runs, and the rows they
+        # assigned (rows x iterations run)
+        obs.counter_add("train.kmeans_fits")
+        obs.counter_add("train.kmeans_row_iters", n_rows * result.epochs)
+        return result
+
+    def with_trail(result):
+        # the centroids every iteration THIS call ran started from
+        result.centroid_trail = (
+            trails[0] if len(trails) == 1 else  # no copy of the usual one
+            np.concatenate(trails) if trails else
+            np.zeros((0,) + np.shape(result.params), np.float32))
+        return result
 
     if checkpoint is None:
         cents0 = np.asarray(_resolve_thunk(init_centroids), dtype=np.float32)
-        return run(max_iter, cents0, _resolve_thunk(device_batch))
+        return with_trail(run(max_iter, cents0, _resolve_thunk(device_batch)))
     dim = Xp.shape[1]
-    return run_chunked_checkpoint(
+    return with_trail(run_chunked_checkpoint(
         run, init_centroids, max_iter, tol, checkpoint, mesh, batch,
         device_batch=device_batch,
         like=np.zeros((k, dim), dtype=np.float32),  # structure template only
-    )
+    ))
 
 
 def _allgather_sample_pool(local_sample: np.ndarray, per: int, dim: int,
@@ -204,22 +326,92 @@ def _allgather_sample_pool(local_sample: np.ndarray, per: int, dim: int,
     return pool
 
 
-def kmeans_plus_plus(X: np.ndarray, k: int, rng: np.random.RandomState) -> np.ndarray:
-    """Standard k-means++ seeding on the host (runs on a bounded sample)."""
-    n = X.shape[0]
-    first = rng.randint(n)
-    centers = [X[first]]
-    d2 = np.sum((X - X[first]) ** 2, axis=1)
-    for _ in range(1, k):
-        total = d2.sum()
-        if total <= 0:
-            centers.append(X[rng.randint(n)])
-            continue
-        probs = d2 / total
-        idx = rng.choice(n, p=probs)
-        centers.append(X[idx])
-        d2 = np.minimum(d2, np.sum((X - X[idx]) ** 2, axis=1))
-    return np.stack(centers)
+def _d2_race(sample, k: int, seed):
+    """k-means++ over the rows of ``sample`` (S, d) float32: the first
+    centre uniform, each further one by D² sampling.
+
+    The draw is an exponential race: row i's clock rings at ``e_i / d2_i``
+    with ``e_i`` standard exponential, and the first to ring is chosen, which
+    picks row i with probability ``d2_i / sum(d2)`` as the inverted
+    cumulative sum does.  The race is an argmax, so that rounding decides a
+    draw only where two clocks ring within a rounding of each other; through
+    100,000 partial sums rounding would pick a neighbouring row every few
+    draws, and no second implementation could be held to the same rows.
+    Returns the centres (k, d)."""
+    size = sample.shape[0]
+    key = jax.random.PRNGKey(seed)
+
+    def dist_to(row):
+        return jnp.sum((sample - sample[row]) ** 2, axis=1)
+
+    first = jax.random.randint(
+        jax.random.fold_in(key, 0), (), 0, size, jnp.int32)
+    chosen0 = jnp.zeros((k,), jnp.int32).at[0].set(first)
+
+    def draw(j, carry):
+        d2, chosen = carry
+        clock = jax.random.exponential(
+            jax.random.fold_in(key, j), (size,), jnp.float32)
+        # every row already a centre: any row, uniformly
+        rate = jnp.where(jnp.sum(d2) > 0, d2, 1.0)
+        row = jnp.argmax(rate / jnp.maximum(clock, 1e-30)).astype(jnp.int32)
+        return jnp.minimum(d2, dist_to(row)), chosen.at[j].set(row)
+
+    _d2, chosen = jax.lax.fori_loop(
+        1, k, draw, (dist_to(first), chosen0))
+    return sample[chosen]
+
+
+@lru_cache(maxsize=32)
+def _kmeans_pp_fn(k: int):
+    return jax.jit(lambda sample, seed: _d2_race(
+        sample.astype(jnp.float32), k, seed))
+
+
+def kmeans_plus_plus(X, k: int, seed: int) -> np.ndarray:
+    """Standard k-means++ seeding of a bounded sample ``X`` (rows, d), from
+    ``seed``, in float32 on the device (:func:`_d2_race`): k − 1 passes over
+    the sample.  Returns the centres (k, d) as a host float32 array."""
+    sample = jnp.asarray(np.asarray(X, dtype=np.float32))
+    centres = _kmeans_pp_fn(int(k))(sample, np.uint32(int(seed) % 2**32))
+    return np.asarray(centres, dtype=np.float32)
+
+
+@lru_cache(maxsize=32)
+def _kmeans_pp_rows_fn(mesh, k: int):
+    """k-means++ over rows ``take`` of the RESIDENT table (the fit's placed
+    batch, rows sharded over ``data``): every device gathers the sample rows
+    it holds, the ``psum`` makes the sample whole on each, and each runs the
+    same race.  No row goes through the host."""
+    from jax.sharding import PartitionSpec as P
+
+    from flink_ml_tpu.parallel.collectives import shard_map
+
+    def local(x, take, seed):
+        rows = x.shape[0]
+        at = take - jax.lax.axis_index("data") * rows
+        mine = (at >= 0) & (at < rows)
+        sample = jnp.where(
+            mine[:, None], x[jnp.clip(at, 0, rows - 1)], 0.0)
+        sample = psum(sample, "data")
+        return _d2_race(sample, k, seed)
+
+    return jax.jit(shard_map(
+        local, mesh=mesh, in_specs=(P("data"), P(), P()), out_specs=P(),
+        check_vma=False,
+    ))
+
+
+def kmeans_plus_plus_rows(device_batch, take: np.ndarray, k: int, seed: int,
+                          mesh) -> np.ndarray:
+    """:func:`kmeans_plus_plus` over the rows ``take`` (row numbers in the
+    table's order) of the placed batch: the same centres as
+    ``kmeans_plus_plus(X[take], k, seed)``, computed where the rows are."""
+    x, _w = device_batch
+    centres = _kmeans_pp_rows_fn(mesh, int(k))(
+        x, jnp.asarray(take, dtype=jnp.int32),
+        np.uint32(int(seed) % 2**32))
+    return np.asarray(centres, dtype=np.float32)
 
 
 class KMeansModelMapper(ModelMapper):
@@ -321,7 +513,13 @@ class KMeansModelMapper(ModelMapper):
 
 
 class KMeansModel(TableModelBase, KMeansParams):
-    """Nearest-centroid assignment model; model data = the centroid table."""
+    """Nearest-centroid assignment model; model data = the centroid table.
+
+    A model that ``KMeans.fit`` returned also says how the fit went:
+    ``train_epochs_`` (iterations run), ``train_costs_`` (the cost of every
+    one: the sum of squared distances under the centroids it started from),
+    ``train_cost_`` (the last of them) and ``train_centroids_`` (those
+    centroids, ``(iterations, k, dim)`` float32, the init first)."""
 
     REQUIRED_MODEL_COL = "centroid"
 
@@ -342,7 +540,7 @@ class KMeans(Estimator, KMeansParams, HasMaxIter, HasTol, HasSeed, HasCheckpoint
     centroid snapshots between them (resume restores the latest snapshot and
     skips re-init)."""
 
-    INIT_SAMPLE_CAP = 100_000  # k-means++ host sample bound
+    INIT_SAMPLE_CAP = 100_000  # k-means++ sample bound
 
     def _checkpoint_config(self):
         directory = self.get_checkpoint_dir()
@@ -359,12 +557,46 @@ class KMeans(Estimator, KMeansParams, HasMaxIter, HasTol, HasSeed, HasCheckpoint
 
         from flink_ml_tpu.table import slab_pool
 
-        self._fit_pool_stats0 = (
-            *slab_pool.pool().counters(), _time.perf_counter()
-        )
-        (table,) = inputs
-        if getattr(table, "is_chunked", False):
-            return self._fit_out_of_core(table)
+        # fit.wall's direct children (fit.prepare, slab_pool.lookup,
+        # kmeans.init, train.*, fit.finish, fit.report) account for a warm
+        # fit, as GlmEstimatorBase.fit's do
+        with obs.span("fit.wall"):
+            self._fit_pool_stats0 = (
+                *slab_pool.pool().counters(), _time.perf_counter()
+            )
+            (table,) = inputs
+            if getattr(table, "is_chunked", False):
+                return self._fit_out_of_core(table)
+            with obs.span("fit.prepare"):
+                train = self._prepare(table)
+            return self._finish(train(), self.get_k())
+
+    #: seeded samples kept a table (400 KB each): a grid of seeds re-fitted
+    #: in turn finds every one of its samples drawn
+    _INIT_ROWS_KEPT = 16
+
+    def _init_rows(self, table, n: int) -> np.ndarray:
+        """The seeded sample k-means++ runs over, as row numbers: all rows
+        of a table within ``INIT_SAMPLE_CAP``, else that many drawn without
+        replacement from the seed.  Kept with the table's packs, one entry
+        for all seeds: the draw shuffles every row number, tens of
+        milliseconds at two million rows."""
+        cap, seed = self.INIT_SAMPLE_CAP, self.get_seed()
+        if n <= cap:
+            return np.arange(n, dtype=np.int32)
+        drawn = table.cached_pack(("kmeans-init-rows", n, cap), dict)
+        if seed not in drawn:
+            while len(drawn) >= self._INIT_ROWS_KEPT:
+                drawn.pop(next(iter(drawn)))
+            drawn[seed] = np.random.RandomState(seed).choice(
+                n, cap, replace=False).astype(np.int32)
+        return drawn[seed]
+
+    def _prepare(self, table):
+        """Everything before the device calls: the features, the (cached)
+        pack, the sample's rows.  Returns the fit, bound to its arguments."""
+        from flink_ml_tpu.table import slab_pool
+
         X, dim = resolve_features(table, self)
         k = self.get_k()
         n = X.shape[0]
@@ -378,42 +610,13 @@ class KMeans(Estimator, KMeansParams, HasMaxIter, HasTol, HasSeed, HasCheckpoint
             agree_max,
             agree_sum,
             local_data_parallel_size,
+            shard_batch_prefetched,
         )
 
         n_global = int(agree_sum(np.asarray([n]))[0]) if n_proc > 1 else n
         if n_global < k:
             raise ValueError(f"k={k} exceeds number of rows {n_global}")
         n_dev = local_data_parallel_size(mesh)
-
-        if n_proc > 1:
-            # cross-process consistent seeding: each process contributes an
-            # equal-size deterministic sample of ITS shard; the allgathered
-            # pool is identical on every process, so the same-seeded
-            # k-means++ pass picks the same replicated centroids everywhere.
-            # Eager (not inside the init thunk): the gather is a collective
-            # every process must reach, never skipped by a lazy resolve.
-            rng = np.random.RandomState(self.get_seed())
-            per = -(-self.INIT_SAMPLE_CAP // n_proc)
-            s_p = min(n, per)
-            local_sample = (
-                X if n == s_p else X[rng.choice(n, s_p, replace=False)]
-            )
-            pool = _allgather_sample_pool(local_sample, per, dim, k)
-
-            def init():
-                return kmeans_plus_plus(
-                    pool, k, np.random.RandomState(self.get_seed())
-                )
-        else:
-            def init():
-                # the k-means++ host pass, as a thunk: resolved by
-                # train_kmeans only on a fresh start — a snapshot resume
-                # skips it entirely
-                rng = np.random.RandomState(self.get_seed())
-                sample = X if n <= self.INIT_SAMPLE_CAP else X[
-                    rng.choice(n, self.INIT_SAMPLE_CAP, replace=False)
-                ]
-                return kmeans_plus_plus(sample.astype(np.float64), k, rng)
 
         # local rows pad to a per-shard row count agreed across processes
         # (shard_batch needs identically-shaped local blocks; pad rows
@@ -422,71 +625,124 @@ class KMeans(Estimator, KMeansParams, HasMaxIter, HasTol, HasSeed, HasCheckpoint
         if n_proc > 1:
             (rows_per_shard,) = agree_max(rows_per_shard)
 
+        width = packed_width(dim)
+
         def build():
             n_pad = rows_per_shard * n_dev
-            Xp = np.zeros((n_pad, dim), dtype=np.float32)
-            Xp[:n] = X
+            Xp = np.zeros((n_pad, width), dtype=np.float32)
+            Xp[:n, :dim] = X
             wp = np.zeros((n_pad,), dtype=np.float32)
             wp[:n] = 1.0
             return Xp, wp
 
         layout_key = ("kmeans", self.get_vector_col(),
                       tuple(self.get_feature_cols() or ()), n_dev,
-                      rows_per_shard)
+                      rows_per_shard, width)
         Xp, wp = table.cached_pack(layout_key, build)
-        # a thunk: a no-op resume (finished snapshot) must not pay the
-        # host->device transfer, so placement resolves lazily downstream;
-        # the placement itself rides the cross-fit slab pool (re-fitting
-        # the same table content skips the transfer) and double-buffers
-        # the H2D hop
-        from flink_ml_tpu.parallel.mesh import shard_batch_prefetched
-        from flink_ml_tpu.table import slab_pool
-
         kmeans_cols = (
             [self.get_vector_col()] if self.get_vector_col() is not None
             else list(self.get_feature_cols() or ())
         )
-        device_batch = lambda: slab_pool.get_or_place(  # noqa: E731
-            table, layout_key + ("dev",), mesh,
-            lambda: shard_batch_prefetched(mesh, (Xp, wp)),
-            cols=kmeans_cols or None,
-        )
+        placed = []
+
+        def device_batch():
+            # a thunk: a no-op resume (finished snapshot) must not pay the
+            # host->device transfer, so placement resolves lazily, once, for
+            # the init and the train call alike; the placement itself rides
+            # the cross-fit slab pool (re-fitting the same table content
+            # skips the transfer) and double-buffers the H2D hop
+            if not placed:
+                placed.append(slab_pool.get_or_place(
+                    table, layout_key + ("dev",), mesh,
+                    lambda: shard_batch_prefetched(mesh, (Xp, wp)),
+                    cols=kmeans_cols or None,
+                ))
+            return placed[0]
+
+        seed = self.get_seed()
+        if n_proc > 1:
+            # cross-process consistent seeding: each process contributes an
+            # equal-size deterministic sample of ITS shard; the allgathered
+            # pool is identical on every process, so the same-seeded
+            # k-means++ pass picks the same replicated centroids everywhere.
+            # Eager (not inside the init thunk): the gather is a collective
+            # every process must reach, never skipped by a lazy resolve.
+            rng = np.random.RandomState(seed)
+            per = -(-self.INIT_SAMPLE_CAP // n_proc)
+            s_p = min(n, per)
+            local_sample = (
+                X if n == s_p else X[rng.choice(n, s_p, replace=False)]
+            )
+            pool = _allgather_sample_pool(local_sample, per, dim, k)
+
+            def init():
+                with obs.span("kmeans.init"):
+                    return np.pad(kmeans_plus_plus(pool, k, seed),
+                                  ((0, 0), (0, width - dim)))
+        else:
+            take = self._init_rows(table, n)
+
+            def init():
+                # the k-means++ pass over the resident rows, as a thunk:
+                # resolved by train_kmeans only on a fresh start — a
+                # snapshot resume skips it entirely.  The pool lookup is
+                # its own span, ahead of this one
+                batch = device_batch()
+                with obs.span("kmeans.init"):
+                    return kmeans_plus_plus_rows(batch, take, k, seed, mesh)
 
         # guarded for the health sentinel's diagnostics, but with NO retry
         # budget: KMeans has no learning rate to back off, so a replay
         # would re-diverge bit-identically — fail fast with the guard's
         # framing instead of multiplying time-to-error
-        result = fault.run_guarded(
-            lambda _lr_scale: train_kmeans(
-                init, k, Xp, wp, mesh,
-                max_iter=self.get_max_iter(), tol=self.get_tol(),
-                n_rows=n_global,
-                checkpoint=checkpoint, device_batch=device_batch,
-            ),
-            what=type(self).__name__, max_retries=0,
-        )
-        return self._finish(result, k)
+        def train():
+            result = fault.run_guarded(
+                lambda _lr_scale: train_kmeans(
+                    init, k, Xp, wp, mesh,
+                    max_iter=self.get_max_iter(), tol=self.get_tol(),
+                    n_rows=n_global,
+                    checkpoint=checkpoint, device_batch=device_batch,
+                ),
+                what=type(self).__name__, max_retries=0,
+            )
+            # the pack's zero columns go (see packed_width)
+            result.params = np.asarray(result.params)[:, :dim]
+            result.centroid_trail = result.centroid_trail[:, :, :dim]
+            return result
+
+        return train
 
     def _finish(self, result, k: int) -> KMeansModel:
         from flink_ml_tpu.lib.common import fit_pool_extra
 
-        centroids = np.asarray(result.params, dtype=np.float64)
-        model_table = Table.from_rows(
-            [(int(i), DenseVector(centroids[i])) for i in range(k)],
-            CENTROID_SCHEMA,
-        )
-        model = KMeansModel()
-        model.get_params().merge(self.get_params())
-        model.set_model_data(model_table)
-        model.train_epochs_ = result.epochs
-        model.train_cost_ = float(result.losses[-1]) if result.losses else 0.0
-        model.train_metrics_ = result.metrics
-        obs.fit_report(
-            type(self).__name__,
-            step_metrics=result.metrics,
-            extra={"epochs": result.epochs, "cost": model.train_cost_,
-                   "k": int(k), **fit_pool_extra(self, result)},
-        )
+        with obs.span("fit.finish"):
+            centroids = np.asarray(result.params, dtype=np.float64)
+            model_table = Table.from_rows(
+                [(int(i), DenseVector(centroids[i])) for i in range(k)],
+                CENTROID_SCHEMA,
+            )
+            model = KMeansModel()
+            model.get_params().merge(self.get_params())
+            model.set_model_data(model_table)
+            model.train_epochs_ = result.epochs
+            # the cost of every iteration run (the sum of squared distances
+            # under the centroids the iteration STARTED from), and the last
+            model.train_costs_ = [float(c) for c in result.losses]
+            model.train_cost_ = (
+                model.train_costs_[-1] if model.train_costs_ else 0.0)
+            # the centroids every iteration this fit ran started from
+            # (iterations, k, dim); the out-of-core fit keeps none
+            model.train_centroids_ = np.asarray(
+                getattr(result, "centroid_trail",
+                        np.zeros((0,) + centroids.shape)), np.float32)
+            model.train_metrics_ = result.metrics
+        with obs.span("fit.report"):
+            obs.fit_report(
+                type(self).__name__,
+                step_metrics=result.metrics,
+                extra={"epochs": result.epochs, "cost": model.train_cost_,
+                       "k": int(k), **fit_pool_extra(self, result)},
+            )
         return model
 
     def _fit_out_of_core(self, table) -> KMeansModel:
@@ -572,9 +828,7 @@ class KMeans(Estimator, KMeansParams, HasMaxIter, HasTol, HasSeed, HasCheckpoint
                 per, dim, k,
             )
             (pad_to_blocks,) = agree_max(-(-n_seen // rows_per_block))
-            cents0 = kmeans_plus_plus(
-                pool, k, np.random.RandomState(self.get_seed())
-            )
+            cents0 = kmeans_plus_plus(pool, k, self.get_seed())
         elif resuming:
             first = next(iter(table.chunks()), None)
             if first is None:
@@ -588,7 +842,7 @@ class KMeans(Estimator, KMeansParams, HasMaxIter, HasTol, HasSeed, HasCheckpoint
             dim = sample.shape[1]
             if n_seen < k:
                 raise ValueError(f"k={k} exceeds number of rows {n_seen}")
-            cents0 = kmeans_plus_plus(sample.astype(np.float64), k, rng)
+            cents0 = kmeans_plus_plus(sample, k, self.get_seed())
 
         blocks = oc.rows_blocks_factory(table, extract, n_dev, rows_per_block,
                                         pad_to_blocks=pad_to_blocks,

@@ -167,3 +167,63 @@ def test_the_split_sparse_step_compiles_with_both_hot_kernels(
     assert "hot_scores" in text and "hot_grad" in text
     if n_dev > 1:
         assert len(re.findall(r"= .* all-reduce\(", text)) >= 1
+
+
+# -- the centroid fit (PR 31) --------------------------------------------------
+
+
+def compiled_kmeans(topo, n_dev, rows, width, k=100, iters=20):
+    """The fused Lloyd program (unbundled: the bundle is float64 under the
+    suite's x64) for ``n_dev`` described chips, ``rows`` a chip."""
+    from flink_ml_tpu.lib import clustering
+
+    mesh = Mesh(np.array(topo.devices[:n_dev]), ("data",))
+    fn = clustering.make_kmeans_train_fn(mesh, k, iters, 0.0, bundle=False)
+    replicated = NamedSharding(mesh, P())
+    sharded = NamedSharding(mesh, P("data"))
+    args = ((jax.ShapeDtypeStruct((k, width), jnp.float32,
+                                  sharding=replicated),
+             jax.ShapeDtypeStruct((iters, k, width), jnp.float32,
+                                  sharding=replicated)),
+            (jax.ShapeDtypeStruct((n_dev * rows, width), jnp.float32,
+                                  sharding=sharded),
+             jax.ShapeDtypeStruct((n_dev * rows,), jnp.float32,
+                                  sharding=sharded)))
+    return fn.lower(*args).compile()
+
+
+def table_layout(text):
+    return re.search(r"entry_computation_layout=\{\(.*?f32\[\d+,\d+,\d+\]"
+                     r"\{[^}]*\}, f32\[\d+,\d+\](\{[^}]*\})", text).group(1)
+
+
+@pytest.mark.parametrize("n_dev", [1, 4], ids=["one-chip", "four-chips"])
+def test_the_lloyd_program_reads_the_lane_aligned_table_where_it_lies(
+        topo, quiet_cache, n_dev):
+    """mnist8m's quarter a chip, packed 896 wide: the table lies
+    features-minor, as the distance product streams it, and the program
+    holds no temporary near the table's size (a tile's worth)."""
+    from flink_ml_tpu.lib import clustering
+
+    width = clustering.packed_width(784)
+    assert width == 896
+    compiled = compiled_kmeans(topo, n_dev, 2_025_000, width)
+    text = compiled.as_text()
+    assert table_layout(text) == "{1,0:T(8,128)}"
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes >= 2_025_000 * 896 * 4
+    assert memory.temp_size_in_bytes < 2**30  # 18 MB at a tile of 16384
+    if n_dev > 1:
+        assert len(re.findall(r"= .* all-reduce\(", text)) >= 1
+
+
+def test_the_chip_lays_the_784_wide_table_rows_minor_and_the_program_copies_it(
+        topo, quiet_cache):
+    """Why the pack pads: left 784 wide the chip lays the table rows-minor
+    (no padding that way) and the program opens with a copy of all of it
+    into the padded features-minor layout, a temporary larger than the
+    table."""
+    compiled = compiled_kmeans(topo, 1, 2_025_000, 784)
+    assert table_layout(compiled.as_text()) == "{0,1:T(8,128)}"
+    assert compiled.memory_analysis().temp_size_in_bytes > \
+        2_025_000 * 784 * 4
