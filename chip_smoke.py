@@ -354,8 +354,9 @@ def phase_kernels(ctx):
     """glm_grad and serve_chain compiled for real (interpret=False) at widths
     28 and 512, each against the XLA formulation it replaces; glm_grad also
     inside the fused training program on the mesh (strict check_vma), chosen
-    there by the program's own rule from the placed slab; and serve_chain
-    through the FusedRun path, raw and masked, f32 and bf16."""
+    there by the program's own rule from the placed slab; lloyd_sums inside
+    a KMeans fit, chosen by the estimator's rule from the table; and
+    serve_chain through the FusedRun path, raw and masked, f32 and bf16."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -442,7 +443,35 @@ def phase_kernels(ctx):
     np.testing.assert_allclose(fits["pallas"].losses, fits["xla"].losses,
                                rtol=1e-5)
 
-    # 3. serve_chain through the product path: a 512-wide scaler -> LR
+    # 3. the Lloyd iteration's one-read kernel: a KMeans fit of the same
+    # 512-wide rows takes it by the estimator's own rule (strict check_vma
+    # on the default mesh); against the XLA tiles from the same init
+    from flink_ml_tpu.lib import clustering
+
+    k, iters = 16, 2
+    before = _counters()
+    t0 = time.perf_counter()
+    model = (clustering.KMeans().set_vector_col("features")
+             .set_prediction_col("cluster").set_k(k).set_max_iter(iters)
+             .set_seed(1)).fit(_table(Xw))
+    after = _counters()
+    assert after["train.kmeans_onepass_fits"] - before.get(
+        "train.kmeans_onepass_fits", 0) == 1, after
+    assert after.get("train.kmeans_onepass_declined", 0) == before.get(
+        "train.kmeans_onepass_declined", 0), after
+    trail = model.train_centroids_
+    tiles = common._run_fused_train(
+        clustering.make_kmeans_train_fn(mesh, k, iters, 0.0),
+        (jnp.asarray(trail[0]), jnp.zeros_like(trail)),
+        (Xw, np.ones((len(Xw),), np.float32)), mesh, n_rows=len(Xw))
+    out["compile_s"] += time.perf_counter() - t0
+    # a row within rounding of two centroids may go either way: the norm
+    want = np.asarray(tiles.params[0], np.float64)
+    gap = np.linalg.norm(model.centroids() - want) / np.linalg.norm(want)
+    assert gap <= 1e-3, gap
+    np.testing.assert_allclose(model.train_costs_, tiles.losses, rtol=1e-5)
+
+    # 4. serve_chain through the product path: a 512-wide scaler -> LR
     # pipeline, FMT_SERVE_PALLAS on against off, raw (quarantine off) and
     # masked (the NaN/Inf scan deferred into the kernel, one bad row
     # planted), f32 and bf16 placement, 4096-row bucket and a 1-row one
@@ -510,6 +539,7 @@ def phase_nothing_hid(ctx):
         "train.sparse_hot_fits", "train.sparse_hot_entries",
         "train.sparse_hot_declined",
         "train.kmeans_fits", "train.kmeans_row_iters",
+        "train.kmeans_onepass_fits", "train.kmeans_onepass_declined",
         "slab_pool.hits", "pipeline.fused_dispatches",
         "fused.shard_map_dispatches", "fused.pallas_dispatches",
         "warmstart.hits", "warmstart.saves", "serving.requests")}

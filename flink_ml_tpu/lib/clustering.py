@@ -8,9 +8,18 @@ the psum'd per-cluster sums/counts that yield the next centroids.
 
 Init is k-means++ over a seeded sample of at most ``INIT_SAMPLE_CAP`` rows,
 run on the device (:func:`kmeans_plus_plus`); empty clusters keep their
-previous centroid.  Every product is computed in float32
-(``Precision.HIGHEST``): the distance product, and the per-cluster sums as a
-one-hot product (:func:`_onehot_sums`).
+previous centroid.  Every product is computed in float32: the distance
+product in six bfloat16 passes (``Precision.HIGHEST``), the per-cluster sums
+as a one-hot product (:func:`_onehot_sums`).  An iteration reads its rows a
+tile at a time (:func:`_lloyd_pass`), by one of two lowerings of the one
+algorithm, picked from what the fit can observe, no knob
+(:func:`_lloyd_kernel_rows`): on a TPU, float32 rows a multiple of 128 wide
+(every table 512 columns wide or wider, as :func:`packed_width` lays it) go
+through ONE Pallas call a pass (``ops/pallas_kernels.py:lloyd_sums``: a tile
+read once, the sums from its three bfloat16 pieces, nine MXU passes a row);
+any other table, mesh or platform through two XLA operations a tile (two
+reads, twelve passes).  ``train.kmeans_onepass_fits`` /
+``train.kmeans_onepass_declined`` say which a fit took.
 """
 
 from __future__ import annotations
@@ -135,14 +144,48 @@ def packed_width(dim: int) -> int:
     return -(-dim // _LANES) * _LANES
 
 
-def _lloyd_pass(x, w, x2, c, k: int, tile: int):
+def _lloyd_kernel_platform(mesh) -> bool:
+    """Are the mesh's devices what the one-read kernel is built for: TPUs.
+    (The tier-1 parity harness says yes for its CPU devices, and the kernel
+    then runs on the interpreter: ``pallas_kernels.launch_interpreted``.)"""
+    return mesh.devices.flat[0].platform == "tpu"
+
+
+def _lloyd_kernel_rows(mesh, Xp, k: int) -> int:
+    """The one-read kernel's row tile for a fit of the pack ``Xp`` (this
+    process's rows, padded), or 0 for the XLA tiles: what the code can
+    observe, no knob.  The kernel (``ops/pallas_kernels.py:lloyd_sums``)
+    takes float32 rows a multiple of 128 wide (what :func:`packed_width`
+    gives every table 512 columns wide or wider), at most 256 centroids, at
+    least a lane chunk of rows a device, on a 1-D mesh of TPUs.  A fit on
+    such a mesh that keeps the XLA tiles for its table is counted
+    (``train.kmeans_onepass_declined``)."""
+    if not _lloyd_kernel_platform(mesh):
+        return 0
+    rows = 0
+    if len(mesh.axis_names) == 1 and Xp.ndim == 2 \
+            and Xp.dtype == np.float32:
+        from flink_ml_tpu.ops import pallas_kernels
+        from flink_ml_tpu.parallel.mesh import local_data_parallel_size
+
+        rows = pallas_kernels.lloyd_sums_tile(
+            Xp.shape[0] // local_data_parallel_size(mesh), Xp.shape[1], k)
+    if not rows:
+        obs.counter_add("train.kmeans_onepass_declined")
+    return rows
+
+
+def _lloyd_pass(x, w, x2, c, k: int, tile: int, kernel_rows: int = 0,
+                interpret: bool = False):
     """One pass over the local rows, a tile at a time: (cost, per-cluster
     sums (k, d), counts (k,)) of the rows under their nearest centroid of
     ``c``.  ``w`` is the pack's mask (1 a row of the table, 0 a pad row),
-    ``x2`` the rows' squared norms."""
+    ``x2`` the rows' squared norms.  With ``kernel_rows`` (what
+    :func:`_lloyd_kernel_rows` found) the whole tiles of that many rows go
+    through ONE Pallas call that reads each once; else two XLA operations
+    read every tile of ``tile`` rows, one after the other.  The rows a whole
+    number of tiles leaves are the XLA operations' either way."""
     n = x.shape[0]
-    tile = max(1, min(int(tile), n))
-    n_full = n // tile
     clusters = jnp.arange(k, dtype=jnp.int32)
 
     def part(xt, wt, x2t):
@@ -158,23 +201,34 @@ def _lloyd_pass(x, w, x2, c, k: int, tile: int):
             counts = jnp.sum(member, axis=0)
         return cost, sums, counts
 
-    def body(i, acc):
-        at = [jax.lax.dynamic_slice_in_dim(a, i * tile, tile, axis=0)
-              for a in (x, w, x2)]
-        return jax.tree_util.tree_map(jnp.add, acc, part(*at))
+    if kernel_rows:
+        from flink_ml_tpu.ops import pallas_kernels
 
-    # the sums of a shard vary over the data axis from the first tile on
-    acc = tuple(pvary(jnp.zeros(shape, jnp.float32)) for shape in
-                ((), (k, x.shape[1]), (k,)))
-    acc = jax.lax.fori_loop(0, n_full, body, acc)
-    if n_full * tile < n:  # the rows a whole number of tiles leaves
+        with jax.named_scope("fmt.train.kmeans.onepass"):
+            acc = pallas_kernels.lloyd_sums(
+                x, w, x2, c, tile_rows=kernel_rows, interpret=interpret)
+        done = n // kernel_rows * kernel_rows
+    else:
+        tile = max(1, min(int(tile), n))
+
+        def body(i, acc):
+            at = [jax.lax.dynamic_slice_in_dim(a, i * tile, tile, axis=0)
+                  for a in (x, w, x2)]
+            return jax.tree_util.tree_map(jnp.add, acc, part(*at))
+
+        # the sums of a shard vary over the data axis from the first tile on
+        acc = tuple(pvary(jnp.zeros(shape, jnp.float32)) for shape in
+                    ((), (k, x.shape[1]), (k,)))
+        acc = jax.lax.fori_loop(0, n // tile, body, acc)
+        done = n // tile * tile
+    if done < n:  # the rows a whole number of tiles leaves
         acc = jax.tree_util.tree_map(
-            jnp.add, acc, part(*(a[n_full * tile:] for a in (x, w, x2))))
+            jnp.add, acc, part(*(a[done:] for a in (x, w, x2))))
     return acc
 
 
 def make_kmeans_train_fn(mesh, k: int, max_iter: int, tol: float,
-                         bundle: bool = True):
+                         bundle: bool = True, kernel_rows: int = 0):
     """The WHOLE Lloyd run as one compiled device program.
 
     Reuses the GLM fused-loop scaffolding (lib/common.py
@@ -189,9 +243,15 @@ def make_kmeans_train_fn(mesh, k: int, max_iter: int, tol: float,
     Rows shard over ``data``; the per-cluster sums/counts/cost ``psum`` over
     it (the reference's reduce-average round, SURVEY.md §3.3, fused
     on-chip); empty clusters keep their previous centroid.  An iteration
-    reads its rows tile by tile (:func:`_lloyd_pass`): distance product,
-    argmin and cost under ``fmt.train.kmeans.assign``, the one-hot sums and
-    counts under ``fmt.train.kmeans.update``.  The program's state is
+    reads its rows tile by tile (:func:`_lloyd_pass`).  With ``kernel_rows``
+    > 0 (what :func:`_lloyd_kernel_rows` found for the fit's table) a tile
+    is read ONCE, by one Pallas call under ``fmt.train.kmeans.onepass``
+    (``ops/pallas_kernels.py:lloyd_sums``: the distance product's six
+    bfloat16 passes, argmin, cost, and the sums from the tile's three
+    bfloat16 pieces, nine MXU passes a row); otherwise twice, by two XLA
+    operations of six passes each: distance product, argmin and cost under
+    ``fmt.train.kmeans.assign``, the one-hot sums and counts under
+    ``fmt.train.kmeans.update``.  The program's state is
     ``(centroids (k, d), trail (max_iter, k, d))``: the trail keeps the
     centroids every iteration started from, which come back with the result
     (``KMeansModel.train_centroids_``).
@@ -199,7 +259,13 @@ def make_kmeans_train_fn(mesh, k: int, max_iter: int, tol: float,
     from flink_ml_tpu.lib.common import _build_fused_train_fn
 
     tile = int(_LLOYD_TILE_ROWS)
-    key = ("kmeans", mesh, int(k), int(max_iter), float(tol), tile)
+    key = ("kmeans", mesh, int(k), int(max_iter), float(tol), tile,
+           int(kernel_rows))
+    interpret = False
+    if kernel_rows:
+        from flink_ml_tpu.ops import pallas_kernels
+
+        interpret = pallas_kernels.launch_interpreted()
 
     def lloyd_epoch(params, batch):
         # the trail holds the centroids each of the last ``max_iter``
@@ -213,7 +279,8 @@ def make_kmeans_train_fn(mesh, k: int, max_iter: int, tol: float,
             # a fit (8 ms) where a tile's own would be one an iteration
             x2 = jnp.sum(x * x, axis=1)
         cost, sums, counts = (
-            psum(a, "data") for a in _lloyd_pass(x, w, x2, c, k, tile))
+            psum(a, "data") for a in _lloyd_pass(
+                x, w, x2, c, k, tile, int(kernel_rows), interpret))
         with jax.named_scope("fmt.train.kmeans.update"):
             new_c = jnp.where(
                 counts[:, None] > 0,
@@ -224,10 +291,16 @@ def make_kmeans_train_fn(mesh, k: int, max_iter: int, tol: float,
             trail = jnp.concatenate([trail[1:], c[None]])
         return (new_c, trail), cost, delta
 
-    return _build_fused_train_fn(
+    train_fn = _build_fused_train_fn(
         key, None, mesh, 0.0, 0.0, max_iter, tol, epoch_fn=lloyd_epoch,
-        bundle=bundle,
+        # the interpreter's pallas_call fails strict vma (see
+        # lib/common.py:make_glm_train_fn); Mosaic's passes it
+        check_vma=not interpret, bundle=bundle,
     )
+    if bundle and kernel_rows:
+        #: read by _run_fused_train, which counts the interpreted fits
+        train_fn.pallas_interpret = interpret
+    return train_fn
 
 
 def train_kmeans(
@@ -262,8 +335,10 @@ def train_kmeans(
 
     def run(n_epochs, cents, dev_batch=None):
         cents = jnp.asarray(cents, dtype=jnp.float32)
+        kernel_rows = _lloyd_kernel_rows(mesh, Xp, k)
         result = _run_fused_train(
-            make_kmeans_train_fn(mesh, k, n_epochs, tol),
+            make_kmeans_train_fn(mesh, k, n_epochs, tol,
+                                 kernel_rows=kernel_rows),
             (cents, jnp.zeros((n_epochs,) + cents.shape, jnp.float32)),
             batch if dev_batch is None else dev_batch, mesh,
             batch_preplaced=dev_batch is not None, n_rows=n_rows,
@@ -276,6 +351,9 @@ def train_kmeans(
         # assigned (rows x iterations run)
         obs.counter_add("train.kmeans_fits")
         obs.counter_add("train.kmeans_row_iters", n_rows * result.epochs)
+        # of those, the runs whose program holds the one-read kernel (0
+        # keeps the counter there from the first fit)
+        obs.counter_add("train.kmeans_onepass_fits", int(kernel_rows > 0))
         return result
 
     def with_trail(result):
@@ -626,6 +704,12 @@ class KMeans(Estimator, KMeansParams, HasMaxIter, HasTol, HasSeed, HasCheckpoint
             (rows_per_shard,) = agree_max(rows_per_shard)
 
         width = packed_width(dim)
+        if _lloyd_kernel_platform(mesh) and width % _LANES == 0:
+            # Pallas and Mosaic take a second of host to import: on a
+            # thread, ahead of the placement that hides it
+            from flink_ml_tpu.lib.common import _start_kernels_import
+
+            _start_kernels_import()
 
         def build():
             n_pad = rows_per_shard * n_dev

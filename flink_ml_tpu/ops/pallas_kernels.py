@@ -50,11 +50,30 @@ chip, and when; a statement about speed is a line of ``PERF_LEDGER.jsonl``:
                       pieces; no knob
                       (lib/common.py:
                       _hot_split_wins)
+  :func:`lloyd_sums`  the centroid fit's pass     2026-10-03, TPU v5 lite,
+                      over its rows               jax 0.9.0: compiles with
+                      (lib/clustering.py:         Mosaic at 2,025,000 x 896,
+                      _lloyd_pass): distance      k 100, row tiles 512 /
+                      product (float32 in six     1024 / 2048 in 1.5 / 2.0 /
+                      bfloat16 passes), argmin,   3.7 s; 23.4 / 22.6 / 22.3
+                      cost, and the per-cluster   ms a Lloyd iteration where
+                      sums from the tile's three  the two XLA operations it
+                      bfloat16 pieces in ONE      replaces take 28.4 (two
+                      read of a row tile, nine    reads, twelve passes): 352
+                      MXU passes a row; the       us a 32768 rows against
+                      (k, tile) distances never   the nine passes' 343 at
+                      leave VMEM.  7.3 MB a       the MXU's peak; an
+                      2048-row tile, read where   iteration's centroids
+                      the table lies.  No knob    within 4.4e-5 of the plain
+                      (lib/clustering.py:         reference's, a repeated
+                      _lloyd_kernel_rows)         fit the same bytes; strict
+                                                  check_vma on 1 and 4 chips
+                                                  (PERF.md 5, 6)
   (sparse grad)       segment-CSR minibatch grad  REJECTED — every path
                                                   that ADDRESSES a slot
                                                   lost to XLA's scatter
                                                   lowering; note below.
-                                                  The two kernels above
+                                                  The kernels above
                                                   address nothing.
   ==================  ==========================  =========================
 
@@ -67,6 +86,12 @@ on the lanes, its features, label and weight on the sublanes), so a row tile
 arrives in VMEM by one strided DMA, both products are float32 multiplies on
 the VPU (a matrix-vector product at precision ``highest`` would bind the MXU
 before HBM), and the accumulators stay in VMEM across the sequential grid.
+:func:`lloyd_sums` lays the other way, CENTROIDS on the sublanes and a tile's
+rows on the lanes: every product streams 128 to 384 centroid rows past a
+latched (128, 128) piece of the table (as :func:`hot_grad` does), so the
+distances come out (k, tile), argmin and cost are sublane reductions on the
+VPU, and the membership is the left operand of the sums' product as it
+stands; its three accumulators stay in VMEM across the sequential grid.
 :func:`serve_chain` is embarrassingly parallel over row tiles (no cross-tile
 accumulators): each tile is scanned for NaN/Inf, scaled through the affine
 stages, and scored without leaving VMEM — the three serving HBM passes
@@ -428,6 +453,30 @@ def _f32_pieces(x):
     return pieces
 
 
+#: half of the last place a bfloat16 keeps, in a float32's bits
+_HALF_PLACE = np.int32(0x8000)
+
+
+def _f32_nearest_pieces(x):
+    """:func:`_f32_pieces` with the first two pieces ROUNDED to the nearest
+    bfloat16 (half a last place added to the magnitude's bits, then the
+    mask: still no convert to drop), the third what is left: they sum to
+    ``x`` exactly too, and they are the pieces ``Precision.HIGHEST`` makes.
+    A product of two float32 values in six passes drops mid·lo, lo·mid and
+    lo·lo.  Pieces cut by the mask alone all have their value's sign, so over
+    a row of non-negative values the dropped terms add up: 0.6 of a float32's
+    last place of the product, one way, where rounded pieces' terms cancel
+    to 0.02 (PERF.md 6, PR 32).  For a product, not a pick, take these."""
+    pieces = []
+    for _ in range(2):
+        top = jax.lax.bitcast_convert_type(
+            (jax.lax.bitcast_convert_type(x, jnp.int32) + _HALF_PLACE)
+            & _TOP_HALF, jnp.float32)
+        pieces.append(top)
+        x = x - top
+    return pieces + [x]
+
+
 def _hot_masks(code, rows: int):
     """A plane of codes ``(1, T)`` as its one-hot masks, slots on the
     lanes: ``(rows, T)`` for the code's row of the hot table as bfloat16,
@@ -593,6 +642,177 @@ def hot_grad(err, codes, vals, k: int, interpret: bool = False):
     )(promote(codes), promote(vals), promote(err))
     g = g.reshape(rows, 3, _LANES)
     return (g[:, 0] + g[:, 1] + g[:, 2]).reshape(k)
+
+
+# -- the Lloyd iteration's pass over the rows ---------------------------------
+
+#: rows of the table a grid step of :func:`lloyd_sums` takes, at most.  Seen
+#: on the chip at 2,025,000 x 896, k 100, ms an iteration of the whole Lloyd
+#: program; seconds the program took to compile (PERF.md 6; my chip run,
+#: PR 32): 512 rows 23.42; 1.5 s.  1024 rows 22.61; 2.0 s.  2048 rows
+#: 22.31; 3.7 s: 352 us a 32768 rows, where the MXU's nine passes at its
+#: peak are 343
+_LLOYD_TILE = 2048
+#: the scoped VMEM the call asks for (v5e holds 128 MiB; the default limit
+#: is 16), and what :func:`lloyd_sums_tile` lets its own reckoning of a
+#: tile's needs come to.  The compiler's own account at 896 wide, k 100:
+#: 2048 rows between 32 and 40 MiB (reckoned 38.5), 1024 between 16 and 18
+#: (reckoned 20.4)
+_LLOYD_VMEM_BYTES = 64 << 20
+_LLOYD_VMEM_BUDGET = 48 << 20
+#: centroids a call takes, at most: two lane chunks (the accumulator, the
+#: centroids' pieces and the (k, tile) distances grow with them)
+_LLOYD_MAX_K = 2 * _LANES
+
+
+def _lloyd_sums_kernel(kp: int, x_ref, w_ref, x2_ref, c_ref, c2_ref,
+                       sums_ref, counts_ref, cost_ref):
+    """One row tile of a Lloyd iteration, CENTROIDS ON SUBLANES, rows on
+    lanes: the distance product, argmin, cost and the per-cluster sums from
+    the one tile in VMEM.
+
+    Refs:
+      x_ref      (T, W) f32     the tile of the table, as it lies
+      w_ref      (1, T) f32     the pack's row mask
+      x2_ref     (1, T) f32     the rows' squared norms
+      c_ref      (3*kp, W) bf16 the centroids' three pieces, hi on mid on lo
+      c2_ref     (kp, 1) f32    their squared norms (+inf a pad centroid)
+      sums_ref   (kp, W) f32    per-cluster sums   } the same block every
+      counts_ref (kp, 128) f32  counts, over lanes } step: the accumulators
+      cost_ref   (8, 128) f32   row 0: the cost, over the lanes
+
+    The product is the float32 one in six bfloat16 passes (hi·hi, mid·hi,
+    lo·hi, hi·mid, mid·mid, hi·lo: what ``Precision.HIGHEST`` multiplies);
+    the sums are the membership, ONE bfloat16 piece, times the tile's three,
+    exact term for term.  Every sum runs in one fixed order.
+    """
+
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        sums_ref[...] = jnp.zeros_like(sums_ref)
+        counts_ref[...] = jnp.zeros_like(counts_ref)
+        cost_ref[...] = jnp.zeros_like(cost_ref)
+
+    tile = x_ref.shape[0]
+    hi, mid, lo = (p.astype(jnp.bfloat16)
+                   for p in _f32_nearest_pieces(x_ref[...]))
+
+    def times(rows, piece):  # (rows, W) x (T, W) -> (rows, T), rows on lanes
+        return jax.lax.dot_general(
+            c_ref[0:rows, :], piece, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    by_hi, by_mid, by_lo = times(3 * kp, hi), times(2 * kp, mid), times(kp, lo)
+    # the smallest terms first
+    xc = (by_lo + by_mid[kp:] + by_hi[2 * kp:]) \
+        + (by_mid[:kp] + by_hi[kp:2 * kp]) + by_hi[:kp]
+    d = jnp.maximum(x2_ref[...] - 2.0 * xc + c2_ref[...], 0.0)
+    least = jnp.min(d, axis=0, keepdims=True)
+    cluster = jax.lax.broadcasted_iota(jnp.int32, (kp, tile), 0)
+    # the first of equals, as jnp.argmin
+    nearest = jnp.min(jnp.where(d == least, cluster, np.int32(kp)),
+                      axis=0, keepdims=True)
+    w = w_ref[...]
+    member = ((cluster == nearest) & (w > 0.0)).astype(jnp.float32)
+
+    def fold_lanes(a):  # (r, T) -> (r, 128), lane chunk on lane chunk
+        out = a[:, :_LANES]
+        for at in range(_LANES, tile, _LANES):
+            out = out + a[:, at:at + _LANES]
+        return out
+
+    one_piece = member.astype(jnp.bfloat16)
+    sums_ref[...] += sum(
+        jnp.dot(one_piece, piece, preferred_element_type=jnp.float32)
+        for piece in (lo, mid, hi))
+    counts_ref[...] += fold_lanes(member)
+    cost_ref[0:1, :] += fold_lanes(least * w)
+
+
+def lloyd_sums_tile(rows: int, width: int, k: int) -> int:
+    """:func:`lloyd_sums`'s row tile for ``rows`` rows ``width`` wide under
+    ``k`` centroids, by arithmetic on the shape alone (nothing is compiled
+    or timed to find it): the most whole lane chunks, at most
+    :data:`_LLOYD_TILE` rows, whose VMEM stays within
+    :data:`_LLOYD_VMEM_BUDGET`.  0 where the kernel does not take the table:
+    rows that are no multiple of the lanes wide, fewer rows than a lane
+    chunk, more centroids than :data:`_LLOYD_MAX_K`, a width at which not
+    even one lane chunk of rows fits."""
+    if width <= 0 or width % _LANES or not 0 < k <= _LLOYD_MAX_K:
+        return 0
+    kp = _round_up(k, _LANES)
+    # the accumulator and the centroids' pieces, two buffers each
+    fixed = 2 * kp * width * 4 + 2 * 3 * kp * width * 2
+    # per row: the tile in two buffers and its three bfloat16 pieces; the
+    # six products' results, the distances, the membership twice
+    per_row = width * (2 * 4 + 3 * 2) + kp * (6 + 4) * 4
+    fit = (_LLOYD_VMEM_BUDGET - fixed) // per_row
+    return max(0, min(_LLOYD_TILE, rows, fit) // _LANES * _LANES)
+
+
+@functools.partial(jax.jit, static_argnames=("tile_rows", "interpret"))
+def lloyd_sums(x, w, x2, c, tile_rows: int, interpret: bool = False):
+    """A Lloyd iteration's pass over the first ``rows // tile_rows`` whole
+    tiles of ``x`` in ONE read: ``(cost, sums (k, W), counts (k,))`` of those
+    rows under their nearest centroid of ``c`` (k, W).
+
+    ``x`` (rows, W) float32, W a multiple of 128, read where it lies; ``w``
+    (rows,) the row mask, ``x2`` (rows,) the rows' squared norms.  The rows a
+    whole number of tiles leaves are the caller's.  The centroids go in as
+    their three bfloat16 pieces, padded to whole lane chunks with centroids
+    no row is nearest to (squared norm +inf)."""
+    rows, width = x.shape
+    k = c.shape[0]
+    kp = _round_up(k, _LANES)
+    if not tile_rows or tile_rows % _LANES or rows < tile_rows \
+            or width % _LANES:
+        raise ValueError(
+            f"lloyd_sums: no row tile for {rows} rows x {width} (tile "
+            f"{tile_rows}; whole {_LANES}-lane chunks both ways)")
+    c = c.astype(jnp.float32)
+    c2 = jnp.pad(jnp.sum(c * c, axis=1), (0, kp - k),
+                 constant_values=jnp.inf)[:, None]
+    pieces = jnp.concatenate(
+        [jnp.pad(p, ((0, kp - k), (0, 0))) for p in _f32_nearest_pieces(c)]
+    ).astype(jnp.bfloat16)
+    operands = [x, w.astype(jnp.float32).reshape(1, rows),
+                x2.astype(jnp.float32).reshape(1, rows), pieces, c2]
+    vma, promote = _vma_of(*operands)
+
+    def row_tile(i):
+        return _I32_ZERO, i
+
+    def same_block(i):
+        return _I32_ZERO, _I32_ZERO
+
+    sums, counts, cost = pl.pallas_call(
+        functools.partial(_lloyd_sums_kernel, kp),
+        grid=(rows // tile_rows,),
+        in_specs=[
+            pl.BlockSpec((tile_rows, width), lambda i: (i, _I32_ZERO)),
+            pl.BlockSpec((1, tile_rows), row_tile),
+            pl.BlockSpec((1, tile_rows), row_tile),
+            pl.BlockSpec((3 * kp, width), same_block),
+            pl.BlockSpec((kp, 1), same_block),
+        ],
+        out_specs=[
+            pl.BlockSpec((kp, width), same_block),
+            pl.BlockSpec((kp, _LANES), same_block),
+            pl.BlockSpec((_SUBLANES, _LANES), same_block),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((kp, width), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((kp, _LANES), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((_SUBLANES, _LANES), jnp.float32, vma=vma),
+        ],
+        # the grid axis carries the accumulators: sequential
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_LLOYD_VMEM_BYTES),
+        interpret=interpret,
+        name="lloyd_sums",
+    )(*(promote(a) for a in operands))
+    return jnp.sum(cost[0]), sums[:k], jnp.sum(counts[:k], axis=1)
 
 
 # -- fused serving chain ------------------------------------------------------

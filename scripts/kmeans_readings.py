@@ -1,10 +1,11 @@
 #!/usr/bin/env python
-"""The chip readings behind the centroid fit's step (PR 31; PERF.md §5, §6):
-what the Lloyd iteration as it was written before PR 31 costs on the chip,
-and what each way of writing it over row tiles costs.
+"""The chip readings behind the centroid fit's step (PR 31 and 32; PERF.md
+§5, §6): what the Lloyd iteration as it was written before PR 31 costs on
+the chip, and what each way of writing it over row tiles costs.
 
     python scripts/kmeans_readings.py --seed <n> [--rows N] [--k 100]
         [--iterations 20] [--tiles 8192,16384,32768] [--skip-as-written]
+        [--kernel-tiles 512,1024,2048] [--skip-pr31] [--out DIR]
 
 On the configuration's table (``chipbench/data_mixture.py``, from ``--seed``),
 placed as the estimator places it, in ONE process:
@@ -24,6 +25,17 @@ placed as the estimator places it, in ONE process:
   packed it: ``tiled_unpadded@<rows>`` (what the lane padding buys),
   ``as_written_highest`` and ``as_written`` (PR 30's iteration: the whole
   table at once, ``segment_sum``, the product at JAX's default precision);
+  PR 32's, on the packed table: ``operand_precision@<rows>`` (the one-hot
+  product at ``(DEFAULT, HIGHEST)``: one piece of the membership, three of
+  the rows, if the compiler honours it) and, for each of ``--kernel-tiles``,
+  ``kernel@<rows>`` (``ops/pallas_kernels.py:lloyd_sums`` at that row tile:
+  one read, nine passes), with ``compile_s``, the seconds the program took
+  to compile, Mosaic's among them; ``--skip-pr31`` leaves out the host init
+  and the 784-wide table, and ``--out chiprun_out/pr32`` is where PR 32's
+  table in PERF.md comes from; ``--estimator`` adds ``estimator``: two
+  fits of the table through ``KMeans.fit`` itself, as the cell makes them,
+  their seconds, gaps and the ``train.kmeans*`` counters (which route the
+  estimator's own rule took);
 * ``gaps``: each variant's centroids and costs against the plain reference
   from the same init, beside the reference's own one-pass bfloat16 control:
   whether the iteration as it was written reads like the control.
@@ -87,6 +99,9 @@ def main(argv=None) -> int:
     parser.add_argument("--iterations", type=int, default=0)
     parser.add_argument("--tiles", default="8192,16384,32768")
     parser.add_argument("--skip-as-written", action="store_true")
+    parser.add_argument("--kernel-tiles", default="")
+    parser.add_argument("--skip-pr31", action="store_true")
+    parser.add_argument("--estimator", action="store_true")
     parser.add_argument("--out", default=os.path.join(
         ROOT, "chiprun_out", "kmeans_readings"))
     args = parser.parse_args(argv)
@@ -125,14 +140,16 @@ def main(argv=None) -> int:
 
     # -- the init, before and after ------------------------------------------
     take = reference.sample_rows(rows, seed).astype(np.int32)
-    sample64 = X[take].astype(np.float64)
-    t = time.perf_counter()
-    for idx in (3, 5, 7):
-        np.sum((sample64 - sample64[idx]) ** 2, axis=1)
-    host_pass = (time.perf_counter() - t) / 3
-    del sample64
-    report["init"] = {"sample_rows": len(take), "host_pass_s": host_pass,
-                      "host_fit_s_at_k": host_pass * (k - 1)}
+    report["init"] = {"sample_rows": len(take)}
+    if not args.skip_pr31:
+        sample64 = X[take].astype(np.float64)
+        t = time.perf_counter()
+        for idx in (3, 5, 7):
+            np.sum((sample64 - sample64[idx]) ** 2, axis=1)
+        host_pass = (time.perf_counter() - t) / 3
+        del sample64
+        report["init"].update(host_pass_s=host_pass,
+                              host_fit_s_at_k=host_pass * (k - 1))
 
     w = np.ones((rows,), np.float32)
     width = clustering.packed_width(dim)
@@ -164,23 +181,34 @@ def main(argv=None) -> int:
             oh, piece, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) for piece in (hi, mid, lo))
 
-    def tiled(tile, pieces=False):
+    from flink_ml_tpu.ops import pallas_kernels
+
+    # True off the chip only: a rehearsal of the kernel's variants
+    interpreted = pallas_kernels.launch_interpreted()
+
+    def one_piece_of_the_membership(member, x):
+        # per-operand precision: 0 and 1 are one bfloat16 piece
+        return jnp.dot(member.T, x, precision=(
+            jax.lax.Precision.DEFAULT, jax.lax.Precision.HIGHEST))
+
+    def tiled(tile, onehot_sums=None, kernel_rows=0):
         def epoch(c, batch):
             x, wt = batch
             cost, sums, counts = (
                 clustering.psum(a, "data")
                 for a in clustering._lloyd_pass(
-                    x, wt, jnp.sum(x * x, axis=1), c, k, tile))
+                    x, wt, jnp.sum(x * x, axis=1), c, k, tile, kernel_rows,
+                    interpreted))
             new_c = jnp.where(counts[:, None] > 0,
                               sums / jnp.maximum(counts[:, None], 1.0), c)
             return new_c, cost, jnp.sqrt(jnp.sum((new_c - c) ** 2))
 
-        if not pieces:
+        if onehot_sums is None:
             return epoch
 
         def patched(c, batch):
             sound = clustering._onehot_sums
-            clustering._onehot_sums = exact_pieces
+            clustering._onehot_sums = onehot_sums
             try:
                 return epoch(c, batch)
             finally:
@@ -191,7 +219,13 @@ def main(argv=None) -> int:
     tiles = [int(t) for t in args.tiles.split(",")]
     middle = tiles[len(tiles) // 2]
     padded = [(f"tiled@{tile}", tiled(tile)) for tile in tiles]
-    padded.append((f"tiled_pieces@{middle}", tiled(middle, True)))
+    if not args.skip_pr31:
+        padded.append((f"tiled_pieces@{middle}", tiled(middle, exact_pieces)))
+    padded.append((f"operand_precision@{middle}",
+                   tiled(middle, one_piece_of_the_membership)))
+    padded += [(f"kernel@{rows_a_step}",
+                tiled(middle, kernel_rows=int(rows_a_step)))
+               for rows_a_step in args.kernel_tiles.split(",") if rows_a_step]
     unpadded = [(f"tiled_unpadded@{middle}", tiled(middle))]
     if not args.skip_as_written:
         unpadded.append(("as_written_highest", as_written_epoch(
@@ -201,26 +235,42 @@ def main(argv=None) -> int:
     os.makedirs(args.out, exist_ok=True)
     answers, report["variants"] = {}, {}
 
+    def with_trail(epoch):
+        # the state the estimator's program carries: the centroids, and
+        # those every iteration started from (what ``gaps`` reads)
+        def carried(state, batch):
+            c, trail = state
+            new_c, cost, delta = epoch(c, batch)
+            return (new_c, jnp.concatenate([trail[1:], c[None]])), cost, delta
+
+        return carried
+
     def read(name, epoch, placed, table_width):
         entry = report["variants"][name] = {"table_width": table_width}
         start = np.pad(np.asarray(init)[:, :dim],
                        ((0, 0), (0, table_width - dim)))
+        start = (jnp.asarray(start, jnp.float32),
+                 jnp.zeros((iterations,) + start.shape, jnp.float32))
         try:
             fn = common._build_fused_train_fn(
                 ("kmeans_readings", name, mesh, k, iterations), None, mesh,
-                0.0, 0.0, iterations, 0.0, epoch_fn=epoch, bundle=True)
+                0.0, 0.0, iterations, 0.0, epoch_fn=with_trail(epoch),
+                bundle=True,
+                check_vma=not (interpreted and name.startswith("kernel")))
             (program,) = [c.cell_contents for c in fn.__closure__
                           if hasattr(c.cell_contents, "lower")]
+            t0 = time.perf_counter()
             analysis = program.lower(
-                jnp.asarray(start), placed).compile().memory_analysis()
+                start, placed).compile().memory_analysis()
+            entry["compile_s"] = time.perf_counter() - t0
             entry["temp_bytes"] = int(analysis.temp_size_in_bytes)
             entry["argument_bytes"] = int(analysis.argument_size_in_bytes)
 
             def fit():
                 t0 = time.perf_counter()
                 result = common._run_fused_train(
-                    fn, jnp.asarray(start, jnp.float32), placed, mesh,
-                    batch_preplaced=True, n_rows=rows)
+                    fn, start, placed, mesh, batch_preplaced=True,
+                    n_rows=rows)
                 return time.perf_counter() - t0, result
 
             entry["first_fit_s"], _r = fit()
@@ -236,10 +286,12 @@ def main(argv=None) -> int:
                 if program["module_s"] else None)
             entry["self_by_scope"] = program.get("self_by_scope")
             entry["ops"] = program["ops"][:14]
+            centroids, trail = result.params
             answers[name] = {
-                "centroids": np.asarray(result.params, np.float64)[:, :dim],
+                "centroids": np.asarray(centroids, np.float64)[:, :dim],
                 "costs": np.asarray(result.losses, np.float64),
-                "epochs": int(result.epochs)}
+                "epochs": int(result.epochs),
+                "trail": np.asarray(trail, np.float32)[:, :, :dim]}
         except Exception as exc:  # noqa: BLE001 - an OOM is a reading
             entry["error"] = repr(exc)[:600]
             entry["memory"] = memory()
@@ -252,12 +304,36 @@ def main(argv=None) -> int:
         read(name, epoch, placed, width)
     del placed
     gc.collect()
-    if width != dim:
+    if width != dim and not args.skip_pr31:
         placed, report["place_unpadded_s"] = place(dim)
         report["memory_placed_unpadded"] = memory()
         for name, epoch in unpadded:
             read(name, epoch, placed, dim)
         del placed
+        gc.collect()
+
+    # -- the estimator itself, by its own rule ---------------------------------
+    if args.estimator:
+        from chipbench import program_kmeans
+        from flink_ml_tpu import obs
+        from flink_ml_tpu.table import slab_pool
+
+        obs.enable()
+        fits = []
+        whole = program_kmeans.table(X)
+        for _ in range(2):
+            t = time.perf_counter()
+            model = program_kmeans.kmeans(
+                {"k": k, "maxIter": iterations, "tol": 0.0}, seed).fit(whole)
+            fits.append(time.perf_counter() - t)
+        answers["estimator"] = program_kmeans.fit_answer(model)
+        report["estimator"] = {"fit_s": fits, "counters": {
+            name: v for name, v in
+            obs.registry().snapshot()["counters"].items()
+            if name.startswith("train.")}}
+        print("estimator", json.dumps(report["estimator"]), flush=True)
+        del whole, model
+        slab_pool.pool().clear()
         gc.collect()
 
     # -- against the plain reference, from the same init -----------------------

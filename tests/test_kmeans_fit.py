@@ -10,7 +10,9 @@ and what the fit's program is made of:
   of its own width;
 * one warm fit observes every span of the fit path once, counts its rows,
   and its program is ``jit_bundled`` with the two ``fmt.train.kmeans.*``
-  scopes.
+  scopes; a program that holds the one-read kernel (PR 32; its own tests are
+  ``tests/test_lloyd_kernel.py``) has ``fmt.train.kmeans.onepass`` too, and
+  a fit through it agrees with the reference as the XLA tiles' does.
 """
 
 import os
@@ -89,12 +91,21 @@ def _answer(model):
 # -- against the plain reference ------------------------------------------------
 
 
-@pytest.mark.parametrize("n,dim,k,iters,seed", [
-    (3001, 24, 8, 6, 1), (1500, 40, 5, 4, 7)],
-    ids=["3001x24-k8", "1500x40-k5"])
-def test_fit_agrees_with_the_plain_reference(n, dim, k, iters, seed):
+@pytest.mark.parametrize("n,dim,k,iters,seed,kernel", [
+    (3001, 24, 8, 6, 1, False), (1500, 40, 5, 4, 7, False),
+    (2900, 784, 8, 4, 3, True)],
+    ids=["3001x24-k8", "1500x40-k5", "2900x784-k8-through-the-kernel"])
+def test_fit_agrees_with_the_plain_reference(n, dim, k, iters, seed, kernel,
+                                             monkeypatch):
+    if kernel:  # packed 896 wide; the kernel on the interpreter
+        monkeypatch.setattr(clustering, "_lloyd_kernel_platform",
+                            lambda mesh: True)
+        obs.enable()
     X = _rows(n, dim)
     model = _kmeans(k, iters, seed).fit(_table(X))
+    if kernel:
+        counted = obs.registry().snapshot()["counters"]
+        assert counted["train.kmeans_onepass_fits"] == 1
     got = _answer(model)
     ref = REFERENCE.Table(X).fit(seed, k, iters)
     assert got["epochs"] == ref["epochs"] == iters
@@ -246,6 +257,9 @@ def test_a_warm_fit_observes_every_span_once_and_counts_its_rows():
     assert counted["train.kmeans_fits"] == counted["train.fused_runs"] == 1
     assert counted["train.kmeans_row_iters"] == n * iters
     assert counted["train.onepass_fits"] == 0
+    # the XLA tiles, and not for the table's sake: off the chip
+    assert counted["train.kmeans_onepass_fits"] == 0
+    assert "train.kmeans_onepass_declined" not in counted
 
     _kmeans(k, iters).fit(table)  # warm: the cached pack, a pool hit
     warm, counted = _snapshot()
@@ -265,6 +279,7 @@ def test_a_warm_fit_observes_every_span_once_and_counts_its_rows():
     assert counted["slab_pool.hits"] == counted["slab_pool.misses"] == 1
     assert counted["train.kmeans_fits"] == 2
     assert counted["train.kmeans_row_iters"] == 2 * n * iters
+    assert counted["train.kmeans_onepass_fits"] == 0
 
 
 def test_another_seed_is_another_init_of_the_same_program():
@@ -283,20 +298,33 @@ def test_another_seed_is_another_init_of_the_same_program():
     assert a.train_costs_ == again.train_costs_
 
 
-def test_the_kmeans_program_carries_its_scopes_and_its_name():
+@pytest.mark.parametrize("width,kernel_rows,rows", [
+    (6, 0, 64), (128, 128, 128), (128, 128, 160)],
+    ids=["xla-tiles", "kernel", "kernel-and-a-remainder"])
+def test_the_kmeans_program_carries_its_scopes_and_its_name(
+        width, kernel_rows, rows):
     mesh = MLEnvironmentFactory.get_default().get_mesh()
     n_dev = len(mesh.devices.flat)
-    fn = clustering.make_kmeans_train_fn(mesh, 4, 3, 0.0)
+    fn = clustering.make_kmeans_train_fn(mesh, 4, 3, 0.0,
+                                         kernel_rows=kernel_rows)
     assert fn.bundle_fetch and fn.loss_hist_len == 3
-    batch = (jnp.zeros((64 * n_dev, 6), jnp.float32),
-             jnp.ones((64 * n_dev,), jnp.float32))
+    assert getattr(fn, "pallas_interpret", False) is bool(kernel_rows)
+    batch = (jnp.zeros((rows * n_dev, width), jnp.float32),
+             jnp.ones((rows * n_dev,), jnp.float32))
     (program,) = [c.cell_contents for c in fn.__closure__
                   if hasattr(c.cell_contents, "lower")]
-    lowered = program.lower((jnp.zeros((4, 6), jnp.float32),
-                             jnp.zeros((3, 4, 6), jnp.float32)), batch)
+    lowered = program.lower((jnp.zeros((4, width), jnp.float32),
+                             jnp.zeros((3, 4, width), jnp.float32)), batch)
     assert lowered.as_text().startswith("module @jit_bundled")
-    assert set(re.findall(r"fmt\.[a-z_.]+",
-                          lowered.as_text(debug_info=True))) == KMEANS_SCOPES
+    scopes = set(re.findall(r"fmt\.[a-z_.]+",
+                            lowered.as_text(debug_info=True)))
+    if not kernel_rows:
+        assert scopes == KMEANS_SCOPES
+    else:
+        # the kernel's call has its own scope; the other two stay on the
+        # XLA side: the rows' squared norms and the new centroids, and the
+        # rows a whole number of the kernel's tiles leaves
+        assert scopes == KMEANS_SCOPES | {"fmt.train.kmeans.onepass"}
     compiled = lowered.compile().as_text()
     assert KMEANS_SCOPES <= set(re.findall(r"fmt\.[a-z_.]+", compiled))
     # the distance product states its precision

@@ -1,4 +1,5 @@
-"""The one-pass GLM kernel, and the sparse step's hot lookup (PR 30),
+"""The one-pass GLM kernel, the sparse step's hot lookup (PR 30) and the
+Lloyd iteration's one-read kernel (PR 32),
 compiled for a TPU v5e that is described, not attached: what the chip's compiler (Mosaic, XLA:TPU) accepts, which layout it
 gives the slab, and that the kernel's view of the slab copies nothing.  No
 chip time, about two seconds a compile.  Nothing runs, so nothing here is a
@@ -172,13 +173,24 @@ def test_the_split_sparse_step_compiles_with_both_hot_kernels(
 # -- the centroid fit (PR 31) --------------------------------------------------
 
 
-def compiled_kmeans(topo, n_dev, rows, width, k=100, iters=20):
+def compiled_kmeans(topo, n_dev, rows, width, k=100, iters=20,
+                    monkeypatch=None):
     """The fused Lloyd program (unbundled: the bundle is float64 under the
-    suite's x64) for ``n_dev`` described chips, ``rows`` a chip."""
+    suite's x64) for ``n_dev`` described chips, ``rows`` a chip; with a
+    ``monkeypatch`` the one that holds the kernel (the route's rule asks the
+    process's platform which lowering the launch takes; here it is Mosaic)."""
     from flink_ml_tpu.lib import clustering
 
     mesh = Mesh(np.array(topo.devices[:n_dev]), ("data",))
-    fn = clustering.make_kmeans_train_fn(mesh, k, iters, 0.0, bundle=False)
+    kernel_rows = 0
+    if monkeypatch is not None:
+        monkeypatch.setattr(pallas_kernels, "launch_interpreted",
+                            lambda: False)
+        assert clustering._lloyd_kernel_platform(mesh)
+        kernel_rows = pallas_kernels.lloyd_sums_tile(rows, width, k)
+        assert kernel_rows
+    fn = clustering.make_kmeans_train_fn(mesh, k, iters, 0.0, bundle=False,
+                                         kernel_rows=kernel_rows)
     replicated = NamedSharding(mesh, P())
     sharded = NamedSharding(mesh, P("data"))
     args = ((jax.ShapeDtypeStruct((k, width), jnp.float32,
@@ -197,10 +209,39 @@ def table_layout(text):
                      r"\{[^}]*\}, f32\[\d+,\d+\](\{[^}]*\})", text).group(1)
 
 
+@pytest.mark.parametrize("n_dev,k", [(1, 100), (4, 100), (1, 256)],
+                         ids=["one-chip", "four-chips", "two-lane-chunks"])
+def test_the_lloyd_kernel_compiles_and_reads_the_table_in_place(
+        topo, quiet_cache, monkeypatch, n_dev, k):
+    """mnist8m's quarter a chip, packed 896 wide, through the one-read
+    kernel (strict vma on four chips): Mosaic takes it, the table lies
+    features-minor where the kernel's blocks read it, and no (rows, k)
+    distances lie among the program's temporaries but those of the rows a
+    whole number of tiles leaves."""
+    rows, width = 2_025_000, 896
+    compiled = compiled_kmeans(topo, n_dev, rows, width, k=k,
+                               monkeypatch=monkeypatch)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "lloyd_sums" in text
+    assert table_layout(text) == "{1,0:T(8,128)}"
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes >= rows * width * 4
+    # the rows' squared norms (8.1 MB) and little else: far under 2^30
+    assert memory.temp_size_in_bytes < 16 * rows
+    left = rows % pallas_kernels.lloyd_sums_tile(rows, width, k)
+    wide = {int(n) for n in re.findall(r"f32\[(\d+),(?:%d|128|256)\]" % k,
+                                       text)}
+    assert left in wide and max(wide) <= max(left, 3 * 256), wide
+    if n_dev > 1:
+        assert len(re.findall(r"= .* all-reduce\(", text)) >= 1
+
+
 @pytest.mark.parametrize("n_dev", [1, 4], ids=["one-chip", "four-chips"])
 def test_the_lloyd_program_reads_the_lane_aligned_table_where_it_lies(
         topo, quiet_cache, n_dev):
-    """mnist8m's quarter a chip, packed 896 wide: the table lies
+    """The XLA tiles (what a table the kernel declines runs).  mnist8m's
+    quarter a chip, packed 896 wide: the table lies
     features-minor, as the distance product streams it, and the program
     holds no temporary near the table's size (a tile's worth)."""
     from flink_ml_tpu.lib import clustering

@@ -36,6 +36,10 @@ def _obs_on():
     obs.reset()
     obs.flight.reset()
     yield
+    # back off for the file a worker runs next (tests/test_trace.py asserts
+    # the default)
+    obs.disable()
+    obs.reset()
 
 
 # -- compile-cache resolution -------------------------------------------------
