@@ -181,6 +181,9 @@ class SparseMinibatchStack:
     #: the pack was asked for the row-regular layout and the rows' widths
     #: failed its rule (``train.sparse_ell_declined`` counts such fits)
     ell_declined: bool = False
+    #: the widest row the pack observed when it was asked for either layout
+    #: (0 where it was not): with ``mb`` and ``nnz_pad`` the rule's inputs
+    widest_row: int = 0
 
     row_regular = False  # the layout, for ``train.sparse_ell_fits``
     hot_ids = None  # no frequency split: ``train.sparse_hot_fits`` reads it
@@ -196,6 +199,14 @@ class SparseMinibatchStack:
     def step_slots(self) -> int:
         """Slots a device's step walks, pads included."""
         return self.nnz_pad
+
+    @property
+    def ell_step_slots(self) -> int:
+        """Slots a device's step WOULD walk laid row-regular, as the pack
+        reckoned them for its rule (``mb x`` the widest row; 0 where the
+        pack was not asked): ``train.sparse_ell_slots_reckoned`` counts
+        them a fit, beside ``train.sparse_slots``."""
+        return self.mb * self.widest_row
 
     def grad_step(self, kind: str, with_intercept: bool = True):
         """This layout's minibatch gradient step, behind the part of a
@@ -282,6 +293,12 @@ class EllMinibatchStack:
         """Slots a device's step walks, pads included."""
         return self.width * self.mb + self.cold_pad
 
+    @property
+    def ell_step_slots(self) -> int:
+        """As :attr:`SparseMinibatchStack.ell_step_slots`: what the rule
+        reckoned, the cold list's slots not among them."""
+        return self.width * self.mb
+
     def grad_step(self, kind: str, with_intercept: bool = True):
         """As :meth:`SparseMinibatchStack.grad_step`, for this layout."""
         if self.hot_ids is None:
@@ -365,6 +382,25 @@ def _hot_features(indices, dim: int):
     return hot_ids, float(counts[top].sum()) / max(1, len(indices))
 
 
+def padded_nnz(nnz_max: int, pad_multiple: int, min_nnz_pad: int = 0) -> int:
+    """The padded entry count of a segment-CSR step whose fullest minibatch
+    stores ``nnz_max`` entries.  Where a caller brings a floor (the width
+    that processes or out-of-core chunks agreed on) it is the larger of the
+    floor and ``nnz_max`` rounded up to ``pad_multiple``, as ever.  Where
+    nothing else fixes the width the count is rounded up to an ODD multiple
+    of ``pad_multiple``: the chip's gather of N addresses runs 7% faster
+    at an odd multiple of 512 than at a multiple of 1024 (a TPU v5e, N =
+    3.97 M, my chip runs, PR 33: 6.63 against 7.13 ns an address on five
+    widths of one table and six tables, whatever is done to the slices
+    that feed it), so a table's fit was 2.7% faster or slower by the
+    residue its widths happened to leave; at most ``pad_multiple`` slots
+    more buy the faster one."""
+    blocks = -(-nnz_max // pad_multiple)
+    if min_nnz_pad:
+        return max(blocks * pad_multiple, int(min_nnz_pad))
+    return (blocks | 1) * pad_multiple
+
+
 @obs.phased("pack_sparse")
 def pack_sparse_minibatches(
     vectors: Sequence,
@@ -438,7 +474,7 @@ def pack_sparse_minibatches(
             nnz_max,
             sum(len(vectors[i].indices) for i in range(lo, min(lo + mb, n))),
         )
-    nnz_pad = max(-(-nnz_max // pad_multiple) * pad_multiple, int(min_nnz_pad))
+    nnz_pad = padded_nnz(nnz_max, pad_multiple, min_nnz_pad)
 
     ints = np.zeros((n_groups, 2, nnz_pad), dtype=np.int32)
     ints[:, 1, :] = mb  # pad row id -> dropped segment
@@ -581,11 +617,17 @@ def _pack_sparse_minibatches_csr(
         e0, e1 = int(indptr[lo]), int(indptr[hi])
         bounds.append((lo, hi, e0, e1))
         nnz_max = max(nnz_max, e1 - e0)
-    nnz_pad = max(-(-nnz_max // pad_multiple) * pad_multiple, int(min_nnz_pad))
+    nnz_pad = padded_nnz(nnz_max, pad_multiple, min_nnz_pad)
 
+    width = 0
     if row_regular:
-        # the slots either layout would walk a step, from the widths alone
+        # the slots either layout would walk a step, from the widths alone:
+        # the rule's inputs, said in the pack's own phase
         width = max(1, int(counts.max(initial=0)))
+        obs.gauge_set("pack_sparse.widest_row", width)
+        obs.gauge_set("pack_sparse.mean_row", nnz_total / max(1, n))
+        obs.gauge_set("pack_sparse.ell_step_slots", mb * width)
+        obs.gauge_set("pack_sparse.csr_step_slots", nnz_pad)
         if mb * width <= _ELL_MAX_SLOT_RATIO * nnz_pad:
             # and the feature counts decide whether the hot features leave
             # the gather and the scatter (EllMinibatchStack's split)
@@ -635,6 +677,7 @@ def _pack_sparse_minibatches_csr(
     return SparseMinibatchStack(
         ints=ints, floats=floats, steps=steps, mb=mb, nnz_pad=nnz_pad, dim=dim,
         n_rows=n, n_entries=nnz_total, ell_declined=row_regular,
+        widest_row=width,
     )
 
 
@@ -1431,19 +1474,24 @@ def _segment_csr_forward(wts, idx, rid, vals, mb: int):
     Entries are packed row-major (rid non-decreasing, pads at the tail —
     asserted by the pack tests), so the segment reduction takes the
     sorted-indices lowering."""
-    return jax.ops.segment_sum(
-        vals * jnp.take(wts, idx, axis=0), rid, num_segments=mb,
-        indices_are_sorted=True,
-    )
+    # the step's four random-access operations, each named for a profile
+    # (the split step's cold list runs these too)
+    with jax.named_scope("fmt.train.sparse.take_weights"):
+        prods = vals * jnp.take(wts, idx, axis=0)
+    with jax.named_scope("fmt.train.sparse.row_sum"):
+        return jax.ops.segment_sum(
+            prods, rid, num_segments=mb, indices_are_sorted=True,
+        )
 
 
 def _segment_csr_backward(err, idx, rid, vals, dim: int):
     """Feature-gradient scatter through the same segments; the appended
     zero row is the pad sink (rid == mb gathers it, contributing nothing)."""
-    err_ext = jnp.concatenate([err, jnp.zeros((1,), err.dtype)])
-    return jax.ops.segment_sum(
-        vals * jnp.take(err_ext, rid, axis=0), idx, num_segments=dim
-    )
+    with jax.named_scope("fmt.train.sparse.take_error"):
+        err_ext = jnp.concatenate([err, jnp.zeros((1,), err.dtype)])
+        prods = vals * jnp.take(err_ext, rid, axis=0)
+    with jax.named_scope("fmt.train.sparse.scatter"):
+        return jax.ops.segment_sum(prods, idx, num_segments=dim)
 
 
 def make_sparse_glm_train_fn(
@@ -2690,6 +2738,11 @@ def train_glm_sparse(
         obs.counter_add("train.sparse_entries", sstack.n_entries * r.epochs)
         obs.counter_add("train.sparse_slots",
                         sstack.step_slots * len(sstack.ints) * r.epochs)
+        # and the slots the rule reckoned for the row-regular layout (0
+        # where the pack was not asked): over train.sparse_slots, by how
+        # much the table passed or failed _ELL_MAX_SLOT_RATIO
+        obs.counter_add("train.sparse_ell_slots_reckoned",
+                        sstack.ell_step_slots * len(sstack.ints) * r.epochs)
         return TrainResult(params=trim(r.params), epochs=r.epochs,
                            losses=r.losses, final_delta=r.final_delta,
                            metrics=r.metrics)

@@ -3,6 +3,7 @@
 its program listed: the per-operation split of PERF.md §5's sparse cell.
 
     python scripts/sparse_step_trace.py --seed <n> [--rows N]
+                                        [--config criteo_sparse_lr]
                                         [--segment-csr | --unsplit]
 
 The benchmark's breakdown (``chipbench/trace_reduce.py``) keeps one of two
@@ -27,8 +28,13 @@ profile
                      the step's start.
 
 Data are made from ``--seed`` by the benchmark's generator at the
-configuration's size (``--rows`` cuts it for a rehearsal).  A summary goes to
-standard output, everything to ``chiprun_out/sparse_step_trace/<layout>.json``.
+configuration's size (``--rows`` cuts it for a rehearsal); ``--config
+url_ragged_lr`` takes the ragged table (PR 33), which the pack lays
+segment-CSR by its own rule: its four operations then read under
+``fmt.train.sparse.take_weights``, ``.row_sum``, ``.take_error`` and
+``.scatter``.  A summary goes to
+standard output, everything to
+``chiprun_out/sparse_step_trace/<config>.<layout>.json``.
 Runs on whatever JAX finds; times mean something only on the chip.
 """
 
@@ -106,6 +112,7 @@ def main(argv=None) -> int:
     parser = argparse.ArgumentParser()
     parser.add_argument("--seed", type=int, required=True)
     parser.add_argument("--rows", type=int, default=0)
+    parser.add_argument("--config", default="criteo_sparse_lr")
     parser.add_argument("--segment-csr", action="store_true")
     parser.add_argument("--unsplit", action="store_true")
     parser.add_argument("--out", default=os.path.join(
@@ -115,15 +122,16 @@ def main(argv=None) -> int:
     import jax.numpy as jnp
 
     import fit_gaps
-    from chipbench import data_sparse, run
+    from chipbench import data_ragged, data_sparse, run
     from flink_ml_tpu.lib import common
     from flink_ml_tpu.ops.batch import CsrRows
     from flink_ml_tpu.parallel.mesh import shard_batch_prefetched
     from flink_ml_tpu.utils.environment import MLEnvironmentFactory
 
-    config = run.load_json(run.HERE, "configs", "criteo_sparse_lr.json")
+    config = run.load_json(run.HERE, "configs", args.config + ".json")
     dim, batch = int(config["numFeatures"]), int(config["globalBatchSize"])
-    indptr, indices, values, y = data_sparse.make_rows(
+    maker = data_ragged if "width_sigma" in config["data"] else data_sparse
+    indptr, indices, values, y = maker.make_rows(
         config["data"], args.rows or int(config["rows"]), dim, args.seed)
     mesh = MLEnvironmentFactory.get_default().get_mesh()
     if args.unsplit:
@@ -153,7 +161,8 @@ def main(argv=None) -> int:
     report["traced_fit_s"], path = fit_gaps.traced(trace_dir, fit)
     report.update(read_program(path))
     shutil.rmtree(trace_dir)  # read; the report is what goes back
-    with open(os.path.join(args.out, f"{layout}.json"), "w") as f:
+    with open(os.path.join(args.out, f"{args.config}.{layout}.json"),
+              "w") as f:
         json.dump(report, f, indent=1)
     print(json.dumps({k: v for k, v in report.items()
                       if k not in ("ops", "one_step")}))

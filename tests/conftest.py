@@ -63,3 +63,15 @@ def _no_warm_store_leaks_into_the_next_file():
     warmstart = sys.modules.get("flink_ml_tpu.serving.warmstart")
     if warmstart is not None:
         warmstart.configure(None)
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _no_obs_switch_leaks_into_the_next_file():
+    """The same, for the registry's switch: a file that turns telemetry on
+    and leaves it on (the benchmark's rehearsals do: ``chipbench/program.py``
+    ``prepare``) made ``test_trace.py``'s "off by default" fail whenever it
+    ran next on the same worker, by worker order."""
+    yield
+    obs = sys.modules.get("flink_ml_tpu.obs")
+    if obs is not None:
+        obs.disable()

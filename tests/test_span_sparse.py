@@ -41,6 +41,10 @@ MISS_ONLY = ("slab_pool.build", "place.h2d", "phase.pack_sparse",
 SPARSE_SCOPES = {"fmt.train", "fmt.train.sparse.forward",
                  "fmt.train.sparse.backward", "fmt.train.grad",
                  "fmt.train.update", "fmt.train.bundle"}
+#: segment-CSR's four random-access operations, inside forward and backward
+#: (PR 33); the row-regular step has one gather and one scatter, unnamed
+SEGMENT_SCOPES = {"fmt.train.sparse.take_weights", "fmt.train.sparse.row_sum",
+                  "fmt.train.sparse.take_error", "fmt.train.sparse.scatter"}
 ROWS, WIDTH, DIM, BATCH, EPOCHS = 2048, 7, 300, 512, 2
 
 
@@ -160,10 +164,12 @@ def test_the_sparse_train_program_carries_its_scopes_and_its_name(layout):
                   if hasattr(c.cell_contents, "lower")]
     lowered = program.lower(params, batch)
     assert lowered.as_text().startswith("module @jit_bundled")
+    scopes = SPARSE_SCOPES | (SEGMENT_SCOPES if layout == "segment_csr"
+                              else set())
     assert set(re.findall(r"fmt\.[a-z_.]+",
-                          lowered.as_text(debug_info=True))) == SPARSE_SCOPES
+                          lowered.as_text(debug_info=True))) == scopes
     compiled = lowered.compile().as_text()
-    assert SPARSE_SCOPES <= set(re.findall(r"fmt\.[a-z_.]+", compiled))
+    assert scopes <= set(re.findall(r"fmt\.[a-z_.]+", compiled))
 
 
 def _whole_column_check(indptr, indices):

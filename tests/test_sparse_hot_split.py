@@ -408,8 +408,13 @@ def test_the_split_program_is_jit_bundled_and_carries_the_hot_scope(
     assert lowered.as_text().startswith("module @jit_bundled")
     scopes = set(re.findall(r"fmt\.[a-z_.]+",
                             lowered.as_text(debug_info=True)))
+    # the cold list runs segment-CSR's four operations, under their names
     assert scopes == {"fmt.train", "fmt.train.sparse.forward",
                       "fmt.train.sparse.backward", "fmt.train.sparse.hot",
+                      "fmt.train.sparse.take_weights",
+                      "fmt.train.sparse.row_sum",
+                      "fmt.train.sparse.take_error",
+                      "fmt.train.sparse.scatter",
                       "fmt.train.grad", "fmt.train.update",
                       "fmt.train.bundle"}
     assert "fmt.train.sparse.hot" in lowered.compile().as_text()
