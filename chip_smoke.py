@@ -536,6 +536,7 @@ def phase_nothing_hid(ctx):
     ctx["counters"] = {k: snap["counters"].get(k, 0) for k in MUST_BE_ZERO + (
         "train.fused_runs", "train.onepass_fits", "train.onepass_declined",
         "train.sparse_ell_fits", "train.sparse_ell_declined",
+        "train.sparse_ell_classes",
         "train.sparse_slots", "train.sparse_ell_slots_reckoned",
         "train.sparse_hot_fits", "train.sparse_hot_entries",
         "train.sparse_hot_declined",

@@ -21,6 +21,8 @@ rows applied in padded power-of-two buckets to bound jit recompiles.
 
 from __future__ import annotations
 
+import os
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional, Sequence, Tuple
 
@@ -154,9 +156,10 @@ def make_sgd_update(learning_rate: float, l2: float):
 @dataclass
 class SparseMinibatchStack:
     """Device-major sparse minibatches in padded segment-CSR layout: one of
-    the sparse route's two step layouts, the other being the row-regular
-    :class:`EllMinibatchStack` that the pack lays where the rows' widths
-    allow it (:data:`_ELL_MAX_SLOT_RATIO`).
+    the sparse route's step layouts, the others being the row-regular
+    :class:`EllMinibatchStack` and :class:`ClassedEllMinibatchStack` that
+    the pack lays where the rows' widths allow it
+    (:data:`_ELL_MAX_SLOT_RATIO`).
 
     The Criteo-scale replacement for per-record SparseVector math
     (BLAS.java:205-233, SURVEY.md §7.3 'sparse features at Criteo scale'):
@@ -184,8 +187,14 @@ class SparseMinibatchStack:
     #: the widest row the pack observed when it was asked for either layout
     #: (0 where it was not): with ``mb`` and ``nnz_pad`` the rule's inputs
     widest_row: int = 0
+    #: slots a device's step WOULD walk laid row-regular, as the pack
+    #: reckoned them for its rule (in the width classes it would cut; 0
+    #: where the pack was not asked): ``train.sparse_ell_slots_reckoned``
+    #: counts them a fit, beside ``train.sparse_slots``
+    ell_step_slots: int = 0
 
     row_regular = False  # the layout, for ``train.sparse_ell_fits``
+    ell_classes = 0  # and its width classes: ``train.sparse_ell_classes``
     hot_ids = None  # no frequency split: ``train.sparse_hot_fits`` reads it
     hot_declined = False
 
@@ -199,14 +208,6 @@ class SparseMinibatchStack:
     def step_slots(self) -> int:
         """Slots a device's step walks, pads included."""
         return self.nnz_pad
-
-    @property
-    def ell_step_slots(self) -> int:
-        """Slots a device's step WOULD walk laid row-regular, as the pack
-        reckoned them for its rule (``mb x`` the widest row; 0 where the
-        pack was not asked): ``train.sparse_ell_slots_reckoned`` counts
-        them a fit, beside ``train.sparse_slots``."""
-        return self.mb * self.widest_row
 
     def grad_step(self, kind: str, with_intercept: bool = True):
         """This layout's minibatch gradient step, behind the part of a
@@ -275,6 +276,7 @@ class EllMinibatchStack:
 
     row_regular = True
     ell_declined = False
+    ell_classes = 1
 
     @property
     def batch(self):
@@ -311,6 +313,83 @@ class EllMinibatchStack:
                                        with_intercept))
 
 
+@dataclass
+class ClassedEllMinibatchStack:
+    """Device-major sparse minibatches in the row-regular layout IN WIDTH
+    CLASSES: what the pack lays where a table's widest row fails
+    :data:`_ELL_MAX_SLOT_RATIO` at one width and its rows, ordered by width
+    inside each step, pass it at a few (:func:`_width_classes`).  A step's
+    rows stand in descending order of stored width (stable; the pad rows of
+    a short step have width 0 and fall last); ``classes`` cuts the ``mb``
+    places of that order into runs of whole lane blocks, ``(rows, width)``
+    each, the same for every step and device, a class as wide as the widest
+    row any step holds in it.  One gather and one scatter a slot, as
+    :class:`EllMinibatchStack`, and no row ids.
+
+      ints   (n_dev*steps, slots + 2*mb) int32 -- feature ids, class after
+             class, each class entries-major ``(width, rows)``; a shorter
+             row pads with id 0 at value 0.0, and a tail of such pads rounds
+             the classes' slots up to ``slots`` (:func:`padded_nnz`'s
+             length).  Then the step's order both ways: the row at each
+             place, and the place of each row.
+      floats (n_dev*steps, slots + 2*mb) -- values at the ids' places, then
+             labels and row weights in the TABLE's row order, as
+             segment-CSR holds them (weight 0 past the table's end).
+
+    The order of rows inside a step changes no sum's members: a step's
+    gradient is the mean over the same rows.  The step puts the scores back
+    into the table's order before the loss, so that the loss and the
+    intercept's gradient are summed over the rows in the order every other
+    layout (and a plain reference) sums them: summed in the step's order
+    the loss read up to two float32 places off the reference's on the chip,
+    of the three its limit allows (my chip runs, PR 34).  Never split by
+    frequency.
+    """
+
+    ints: np.ndarray
+    floats: np.ndarray
+    steps: int
+    mb: int
+    classes: tuple  # ((rows, width), ...): rows sum to mb
+    slots: int
+    dim: int
+    n_rows: int = 0  # true (un-padded) row count, for throughput metrics
+    n_entries: int = 0  # stored entries (pads not counted), likewise
+
+    row_regular = True
+    ell_declined = False
+    hot_ids = None
+    hot_declined = False
+
+    @property
+    def batch(self):
+        """As :attr:`SparseMinibatchStack.batch`."""
+        return self.ints, self.floats
+
+    @property
+    def ell_classes(self) -> int:
+        return len(self.classes)
+
+    @property
+    def step_slots(self) -> int:
+        """Slots a device's step walks, the pads of the tail included."""
+        return self.slots
+
+    @property
+    def ell_step_slots(self) -> int:
+        """As :attr:`SparseMinibatchStack.ell_step_slots`: the classes'
+        slots, which the rule reckoned."""
+        return sum(rows * width for rows, width in self.classes)
+
+    def grad_step(self, kind: str, with_intercept: bool = True):
+        """As :meth:`SparseMinibatchStack.grad_step`, for this layout."""
+        return (("sparse-ell-classed", self.mb, self.classes, self.slots,
+                 self.dim),
+                make_classed_ell_grad_step(kind, self.mb, self.classes,
+                                           self.slots, self.dim,
+                                           with_intercept))
+
+
 #: the most slots a row-regular step may walk for ONE slot of the
 #: segment-CSR step it replaces (``mb * width <= 1.75 * nnz_pad``).  By the
 #: chip's per-slot costs (PERF.md §5; my chip runs, PR 28) a segment-CSR
@@ -324,8 +403,29 @@ class EllMinibatchStack:
 #: segment-CSR's bytes (the int leaf, no row-id plane, 0.875 of it; the
 #: float leaf 1.75).  (ISSUE 28 reckoned 26.2 / 10.4 = 2.5, and so asked
 #: for 2, from a scatter at 3.3 ns: half its cost, lost by the benchmark's
-#: breakdown where two programs name an operation alike)
+#: breakdown where two programs name an operation alike).  Since PR 34 the
+#: pack holds a table that fails the rule at ONE width to the same rule in
+#: width classes (:func:`_width_classes`): on LIBSVM url_combined's shape
+#: one width would walk 3.9-4.2 slots for one, the classes walk 1.026, and
+#: the step runs x1.96 faster than segment-CSR, 61.2 against 119.8 ms (a
+#: take 6.63 + a scatter 7.99 ns a slot against 6.63 + 8.61 + 6.63 + 7.98;
+#: my chip runs, PR 34)
 _ELL_MAX_SLOT_RATIO = 1.75
+
+#: the classed row-regular layout (:class:`ClassedEllMinibatchStack`) cuts a
+#: step's rows into classes of whole lane blocks of this many rows (the
+#: step's rows lie on the chip's 128 lanes), at most this many classes: a
+#: class is a reshape, a sum over an axis and a broadcast of its own in the
+#: step's program, about 5 microseconds of a 60 ms step.  On LIBSVM
+#: url_combined's shape (2.4 M rows of 24-512 entries, log-normal about
+#: 115.6; my chip runs, PR 34) the best cut into 8 / 16 / 32 classes walks
+#: 4.38 / 4.16 / 4.07 M slots a step for segment-CSR's 3.97 M and a fit
+#: lasted 4.838 / 4.593 / 4.477 s against 8.868 (before the scores went
+#: back into the table's order for the loss: 0.05 s a fit more): the slots
+#: decide (a take 6.63 ns, a scatter 8.00 ns a slot at every cut), so the
+#: cap is the largest read; past it a cut gains under 1%
+_LANE_BLOCK = 128
+_ELL_MAX_CLASSES = 32
 
 #: features the frequency split looks up by comparison: 128 x 128, one MXU
 #: tile squared (a code is a row of the hot weights' table and a lane).  On
@@ -428,14 +528,18 @@ def pack_sparse_minibatches(
     2-D mesh, multi-process and out-of-core fits read segment-CSR and leave
     it off).  A CSR column then packs as an :class:`EllMinibatchStack`
     where ``mb * width <= _ELL_MAX_SLOT_RATIO * nnz_pad`` — a choice made
-    from the row widths the pack observes — and as segment-CSR, byte for
-    byte what it is without the flag and marked ``ell_declined``, where
-    they fail that rule.  A per-object column keeps segment-CSR.  Where
-    the row-regular layout is taken, on a TPU, the pack also counts the
-    stored entries a feature and lays the frequency split
+    from the row widths the pack observes — else as a
+    :class:`ClassedEllMinibatchStack` where the slots of a step whose rows
+    are ordered by width and laid in a few width classes
+    (:func:`_width_classes`) pass the same rule, and as segment-CSR, byte
+    for byte what it is without the flag and marked ``ell_declined``, where
+    they fail it too.  A per-object column keeps segment-CSR.  Where the
+    one-width layout is taken, on a TPU, the pack also counts the stored
+    entries a feature and lays the frequency split
     (:class:`EllMinibatchStack`) where :func:`_hot_split_wins` says it
     pays; a table that fails that keeps the unsplit leaves byte for byte
-    and is marked ``hot_declined``.
+    and is marked ``hot_declined``.  A classed table is neither counted
+    nor split.
     """
     from flink_ml_tpu.ops.batch import CsrRows
 
@@ -584,8 +688,9 @@ def _pack_sparse_minibatches_csr(
     """Vectorized packing from a CSR column: identical layout and validation
     to the per-row path (shared tests assert bit-equality), but the inner
     work is numpy slice copies — O(groups) Python instead of O(rows).  With
-    ``row_regular`` the row widths decide between this layout and
-    :func:`_pack_ell` (see :func:`pack_sparse_minibatches`)."""
+    ``row_regular`` the row widths decide between this layout,
+    :func:`_pack_ell` and :func:`_pack_ell_classed` (see
+    :func:`pack_sparse_minibatches`)."""
     n = len(rows)
     indptr, indices, values = rows.indptr, rows.indices, rows.values
     nnz_total = int(indptr[-1]) if n else 0
@@ -619,16 +724,27 @@ def _pack_sparse_minibatches_csr(
         nnz_max = max(nnz_max, e1 - e0)
     nnz_pad = padded_nnz(nnz_max, pad_multiple, min_nnz_pad)
 
-    width = 0
+    width = slots = 0
     if row_regular:
         # the slots either layout would walk a step, from the widths alone:
-        # the rule's inputs, said in the pack's own phase
+        # the rule's inputs, said in the pack's own phase.  Row-regular at
+        # ONE width first; where that fails the rule, with a step's rows
+        # ordered by width and laid in a few width classes
         width = max(1, int(counts.max(initial=0)))
+        slots, classes = mb * width, ((mb, width),)
+        if slots > _ELL_MAX_SLOT_RATIO * nnz_pad:
+            orders, classes = _width_classes(bounds, counts, mb)
+            slots = sum(r * w for r, w in classes)
         obs.gauge_set("pack_sparse.widest_row", width)
         obs.gauge_set("pack_sparse.mean_row", nnz_total / max(1, n))
-        obs.gauge_set("pack_sparse.ell_step_slots", mb * width)
+        obs.gauge_set("pack_sparse.ell_classes", len(classes))
+        obs.gauge_set("pack_sparse.ell_step_slots", slots)
         obs.gauge_set("pack_sparse.csr_step_slots", nnz_pad)
-        if mb * width <= _ELL_MAX_SLOT_RATIO * nnz_pad:
+        if slots <= _ELL_MAX_SLOT_RATIO * nnz_pad:
+            if len(classes) > 1:
+                return _pack_ell_classed(rows, y, bounds, counts, orders,
+                                         classes, mb, steps, dim,
+                                         pad_multiple)
             # and the feature counts decide whether the hot features leave
             # the gather and the scatter (EllMinibatchStack's split)
             hot_ids = None
@@ -677,7 +793,114 @@ def _pack_sparse_minibatches_csr(
     return SparseMinibatchStack(
         ints=ints, floats=floats, steps=steps, mb=mb, nnz_pad=nnz_pad, dim=dim,
         n_rows=n, n_entries=nnz_total, ell_declined=row_regular,
-        widest_row=width,
+        widest_row=width, ell_step_slots=slots,
+    )
+
+
+def _width_classes(bounds, counts, mb: int):
+    """``(orders, classes)`` of the classed row-regular layout, from the
+    widths alone.  ``orders[g]`` puts a device-step's rows (``bounds[g]``)
+    in descending order of stored width, stable.  The ENVELOPE of those
+    orders, the widest row any step holds at each of the ``mb`` places, is
+    what one program for every step has to hold; ``classes`` cuts the places
+    into at most :data:`_ELL_MAX_CLASSES` runs of whole lane blocks,
+    ``((rows, width), ...)``, each as wide as its first place's envelope (at
+    least 1), so that the slots a step walks, ``sum(rows * width)``, are the
+    fewest such a cut allows (a dynamic programme over the blocks; of two
+    cuts with the same slots, the one with fewer classes)."""
+    envelope = np.zeros(mb, np.int64)
+    orders = []
+    for lo, hi, _e0, _e1 in bounds:
+        widths = counts[lo:hi]
+        order = np.argsort(-widths, kind="stable")
+        orders.append(order)
+        np.maximum(envelope[: hi - lo], widths[order],
+                   out=envelope[: hi - lo])
+    starts = np.arange(0, mb, _LANE_BLOCK)
+    ends = np.minimum(starts + _LANE_BLOCK, mb)
+    n_blocks = len(starts)
+    need = np.maximum(envelope[starts], 1)
+    # cost[a, b - 1]: the slots of ONE class over blocks a..b-1
+    # (float64 holds every count exactly, and inf marks b <= a)
+    cost = np.where(ends[None, :] > starts[:, None],
+                    (ends[None, :] - starts[:, None]) * need[:, None],
+                    np.inf)
+    # best[k][b]: the fewest slots of blocks 0..b-1 cut into k + 1 classes
+    best, cut = [cost[0]], []
+    for _k in range(1, min(_ELL_MAX_CLASSES, n_blocks)):
+        # the last class starts at block a >= 1, after best[-1][a - 1]
+        total = best[-1][:-1, None] + cost[1:, :]
+        cut.append(np.argmin(total, axis=0) + 1)
+        best.append(np.min(total, axis=0))
+    totals = [b[-1] for b in best]
+    k = totals.index(min(totals))
+    edges, b = [n_blocks], n_blocks
+    for a_of in reversed(cut[:k]):
+        b = int(a_of[b - 1])
+        edges.append(b)
+    edges.append(0)
+    edges.reverse()
+    classes = tuple(
+        (int(ends[b - 1] - starts[a]), int(need[a]))
+        for a, b in zip(edges[:-1], edges[1:]))
+    return orders, classes
+
+
+def _pack_ell_classed(rows, y, bounds, counts, orders, classes, mb: int,
+                      steps: int, dim: int,
+                      pad_multiple: int) -> ClassedEllMinibatchStack:
+    """Lay a validated CSR column out row-regular in the width classes
+    :func:`_width_classes` chose, a device's step at a time.  A step's
+    destination order is built ONCE for both leaves, as the stored entry each
+    slot holds (a pad holds the step's appended zero entry), and the leaves
+    are gathered through it, not scattered; the steps are spread over
+    threads (numpy's ``take`` releases the lock)."""
+    indptr, indices, values = rows.indptr, rows.indices, rows.values
+    slots = padded_nnz(sum(r * w for r, w in classes), pad_multiple)
+    ints = np.zeros((len(bounds), slots + 2 * mb), dtype=np.int32)
+    floats = np.zeros((len(bounds), slots + 2 * mb), dtype=np.float32)
+
+    def lay(g):
+        lo, hi, e0, e1 = bounds[g]
+        m, n = hi - lo, e1 - e0
+        # the pad rows of a short step keep their places, after every row
+        order = np.arange(mb, dtype=np.int32)
+        order[:m] = orders[g]
+        ints[g, slots : slots + mb] = order
+        ints[g, slots + mb :][order] = np.arange(mb, dtype=np.int32)
+        if not m:
+            return
+        order = order[:m]
+        # a place's row: its first entry in the step, and its width (a pad
+        # row: width 0)
+        first = np.zeros(mb, np.int32)
+        width = np.zeros(mb, np.int32)
+        first[:m] = indptr[lo:hi][order] - e0
+        width[:m] = counts[lo:hi][order]
+        ids = np.zeros(n + 1, np.int32)  # the step's entries, then the pad's
+        vals = np.zeros(n + 1, np.float32)
+        ids[:n], vals[:n] = indices[e0:e1], values[e0:e1]
+        at = place = 0
+        for rows_c, width_c in classes:
+            here = slice(place, place + rows_c)
+            nth = np.arange(width_c, dtype=np.int32)[:, None]
+            src = first[None, here] + nth  # (width_c, rows_c)
+            np.copyto(src, n, where=nth >= width[None, here])
+            src = src.ravel()
+            np.take(ids, src, out=ints[g, at : at + len(src)], mode="clip")
+            np.take(vals, src, out=floats[g, at : at + len(src)],
+                    mode="clip")
+            at += len(src)
+            place += rows_c
+        floats[g, slots : slots + m] = y[lo:hi]
+        floats[g, slots + mb : slots + mb + m] = 1.0
+
+    with ThreadPoolExecutor(min(len(bounds), os.cpu_count() or 1)) as pool:
+        list(pool.map(lay, range(len(bounds))))
+    return ClassedEllMinibatchStack(
+        ints=ints, floats=floats, steps=steps, mb=mb, classes=classes,
+        slots=slots, dim=dim, n_rows=len(rows),
+        n_entries=int(indptr[-1]) if len(rows) else 0,
     )
 
 
@@ -1405,6 +1628,52 @@ def make_ell_mb_grad_step(kind: str, mb: int, width: int, dim: int,
     return mb_grad_step
 
 
+def make_classed_ell_grad_step(kind: str, mb: int, classes, slots: int,
+                               dim: int, with_intercept: bool = True):
+    """:func:`make_ell_mb_grad_step`'s gradient over one step of a
+    :class:`ClassedEllMinibatchStack`: ONE take of the weights over all
+    ``slots``, a class's scores the sum of its ``(width, rows)`` products
+    over the entries' axis, the error broadcast back class by class and ONE
+    scatter into ``dim`` (``fmt.train.sparse.take_weights`` and ``.scatter``
+    inside the step's two halves, as segment-CSR names its own).  The
+    scores go back into the table's row order for the loss and the error
+    back into the step's (two takes of ``mb`` addresses); float32
+    throughout, a row's products summed along its own axis."""
+    keep_b = 1.0 if with_intercept else 0.0
+    spans, at, place = [], 0, 0  # a class: its slots and its places
+    for rows, width in classes:
+        spans.append((at, at + rows * width, place, place + rows, width))
+        at, place = at + rows * width, place + rows
+
+    def mb_grad_step(params, xs):
+        ints, floats = xs  # (slots + 2*mb,) each
+        idx, order, place_of = (ints[:slots], ints[slots : slots + mb],
+                                ints[slots + mb :])
+        vals, y, w = (floats[:slots], floats[slots : slots + mb],
+                      floats[slots + mb :])
+        wts, b = params
+        with jax.named_scope("fmt.train.sparse.forward"):
+            with jax.named_scope("fmt.train.sparse.take_weights"):
+                prods = vals * jnp.take(wts, idx, axis=0)
+            logits = jnp.take(jnp.concatenate([
+                prods[lo:hi].reshape(width, -1).sum(axis=0)
+                for lo, hi, _p, _q, width in spans]), place_of, axis=0) + b
+        err_rows, loss_sum = _sparse_loss(kind, logits, y, w)
+        with jax.named_scope("fmt.train.sparse.backward"):
+            err = jnp.take(err_rows, order, axis=0)
+            spread = [
+                (err[p:q] * vals[lo:hi].reshape(width, -1)).reshape(-1)
+                for lo, hi, p, q, width in spans]
+            spread.append(jnp.zeros((slots - at,), vals.dtype))  # the tail
+            with jax.named_scope("fmt.train.sparse.scatter"):
+                g_w = jax.ops.segment_sum(
+                    jnp.concatenate(spread), idx, num_segments=dim)
+        g_b = jnp.sum(err_rows) * keep_b
+        return (g_w, g_b), loss_sum, jnp.sum(w)
+
+    return mb_grad_step
+
+
 def make_hot_ell_grad_step(kind: str, mb: int, width: int, dim: int,
                            with_intercept: bool = True,
                            interpret: Optional[bool] = None):
@@ -1505,12 +1774,14 @@ def make_sparse_glm_train_fn(
     with_intercept: bool = True,
 ):
     """Fused training over the batches of ``sstack``, a
-    :class:`SparseMinibatchStack` or an :class:`EllMinibatchStack` (read for
-    its shapes only): the stack names its step (``grad_step``).
+    :class:`SparseMinibatchStack`, an :class:`EllMinibatchStack` or a
+    :class:`ClassedEllMinibatchStack` (read for its shapes only): the stack
+    names its step (``grad_step``).
 
     ``kind`` picks the loss ('logistic' | 'squared'); the minibatch math is
-    :func:`make_sparse_mb_grad_step`, :func:`make_ell_mb_grad_step` or, on
-    a stack split by frequency, :func:`make_hot_ell_grad_step`.
+    :func:`make_sparse_mb_grad_step`, :func:`make_ell_mb_grad_step`,
+    :func:`make_classed_ell_grad_step` or, on a stack split by frequency,
+    :func:`make_hot_ell_grad_step`.
     Program structure is shared with the dense path via
     :func:`_build_fused_train_fn`, bundled as the dense estimator fit is
     (one program named ``jit_bundled``, one buffer to fetch):
@@ -2727,6 +2998,8 @@ def train_glm_sparse(
         obs.counter_add("train.sparse_ell_fits", int(sstack.row_regular))
         if sstack.ell_declined:
             obs.counter_add("train.sparse_ell_declined")
+        # and in how many width classes (0 on segment-CSR, 1 at one width)
+        obs.counter_add("train.sparse_ell_classes", sstack.ell_classes)
         # and whether its hot features were looked up by comparison
         obs.counter_add("train.sparse_hot_fits",
                         int(sstack.hot_ids is not None))

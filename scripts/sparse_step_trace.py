@@ -5,6 +5,7 @@ its program listed: the per-operation split of PERF.md §5's sparse cell.
     python scripts/sparse_step_trace.py --seed <n> [--rows N]
                                         [--config criteo_sparse_lr]
                                         [--segment-csr | --unsplit]
+                                        [--classes N]
 
 The benchmark's breakdown (``chipbench/trace_reduce.py``) keeps one of two
 programs' operations where both name one alike (PERF.md §7 (e)), so half the
@@ -30,11 +31,15 @@ profile
 Data are made from ``--seed`` by the benchmark's generator at the
 configuration's size (``--rows`` cuts it for a rehearsal); ``--config
 url_ragged_lr`` takes the ragged table (PR 33), which the pack lays
-segment-CSR by its own rule: its four operations then read under
-``fmt.train.sparse.take_weights``, ``.row_sum``, ``.take_error`` and
-``.scatter``.  A summary goes to
-standard output, everything to
-``chiprun_out/sparse_step_trace/<config>.<layout>.json``.
+row-regular in width classes by its own rule since PR 34 (a step's rows
+ordered by width; ``--classes N`` moves the cap on their number inside this
+script): the step's two random-access operations then read under
+``fmt.train.sparse.take_weights`` and ``.scatter``.  With ``--segment-csr``
+the same table is laid as the parent laid it, and segment-CSR's four read
+under ``.take_weights``, ``.row_sum``, ``.take_error`` and ``.scatter``: one
+tree gives both sides.  A summary goes to standard output, everything to
+``<--out>/<config>.<layout>.json`` (``chiprun_out/sparse_step_trace/``
+unless given).
 Runs on whatever JAX finds; times mean something only on the chip.
 """
 
@@ -115,6 +120,7 @@ def main(argv=None) -> int:
     parser.add_argument("--config", default="criteo_sparse_lr")
     parser.add_argument("--segment-csr", action="store_true")
     parser.add_argument("--unsplit", action="store_true")
+    parser.add_argument("--classes", type=int, default=0)
     parser.add_argument("--out", default=os.path.join(
         ROOT, "chiprun_out", "sparse_step_trace"))
     args = parser.parse_args(argv)
@@ -136,12 +142,18 @@ def main(argv=None) -> int:
     mesh = MLEnvironmentFactory.get_default().get_mesh()
     if args.unsplit:
         common._hot_split_wins = lambda *a: False
+    if args.classes:
+        common._ELL_MAX_CLASSES = args.classes
+    t = time.perf_counter()
     stack = common.pack_sparse_minibatches(
         CsrRows(dim, indptr, indices, values), y, len(mesh.devices.flat),
         batch, dim=dim, row_regular=not args.segment_csr)
+    pack_s = time.perf_counter() - t
     layout = "row_regular" if stack.row_regular else "segment_csr"
     if stack.hot_ids is not None:
         layout += "_split"
+    if stack.ell_classes > 1:
+        layout += f"_classed{stack.ell_classes}"
     placed = shard_batch_prefetched(mesh, stack.batch)
 
     def fit():
@@ -154,6 +166,8 @@ def main(argv=None) -> int:
 
     report = {"layout": layout, "step_slots": stack.step_slots,
               "cold_pad": getattr(stack, "cold_pad", 0),
+              "classes": getattr(stack, "classes", None),
+              "pack_s": pack_s,
               "steps": len(stack.ints), "first_fit_s": fit(),
               "warm_fit_s": fit()}
     os.makedirs(args.out, exist_ok=True)

@@ -1167,28 +1167,45 @@ def _one_long_row(n_dev, long_width):
     return _csr_table_parts(counts, 2000, 37)
 
 
-@pytest.mark.parametrize("long_width, takes_ell", [(10, True), (11, False)],
-                         ids=["just_inside", "just_outside"])
+@pytest.mark.parametrize(
+    "long_width, classes", [(10, 1), (11, 2), (17, 2), (18, 0)],
+    ids=["one_width_just_inside", "one_width_just_outside",
+         "classed_just_inside", "classed_just_outside"])
 def test_the_rule_splits_tables_by_the_slots_they_would_walk(
-        long_width, takes_ell, sparse_counters):
+        long_width, classes, sparse_counters):
+    """The rule's three outcomes, each side of both lines (a device's step
+    is two lane blocks: the long row's block at its width, the other at 4)."""
     from flink_ml_tpu.lib import common
 
     n_dev = _mesh_devices()
     assert common._ELL_MAX_SLOT_RATIO == 1.75
-    # 256 * 10 = 2560 <= 1.75 * 1536 = 2688 < 256 * 11 = 2816
+    # 256 * 10 = 2560 <= 1.75 * 1536 = 2688 < 256 * 11 = 2816, and in two
+    # classes 128 * 17 + 128 * 4 = 2688 <= 2688 < 128 * 18 + 128 * 4 = 2816
     parts = _one_long_row(n_dev, long_width)
     table = _sparse_table(2000, *parts)
     _sparse_est(2000, 256 * n_dev, epochs=1).fit(table)
     (stack,) = _packed(table)
     counted = sparse_counters()
     assert counted["train.sparse_fits"] == 1
-    assert counted["train.sparse_ell_fits"] == int(takes_ell)
-    assert counted.get("train.sparse_ell_declined", 0) == int(not takes_ell)
-    if takes_ell:
+    assert counted["train.sparse_ell_fits"] == int(classes > 0)
+    assert counted.get("train.sparse_ell_declined", 0) == int(classes == 0)
+    assert counted["train.sparse_ell_classes"] == classes
+    if classes == 1:
         assert isinstance(stack, common.EllMinibatchStack)
         assert (stack.mb, stack.width) == (256, long_width)
         assert counted["train.sparse_slots"] == 256 * long_width * n_dev
+        assert counted["train.sparse_ell_slots_reckoned"] == \
+            256 * long_width * n_dev
+    elif classes == 2:
+        assert isinstance(stack, common.ClassedEllMinibatchStack)
+        assert stack.classes == ((128, long_width), (128, 4))
+        reckoned = 128 * long_width + 128 * 4
+        assert stack.slots == (-(-reckoned // 512) | 1) * 512
+        assert counted["train.sparse_slots"] == stack.slots * n_dev
+        assert counted["train.sparse_ell_slots_reckoned"] == reckoned * n_dev
     else:
+        assert counted["train.sparse_ell_slots_reckoned"] == \
+            (128 * long_width + 128 * 4) * n_dev  # what failed the rule
         # segment-CSR, byte for byte what the pack lays without the flag
         plain = pack_sparse_minibatches(
             _csr_rows(2000, *parts[:3]), parts[3], n_dev, 256 * n_dev,

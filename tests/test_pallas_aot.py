@@ -1,5 +1,6 @@
-"""The one-pass GLM kernel, the sparse step's hot lookup (PR 30) and the
-Lloyd iteration's one-read kernel (PR 32),
+"""The one-pass GLM kernel, the sparse step's hot lookup (PR 30), the
+Lloyd iteration's one-read kernel (PR 32) and the ragged table's step in
+width classes (PR 34),
 compiled for a TPU v5e that is described, not attached: what the chip's compiler (Mosaic, XLA:TPU) accepts, which layout it
 gives the slab, and that the kernel's view of the slab copies nothing.  No
 chip time, about two seconds a compile.  Nothing runs, so nothing here is a
@@ -166,6 +167,52 @@ def test_the_split_sparse_step_compiles_with_both_hot_kernels(
     # hot_scores and hot_grad, under strict check_vma on four chips too
     assert text.count('custom_call_target="tpu_custom_call"') == 2
     assert "hot_scores" in text and "hot_grad" in text
+    if n_dev > 1:
+        assert len(re.findall(r"= .* all-reduce\(", text)) >= 1
+
+
+# -- the ragged table in width classes (PR 34) ---------------------------------
+
+#: the cut of the ragged cell's table at seed 3405000003 (my chip run, PR 34)
+URL_CLASSES = (
+    (128, 472), (128, 264), (256, 243), (256, 223), (384, 211), (512, 200),
+    (640, 189), (512, 180), (512, 174), (768, 169), (896, 163), (1280, 157),
+    (1024, 150), (1408, 145), (1408, 139), (1152, 134), (1280, 130),
+    (1408, 126), (1536, 122), (1408, 118), (1536, 114), (1536, 110),
+    (1536, 106), (1664, 102), (1792, 98), (1408, 93), (1536, 89), (1408, 84),
+    (1152, 79), (896, 74), (768, 69), (640, 62))
+
+
+@pytest.mark.parametrize("n_dev", [1, 4], ids=["url", "four-chips"])
+def test_the_classed_sparse_step_keeps_one_gather_and_one_scatter(
+        topo, quiet_cache, n_dev):
+    """At the ragged cell's shapes the chip's compiler keeps ONE gather and
+    ONE scatter over all of a step's slots, whatever the number of classes:
+    the concatenations become slice updates in place."""
+    mb, dim, steps = ROWS, 3_231_961, 74 // n_dev
+    mesh = Mesh(np.array(topo.devices[:n_dev]), ("data",))
+    slots = common.padded_nnz(sum(r * w for r, w in URL_CLASSES), 512)
+    assert slots == 4_069_888 and (slots // 512) % 2 == 1
+    step = common.make_classed_ell_grad_step("logistic", mb, URL_CLASSES,
+                                             slots, dim, True)
+    fn = common._build_fused_train_fn(
+        ("aot-sparse-ell-classed", n_dev, mesh), step, mesh, 0.1, 1e-4, 1,
+        0.0)
+    replicated = NamedSharding(mesh, P())
+    sharded = NamedSharding(mesh, P("data"))
+    blocks = n_dev * steps
+    args = ((jax.ShapeDtypeStruct((dim,), jnp.float32, sharding=replicated),
+             jax.ShapeDtypeStruct((), jnp.float32, sharding=replicated)),
+            (jax.ShapeDtypeStruct((blocks, slots + 2 * mb), jnp.int32,
+                                  sharding=sharded),
+             jax.ShapeDtypeStruct((blocks, slots + 2 * mb), jnp.float32,
+                                  sharding=sharded)))
+    text = fn.lower(*args).compile().as_text()
+    assert len(re.findall(rf"f32\[{slots}\]\S* gather\(", text)) == 1
+    assert len(re.findall(r" gather\(", text)) == 3  # and two of mb rows
+    assert len(re.findall(r" scatter\(", text)) == 1
+    assert "fmt.train.sparse.take_weights" in text
+    assert "fmt.train.sparse.scatter" in text
     if n_dev > 1:
         assert len(re.findall(r"= .* all-reduce\(", text)) >= 1
 

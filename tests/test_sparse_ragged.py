@@ -1,18 +1,31 @@
-"""The plain sparse route on RAGGED tables (PR 33): rows whose stored entry
-counts differ, made by the benchmark's generator (``chipbench/data_ragged.py``)
-and held against its plain reference (``chipbench/references/csr_glm_sgd.py``).
+"""The plain sparse route on RAGGED tables (PR 33, PR 34): rows whose stored
+entry counts differ, made by the benchmark's generator
+(``chipbench/data_ragged.py``) and held against its plain reference
+(``chipbench/references/csr_glm_sgd.py``).
 
 * ``LogisticRegression.fit`` of a CSR column on the default route agrees with
-  the reference on both sides of the layout rule: a table whose widths fail
-  ``_ELL_MAX_SLOT_RATIO`` (segment-CSR, ``ell_declined``) and one whose
-  widths pass it (row-regular); the reference's bfloat16 control fails the
-  same tolerances;
-* one ragged table laid both ways by hand gives the same sums within float32
-  rounding;
+  the reference on each of the layout rule's three outcomes: a table whose
+  widths pass ``_ELL_MAX_SLOT_RATIO`` at one width (row-regular, one class),
+  one that fails it at one width and passes it with a step's rows ordered by
+  width and laid in a few width classes (row-regular, classed: PR 34), and
+  one that fails it either way (segment-CSR, ``ell_declined``); the
+  reference's bfloat16 control fails the same tolerances;
+* one ragged table laid all three ways by hand gives the same loss and
+  gradient within float32 rounding;
+* the classed pack holds every stored entry in exactly one slot, a step's
+  order of rows both ways beside labels and weights in the table's order,
+  one shape for every step, an odd multiple of 512 slots, and the same
+  classes for the same seed; a table of
+  one width packs as the parent packed it, byte for byte, behind the
+  parent's cache key;
 * the rule's inputs are on the stack, in the pack's gauges and in
-  ``train.sparse_ell_slots_reckoned``, by the widths;
+  ``train.sparse_ell_slots_reckoned`` / ``train.sparse_ell_classes``, by the
+  widths;
+* a fit through the classed layout on the suite's 1-D mesh of CPU devices
+  matches the fit on one device;
 * segment-CSR's four random-access operations carry their scopes in the
-  step's jaxpr, on the segment-CSR step and on the split step's cold list.
+  step's jaxpr, on the segment-CSR step and on the split step's cold list,
+  and the classed step carries the two that remain.
 """
 
 import os
@@ -37,15 +50,21 @@ from flink_ml_tpu.table.table import Table  # noqa: E402
 from flink_ml_tpu.utils.environment import MLEnvironmentFactory  # noqa: E402
 
 SCHEMA = Schema.of(("features", DataTypes.SPARSE_VECTOR), ("label", "double"))
-ROWS, DIM, BATCH, EPOCHS, LR, REG = 3000, 4000, 512, 2, 0.5, 1e-4
+#: a device's step is 512 rows on the suite's eight devices: four lane blocks
+ROWS, DIM, BATCH, EPOCHS, LR, REG = 12000, 4000, 4096, 2, 0.5, 1e-4
 #: the configuration's laws (``chipbench/configs/url_ragged_lr.json``) ...
 RAGGED = {"days": 11, "width_mean_day0": 110.1, "width_growth": 0.10,
           "width_sigma": 0.30, "width_min": 24, "width_max": 512,
           "real_features": 64, "real_share": 0.5, "zipf_exponent": 1.1,
           "vocabulary_day0": 0.2, "label_noise": 0.5, "positive_share": 0.3333}
-#: ... and the same with widths that hardly differ: the rule's other side
+#: ... the same with widths that hardly differ: one width passes the rule
 EVEN = dict(RAGGED, width_sigma=0.02, width_growth=0.0, width_max=128)
-TABLES = {"ragged": RAGGED, "even": EVEN}
+#: ... and with widths so spread that every step's widest lane block is 512
+#: wide over rows of 24 to 60: the classes fail the rule too
+SPIKY = dict(RAGGED, width_sigma=1.5, width_mean_day0=60)
+TABLES = {"ragged": RAGGED, "even": EVEN, "spiky": SPIKY}
+#: what the pack lays for each on the suite's mesh: the rule's three outcomes
+OUTCOME = {"ragged": "classed", "even": "one_width", "spiky": "declined"}
 SEGMENT_SCOPES = {"fmt.train.sparse.take_weights", "fmt.train.sparse.row_sum",
                   "fmt.train.sparse.take_error", "fmt.train.sparse.scatter"}
 #: The program and the reference add the same float32 products in another
@@ -100,13 +119,16 @@ def test_a_ragged_fit_agrees_with_the_plain_reference_on_either_layout(name):
     obs.enable()
     indptr, indices, values, y = _rows(name)
     widths = np.diff(indptr)
-    assert widths.min() < widths.max()  # ragged, both of them
+    assert widths.min() < widths.max()  # ragged, all of them
     got = _answer(_logreg().fit(_table(indptr, indices, values, y)))
     counted = obs.registry().snapshot()["counters"]
     assert counted["train.sparse_fits"] == 1
-    declined = name == "ragged"
+    declined = OUTCOME[name] == "declined"
     assert counted["train.sparse_ell_fits"] == int(not declined)
     assert counted.get("train.sparse_ell_declined", 0) == int(declined)
+    classes = counted["train.sparse_ell_classes"]
+    assert {"declined": classes == 0, "one_width": classes == 1,
+            "classed": classes > 1}[OUTCOME[name]], classes
     reference = references.load("csr_glm_sgd")
     table = reference.Table(indptr, indices, values, y, DIM, BATCH)
     gaps = reference.gaps(got, table.fit(LR, REG, EPOCHS))
@@ -118,6 +140,13 @@ def test_a_ragged_fit_agrees_with_the_plain_reference_on_either_layout(name):
     assert control["coef_gap"] > 10 * gaps["coef_gap"]
 
 
+def _pack(name, n_dev=None, **kwargs):
+    indptr, indices, values, y = _rows(name)
+    return common.pack_sparse_minibatches(
+        CsrRows(DIM, indptr, indices, values), y, n_dev or _n_dev(), BATCH,
+        dim=DIM, **kwargs)
+
+
 def _fit_stack(stack, mesh):
     start = (jnp.zeros((DIM,), jnp.float32), jnp.zeros((), jnp.float32))
     r = common.train_glm_sparse(start, stack, "logistic", mesh, LR, EPOCHS,
@@ -126,29 +155,194 @@ def _fit_stack(stack, mesh):
             np.asarray(r.losses, np.float64))
 
 
-def test_one_ragged_table_laid_both_ways_gives_the_same_sums(monkeypatch):
-    indptr, indices, values, y = _rows("ragged")
-    mesh = MLEnvironmentFactory.get_default().get_mesh()
-    column = CsrRows(DIM, indptr, indices, values)
-
-    def pack():
-        return common.pack_sparse_minibatches(
-            column, y, _n_dev(), BATCH, dim=DIM, row_regular=True)
-
-    csr = pack()
-    assert not csr.row_regular and csr.ell_declined
-    # the rule lifted, here only: the same rows side by side at the widest
+def _three_layouts(monkeypatch):
+    """The "ragged" table as segment-CSR, in width classes (the pack's own
+    choice) and, the rule lifted here only, side by side at the widest."""
+    csr, classed = _pack("ragged"), _pack("ragged", row_regular=True)
     monkeypatch.setattr(common, "_ELL_MAX_SLOT_RATIO", 1e9)
-    ell = pack()
-    assert ell.row_regular and ell.hot_ids is None
-    assert ell.width == csr.widest_row == int(np.diff(indptr).max())
-    assert ell.n_entries == csr.n_entries == int(indptr[-1])
-    (w_a, b_a, l_a), (w_b, b_b, l_b) = _fit_stack(csr, mesh), \
-        _fit_stack(ell, mesh)
-    # float32 rounding of sums taken in another order, nothing more
-    assert np.linalg.norm(w_a - w_b) / np.linalg.norm(w_a) < 1e-6
-    assert abs(b_a - b_b) < 1e-6
-    assert np.allclose(l_a, l_b, rtol=1e-6)
+    one_width = _pack("ragged", row_regular=True)
+    assert type(csr) is common.SparseMinibatchStack
+    assert type(classed) is common.ClassedEllMinibatchStack
+    assert type(one_width) is common.EllMinibatchStack
+    assert csr.n_entries == classed.n_entries == one_width.n_entries
+    return csr, classed, one_width
+
+
+def test_one_ragged_table_laid_three_ways_gives_the_same_sums(monkeypatch):
+    mesh = MLEnvironmentFactory.get_default().get_mesh()
+    csr, classed, one_width = _three_layouts(monkeypatch)
+    assert classed.row_regular and classed.hot_ids is None
+    assert classed.ell_classes > 1 == one_width.ell_classes > csr.ell_classes
+    assert one_width.width == int(np.diff(_rows("ragged")[0]).max())
+    w_a, b_a, l_a = _fit_stack(csr, mesh)
+    for stack in (classed, one_width):
+        w_b, b_b, l_b = _fit_stack(stack, mesh)
+        # float32 rounding of sums taken in another order, nothing more
+        assert np.linalg.norm(w_a - w_b) / np.linalg.norm(w_a) < 1e-6
+        assert abs(b_a - b_b) < 1e-6
+        assert np.allclose(l_a, l_b, rtol=1e-6)
+
+
+@pytest.mark.parametrize("kind", ["logistic", "squared"])
+def test_a_classed_step_gives_the_segment_csr_steps_loss_and_gradient(
+        kind, monkeypatch):
+    """One step of each layout from the same weights: the squared loss is
+    half the weighted sum of squared (score - label), so it holds the scores
+    themselves to rounding, the gradient the error's way back."""
+    csr, classed, one_width = _three_layouts(monkeypatch)
+    rng = np.random.default_rng(34)
+    params = (jnp.asarray(rng.normal(0, 0.3, DIM), jnp.float32),
+              jnp.asarray(0.25, jnp.float32))
+    block = len(csr.ints) - 1  # the last device's last step: pad rows too
+    outs = []
+    for stack in (csr, classed, one_width):
+        _key, step = stack.grad_step(kind)
+        (g_w, g_b), loss_sum, w_sum = jax.jit(step)(
+            params, tuple(jnp.asarray(leaf[block]) for leaf in stack.batch))
+        outs.append((np.asarray(g_w, np.float64), float(g_b),
+                     float(loss_sum), float(w_sum)))
+    (g_a, b_a, l_a, n_a) = outs[0]
+    assert 0 < n_a < csr.mb  # a short step
+    for g_b_, b_b, l_b, n_b in outs[1:]:
+        assert n_b == n_a
+        assert np.linalg.norm(g_a - g_b_) / np.linalg.norm(g_a) < 1e-6
+        assert b_b == pytest.approx(b_a, rel=1e-5, abs=1e-5)
+        assert l_b == pytest.approx(l_a, rel=1e-6)
+
+
+def _device_steps(indptr, n_dev, mb, steps):
+    """Row bounds of every device step, in the leaves' order (device-major:
+    block ``k * steps + s`` is device ``k``'s step ``s``)."""
+    n = len(indptr) - 1
+    for k in range(n_dev):
+        for s in range(steps):
+            lo = min(s * n_dev * mb + k * mb, n)
+            yield k * steps + s, lo, min(lo + mb, n)
+
+
+def test_the_classed_pack_holds_every_entry_once_in_its_rows_own_order():
+    indptr, indices, values, y = _rows("ragged")
+    widths = np.diff(indptr)
+    n_dev = _n_dev()
+    stack = _pack("ragged", row_regular=True)
+    mb, slots = stack.mb, stack.slots
+    assert type(stack) is common.ClassedEllMinibatchStack
+    # whole lane blocks, widest first, every place in one class
+    rows_c = np.array([r for r, _w in stack.classes])
+    width_c = np.array([w for _r, w in stack.classes])
+    assert rows_c.sum() == mb and not (rows_c % 128).any()
+    assert (np.diff(width_c) < 0).all() and width_c.min() >= 1
+    # one shape for every step; an odd multiple of 512 slots, a tail of pads
+    assert stack.ints.shape == (n_dev * stack.steps, slots + 2 * mb)
+    assert stack.floats.shape == (n_dev * stack.steps, slots + 2 * mb)
+    assert stack.ints.dtype == np.int32 and stack.floats.dtype == np.float32
+    classed = int((rows_c * width_c).sum())
+    assert slots % 512 == 0 and (slots // 512) % 2 == 1
+    assert 0 <= slots - classed < 1024 and stack.ell_step_slots == classed
+    assert stack.step_slots == slots
+    assert not stack.ints[:, classed:slots].any()
+    assert not stack.floats[:, classed:slots].any()
+    seen = 0
+    for block, lo, hi in _device_steps(indptr, n_dev, mb, stack.steps):
+        m = hi - lo
+        # the step's order both ways: rows by descending width, stable,
+        # the pad rows of a short step after them where they stood
+        order = stack.ints[block, slots : slots + mb]
+        place_of = stack.ints[block, slots + mb :]
+        assert np.array_equal(order[:m],
+                              np.argsort(-widths[lo:hi], kind="stable"))
+        assert np.array_equal(order[m:], np.arange(m, mb))
+        assert np.array_equal(place_of[order], np.arange(mb))
+        # labels and row weights in the table's order, as segment-CSR's
+        labels = stack.floats[block, slots : slots + mb]
+        weights = stack.floats[block, slots + mb :]
+        assert np.array_equal(labels[:m], y[lo:hi].astype(np.float32))
+        assert not labels[m:].any()
+        assert np.array_equal(weights, (np.arange(mb) < m).astype(np.float32))
+        at = place = 0
+        for rows, width in stack.classes:
+            ids = stack.ints[block, at : at + rows * width].reshape(
+                width, rows)
+            vals = stack.floats[block, at : at + rows * width].reshape(
+                width, rows)
+            for p in range(place, min(place + rows, m)):
+                row = lo + order[p]
+                e0, e1 = indptr[row], indptr[row + 1]
+                assert e1 - e0 <= width
+                # the row's entries in their stored order, then pads
+                assert np.array_equal(ids[: e1 - e0, p - place],
+                                      indices[e0:e1])
+                assert np.array_equal(vals[: e1 - e0, p - place],
+                                      values[e0:e1].astype(np.float32))
+                assert not ids[e1 - e0 :, p - place].any()
+                assert not vals[e1 - e0 :, p - place].any()
+                seen += e1 - e0
+            assert not vals[:, max(0, m - place) :].any()  # pad rows
+            at += rows * width
+            place += rows
+    assert seen == indptr[-1] == stack.n_entries
+    assert np.count_nonzero(stack.floats[:, :slots]) == indptr[-1]
+
+
+def test_the_same_seed_gives_the_same_classes_and_leaves():
+    first, again = (_pack("ragged", row_regular=True) for _ in range(2))
+    assert first.classes == again.classes and first.slots == again.slots
+    assert first.ints.tobytes() == again.ints.tobytes()
+    assert first.floats.tobytes() == again.floats.tobytes()
+    assert first.grad_step("logistic")[0] == again.grad_step("logistic")[0]
+    # the classes are the widths', not the values': a table that keeps this
+    # one's widths and has every other value of its own cuts the same
+    indptr, indices, values, y = _rows("ragged")
+    other = common.pack_sparse_minibatches(
+        CsrRows(DIM, indptr, (indices[::-1] % DIM).copy(), values[::-1].copy()),
+        1.0 - y, _n_dev(), BATCH, dim=DIM, row_regular=True)
+    assert other.classes == first.classes
+    assert other.ints.tobytes() != first.ints.tobytes()
+    # and another seed's widths cut their own
+    indptr2, indices2, values2, y2 = _rows("ragged", seed=2**31 + 34)
+    another = common.pack_sparse_minibatches(
+        CsrRows(DIM, indptr2, indices2, values2), y2, _n_dev(), BATCH,
+        dim=DIM, row_regular=True)
+    assert type(another) is common.ClassedEllMinibatchStack
+    assert another.classes != first.classes
+
+
+def _parents_bounds(indptr, n_dev, mb, steps):
+    return [(lo, hi, int(indptr[lo]), int(indptr[hi])) if hi > lo
+            else (lo, lo, 0, 0)
+            for _b, lo, hi in sorted(_device_steps(indptr, n_dev, mb, steps))]
+
+
+@pytest.mark.parametrize("name", ["even", "criteo_like"])
+def test_a_table_of_one_width_packs_as_the_parent_packed_it(name):
+    """Byte for byte ``_pack_ell`` called as the parent's pack calls it, and
+    behind the parent's cache key: the program is the parent's."""
+    if name == "even":
+        indptr, indices, values, y = _rows("even")
+    else:  # every row 39 wide, as Criteo's
+        rng = np.random.default_rng(39)
+        indptr = 39 * np.arange(ROWS + 1, dtype=np.int64)
+        indices = rng.integers(0, DIM, 39 * ROWS).astype(np.int32)
+        values = np.full(39 * ROWS, 39 ** -0.5, np.float32)
+        y = rng.integers(0, 2, ROWS).astype(np.float64)
+    column = CsrRows(DIM, indptr, indices, values)
+    n_dev = _n_dev()
+    stack = common.pack_sparse_minibatches(column, y, n_dev, BATCH, dim=DIM,
+                                           row_regular=True)
+    assert type(stack) is common.EllMinibatchStack and stack.hot_ids is None
+    counts = np.diff(indptr)
+    width, mb, steps = int(counts.max()), BATCH // n_dev, -(-ROWS // BATCH)
+    want = common._pack_ell(
+        column, y, _parents_bounds(indptr, n_dev, mb, steps), counts, width,
+        mb, steps, DIM, n_dev, 512, None)
+    assert (stack.steps, stack.mb, stack.width, stack.dim) == \
+        (want.steps, want.mb, want.width, want.dim) == (steps, mb, width, DIM)
+    assert stack.ints.tobytes() == want.ints.tobytes()
+    assert stack.floats.tobytes() == want.floats.tobytes()
+    assert stack.ints.shape == (n_dev * steps, width, mb)
+    key, _step = stack.grad_step("logistic")
+    assert key == ("sparse-ell", mb, width, DIM)
+    assert stack.ell_classes == 1 and stack.ell_step_slots == mb * width
 
 
 @pytest.mark.parametrize("name", sorted(TABLES))
@@ -158,41 +352,78 @@ def test_the_rules_inputs_are_kept_said_and_counted(name):
     widths = np.diff(indptr)
     n_dev, steps = _n_dev(), -(-ROWS // BATCH)
     mb = BATCH // n_dev
-    stack = common.pack_sparse_minibatches(
-        CsrRows(DIM, indptr, indices, values), y, n_dev, BATCH, dim=DIM,
-        row_regular=True)
+    stack = _pack(name, row_regular=True)
     # the fullest device step, rounded up to an odd multiple of the pack's 512
-    starts = np.minimum(mb * np.arange(n_dev * steps + 1), ROWS)
-    fullest = int((indptr[starts[1:]] - indptr[starts[:-1]]).max())
+    fullest = max(int(indptr[hi] - indptr[lo])
+                  for _b, lo, hi in _device_steps(indptr, n_dev, mb, steps))
     nnz_pad = (-(-fullest // 512) | 1) * 512
-    passes = mb * int(widths.max()) <= common._ELL_MAX_SLOT_RATIO * nnz_pad
-    assert passes == (name == "even") == stack.row_regular
-    assert stack.ell_step_slots == mb * int(widths.max())
-    if not passes:
-        assert stack.widest_row == int(widths.max())
-        assert stack.nnz_pad == nnz_pad == stack.step_slots
+    one_width = mb * int(widths.max())
+    outcome = OUTCOME[name]
+    assert (one_width <= common._ELL_MAX_SLOT_RATIO * nnz_pad) == \
+        (outcome == "one_width")
     gauges = obs.registry().snapshot()["gauges"]
     assert gauges["pack_sparse.widest_row"] == widths.max()
     assert gauges["pack_sparse.mean_row"] == pytest.approx(widths.mean())
-    assert gauges["pack_sparse.ell_step_slots"] == mb * widths.max()
     assert gauges["pack_sparse.csr_step_slots"] == nnz_pad
+    # what the rule reckoned: one width where that passes, else the classes'
+    reckoned = gauges["pack_sparse.ell_step_slots"]
+    assert reckoned == stack.ell_step_slots
+    assert (reckoned <= common._ELL_MAX_SLOT_RATIO * nnz_pad) == \
+        (outcome != "declined") == stack.row_regular
+    if outcome == "one_width":
+        assert reckoned == one_width == stack.step_slots
+        assert gauges["pack_sparse.ell_classes"] == 1 == stack.ell_classes
+    else:
+        assert gauges["pack_sparse.ell_classes"] > 1
+        assert nnz_pad < reckoned < one_width
+    if outcome == "classed":
+        assert gauges["pack_sparse.ell_classes"] == stack.ell_classes
+        assert reckoned == sum(r * w for r, w in stack.classes)
+        assert 0 <= stack.step_slots - reckoned < 1024
+    if outcome == "declined":
+        assert stack.ell_declined and stack.ell_classes == 0
+        assert stack.widest_row == int(widths.max())
+        assert stack.nnz_pad == nnz_pad == stack.step_slots
     # a pack that was not asked reckons nothing
-    plain = common.pack_sparse_minibatches(
-        CsrRows(DIM, indptr, indices, values), y, n_dev, BATCH, dim=DIM)
+    plain = _pack(name)
     assert plain.widest_row == 0 == plain.ell_step_slots
-    assert not plain.ell_declined
+    assert not plain.ell_declined and plain.ell_classes == 0
 
     mesh = MLEnvironmentFactory.get_default().get_mesh()
     for s in (stack, plain):
         _fit_stack(s, mesh)
     counted = obs.registry().snapshot()["counters"]
     blocks = n_dev * steps * EPOCHS
+    assert counted["train.sparse_fits"] == 2
+    assert counted["train.sparse_ell_fits"] == int(outcome != "declined")
+    assert counted.get("train.sparse_ell_declined", 0) == \
+        int(outcome == "declined")
+    assert counted["train.sparse_ell_classes"] == stack.ell_classes
     assert counted["train.sparse_ell_slots_reckoned"] == \
-        mb * int(widths.max()) * blocks  # the asked pack's fit alone
+        reckoned * blocks  # the asked pack's fit alone
     assert counted["train.sparse_slots"] == \
         (stack.step_slots + plain.step_slots) * blocks
-    ratio = mb * int(widths.max()) / stack.step_slots
-    assert (ratio > common._ELL_MAX_SLOT_RATIO) == (name == "ragged")
+
+
+def test_a_classed_fit_on_the_mesh_matches_the_fit_on_one_device():
+    from flink_ml_tpu.parallel.mesh import create_mesh
+
+    mesh = MLEnvironmentFactory.get_default().get_mesh()
+    n_dev = len(mesh.devices.flat)
+    if n_dev < 2:
+        pytest.skip("needs the suite's CPU devices")
+    many = _pack("ragged", row_regular=True)
+    one = _pack("ragged", n_dev=1, row_regular=True)
+    for stack, devices in ((many, n_dev), (one, 1)):
+        assert type(stack) is common.ClassedEllMinibatchStack
+        assert stack.mb == BATCH // devices
+        assert len(stack.ints) == devices * stack.steps
+    assert one.ell_classes > many.ell_classes  # 32 lane blocks to cut, not 4
+    w_a, b_a, l_a = _fit_stack(many, mesh)
+    w_b, b_b, l_b = _fit_stack(one, create_mesh({"data": 1}, jax.devices()[:1]))
+    assert np.linalg.norm(w_a - w_b) / np.linalg.norm(w_a) < 1e-6
+    assert abs(b_a - b_b) < 1e-6
+    assert np.allclose(l_a, l_b, rtol=1e-6)
 
 
 @pytest.mark.parametrize("nnz_max,floor,expected", [
@@ -210,6 +441,30 @@ def test_a_steps_padded_width_is_an_odd_multiple_where_nothing_fixes_it(
 
 def _lowered(step, params, xs):
     return jax.jit(step).lower(params, *xs).as_text(debug_info=True)
+
+
+def test_the_classed_step_carries_the_two_scopes_that_remain():
+    classes, mb = ((128, 7), (256, 3)), 384
+    slots = common.padded_nnz(128 * 7 + 256 * 3, 512)
+    fn = common.make_classed_ell_grad_step("logistic", mb, classes, slots,
+                                           DIM)
+    params = (jnp.zeros((DIM,), jnp.float32), jnp.zeros((), jnp.float32))
+    text = _lowered(fn, params, ((jnp.zeros((slots + 2 * mb,), jnp.int32),
+                                  jnp.zeros((slots + 2 * mb,), jnp.float32)),))
+    scopes = set(re.findall(r"fmt\.[a-z_.]+", text))
+    assert scopes == {"fmt.train.sparse.forward", "fmt.train.sparse.backward",
+                      "fmt.train.sparse.take_weights",
+                      "fmt.train.sparse.scatter"}
+    assert "/fmt.train.sparse.forward/fmt.train.sparse.take_weights/mul" \
+        in text
+    assert "/fmt.train.sparse.backward/fmt.train.sparse.scatter/scatter-add" \
+        in text
+    # one take and one scatter over all the slots, no row ids; the two
+    # other takes put mb scores into the table's order and mb errors back
+    gathers = re.findall(r'"stablehlo\.gather"\(.*-> tensor<(\d+)xf32>', text)
+    assert gathers.count(str(slots)) == 1 and set(gathers) == {
+        str(slots), str(mb)}
+    assert text.count('"stablehlo.scatter"(') == 1
 
 
 @pytest.mark.parametrize("step", ["segment_csr", "split_cold_list"])
