@@ -544,7 +544,14 @@ def phase_nothing_hid(ctx):
         "train.kmeans_onepass_fits", "train.kmeans_onepass_declined",
         "slab_pool.hits", "pipeline.fused_dispatches",
         "fused.shard_map_dispatches", "fused.pallas_dispatches",
-        "warmstart.hits", "warmstart.saves", "serving.requests")}
+        "warmstart.hits", "warmstart.saves", "serving.requests",
+        "compile.cache_hits", "compile.cache_misses")}
+    # beside them what the whole run spent building programs, in seconds:
+    # traced, lowered, in the backend (compiles AND cache reads), reading
+    ctx["counters"].update({
+        k: round(snap["timings"].get(k, {}).get("total_s", 0.0), 4)
+        for k in ("compile.trace", "compile.lower", "compile.backend",
+                  "compile.cache_read")})
     return {"compile_s": 0.0, "steady_s": 0.0}
 
 

@@ -10,9 +10,14 @@ measurement layer:
     registry, writes it as ``fmt.train.dispatch`` on the profiler's clock
     (``jax.profiler.TraceAnnotation``, imported there and nowhere else)
     and into the thread's request trace; ``phase("pack_csr")`` is a span
-    whose name nests.  **Off by default** and near-zero-cost when off:
-    every hook degrades to one module-level boolean check.  Enable with
-    ``obs.enable()`` or ``FMT_OBS=1``.
+    whose name nests.  While it is on it also listens to
+    ``jax.monitoring`` (imported there and nowhere else): every program
+    traced, lowered, compiled or read from the persistent cache is timed
+    under ``compile.*`` and ``compile.under/<the open span>`` and leaves
+    a ``compile`` event in the flight recorder.  **Off by default** and
+    near-zero-cost when off: every hook degrades to one module-level
+    boolean check and no listener stands.  Enable with ``obs.enable()``
+    or ``FMT_OBS=1``.
   * :mod:`flink_ml_tpu.obs.report` — structured JSONL :class:`RunReport`
     records (git SHA, device topology, registry snapshot, StepMetrics
     summary) written by every ``fit`` / ``transform`` / serving run

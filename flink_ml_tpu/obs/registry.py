@@ -20,6 +20,12 @@ phases land under their own root rather than a racing parent's.
 timing under its name, a ``fmt.<name>`` scope on the profiler's clock and
 a child span of the thread's request trace, from one pair of clock
 readings.  ``phase`` is a span whose name nests.
+
+While recording is on the registry also listens to ``jax.monitoring``
+(:func:`_listen`): every program JAX traces, lowers and compiles (or reads
+from the persistent cache) is timed under ``compile.*`` and under the span
+that was open on the compiling thread, and leaves one ``compile`` event in
+the flight recorder.  A warm call compiles nothing and calls no listener.
 """
 
 from __future__ import annotations
@@ -41,9 +47,11 @@ def enabled() -> bool:
 
 
 def enable(on: bool = True) -> None:
-    """Turn telemetry recording on (or off with ``enable(False)``)."""
+    """Turn telemetry recording on (or off with ``enable(False)``): the
+    switch, and with it the compile listeners (:func:`_listen`)."""
     global _ENABLED
     _ENABLED = bool(on)
+    _listen(_ENABLED)
 
 
 def disable() -> None:
@@ -303,7 +311,7 @@ class _Span:
     duration once the block has ended."""
 
     __slots__ = ("name", "seconds", "_nest", "_observe", "_t0",
-                 "_annotation", "_close_child")
+                 "_annotation", "_close_child", "_parent")
 
     def __init__(self, name: str, nest: bool = False):
         self.name = name
@@ -323,6 +331,9 @@ class _Span:
             self.name = "phase." + "/".join(stack)
         elif _TRACE_HOOK is not None:
             self._close_child = _TRACE_HOOK(self.name)
+        # the thread's innermost open span: what a compile is booked under
+        self._parent = getattr(_PHASE_LOCAL, "span", None)
+        _PHASE_LOCAL.span = self
         self._annotation = profiler_annotation("fmt." + self.name)
         self._annotation.__enter__()
         self._t0 = time.perf_counter()
@@ -331,6 +342,7 @@ class _Span:
     def __exit__(self, exc_type, exc, tb):
         self.seconds = dt = time.perf_counter() - self._t0
         self._annotation.__exit__(exc_type, exc, tb)
+        _PHASE_LOCAL.span = self._parent
         if self._nest:
             _PHASE_LOCAL.stack.pop()
         if self._observe:
@@ -387,6 +399,143 @@ def phased(name: str):
     return deco
 
 
+# -- compiles on the program's clock -------------------------------------------
+#
+# JAX 0.9.0 reports every stage of a compile through ``jax.monitoring``, on
+# the compiling thread, with the program's name: ``jit(bundled)`` for
+# lowering and backend, the bare ``bundled`` for tracing.  A warm call
+# reports nothing.  The registry names (PERF.md section 3):
+#
+#   compile.trace, compile.lower, compile.backend
+#       timings, one observation a lowered / compiled program.
+#       ``compile.backend`` is JAX's event around ``compile_or_get_cached``
+#       and so HOLDS the cache reads: the seconds truly compiled are
+#       ``compile.backend`` less ``compile.cache_read``.
+#   compile.cache_read
+#       timing, a persistent-cache hit's read
+#   compile.cache_hits, compile.cache_misses
+#       counters (a miss is counted where the compiled program is written
+#       to the cache)
+#   compile.under/<span>
+#       the three stages' seconds again, by the innermost ``obs.span`` open
+#       on the thread (``none`` outside any): the totals sum to trace +
+#       lower + backend
+#
+# and each backend stage leaves one ``compile`` event in the flight recorder.
+
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_READ_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+#: JAX's cache event -> (the flight event's ``cache``, the counter)
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": ("hit", "compile.cache_hits"),
+    "/jax/compilation_cache/cache_misses": ("miss", "compile.cache_misses"),
+}
+
+_LISTENING = False
+
+
+class _CompileLocal(threading.local):
+    """What the compiling thread has reported and no later stage has taken."""
+
+    def __init__(self):
+        #: disjoint (start, end) trace intervals since the last lowering
+        self.traces = []
+        #: program -> [trace_s, lower_s] since its last backend stage
+        self.pending = {}
+        #: the cache event inside the running backend stage, and its read
+        self.cache = "off"
+        self.cache_read_s = 0.0
+
+
+_COMPILE_LOCAL = _CompileLocal()
+
+
+def _open_span_name() -> Optional[str]:
+    open_span = getattr(_PHASE_LOCAL, "span", None)
+    return open_span.name if open_span is not None else None
+
+
+def _observe_stage(name: str, seconds: float) -> None:
+    """One stage's seconds, under its own name and under the open span's."""
+    _REGISTRY.observe(name, seconds)
+    _REGISTRY.observe("compile.under/" + (_open_span_name() or "none"),
+                      seconds)
+
+
+def _on_time_span(event, start, end, **_kw) -> None:
+    # a jitted function traced inside another reports its own interval
+    # before the outer one's, which holds it: keep the union, not the sum
+    if event != _TRACE_EVENT:
+        return
+    traces = _COMPILE_LOCAL.traces
+    while traces and traces[-1][1] >= start:
+        inner = traces.pop()
+        start, end = min(start, inner[0]), max(end, inner[1])
+    traces.append((start, end))
+
+
+def _on_duration(event, seconds, fun_name="", **_kw) -> None:
+    local = _COMPILE_LOCAL
+    if event == _LOWER_EVENT:
+        trace_s = sum(end - start for start, end in local.traces)
+        local.traces.clear()
+        pending = local.pending.setdefault(fun_name, [0.0, 0.0])
+        pending[0] += trace_s
+        pending[1] += seconds
+        _observe_stage("compile.trace", trace_s)
+        _observe_stage("compile.lower", seconds)
+    elif event == _BACKEND_EVENT:
+        trace_s, lower_s = local.pending.pop(fun_name, (0.0, 0.0))
+        cache, cache_read_s = local.cache, local.cache_read_s
+        local.cache, local.cache_read_s = "off", 0.0
+        _observe_stage("compile.backend", seconds)
+        from flink_ml_tpu.obs import flight
+
+        flight.record("compile", program=fun_name, span=_open_span_name(),
+                      trace_s=trace_s, lower_s=lower_s, backend_s=seconds,
+                      cache=cache, cache_read_s=cache_read_s)
+    elif event == _CACHE_READ_EVENT:
+        local.cache_read_s += seconds
+        _REGISTRY.observe("compile.cache_read", seconds)
+
+
+def _on_event(event, **_kw) -> None:
+    if event in _CACHE_EVENTS:
+        _COMPILE_LOCAL.cache, counter = _CACHE_EVENTS[event]
+        _REGISTRY.add(counter)
+
+
+def _listen(on: bool) -> None:
+    """Put the three ``jax.monitoring`` listeners up, or take them down
+    (twice is harmless).  ``jax.monitoring`` is imported here and nowhere
+    else in the package; an image without JAX compiles nothing to hear."""
+    global _LISTENING
+    if on == _LISTENING:
+        return
+    try:
+        from jax import monitoring
+    except ImportError:
+        return
+    _LISTENING = on
+    if on:
+        monitoring.register_event_listener(_on_event)
+        monitoring.register_event_duration_secs_listener(_on_duration)
+        monitoring.register_event_time_span_listener(_on_time_span)
+    else:
+        for unregister, callback in (
+                (monitoring.unregister_event_listener, _on_event),
+                (monitoring.unregister_event_duration_listener, _on_duration),
+                (monitoring.unregister_event_time_span_listener,
+                 _on_time_span)):
+            try:
+                unregister(callback)
+            except (AssertionError, ValueError):
+                # someone's ``clear_event_listeners`` took it down already
+                pass
+
+
 def record_hbm_gauges(prefix: str = "hbm") -> None:
     """Record device-memory watermark gauges from ``device.memory_stats()``.
 
@@ -417,3 +566,7 @@ def record_hbm_gauges(prefix: str = "hbm") -> None:
             gauge_set(f"{prefix}.bytes_limit", max(limits))
     except Exception:  # noqa: BLE001 - telemetry must never break training
         pass
+
+
+if _ENABLED:  # FMT_OBS=1: recording is on from import, the listeners with it
+    _listen(True)

@@ -14,9 +14,11 @@ re-execution warning — the package __init__ already imports this module).
 
 prints what the reports say went wrong or deserves a look: the
 FAULT-ASSISTED / SERVE-DEGRADED / PALLAS-DEGRADED / WARMSTART-DEGRADED
-flags, DRIFT rows, fmtlint's ANALYSIS line and the TIMING tail
-quantiles.  It is no benchmark and compares no number: speed is measured
-on the chip (``BENCHMARK.json``, ``PERF.md``).  ``--check`` exits
+flags, DRIFT rows, the COMPILED lines (every fit that compiled a program
+or read one from the persistent cache inside itself: seconds by stage,
+hits and misses, the span it happened under), fmtlint's ANALYSIS line and
+the TIMING tail quantiles.  It is no benchmark and compares no number:
+speed is measured on the chip (``BENCHMARK.json``, ``PERF.md``).  ``--check`` exits
 non-zero when there are no reports to read.  ``--json`` swaps the human
 text for one machine-readable object; ``python -m flink_ml_tpu.obs
 trace`` renders a request waterfall from the span sink
@@ -196,15 +198,11 @@ def _build_report(kind: str, name: str, shape=None, step_metrics=None,
     if step_metrics is not None:
         try:
             summary = step_metrics.summary()
-            # the compile-vs-steady split: fused drivers stamp per-step
-            # dispatch (trace+compile+enqueue) and sync (device execution)
-            # seconds into their StepMetrics records — surface the last
-            # step's split at the top level so reports are greppable
+            # the last step's device-call window at the top level; what of
+            # a fit was compiling is the metrics' ``compile.*`` timings
             last = step_metrics.steps[-1] if step_metrics.steps else {}
-            for k in ("dispatch_seconds", "sync_seconds", "place_seconds",
-                      "call_latency_ms"):
-                if k in last:
-                    summary[k] = last[k]
+            if "call_latency_ms" in last:
+                summary["call_latency_ms"] = last["call_latency_ms"]
         except Exception:  # noqa: BLE001 - never fail a fit over telemetry
             summary = None
     # fit reports scope metrics to the fit itself; the other kinds keep
@@ -611,11 +609,47 @@ def fault_assisted_runs(reports: List[dict]) -> List[dict]:
     return flagged
 
 
+#: the stages a compile is timed by (``obs/registry.py``), in order
+_COMPILE_STAGES = ("trace", "lower", "backend", "cache_read")
+
+
+def compiled_fits(reports: List[dict]) -> List[dict]:
+    """Fit reports whose own delta holds ``compile.backend``: the fit
+    compiled a program, or read one from the persistent cache, inside
+    itself.  EVERY such fit, in file order with its place among the fit
+    reports (``fit_index``), not the latest per name: "which fit
+    recompiled" is a question about one fit of hundreds alike.  Seconds by
+    stage (``backend_s`` holds ``cache_read_s``), the cache's hits and
+    misses, and the stages' seconds by the span they ran under."""
+    out = []
+    fits = (r for r in reports if r.get("kind") == "fit")
+    for index, r in enumerate(fits):
+        metrics = r.get("metrics") or {}
+        timings = metrics.get("timings") or {}
+        if "compile.backend" not in timings:
+            continue
+        counters = metrics.get("counters") or {}
+        row = {"name": r.get("name"), "ts": r.get("ts"),
+               "git_sha": r.get("git_sha"), "fit_index": index,
+               "programs": timings["compile.backend"].get("count", 0),
+               "cache_hits": counters.get("compile.cache_hits", 0),
+               "cache_misses": counters.get("compile.cache_misses", 0),
+               "under": {k[len("compile.under/"):]: t.get("total_s", 0.0)
+                         for k, t in sorted(timings.items())
+                         if k.startswith("compile.under/")}}
+        for stage in _COMPILE_STAGES:
+            row[stage + "_s"] = (timings.get("compile." + stage)
+                                 or {}).get("total_s", 0.0)
+        out.append(row)
+    return out
+
+
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(
         prog="python -m flink_ml_tpu.obs",
         description="Summarise the RunReports: degraded and "
-                    "fault-assisted runs, drift, timing tails.",
+                    "fault-assisted runs, drift, the fits that compiled, "
+                    "timing tails.",
     )
     parser.add_argument("--reports", default=None,
                         help="reports directory (default: repo reports/)")
@@ -654,6 +688,7 @@ def main(argv=None) -> int:
     pallas_degraded = pallas_degraded_runs(reports)
     warmstart_degraded = warmstart_degraded_runs(reports)
     drift_rows = drift_runs(reports)
+    compiled = compiled_fits(reports)
     analysis = analysis_summary(args.reports)
     timing_summary = timing_quantile_summary(reports)
 
@@ -667,6 +702,7 @@ def main(argv=None) -> int:
             "pallas_degraded": pallas_degraded,
             "warmstart_degraded": warmstart_degraded,
             "drift": drift_rows,
+            "compiled_fits": compiled,
             "analysis": analysis,
             "timings": timing_summary,
         }, sort_keys=True, indent=1))
@@ -739,6 +775,16 @@ def main(argv=None) -> int:
                   f"{dr['worst_column']} psi={dr['psi']:g} "
                   f"ks={dr['ks']:g} (threshold {dr['threshold']:g}) "
                   f"{verdict}")
+    # the fits that compiled inside themselves, by stage, cache and span:
+    # a fit that recompiled in a warm loop names itself here
+    for cf in compiled:
+        stages = " ".join(f"{stage}={cf[stage + '_s']:.3f}s"
+                          for stage in _COMPILE_STAGES)
+        under = ", ".join(f"{k}={v:.3f}s" for k, v in cf["under"].items())
+        print(f"COMPILED fit {cf['name']} #{cf['fit_index']} "
+              f"[{cf.get('git_sha', '')}]: {cf['programs']:g} program(s) "
+              f"{stages} hits={cf['cache_hits']:g} "
+              f"misses={cf['cache_misses']:g}; under {under}")
     # tail-quantile lines for the latest fit/transform per name
     for line in _timing_lines(timing_summary):
         print(line)

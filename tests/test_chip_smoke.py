@@ -65,6 +65,12 @@ def test_phases_pass_at_a_tiny_size(tmp_path, monkeypatch):
     assert counters["slab_pool.hits"] >= 1
     assert counters["warmstart.hits"] >= 1
     assert counters["fused.shard_map_dispatches"] >= 1
+    # the six compile totals: seconds by stage, the cache's hits and misses
+    assert counters["compile.backend"] > 0 and counters["compile.lower"] > 0
+    assert counters["compile.trace"] > 0
+    assert counters["compile.cache_read"] == 0  # the suite runs cache off
+    assert counters["compile.cache_hits"] == 0 \
+        and counters["compile.cache_misses"] == 0
     json.dumps(summary)  # the "chip_smoke: summary" line must serialize
     # the LAST stdout line: exactly the two keys the driver accepts
     last = json.loads(json.dumps(chip_smoke.verdict(summary)))

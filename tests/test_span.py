@@ -266,8 +266,16 @@ def test_a_warm_fit_observes_every_span_once_and_the_miss_spans_on_the_miss():
     assert counters["slab_pool.hits"] == counters["slab_pool.misses"] == 1
     assert counters["slab_pool.bytes_placed"] > 0
     assert counters["train.fused_runs"] == 2
+    # the dispatch/sync split of a step is the registry's (the step's own
+    # copies went with PR 35): one more observation each, inside the step
     step = _logreg().fit(table).train_metrics_.steps[-1]
-    assert {"dispatch_seconds", "sync_seconds", "call_latency_ms"} <= set(step)
+    third = _timings()
+    split = [third[n]["total_s"] - warm[n]["total_s"]
+             for n in ("train.dispatch", "train.sync")]
+    assert all(third[n]["count"] == 3
+               for n in ("train.dispatch", "train.sync"))
+    assert 0 < sum(split) <= step["seconds"]
+    assert not {"dispatch_seconds", "sync_seconds", "place_seconds"} & set(step)
     assert step["call_latency_ms"] == pytest.approx(step["seconds"] * 1e3)
 
 
