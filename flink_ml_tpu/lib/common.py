@@ -242,20 +242,39 @@ class EllMinibatchStack:
     place in ``hot_ids``, and the step finds the weight of a code by a
     one-hot product with the hot weights laid ``(codes / 128, 128)``
     (``ops/pallas_kernels.py:hot_scores``), which costs nothing a slot that
-    depends on ``dim``.  Every other entry leaves the two leaves (code 0 at value 0.0
-    in its place, as a pad) for a compact segment-COO list of the step,
-    row-major as :class:`SparseMinibatchStack` lays it, in the table's own
-    feature ids:
+    depends on ``dim``.  Every other entry leaves the two leaves (code 0 at
+    value 0.0 in its place, as a pad) for the step's COLD LIST, row-regular
+    too, in the table's own feature ids.  A split step's rows stand in
+    descending order of their COLD width (the count of a row's entries
+    that hold no code; stable, the pad rows of a short step last), in both
+    leaves, labels and row weights with them (``order``: the row at each
+    place), and the cold entries lie plane by plane: plane ``j`` holds the
+    ``j``-th cold entry, in stored order, of every row that has one, which
+    are the first ``n_j`` places, and the planes follow one another with no
+    pad between them:
 
-      cold_ints (n_dev*steps, 2, cold_pad) int32 — [feature id, row id];
-                pads carry row id ``mb`` at value 0.0.
-      cold_vals (n_dev*steps, cold_pad) float32.
-      hot_ids   (n_dev, K) int32 — the same ids for every device (a leaf is
+      cold_idx  (n_dev*steps, cold_slots) int32 -- feature ids; past the
+                step's cold entries id 0 at value 0.0, up to ``cold_slots``,
+                the fullest step's count rounded by :func:`padded_nnz`.
+      cold_vals (n_dev*steps, cold_slots) float32.
+      cold_cuts (n_dev*steps, 2, width) int32 -- [``off_j``, ``n_j``]: where
+                plane ``j`` starts in the list and how many places it holds
+                (an empty plane starts where the entries end).  DATA, not
+                shapes: the program's constants are ``mb``, ``width``,
+                ``dim``, ``cold_slots`` and K, so one program serves every
+                table of the shape whatever cold widths its rows draw.
+      hot_ids   (n_dev, K) int32 -- the same ids for every device (a leaf is
                 sharded on its first axis); past ``dim`` features, id 0,
                 whose codes no entry holds.
 
-    Every stored entry is in exactly one of the two parts, and the weights
-    stay in the table's own id space: no permutation, float32 throughout.
+    With the rows in that order a row's cold score is a sum over planes of
+    contiguous slices and its error reaches its cold entries by contiguous
+    writes: ONE take and ONE scatter a cold slot where a segment-COO list
+    made two of each (3.46 -> 1.62 ms a step on the Criteo-shaped table: my
+    chip runs, PR 36).  The order of rows inside a step changes no sum's
+    members; the loss is summed in the step's order.  Every stored entry is
+    in exactly one of the two parts, and the weights stay in the table's
+    own id space: no permutation of features, float32 throughout.
     """
 
     ints: np.ndarray
@@ -266,9 +285,13 @@ class EllMinibatchStack:
     dim: int
     n_rows: int = 0  # true (un-padded) row count, for throughput metrics
     n_entries: int = 0  # stored entries (pads not counted), likewise
-    cold_ints: Optional[np.ndarray] = None
+    cold_idx: Optional[np.ndarray] = None
     cold_vals: Optional[np.ndarray] = None
+    cold_cuts: Optional[np.ndarray] = None
     hot_ids: Optional[np.ndarray] = None
+    #: a split step's order of rows, the row at each place ``(n_dev*steps,
+    #: mb)``; host only (no program reads it: the loss is summed in it)
+    order: Optional[np.ndarray] = None
     n_hot_entries: int = 0  # stored entries that hold a code
     #: the pack counted the features' entries and kept the unsplit step
     #: (``train.sparse_hot_declined`` counts such fits)
@@ -283,17 +306,18 @@ class EllMinibatchStack:
         """As :attr:`SparseMinibatchStack.batch`."""
         if self.hot_ids is None:
             return self.ints, self.floats
-        return (self.ints, self.floats, self.cold_ints, self.cold_vals,
-                self.hot_ids)
+        return (self.ints, self.floats, self.cold_idx, self.cold_vals,
+                self.cold_cuts, self.hot_ids)
 
     @property
-    def cold_pad(self) -> int:
-        return 0 if self.hot_ids is None else self.cold_ints.shape[-1]
+    def cold_slots(self) -> int:
+        """Slots of a device's step's cold list (0 unsplit)."""
+        return 0 if self.hot_ids is None else self.cold_idx.shape[-1]
 
     @property
     def step_slots(self) -> int:
         """Slots a device's step walks, pads included."""
-        return self.width * self.mb + self.cold_pad
+        return self.width * self.mb + self.cold_slots
 
     @property
     def ell_step_slots(self) -> int:
@@ -308,7 +332,7 @@ class EllMinibatchStack:
                     make_ell_mb_grad_step(kind, self.mb, self.width,
                                           self.dim, with_intercept))
         return (("sparse-ell-hot", self.mb, self.width, self.dim,
-                 self.cold_pad, self.hot_ids.shape[-1]),
+                 self.cold_slots, self.hot_ids.shape[-1]),
                 make_hot_ell_grad_step(kind, self.mb, self.width, self.dim,
                                        with_intercept))
 
@@ -432,20 +456,27 @@ _ELL_MAX_CLASSES = 32
 #: the cell's table 16384 features hold 90.9% of the stored entries, 4096
 #: hold 84.8%, and a fit lasts a third longer at 4096 (my chip run, PR 30)
 _HOT_K = 16384
-#: what a slot costs on a TPU v5e, in ns, on the cell's table (PERF.md §5;
-#: my chip runs, PR 30): a row-regular slot 3.108 s / (175 x 1,277,952) =
-#: 13.9 (take 7.1 + scatter 6.6 + the rest); a cold slot, segment-CSR's
-#: four random accesses, 3.54 ms / 116,736 = 30.3; the hot lookup, both
-#: kernels and the hot weights' take and scatter, 1.87 ms a step of
-#: 1,277,952 slots = 1.5, whatever the slot holds.  They predicted the
-#: fits of three thinner skews (hot shares 0.85, 0.61, 0.31) to 2%
+#: what a slot costs on a TPU v5e, in ns, on the cell's table (PERF.md §5):
+#: a row-regular slot 3.108 s / (175 x 1,277,952) = 13.9 (take 7.1 +
+#: scatter 6.6 + the rest; my chip runs, PR 30, and 13.90 again in PR 36);
+#: the hot lookup, both kernels and the hot weights' take and scatter, 1.87
+#: ms a step of 1,277,952 slots = 1.5, whatever the slot holds (PR 30; 1.45
+#: in PR 36).  A cold slot, since PR 36 ONE take and ONE scatter over a list
+#: laid plane by plane: 1.619 ms a step of 117,248 slots = 13.8 (take 6.63
+#: + scatter 6.66 + the planes' slices, writes and gaps 0.5; one traced fit,
+#: my chip runs, PR 36; whole fits of three thinner skews less the hot
+#: lookup read 13.4-13.5).  As a segment-COO list with row ids it paid four
+#: random accesses, 30.3 (PR 30).  The three costs predicted the split fits
+#: of those skews (hot shares 0.82, 0.55, 0.28) to 1-2.5%
 _ELL_SLOT_NS = 13.9
-_COLD_SLOT_NS = 30.3
+_COLD_SLOT_NS = 13.8
 _HOT_SLOT_NS = 1.5
 #: the split engages where those costs say it takes at most this share of
-#: the unsplit step's time: break-even is a hot share of 0.59, the rule
-#: asks for 0.68 on a table of one width (at 0.61 the chip read the split
-#: x1.06 faster, at 0.31 x0.64)
+#: the unsplit step's time: break-even is a hot share of 0.10, the rule
+#: asks for 0.30 on a table of one width (0.59 and 0.68 while a cold slot
+#: cost 30.3).  The chip read the split x3.51 faster at a hot share of
+#: 0.82, x1.84 at 0.55 and x1.25 at 0.28, where the costs say 0.82 of the
+#: unsplit step's time and the rule declines (my chip runs, PR 36)
 _HOT_SPLIT_ROOM = 0.8
 
 
@@ -453,8 +484,8 @@ def _hot_split_wins(hot_share: float, slots: int, entries: int) -> bool:
     """Does a row-regular step of ``slots`` slots, ``hot_share`` of whose
     ``entries`` stored entries fall on the :data:`_HOT_K` most frequent
     features, run faster split?  By the per-slot costs above: every slot
-    pays the hot lookup, the cold entries pay segment-CSR's four random
-    accesses, and the unsplit step pays two a slot."""
+    pays the hot lookup, a cold entry one take and one scatter in the cold
+    list, and the unsplit step pays the two on every slot."""
     split = slots * _HOT_SLOT_NS + (1.0 - hot_share) * entries * _COLD_SLOT_NS
     return split <= _HOT_SPLIT_ROOM * slots * _ELL_SLOT_NS
 
@@ -537,9 +568,13 @@ def pack_sparse_minibatches(
     one-width layout is taken, on a TPU, the pack also counts the stored
     entries a feature and lays the frequency split
     (:class:`EllMinibatchStack`) where :func:`_hot_split_wins` says it
-    pays; a table that fails that keeps the unsplit leaves byte for byte
-    and is marked ``hot_declined``.  A classed table is neither counted
-    nor split.
+    pays: the hot features' entries as codes, each step's rows ordered by
+    their cold width and its cold entries plane by plane, the planes' cuts
+    as data (:func:`_pack_ell_split`; gauges ``pack_sparse.cold_step_slots``
+    and ``pack_sparse.cold_planes`` beside ``.ell_step_slots``).  A table
+    that fails the split's rule keeps the unsplit leaves byte for byte and
+    is marked ``hot_declined``.  A classed table is neither counted nor
+    split.
     """
     from flink_ml_tpu.ops.batch import CsrRows
 
@@ -753,8 +788,13 @@ def _pack_sparse_minibatches_csr(
                 hot_ids, hot_share = _hot_features(indices[:nnz_total], dim)
                 if not _hot_split_wins(hot_share, mb * width, nnz_max):
                     hot_ids = None
-            stack = _pack_ell(rows, y, bounds, counts, width, mb, steps, dim,
-                              n_dev, pad_multiple, hot_ids)
+            if hot_ids is None:
+                stack = _pack_ell(rows, y, bounds, counts, width, mb, steps,
+                                  dim)
+            else:
+                stack = _pack_ell_split(rows, y, bounds, counts, width, mb,
+                                        steps, dim, n_dev, pad_multiple,
+                                        hot_ids)
             stack.hot_declined = counted and hot_ids is None
             return stack
 
@@ -905,73 +945,128 @@ def _pack_ell_classed(rows, y, bounds, counts, orders, classes, mb: int,
 
 
 def _pack_ell(rows, y, bounds, counts, width: int, mb: int, steps: int,
-              dim: int, n_dev: int, pad_multiple: int,
-              hot_ids=None) -> EllMinibatchStack:
+              dim: int) -> EllMinibatchStack:
     """Lay a validated CSR column out row-regular, a device's step (one
     entry of ``bounds``: rows ``[lo, hi)``, entries ``[e0, e1)``) at a
     time, so that the temporaries are a step's: a step whose rows are all
     ``width`` long is one transposing copy, a ragged one scatters its
     entries by (position in the row, row).  Rows keep their stored order of
-    entries: the step sums a row whatever its order.
-
-    With ``hot_ids`` (the frequency split) an entry of one of those
-    features is laid as its code, and every other entry goes to its step's
-    cold list in the order it is stored (row-major: the cold list's row ids
-    do not fall), leaving code 0 at value 0.0 behind."""
+    entries: the step sums a row whatever its order.  (Split by frequency:
+    :func:`_pack_ell_split`.)"""
     indptr, indices, values = rows.indptr, rows.indices, rows.values
     ints = np.zeros((len(bounds), width, mb), dtype=np.int32)
     floats = np.zeros((len(bounds), width + 2, mb), dtype=np.float32)
-    split = hot_ids is not None
-    if split:
-        code_of = np.full(dim, -1, np.int32)
-        n_hot = min(len(hot_ids), dim)
-        code_of[hot_ids[:n_hot]] = np.arange(n_hot, dtype=np.int32)
-        cold = [None] * len(bounds)  # a step's (feature ids, row ids, values)
     for g, (lo, hi, e0, e1) in enumerate(bounds):
         m = hi - lo
         if not m:
             continue
         ids, vals = indices[e0:e1], values[e0:e1]
-        regular = e1 - e0 == m * width
-        if not regular:
-            rid = np.repeat(np.arange(m, dtype=np.int32), counts[lo:hi])
-        if split:
-            ids = code_of[ids]
-            at = np.flatnonzero(ids < 0)
-            cold[g] = (indices[e0:e1][at],
-                       at // width if regular else rid[at], vals[at])
-            ids = np.maximum(ids, 0)
-            vals = vals.copy()
-            vals[at] = 0.0
-        if regular:
+        if e1 - e0 == m * width:
             ints[g, :, :m] = ids.reshape(m, width).T
             floats[g, :width, :m] = vals.reshape(m, width).T
         elif e1 > e0:
+            rid = np.repeat(np.arange(m, dtype=np.int32), counts[lo:hi])
             pos = np.arange(e1 - e0, dtype=np.int32) - np.repeat(
                 (indptr[lo:hi] - e0).astype(np.int32), counts[lo:hi])
             ints[g, pos, rid] = ids
             floats[g, pos, rid] = vals
         floats[g, width, :m] = y[lo:hi]
         floats[g, width + 1, :m] = 1.0
-    stack = EllMinibatchStack(
+    return EllMinibatchStack(
         ints=ints, floats=floats, steps=steps, mb=mb, width=width, dim=dim,
         n_rows=len(rows), n_entries=int(indptr[-1]) if len(rows) else 0,
     )
-    if not split:
-        return stack
+
+
+def _pack_ell_split(rows, y, bounds, counts, width: int, mb: int, steps: int,
+                    dim: int, n_dev: int, pad_multiple: int,
+                    hot_ids) -> EllMinibatchStack:
+    """:func:`_pack_ell` with the frequency split (see
+    :class:`EllMinibatchStack`): an entry of one of ``hot_ids``' features is
+    laid as its code, every other entry leaves code 0 at value 0.0 behind
+    and goes to its step's cold list.  A step's rows are ordered by their
+    cold width (descending, stable) and EVERYTHING of the step is laid in
+    that order: both planes' columns, labels, row weights, and the cold
+    entries plane by plane (a row's ``j``-th cold entry, in stored order, at
+    its place in plane ``j``), the planes' starts and lengths beside them
+    as data.  The steps are spread over threads (numpy's copies and takes
+    release the lock), as :func:`_pack_ell_classed`'s."""
+    indptr, indices, values = rows.indptr, rows.indices, rows.values
+    blocks = len(bounds)
+    ints = np.zeros((blocks, width, mb), dtype=np.int32)
+    floats = np.zeros((blocks, width + 2, mb), dtype=np.float32)
+    cuts = np.zeros((blocks, 2, width), dtype=np.int32)
+    order = np.tile(np.arange(mb, dtype=np.int32), (blocks, 1))
+    code_of = np.full(dim, -1, np.int32)
+    n_hot = min(len(hot_ids), dim)
+    code_of[hot_ids[:n_hot]] = np.arange(n_hot, dtype=np.int32)
+    cold = [None] * blocks  # a step's (feature ids, values) in plane order
+
+    def lay(g):
+        lo, hi, e0, e1 = bounds[g]
+        m, n = hi - lo, e1 - e0
+        if not m:
+            return
+        codes = code_of[indices[e0:e1]]
+        is_cold = codes < 0
+        # cold entries stored before each entry, and before each row's first
+        before = np.zeros(n + 1, np.int32)
+        np.cumsum(is_cold, out=before[1:])
+        first = (indptr[lo : hi + 1] - e0).astype(np.int32)
+        cold_width = np.diff(before[first])
+        by_width = np.argsort(-cold_width, kind="stable")
+        order[g, :m] = by_width
+        place_of = np.empty(m, np.int32)
+        place_of[by_width] = np.arange(m, dtype=np.int32)
+        # plane j holds the rows with more than j cold entries: the first
+        # n_j places; it starts where the planes before it end
+        wider = m - np.cumsum(np.bincount(cold_width, minlength=width + 1))
+        cuts[g, 1] = wider[:width]
+        cuts[g, 0, 1:] = np.cumsum(wider[: width - 1])
+        np.maximum(codes, 0, out=codes)
+        vals = np.where(is_cold, np.float32(0.0), values[e0:e1])
+        rid = None  # a step whose rows are all ``width`` long needs none
+        if n == m * width:
+            ints[g, :, :m] = codes.reshape(m, width)[by_width].T
+            floats[g, :width, :m] = vals.reshape(m, width)[by_width].T
+        elif n:
+            rid = np.repeat(np.arange(m, dtype=np.int32), counts[lo:hi])
+            pos = np.arange(n, dtype=np.int32) - first[rid]
+            ints[g, pos, place_of[rid]] = codes
+            floats[g, pos, place_of[rid]] = vals
+        floats[g, width, :m] = y[lo:hi][by_width]
+        floats[g, width + 1, :m] = 1.0
+        at = np.flatnonzero(is_cold)
+        if not len(at):
+            return
+        row = at // width if rid is None else rid[at]
+        dest = cuts[g, 0][before[at] - before[first[row]]] + place_of[row]
+        ids_c = np.empty(len(at), np.int32)
+        vals_c = np.empty(len(at), np.float32)
+        ids_c[dest] = indices[e0:e1][at]
+        vals_c[dest] = values[e0:e1][at]
+        cold[g] = (ids_c, vals_c)
+
+    with ThreadPoolExecutor(min(blocks, os.cpu_count() or 1)) as pool:
+        list(pool.map(lay, range(blocks)))
     n_cold = [0 if c is None else len(c[0]) for c in cold]
-    cold_pad = max(1, -(-max(n_cold) // pad_multiple)) * pad_multiple
-    stack.cold_ints = np.zeros((len(bounds), 2, cold_pad), dtype=np.int32)
-    stack.cold_ints[:, 1, :] = mb  # pad row id -> dropped segment
-    stack.cold_vals = np.zeros((len(bounds), cold_pad), dtype=np.float32)
+    cold_slots = padded_nnz(max(n_cold), pad_multiple)
+    cold_idx = np.zeros((blocks, cold_slots), dtype=np.int32)
+    cold_vals = np.zeros((blocks, cold_slots), dtype=np.float32)
     for g, c in enumerate(cold):
         if c is not None:
-            stack.cold_ints[g, 0, : n_cold[g]] = c[0]
-            stack.cold_ints[g, 1, : n_cold[g]] = c[1]
-            stack.cold_vals[g, : n_cold[g]] = c[2]
-    stack.hot_ids = np.tile(hot_ids, (n_dev, 1))
-    stack.n_hot_entries = stack.n_entries - sum(n_cold)
-    return stack
+            cold_idx[g, : n_cold[g]], cold_vals[g, : n_cold[g]] = c
+    n_entries = int(indptr[-1]) if len(rows) else 0
+    obs.gauge_set("pack_sparse.cold_step_slots", cold_slots)
+    obs.gauge_set("pack_sparse.cold_planes",
+                  int(np.count_nonzero(cuts[:, 1].max(axis=0))))
+    return EllMinibatchStack(
+        ints=ints, floats=floats, steps=steps, mb=mb, width=width, dim=dim,
+        n_rows=len(rows), n_entries=n_entries, cold_idx=cold_idx,
+        cold_vals=cold_vals, cold_cuts=cuts, order=order,
+        hot_ids=np.tile(hot_ids, (n_dev, 1)),
+        n_hot_entries=n_entries - sum(n_cold),
+    )
 
 
 # Compiled epoch steps are reused across fit() calls: rebuilding the jitted
@@ -1683,9 +1778,15 @@ def make_hot_ell_grad_step(kind: str, mb: int, width: int, dim: int,
     (``ops/pallas_kernels.py:hot_scores`` / ``hot_grad``, under
     ``fmt.train.sparse.hot``; ``interpret`` as
     ``pallas_kernels.launch_interpreted`` says unless given, and the step
-    says which as ``pallas_interpret``); the step's cold list runs
-    segment-CSR's gather and scatter in the table's own ids; the same
-    loss, the same scopes, float32 throughout."""
+    says which as ``pallas_interpret``).  The cold list, laid plane by
+    plane over rows that stand in the order of their cold width, pays ONE
+    take and ONE scatter a slot (:func:`_cold_planes_forward` /
+    :func:`_cold_planes_backward`: ``fmt.train.sparse.take_weights`` and
+    ``.scatter`` inside the step's two halves); the planes' starts and
+    lengths are DATA (``cold_cuts``), so no constant of the program comes
+    from the widths a table happened to draw.  The same loss, summed in the
+    step's order of rows; float32 throughout, a row's products summed in
+    its stored order."""
     from flink_ml_tpu.ops import pallas_kernels
 
     if interpret is None:
@@ -1693,34 +1794,67 @@ def make_hot_ell_grad_step(kind: str, mb: int, width: int, dim: int,
     keep_b = 1.0 if with_intercept else 0.0
 
     def grad_step(params, batch, step):
-        codes, floats, cold_ints, cold_vals = (
+        codes, floats, cold_idx, cold_vals, cuts = (
             jax.lax.dynamic_index_in_dim(leaf, step, keepdims=False)
-            for leaf in batch[:4])
-        hot_ids = batch[4][0]
+            for leaf in batch[:5])
+        hot_ids = batch[5][0]
         vals, y, w = floats[:width], floats[width], floats[width + 1]
-        cold_idx, cold_rid = cold_ints[0], cold_ints[1]
         wts, b = params
         with jax.named_scope("fmt.train.sparse.forward"):
             with jax.named_scope("fmt.train.sparse.hot"):
                 logits = pallas_kernels.hot_scores(
                     jnp.take(wts, hot_ids, axis=0), codes, vals,
                     interpret=interpret)
-            logits = logits + _segment_csr_forward(
-                wts, cold_idx, cold_rid, cold_vals, mb) + b
+            logits = logits + _cold_planes_forward(
+                wts, cold_idx, cold_vals, cuts, mb) + b
         err, loss_sum = _sparse_loss(kind, logits, y, w)
         with jax.named_scope("fmt.train.sparse.backward"):
+            g_w = _cold_planes_backward(err, cold_idx, cold_vals, cuts, dim)
             with jax.named_scope("fmt.train.sparse.hot"):
-                g_hot = pallas_kernels.hot_grad(
+                g_w = g_w.at[hot_ids].add(pallas_kernels.hot_grad(
                     err, codes, vals, k=hot_ids.shape[0],
-                    interpret=interpret)
-            g_w = _segment_csr_backward(
-                err, cold_idx, cold_rid, cold_vals, dim
-            ).at[hot_ids].add(g_hot)
+                    interpret=interpret))
         g_b = jnp.sum(err) * keep_b
         return (g_w, g_b), loss_sum, jnp.sum(w)
 
     grad_step.pallas_interpret = interpret
     return grad_step
+
+
+def _cold_planes_forward(wts, idx, vals, cuts, mb: int):
+    """A split step's cold scores, ``(mb,)`` in the step's order of rows,
+    from its cold list laid plane by plane (:class:`EllMinibatchStack`):
+    ONE take of the weights over the list, then a row's score is the sum
+    over planes, in stored order, of the ``mb`` products from the plane's
+    start on, masked to the plane's length (``cuts``: [starts, lengths],
+    data): contiguous reads, no addresses.  ``mb`` zeros behind the list
+    keep every plane's slice inside it."""
+    starts, lengths = cuts[0], cuts[1]
+    with jax.named_scope("fmt.train.sparse.take_weights"):
+        prods = vals * jnp.take(wts, idx, axis=0)
+    prods = jnp.concatenate([prods, jnp.zeros((mb,), prods.dtype)])
+    place = jnp.arange(mb, dtype=jnp.int32)
+    scores = jnp.zeros((mb,), prods.dtype)
+    for j in range(cuts.shape[1]):
+        plane = jax.lax.dynamic_slice(prods, (starts[j],), (mb,))
+        scores = scores + jnp.where(place < lengths[j], plane, 0.0)
+    return scores
+
+
+def _cold_planes_backward(err, idx, vals, cuts, dim: int):
+    """The cold list's gradient: the error (``(mb,)``, in the step's order)
+    reaches the slots by writing it whole at each plane's start, plane
+    after plane in ascending order, into a buffer ``mb`` longer than the
+    list (a later plane overwrites what the one before wrote past its own
+    length; what the last leaves past the entries meets value 0.0 at id
+    0); times the values, then ONE scatter-add into ``dim``."""
+    starts, slots = cuts[0], vals.shape[0]
+    spread = jnp.zeros((slots + err.shape[0],), err.dtype)
+    for j in range(cuts.shape[1]):
+        spread = jax.lax.dynamic_update_slice(spread, err, (starts[j],))
+    with jax.named_scope("fmt.train.sparse.scatter"):
+        return jax.ops.segment_sum(spread[:slots] * vals, idx,
+                                   num_segments=dim)
 
 
 def _segment_csr_unpack(ints, floats, nnz_pad: int, mb: int):
@@ -1742,7 +1876,6 @@ def _segment_csr_forward(wts, idx, rid, vals, mb: int):
     asserted by the pack tests), so the segment reduction takes the
     sorted-indices lowering."""
     # the step's four random-access operations, each named for a profile
-    # (the split step's cold list runs these too)
     with jax.named_scope("fmt.train.sparse.take_weights"):
         prods = vals * jnp.take(wts, idx, axis=0)
     with jax.named_scope("fmt.train.sparse.row_sum"):
@@ -3006,6 +3139,9 @@ def train_glm_sparse(
         if sstack.hot_ids is not None:
             obs.counter_add("train.sparse_hot_entries",
                             sstack.n_hot_entries * r.epochs)
+            # and the cold list's slots walked, its tail of pads included
+            obs.counter_add("train.sparse_cold_slots",
+                            sstack.cold_slots * len(sstack.ints) * r.epochs)
         obs.counter_add("train.sparse_entries", sstack.n_entries * r.epochs)
         obs.counter_add("train.sparse_slots",
                         sstack.step_slots * len(sstack.ints) * r.epochs)

@@ -14,16 +14,18 @@ on the sparse cell's table made from ``--seed``:
                   lookup (``ops/pallas_kernels.py:hot_scores`` / ``hot_grad``)
                   both directions at each K, as shipped and at each ``--tile`` x
                   ``--unroll`` (slots a grid step, planes a loop trip; with
-                  the seconds to the first call's return), and
-                  segment-CSR's forward and backward over a cold list of
-                  the step's size;
+                  the seconds to the first call's return), and the cold
+                  list's forward and backward alone (``lib/common.py:
+                  _cold_planes_forward`` / ``_backward``: one take, the
+                  planes' sum; the error's writes, one scatter), with the
+                  planes the step holds;
 * ``fits``        whole warm fits by the builders (``train_glm_sparse``):
                   the unsplit row-regular step, and the split step at each
                   K, with the pack's seconds, the leaves' bytes, the loss and
                   the coefficients' distance from the unsplit fit's.
 
 A summary line each to standard output, everything to
-``chiprun_out/pr30/readings.json``.  Runs on whatever JAX finds; times mean
+``<--out>/readings.json`` (``chiprun_out/sparse_hot_readings/`` unless given).  Runs on whatever JAX finds; times mean
 something only on the chip.
 """
 
@@ -62,7 +64,7 @@ def main(argv=None) -> int:
     parser.add_argument("--unroll", type=int, action="append", default=[])
     parser.add_argument("--no-fits", action="store_true")
     parser.add_argument("--out", default=os.path.join(
-        ROOT, "chiprun_out", "pr30"))
+        ROOT, "chiprun_out", "sparse_hot_readings"))
     args = parser.parse_args(argv)
     ks = args.k or [4096, 16384]
 
@@ -139,7 +141,7 @@ def main(argv=None) -> int:
         line = {
             "pack_s": pack_s, "first_fit_s": first_s,
             "warm_fit_s": sorted(s for s, _r in warm)[len(warm) // 2],
-            "step_slots": stack.step_slots, "cold_pad": stack.cold_pad,
+            "step_slots": stack.step_slots, "cold_slots": stack.cold_slots,
             "hot_entry_share": stack.n_hot_entries / max(1, stack.n_entries),
             "leaf_bytes": int(sum(a.nbytes for a in stack.batch)),
             "loss": float(first.losses[-1]),
@@ -199,19 +201,20 @@ def main(argv=None) -> int:
                 pallas_kernels._HOT_TILE, pallas_kernels._HOT_UNROLL = shipped
                 jax.clear_caches()
         jax.config.update("jax_enable_compilation_cache", True)
-        cold_idx = jnp.asarray(stack.cold_ints[0, 0])
-        cold_rid = jnp.asarray(stack.cold_ints[0, 1])
+        cold_idx = jnp.asarray(stack.cold_idx[0])
         cold_vals = jnp.asarray(stack.cold_vals[0])
+        cuts = jnp.asarray(stack.cold_cuts[0])
         w_all = jnp.zeros((dim,), jnp.float32) + 0.5
         ops[f"cold_forward_{k}"] = per_call_ms(
-            jax.jit(lambda w, i, r, v: common._segment_csr_forward(
-                w, i, r, v, stack.mb)),
-            (w_all, cold_idx, cold_rid, cold_vals), args.calls)
+            jax.jit(lambda w, i, v, c: common._cold_planes_forward(
+                w, i, v, c, stack.mb)),
+            (w_all, cold_idx, cold_vals, cuts), args.calls)
         ops[f"cold_backward_{k}"] = per_call_ms(
-            jax.jit(lambda e, i, r, v: common._segment_csr_backward(
-                e, i, r, v, dim)),
-            (err, cold_idx, cold_rid, cold_vals), args.calls)
-        ops[f"cold_pad_{k}"] = stack.cold_pad
+            jax.jit(lambda e, i, v, c: common._cold_planes_backward(
+                e, i, v, c, dim)),
+            (err, cold_idx, cold_vals, cuts), args.calls)
+        ops[f"cold_slots_{k}"] = stack.cold_slots
+        ops[f"cold_planes_{k}"] = int(np.count_nonzero(stack.cold_cuts[0, 1]))
         say("ops", ops)
     ids0 = jnp.asarray(np.ascontiguousarray(
         indices[: batch * stack.width].reshape(batch, stack.width).T))
@@ -220,7 +223,7 @@ def main(argv=None) -> int:
         args.calls)
     ops["slots"] = int(codes.size)
     say("ops", ops)
-    del codes, vals, err, flat, cold_idx, cold_rid, cold_vals, ids0
+    del codes, vals, err, flat, cold_idx, cold_vals, cuts, ids0
 
     if args.no_fits:
         return 0

@@ -115,7 +115,7 @@ def by_builders(name, column, y, config, row_regular, fits, split=False):
     }
     if shares:  # what the pack counted, and what its rule would have done
         line.update(hot_share=shares[0][0], rule_splits=shares[0][1],
-                    cold_pad=stack.cold_pad)
+                    cold_slots=stack.cold_slots)
     return line, np.asarray(first.params[0])
 
 

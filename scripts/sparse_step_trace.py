@@ -25,6 +25,16 @@ profile
                      metadata's ``tf_op``, ``fit_gaps.py:op_metadata``);
 * ``self_by_scope``  self seconds summed by scope: forward, backward, the
                      step's slices (``fmt.train``), the update;
+* ``step_parts_ms``  the same by the step's parts, milliseconds a step (a
+                     step: one run of the dearest operation that runs every
+                     step).  On the split step (PR 36): ``cold_take``
+                     (``.take_weights``: the ONE take over the cold list),
+                     ``cold_scatter`` (``.scatter``), ``planes`` (what lies
+                     under ``.forward`` / ``.backward`` themselves: the
+                     planes' slices and sum, the error's writes),
+                     ``kernels`` (``.hot``: both Pallas calls, the hot
+                     weights' take, the scatter of their sums), ``rest``;
+                     on segment-CSR also ``row_sum`` and ``take_error``;
 * ``one_step``       the operations of one step in order, microseconds from
                      the step's start.
 
@@ -62,6 +72,16 @@ def _short(name):
     return name[1:].split(" ", 1)[0] if name.startswith("%") else name
 
 
+#: a step's parts by the innermost ``fmt.*`` scope of an operation
+PARTS = {"fmt.train.sparse.take_weights": "cold_take",
+         "fmt.train.sparse.scatter": "cold_scatter",
+         "fmt.train.sparse.row_sum": "row_sum",
+         "fmt.train.sparse.take_error": "take_error",
+         "fmt.train.sparse.hot": "kernels",
+         "fmt.train.sparse.forward": "planes",
+         "fmt.train.sparse.backward": "planes"}
+
+
 def read_program(path):
     """The report's fields from the profile at ``path``: the longest
     ``jit_bundled`` module and the operations that lie inside it."""
@@ -93,8 +113,11 @@ def read_program(path):
     # that runs every step
     anchor = max((k for k in inclusive if count[k] >= 100),
                  key=inclusive.get, default=None)
-    step_s, one_step = None, []
+    step_s, one_step, parts = None, [], {}
     if anchor is not None:
+        for name, s in by_scope.items():
+            part = PARTS.get(name, "rest")
+            parts[part] = parts.get(part, 0.0) + 1e3 * s / count[anchor]
         starts = sorted(a for n, a, _b in inside if _short(n) == anchor)
         s_lo, s_hi = starts[len(starts) // 2], starts[len(starts) // 2 + 1]
         step_s = (s_hi - s_lo) / 1e9
@@ -106,6 +129,7 @@ def read_program(path):
     return {
         "module_s": (hi - lo) / 1e9, "covered_s": covered,
         "n_ops": len(inside), "self_by_scope": by_scope,
+        "step_parts_ms": parts,
         "ops": sorted(([k, count[k], inclusive[k], self_s.get(k, 0.0),
                         scope.get(k, "")] for k in inclusive),
                       key=lambda row: -row[2]),
@@ -165,7 +189,7 @@ def main(argv=None) -> int:
         return time.perf_counter() - t
 
     report = {"layout": layout, "step_slots": stack.step_slots,
-              "cold_pad": getattr(stack, "cold_pad", 0),
+              "cold_slots": getattr(stack, "cold_slots", 0),
               "classes": getattr(stack, "classes", None),
               "pack_s": pack_s,
               "steps": len(stack.ints), "first_fit_s": fit(),

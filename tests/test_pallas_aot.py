@@ -128,16 +128,16 @@ def test_the_chip_lays_other_shapes_otherwise(topo, quiet_cache, shape,
 # -- the sparse step's frequency split (PR 30) ---------------------------------
 
 
-def compiled_split_fit(topo, n_dev, steps, mb, width, dim, cold_pad, k,
-                       monkeypatch):
-    """The split sparse fit's program (unbundled, as above) at the sparse
-    cell's shapes for ``n_dev`` described chips."""
+def lowered_split_fit(topo, n_dev, steps, mb, width, dim, cold_slots, k,
+                      monkeypatch):
+    """The split sparse fit's program (unbundled, as above) lowered for
+    ``n_dev`` described chips from the shapes of its six leaves alone."""
     monkeypatch.setattr(pallas_kernels, "launch_interpreted", lambda: False)
     mesh = Mesh(np.array(topo.devices[:n_dev]), ("data",))
     step = common.make_hot_ell_grad_step("logistic", mb, width, dim, True,
                                          interpret=False)
     fn = common._build_fused_train_fn(
-        ("aot-sparse-ell-hot", n_dev, steps, mb, width, dim, cold_pad, k,
+        ("aot-sparse-ell-hot", n_dev, steps, mb, width, dim, cold_slots, k,
          mesh), None, mesh, 0.1, 0.0, 1, 0.0, whole_batch_step=step)
     replicated = NamedSharding(mesh, P())
     sharded = NamedSharding(mesh, P("data"))
@@ -150,10 +150,16 @@ def compiled_split_fit(topo, n_dev, steps, mb, width, dim, cold_pad, k,
              jax.ShapeDtypeStruct((), jnp.float32, sharding=replicated)),
             (leaf((blocks, width, mb), jnp.int32),
              leaf((blocks, width + 2, mb), jnp.float32),
-             leaf((blocks, 2, cold_pad), jnp.int32),
-             leaf((blocks, cold_pad), jnp.float32),
+             leaf((blocks, cold_slots), jnp.int32),
+             leaf((blocks, cold_slots), jnp.float32),
+             leaf((blocks, 2, width), jnp.int32),
              leaf((n_dev, k), jnp.int32)))
-    return fn.lower(*args).compile()
+    return fn.lower(*args)
+
+
+#: the sparse cell's cold list: its fullest step's 116,2xx-117,2xx cold
+#: entries (by seed) rounded up to an odd multiple of 512
+COLD_SLOTS = 117248
 
 
 @pytest.mark.parametrize("n_dev,mb,k", [(1, ROWS, 16384), (1, ROWS, 4096),
@@ -161,14 +167,93 @@ def compiled_split_fit(topo, n_dev, steps, mb, width, dim, cold_pad, k,
                          ids=["criteo", "criteo-4096", "four-chips"])
 def test_the_split_sparse_step_compiles_with_both_hot_kernels(
         topo, quiet_cache, monkeypatch, n_dev, mb, k):
-    compiled = compiled_split_fit(topo, n_dev, 8, mb, 39, 1_000_000,
-                                  116736, k, monkeypatch)
-    text = compiled.as_text()
+    assert COLD_SLOTS == common.padded_nnz(116_300, 512) \
+        == common.padded_nnz(117_200, 512)
+    text = lowered_split_fit(topo, n_dev, 8, mb, 39, 1_000_000, COLD_SLOTS,
+                             k, monkeypatch).compile().as_text()
     # hot_scores and hot_grad, under strict check_vma on four chips too
     assert text.count('custom_call_target="tpu_custom_call"') == 2
     assert "hot_scores" in text and "hot_grad" in text
     if n_dev > 1:
         assert len(re.findall(r"= .* all-reduce\(", text)) >= 1
+
+
+def test_the_split_step_keeps_one_gather_and_one_scatter_over_the_cold_slots(
+        topo, quiet_cache, monkeypatch):
+    """Beside the two kernels the chip's compiler keeps ONE gather and ONE
+    scatter over the cold slots (and the hot weights' take and the scatter
+    of their sums, 16384 long): no sum by row id, no take of the error; the
+    39 planes' slices of the products sit in one fusion, and the error's
+    39 writes update the cold buffer where it lies."""
+    mb, width, k = ROWS, 39, 16384
+    text = lowered_split_fit(topo, 1, 8, mb, width, 1_000_000, COLD_SLOTS, k,
+                             monkeypatch).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    gathers = re.findall(r"= f32\[(\d+)\]\S* gather\(", text)
+    assert sorted(gathers) == sorted([str(COLD_SLOTS), str(k)])
+    scatters = re.findall(r"= f32\[(\d+)\]\S* scatter\(", text)
+    assert scatters == ["1000000", "1000000"]  # the cold slots', the hot sums'
+    assert "indices_are_sorted=true" not in text
+    assert "fmt.train.sparse.take_weights" in text
+    assert "fmt.train.sparse.scatter" in text
+    assert "fmt.train.sparse.row_sum" not in text
+    assert "fmt.train.sparse.take_error" not in text
+    # the error's way to the slots: a write a plane into ONE buffer of
+    # cold_slots + mb, each on the one before, and no copy of it
+    buffer = rf"f32\[{COLD_SLOTS + mb}\]"
+    writes = re.findall(rf"= {buffer}\S* dynamic-update-slice\(", text)
+    assert len(writes) == width
+    assert not re.findall(rf"= {buffer}\S* copy\(", text)
+    assert len(re.findall(rf"= f32\[{mb}\]\S* dynamic-slice\(", text)) \
+        >= width
+
+
+def _split_stack(seed, skew):
+    """A small table of the sparse cell's kind split by frequency: every
+    row 5 wide, a power law over 3000 features, 256 of them hot."""
+    rng = np.random.RandomState(seed)
+    rows, dim, width = 512, 3000, 5
+    ids = np.where(rng.rand(rows * width) < 0.8,
+                   (rng.zipf(skew, rows * width) - 1) * 7919 % dim,
+                   rng.randint(0, dim, rows * width)).astype(np.int32)
+    indptr = width * np.arange(rows + 1, dtype=np.int64)
+    values = rng.randn(rows * width).astype(np.float32)
+    y = (rng.rand(rows) < 0.35).astype(np.float64)
+    counts = np.bincount(ids, minlength=dim)
+    hot_ids = np.argsort(-counts, kind="stable")[:256].astype(np.int32)
+    from flink_ml_tpu.ops.batch import CsrRows
+
+    bounds = [(lo, lo + 128, lo * width, (lo + 128) * width)
+              for lo in range(0, rows, 128)]
+    return common._pack_ell_split(CsrRows(dim, indptr, ids, values), y,
+                                  bounds, np.diff(indptr), width, 128, 4,
+                                  dim, 1, 512, hot_ids)
+
+
+def test_two_tables_of_one_shape_lower_to_the_same_program(
+        topo, quiet_cache, monkeypatch):
+    """The cold planes' starts and lengths are data: two tables of one
+    shape whose rows draw other cold widths give ONE program, letter for
+    letter, so the second finds the first's in the compile cache (the
+    ragged cell's width classes are constants of its program and it has no
+    warm state: PERF.md, section 5, the set-up account)."""
+    one, other = _split_stack(1, 1.4), _split_stack(2, 1.1)
+    assert one.cold_slots == other.cold_slots
+    assert [a.shape for a in one.batch] == [a.shape for a in other.batch]
+    # other cold widths: other planes, other cuts, another order of rows
+    assert not np.array_equal(one.cold_cuts, other.cold_cuts)
+    assert not np.array_equal(one.cold_cuts[:, 1].max(axis=0) > 0,
+                              other.cold_cuts[:, 1].max(axis=0) > 0)
+    texts = []
+    for stack in (one, other):
+        key, _step = stack.grad_step("logistic")
+        assert key == ("sparse-ell-hot", 128, 5, 3000, one.cold_slots, 256)
+        common._EPOCH_STEP_CACHE.clear()
+        texts.append(lowered_split_fit(
+            topo, 1, 4, stack.mb, stack.width, stack.dim, stack.cold_slots,
+            256, monkeypatch).as_text())
+    assert texts[0] == texts[1]
+    assert texts[0].count("dynamic_update_slice") >= 5
 
 
 # -- the ragged table in width classes (PR 34) ---------------------------------

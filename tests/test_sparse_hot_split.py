@@ -1,5 +1,7 @@
-"""The plain sparse route's frequency split (PR 30): the pack that lays it,
-the step that reads it, the rule that engages it and what it counts.
+"""The plain sparse route's frequency split (PR 30): the pack that lays it
+(since PR 36 a step's rows in the order of their cold width, the cold
+entries plane by plane, the planes' cuts as data), the step that reads it,
+the rule that engages it and what it counts.
 
 On the CPU the pack neither counts nor splits (the rule's costs are a TPU's:
 ``common._hot_split_measured``), so every test here that wants the split
@@ -53,9 +55,32 @@ TABLES = {
     "duplicate_id": (480, 3000, 5, False, 7, 256),
     "dim_under_k": (480, 200, 5, False, None, 256),   # an empty cold list
     "last_short_step": (403, 3000, 5, True, None, 256),
+    # rows 3 and 130 hold no hot entry at all: every plane of the cold list
+    "all_cold_rows": (480, 3000, 5, False, None, 256),
+    "all_cold_rows_ragged": (403, 3000, 6, True, None, 256),
+    # the second step of 128 rows holds hot entries only
+    "a_step_without_cold": (480, 3000, 5, False, None, 256),
     # the table as the program lays it, 128 x 128: over 16384 features seen
     "whole_hot_table": (25000, 200000, 4, False, None, 16384),
 }
+
+
+def _table(name):
+    """The CSR parts of one of ``TABLES`` (and the ids counted hot in it)."""
+    rows, dim, width, ragged, dup, k = TABLES[name]
+    indptr, ids, values, y = _skewed(rows, dim, width, 13, ragged, dup)
+    seen = np.bincount(ids, minlength=dim)
+    hot = np.argsort(-seen, kind="stable")[:k]
+    if name.startswith("all_cold_rows"):
+        # ids no row holds, from the top: at one entry each they lose the
+        # tie for the last hot places to the lower ids
+        rare = np.flatnonzero(seen == 0)
+        for r in (3, 130):
+            n = indptr[r + 1] - indptr[r]
+            ids[indptr[r]:indptr[r + 1]] = rare[-(r + n):][:n]
+    elif name == "a_step_without_cold":
+        ids[indptr[128]:indptr[256]] = hot[:5].tolist() * 128
+    return indptr, ids, values, y
 
 
 @pytest.fixture
@@ -65,8 +90,8 @@ def measured(monkeypatch):
 
 
 def _pack(name, n_dev, batch, monkeypatch, split):
-    rows, dim, width, ragged, dup, k = TABLES[name]
-    indptr, ids, values, y = _skewed(rows, dim, width, 13, ragged, dup)
+    dim, k = TABLES[name][1], TABLES[name][5]
+    indptr, ids, values, y = _table(name)
     monkeypatch.setattr(common, "_HOT_K", k)
     monkeypatch.setattr(common, "_hot_split_measured", lambda: split)
     monkeypatch.setattr(common, "_hot_split_wins", lambda *a: True)
@@ -79,20 +104,24 @@ def _pack(name, n_dev, batch, monkeypatch, split):
 
 
 def _dense(stack, n_dev):
-    """The table a stack holds, rows in table order: (rows, dim) float64."""
+    """The table a stack holds, rows in table order: (rows, dim) float64.
+    A split stack's through each step's order of rows (the row at each
+    place) and the cold planes' starts and lengths."""
     blocks = len(stack.ints)
     out = np.zeros((blocks, stack.mb, stack.dim))
-    rows = np.broadcast_to(np.arange(stack.mb), (stack.width, stack.mb))
     for g in range(blocks):
         ids = stack.ints[g]
+        order = np.arange(stack.mb)
         if stack.hot_ids is not None:
             ids = stack.hot_ids[0][ids]
+            order = stack.order[g]
+        rows = np.broadcast_to(order, (stack.width, stack.mb))
         np.add.at(out[g], (rows, ids), stack.floats[g, :stack.width])
         if stack.hot_ids is not None:
-            kept = stack.cold_ints[g, 1] < stack.mb
-            np.add.at(out[g], (stack.cold_ints[g, 1, kept],
-                               stack.cold_ints[g, 0, kept]),
-                      stack.cold_vals[g, kept])
+            for start, length in stack.cold_cuts[g].T:
+                plane = slice(start, start + length)
+                np.add.at(out[g], (order[:length], stack.cold_idx[g, plane]),
+                          stack.cold_vals[g, plane])
     # block g = device k, local step s; table order is step-major
     out = out.reshape(n_dev, stack.steps, stack.mb, stack.dim)
     return out.transpose(1, 0, 2, 3).reshape(-1, stack.dim)[:stack.n_rows]
@@ -107,15 +136,36 @@ def test_the_split_pack_holds_every_entry_once_and_restores_the_table(
     plain, _ = _pack(name, n_dev, batch, monkeypatch, False)
     indptr, ids, values, _y = parts
     k = TABLES[name][5]
-    # the leaves keep their shapes; the cold list is segment-COO, row-major
+    # the leaves keep their shapes; the cold list lies plane by plane, its
+    # length an odd multiple of 512, the planes' starts and lengths beside it
+    blocks = len(split.ints)
     assert split.ints.shape == plain.ints.shape
     assert split.floats.shape == plain.floats.shape
     assert split.hot_ids.shape == (n_dev, k)
     assert split.ints.min() >= 0 and split.ints.max() < k
-    assert split.cold_pad % 512 == 0 and split.cold_pad >= 512
-    assert split.cold_ints.shape == (len(split.ints), 2, split.cold_pad)
-    assert split.cold_vals.shape == (len(split.ints), split.cold_pad)
-    assert (np.diff(split.cold_ints[:, 1, :].astype(np.int64)) >= 0).all()
+    assert split.cold_slots % 1024 == 512
+    assert split.cold_idx.shape == split.cold_vals.shape == \
+        (blocks, split.cold_slots)
+    assert split.cold_cuts.shape == (blocks, 2, split.width)
+    assert split.cold_cuts.dtype == split.cold_idx.dtype == np.int32
+    starts, lengths = split.cold_cuts[:, 0], split.cold_cuts[:, 1]
+    # a plane holds the first places, no more than the plane before it, and
+    # starts where that one ends: no pad between planes
+    assert (lengths >= 0).all() and (lengths <= split.mb).all()
+    assert (np.diff(lengths, axis=1) <= 0).all()
+    assert (starts[:, 0] == 0).all()
+    assert np.array_equal(starts[:, 1:], np.cumsum(lengths, axis=1)[:, :-1])
+    held = lengths.sum(axis=1)
+    assert held.max() <= split.cold_slots < held.max() + 1024
+    for g in range(blocks):  # past the entries: id 0 at value 0.0
+        assert not split.cold_idx[g, held[g]:].any()
+        assert not split.cold_vals[g, held[g]:].any()
+    # a step's order is a permutation of its places, its rows in
+    # descending order of cold width, the pad rows of a short step last
+    assert np.array_equal(np.sort(split.order, axis=1),
+                          np.tile(np.arange(split.mb), (blocks, 1)))
+    weights = split.floats[:, split.width + 1]
+    assert (np.diff(weights, axis=1) <= 0).all()
     # hot ids: the most frequent features, ties to the lower id
     counts = np.bincount(ids, minlength=split.dim)
     want = np.argsort(-counts, kind="stable")[:k]
@@ -123,15 +173,26 @@ def test_the_split_pack_holds_every_entry_once_and_restores_the_table(
     assert (split.hot_ids == split.hot_ids[0]).all()
     # every stored entry in exactly one part
     hot_held = int(np.count_nonzero(split.floats[:, :split.width]))
-    cold_held = int((split.cold_ints[:, 1] < split.mb).sum())
+    cold_held = int(held.sum())
+    assert cold_held == int(np.count_nonzero(split.cold_vals))
     assert hot_held == split.n_hot_entries
     assert hot_held + cold_held == split.n_entries == len(ids)
     is_hot = np.isin(ids, want)
     assert split.n_hot_entries == int(is_hot.sum())
     if name == "dim_under_k":
-        assert cold_held == 0
+        assert cold_held == 0 and split.cold_slots == 512
     else:
         assert 0 < cold_held < len(ids)
+    if name.startswith("all_cold_rows"):
+        # a row that is all cold leaves no value in the coded planes and
+        # has a place in every cold plane up to its own width
+        row_width = int(indptr[4] - indptr[3])
+        (place,) = np.flatnonzero(split.order[0] == 3)
+        assert lengths[0, row_width - 1] > place
+        assert not split.floats[0, :split.width, place].any()
+    if name == "a_step_without_cold" and n_dev == 1:
+        assert held[1] == 0 and not lengths[1].any()
+        assert np.array_equal(split.order[1], np.arange(split.mb))
     # and the two parts together are the table
     if name != "whole_hot_table":  # (25000 x 200000 is not laid dense)
         table = np.zeros((split.n_rows, split.dim))
@@ -139,7 +200,7 @@ def test_the_split_pack_holds_every_entry_once_and_restores_the_table(
                                     np.diff(indptr)), ids), values)
         np.testing.assert_array_equal(_dense(split, n_dev), table)
         np.testing.assert_array_equal(_dense(plain, n_dev), table)
-    assert split.step_slots == split.width * split.mb + split.cold_pad
+    assert split.step_slots == split.width * split.mb + split.cold_slots
 
 
 @pytest.mark.parametrize("with_intercept", [True, False],
@@ -190,8 +251,11 @@ def _mesh_devices():
 
 
 @pytest.mark.parametrize("kind,with_intercept", [
-    ("logistic", True), ("logistic", False), ("squared", True)])
-@pytest.mark.parametrize("name", ["ragged", "dim_under_k", "last_short_step"])
+    ("logistic", True), ("logistic", False), ("squared", True),
+    ("squared", False)])
+@pytest.mark.parametrize("name", ["ragged", "dim_under_k", "last_short_step",
+                                  "all_cold_rows_ragged",
+                                  "a_step_without_cold"])
 def test_a_three_epoch_split_fit_equals_the_row_regular_fit(
         name, kind, with_intercept, monkeypatch):
     n_dev = _mesh_devices()
@@ -254,21 +318,34 @@ def test_the_three_pieces_sum_to_the_float32():
 @pytest.mark.parametrize("hot_share,wins", [
     (0.909, True),    # the cell's table at 16384 features
     (0.848, True),    # at 4096
-    (0.70, True), (0.66, False),
-    (0.6, False),     # faster split by 4%: no room
-    (0.3, False), (0.016, False),   # a table hashed without skew
+    (0.82, True),     # thinned by a tenth: x3.51 on the chip (PR 36)
+    (0.70, True), (0.66, True),   # under the threshold of PR 30's cold list
+    (0.55, True),     # thinned by four tenths: x1.84
+    (0.35, True), (0.31, True),
+    (0.28, False),    # thinned by seven tenths: x1.25, the rule's room
+    (0.1, False),     # break-even by the costs
+    (0.016, False),   # a table hashed without skew
 ])
 def test_the_rule_by_the_hot_share(hot_share, wins):
     slots = 39 * 32768
     assert common._hot_split_wins(hot_share, slots, slots) is wins
 
 
+def test_the_rule_reckons_a_cold_slot_at_two_random_accesses():
+    """The constant is PR 36's reading of the cold list laid plane by
+    plane: about a row-regular slot's two accesses, not segment-COO's
+    four."""
+    assert common._COLD_SLOT_NS == 13.8
+    assert 0.9 < common._COLD_SLOT_NS / common._ELL_SLOT_NS < 1.1
+    assert common._HOT_SPLIT_ROOM == 0.8 and common._HOT_SLOT_NS == 1.5
+
+
 def test_a_ragged_table_pays_the_hot_lookup_on_every_slot():
     # half the slots hold entries: the cold list is half as long for the
     # same share, and the split wins from a lower share
     slots = 39 * 32768
-    assert common._hot_split_wins(0.5, slots, slots // 2)
-    assert not common._hot_split_wins(0.5, slots, slots)
+    assert common._hot_split_wins(0.2, slots, slots // 2)
+    assert not common._hot_split_wins(0.2, slots, slots)
 
 
 def _uniform(rows, dim, width, seed):
@@ -292,7 +369,7 @@ def test_a_skewed_table_engages_and_a_uniform_one_packs_as_the_parent(
         assert (stack.hot_ids is not None) == engages
         assert stack.hot_declined == (not engages)
         if engages:
-            assert 0.68 < stack.n_hot_entries / stack.n_entries < 1.0
+            assert 0.3 < stack.n_hot_entries / stack.n_entries < 1.0
             continue
         # declined: the parent's leaves, byte for byte
         monkeypatch.setattr(common, "_hot_split_measured", lambda: False)
@@ -351,10 +428,25 @@ def test_an_estimator_fit_takes_the_split_and_counts_it(
     assert "train.sparse_hot_declined" not in counted
     assert counted["train.sparse_hot_entries"] == stack.n_hot_entries * epochs
     assert counted["train.sparse_entries"] == len(ids) * epochs
-    assert 0.68 < counted["train.sparse_hot_entries"] \
+    assert 0.3 < counted["train.sparse_hot_entries"] \
         / counted["train.sparse_entries"] < 1.0
     assert counted["train.sparse_slots"] == \
-        (5 * 8 + stack.cold_pad) * blocks * epochs
+        (5 * 8 + stack.cold_slots) * blocks * epochs
+    # the mechanism's counter: the cold list's slots walked, which over
+    # the steps are the fullest step's cold entries rounded by padded_nnz
+    held = stack.cold_cuts[:, 1].sum(axis=1)
+    assert counted["train.sparse_cold_slots"] == \
+        stack.cold_slots * blocks * epochs
+    assert counted["train.sparse_cold_slots"] // (blocks * epochs) == \
+        common.padded_nnz(int(held.max()), 512)
+    assert held.sum() == stack.n_entries - stack.n_hot_entries
+    # and the pack's gauges, said once in its own phase
+    gauges = obs.registry().snapshot()["gauges"]
+    assert gauges["pack_sparse.cold_step_slots"] == stack.cold_slots
+    assert gauges["pack_sparse.cold_planes"] == \
+        np.count_nonzero(stack.cold_cuts[:, 1].max(axis=0))
+    assert 1 <= gauges["pack_sparse.cold_planes"] <= 5
+    assert gauges["pack_sparse.ell_step_slots"] == 5 * 8
     assert counted["train.pallas_interpreted"] == 1  # on the CPU, and said
     # the unsplit fit of the same table gives the same model (the pool
     # holds placed leaves by the table's content: a process does not change
@@ -386,6 +478,9 @@ def test_a_declined_fit_counts_itself_and_runs_the_unsplit_step(
     assert counted["train.sparse_hot_fits"] == 0  # there from the first fit
     assert counted["train.sparse_hot_declined"] == 1
     assert "train.sparse_hot_entries" not in counted
+    assert "train.sparse_cold_slots" not in counted
+    assert "pack_sparse.cold_step_slots" not in \
+        obs.registry().snapshot()["gauges"]
     assert "train.pallas_interpreted" not in counted
     assert counted["train.sparse_slots"] == 5 * 8 * n_dev * -(-500 // (8 * n_dev))
 
@@ -408,12 +503,11 @@ def test_the_split_program_is_jit_bundled_and_carries_the_hot_scope(
     assert lowered.as_text().startswith("module @jit_bundled")
     scopes = set(re.findall(r"fmt\.[a-z_.]+",
                             lowered.as_text(debug_info=True)))
-    # the cold list runs segment-CSR's four operations, under their names
+    # the cold list's two random-access operations under their names; no
+    # sum by row id and no take of the error, which segment-CSR names
     assert scopes == {"fmt.train", "fmt.train.sparse.forward",
                       "fmt.train.sparse.backward", "fmt.train.sparse.hot",
                       "fmt.train.sparse.take_weights",
-                      "fmt.train.sparse.row_sum",
-                      "fmt.train.sparse.take_error",
                       "fmt.train.sparse.scatter",
                       "fmt.train.grad", "fmt.train.update",
                       "fmt.train.bundle"}

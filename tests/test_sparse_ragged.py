@@ -24,8 +24,8 @@ entry counts differ, made by the benchmark's generator
 * a fit through the classed layout on the suite's 1-D mesh of CPU devices
   matches the fit on one device;
 * segment-CSR's four random-access operations carry their scopes in the
-  step's jaxpr, on the segment-CSR step and on the split step's cold list,
-  and the classed step carries the two that remain.
+  step's jaxpr; the split step's cold list (laid plane by plane since PR
+  36) and the classed step carry the two that remain.
 """
 
 import os
@@ -334,7 +334,7 @@ def test_a_table_of_one_width_packs_as_the_parent_packed_it(name):
     width, mb, steps = int(counts.max()), BATCH // n_dev, -(-ROWS // BATCH)
     want = common._pack_ell(
         column, y, _parents_bounds(indptr, n_dev, mb, steps), counts, width,
-        mb, steps, DIM, n_dev, 512, None)
+        mb, steps, DIM)
     assert (stack.steps, stack.mb, stack.width, stack.dim) == \
         (want.steps, want.mb, want.width, want.dim) == (steps, mb, width, DIM)
     assert stack.ints.tobytes() == want.ints.tobytes()
@@ -468,7 +468,7 @@ def test_the_classed_step_carries_the_two_scopes_that_remain():
 
 
 @pytest.mark.parametrize("step", ["segment_csr", "split_cold_list"])
-def test_the_four_random_access_operations_carry_their_scopes(step):
+def test_the_random_access_operations_carry_their_scopes(step):
     mb, nnz_pad, width, k = 128, 512, 4, common._HOT_K
     params = (jnp.zeros((DIM,), jnp.float32), jnp.zeros((), jnp.float32))
     if step == "segment_csr":
@@ -480,16 +480,24 @@ def test_the_four_random_access_operations_carry_their_scopes(step):
                                            interpret=True)
         xs = ((jnp.zeros((1, width, mb), jnp.int32),
                jnp.zeros((1, width + 2, mb), jnp.float32),
-               jnp.zeros((1, 2, nnz_pad), jnp.int32),
+               jnp.zeros((1, nnz_pad), jnp.int32),
                jnp.zeros((1, nnz_pad), jnp.float32),
+               jnp.zeros((1, 2, width), jnp.int32),
                jnp.zeros((1, k), jnp.int32)), jnp.int32(0))
     text = _lowered(fn, params, xs)
     scopes = set(re.findall(r"fmt\.[a-z_.]+", text))
-    assert SEGMENT_SCOPES <= scopes
-    assert ("fmt.train.sparse.hot" in scopes) == (step == "split_cold_list")
+    # segment-CSR runs four; the split step's cold list, laid plane by
+    # plane since PR 36, the two that a row-regular layout leaves
+    split = step == "split_cold_list"
+    assert (SEGMENT_SCOPES <= scopes) == (not split)
+    assert ("fmt.train.sparse.hot" in scopes) == split
+    assert ("fmt.train.sparse.row_sum" in scopes) == (not split)
+    assert ("fmt.train.sparse.take_error" in scopes) == (not split)
     # each inside its half of the step, with the operation it names
-    for path in ("forward/fmt.train.sparse.take_weights/mul",
-                 "forward/fmt.train.sparse.row_sum/scatter-add",
-                 "backward/fmt.train.sparse.take_error/mul",
-                 "backward/fmt.train.sparse.scatter/scatter-add"):
+    paths = ["forward/fmt.train.sparse.take_weights/mul",
+             "backward/fmt.train.sparse.scatter/scatter-add"]
+    if not split:
+        paths += ["forward/fmt.train.sparse.row_sum/scatter-add",
+                  "backward/fmt.train.sparse.take_error/mul"]
+    for path in paths:
         assert "/fmt.train.sparse." + path in text, path
