@@ -69,19 +69,37 @@ def resolve_features(
 
 @dataclass
 class MinibatchStack:
-    """Device-major stacked minibatches with a padding mask.
+    """Device-major stacked minibatches with a padding mask, in ONE host
+    array: ``combined`` ``(n_dev*steps, mb, d+2)`` — features, then the
+    label, then the weight: the slab the fused program scans.
 
-    ``x``/``y``/``w`` have leading dims ``(n_dev * steps, mb)`` — dim 0 is
-    sharded over the ``data`` mesh axis, so each device scans ``steps`` local
-    minibatches of ``mb`` rows.  ``w`` is 1.0 for real rows, 0.0 for padding.
+    ``x``/``y``/``w`` are views of it with leading dims ``(n_dev * steps,
+    mb)`` — dim 0 is sharded over the ``data`` mesh axis, so each device
+    scans ``steps`` local minibatches of ``mb`` rows.  ``w`` is 1.0 for real
+    rows, 0.0 for padding.
     """
 
-    x: np.ndarray  # (n_dev*steps, mb, d)
-    y: np.ndarray  # (n_dev*steps, mb)
-    w: np.ndarray  # (n_dev*steps, mb)
+    combined: np.ndarray  # (n_dev*steps, mb, d+2)
     steps: int
     mb: int
     n_rows: int = 0  # true (un-padded) row count, for throughput metrics
+
+    @property
+    def x(self) -> np.ndarray:  # (n_dev*steps, mb, d)
+        return self.combined[..., :-2]
+
+    @property
+    def y(self) -> np.ndarray:  # (n_dev*steps, mb)
+        return self.combined[..., -2]
+
+    @property
+    def w(self) -> np.ndarray:  # (n_dev*steps, mb)
+        return self.combined[..., -1]
+
+
+#: the dense pack takes one more thread for every 16 MB it lays: a small
+#: table (the tests', a serving batch) is laid on the caller's thread
+_PACK_BYTES_A_THREAD_SHIFT = 24
 
 
 @obs.phased("pack_dense")
@@ -100,20 +118,18 @@ def pack_minibatches(
     rows carry weight 0 so sums/counts are exact.  ``min_steps`` floors the
     step count (whole-pad steps are all-zero-weight) — the out-of-core feed
     uses it so every chunk shares one compiled program shape.
+
+    The table is copied ONCE, straight into the slab the device will hold
+    (features, label and weight side by side, device-major), a device's
+    minibatch at a time over the machine's cores: a table that needs a
+    host's four chips is 25 GB, and a padded copy, a transposed copy and a
+    concatenated copy of it beside it were three times that.
     """
     n, d = X.shape
     if global_batch_size <= 0:
         global_batch_size = max(n, n_dev)
     mb = max(1, -(-global_batch_size // n_dev))  # per-device minibatch rows
     steps = max(max(1, -(-n // (mb * n_dev))), int(min_steps))
-    n_pad = steps * mb * n_dev
-
-    Xp = np.zeros((n_pad, d), dtype=dtype)
-    yp = np.zeros((n_pad,), dtype=dtype)
-    wp = np.zeros((n_pad,), dtype=dtype)
-    Xp[:n] = X
-    yp[:n] = y
-    wp[:n] = 1.0
 
     # step-major rows in a device-contiguous layout: global SGD step s
     # consumes rows [s*G, (s+1)*G) where G = n_dev*mb — the reference's
@@ -122,10 +138,28 @@ def pack_minibatches(
     # axis; crucially the step->rows mapping does not depend on the total
     # row count, so a chunked (out-of-core) feed cut at G-row boundaries
     # replays the identical update schedule (lib/out_of_core.py).
-    Xp = Xp.reshape(steps, n_dev, mb, d).transpose(1, 0, 2, 3).reshape(n_dev * steps, mb, d)
-    yp = yp.reshape(steps, n_dev, mb).transpose(1, 0, 2).reshape(n_dev * steps, mb)
-    wp = wp.reshape(steps, n_dev, mb).transpose(1, 0, 2).reshape(n_dev * steps, mb)
-    return MinibatchStack(x=Xp, y=yp, w=wp, steps=steps, mb=mb, n_rows=n)
+    comb = np.empty((n_dev * steps, mb, d + 2), dtype=dtype)
+
+    def lay(block):  # the block-th mb rows of the table: step s, device k
+        s, k = divmod(block, n_dev)
+        lo = min(block * mb, n)
+        m = min(lo + mb, n) - lo
+        out = comb[k * steps + s]
+        out[:m, :d] = X[lo:lo + m]
+        out[:m, d] = y[lo:lo + m]
+        out[:m, d + 1] = 1.0
+        out[m:] = 0.0
+
+    blocks = n_dev * steps
+    threads = min(blocks, os.cpu_count() or 1,
+                  max(1, comb.nbytes >> _PACK_BYTES_A_THREAD_SHIFT))
+    if threads > 1:
+        with ThreadPoolExecutor(threads) as pool:
+            list(pool.map(lay, range(blocks)))
+    else:
+        for block in range(blocks):
+            lay(block)
+    return MinibatchStack(combined=comb, steps=steps, mb=mb, n_rows=n)
 
 
 # A gradient function: (params, x_mb, y_mb, w_mb) ->
@@ -151,6 +185,17 @@ def make_sgd_update(learning_rate: float, l2: float):
         )
 
     return update
+
+
+def _psum_step(grads, loss_sum, w_sum):
+    """An SGD step's collectives: the sums of every gradient leaf, of the
+    loss and of the row weights over the ``data`` axis, under a scope of
+    their own (``fmt.train.psum``, inside the caller's ``fmt.train.grad``)
+    so that a trace names the all-reduces of every family's fit.  One
+    ``psum`` a leaf and two more: what ``train.psum_calls`` counts."""
+    with jax.named_scope("fmt.train.psum"):
+        grads = jax.tree_util.tree_map(lambda g: psum(g, "data"), grads)
+        return grads, psum(loss_sum, "data"), psum(w_sum, "data")
 
 
 @dataclass
@@ -1141,9 +1186,8 @@ def make_glm_epoch_step(
         def mb_step(p, xs):
             xb, yb, wb = xs
             grads, loss_sum, w_sum = grad_fn(p, xb, yb, wb)
-            grads = jax.tree_util.tree_map(lambda g: psum(g, "data"), grads)
-            loss_sum = psum(loss_sum, "data")
-            w_sum = psum(w_sum, "data")
+            with jax.named_scope("fmt.train.grad"):
+                grads, loss_sum, w_sum = _psum_step(grads, loss_sum, w_sum)
             count = jnp.maximum(w_sum, 1.0)
             new_p = sgd_update(p, grads, count)
             return new_p, (loss_sum / count, w_sum)
@@ -1181,27 +1225,14 @@ class TrainResult:
 
 
 def _combined_view(stack: MinibatchStack) -> np.ndarray:
-    """x, y, w packed into one (n_dev*steps, mb, d+2) array — a single
-    host->device transfer instead of three (one placement, one pooled slab,
-    one scanned operand in the fused program)."""
+    """x, y, w in one (n_dev*steps, mb, d+2) array — a single host->device
+    transfer instead of three (one placement, one pooled slab, one scanned
+    operand in the fused program).  The pack lays it (no copy here: the span
+    stays for the readers of a placement's parts), and every call presents
+    the SAME host array, so the slab pool's identity keying hits on a
+    repeated fit from a retained stack."""
     with obs.span("place.host_view"):
-        return np.concatenate(
-            [stack.x, stack.y[..., None], stack.w[..., None]], axis=2
-        )
-
-
-def _combined_view_memo(stack: MinibatchStack) -> np.ndarray:
-    """Per-stack memo of :func:`_combined_view`: repeated fused fits from
-    the SAME stack must present the SAME host array, or the slab pool's
-    identity keying would see a fresh buffer (and re-place) every call.
-    Estimator paths supply a pooled ``device_batch`` and never reach this;
-    it serves direct ``train_glm`` callers (tests, sweeps over a retained
-    stack)."""
-    comb = getattr(stack, "_combined_memo", None)
-    if comb is None:
-        comb = _combined_view(stack)
-        stack._combined_memo = comb
-    return comb
+        return stack.combined
 
 
 def _build_fused_train_fn(key, mb_grad_step, mesh, learning_rate, reg,
@@ -1275,10 +1306,7 @@ def _build_fused_train_fn(key, mb_grad_step, mesh, learning_rate, reg,
         def mb_step(p, xs):
             grads, loss_sum, w_sum = grad_step(p, xs)
             with jax.named_scope("fmt.train.grad"):
-                grads = jax.tree_util.tree_map(
-                    lambda g: psum(g, "data"), grads)
-                loss_sum = psum(loss_sum, "data")
-                w_sum = psum(w_sum, "data")
+                grads, loss_sum, w_sum = _psum_step(grads, loss_sum, w_sum)
             with jax.named_scope("fmt.train.update"):
                 count = jnp.maximum(w_sum, 1.0)
                 new_p = sgd_update(p, grads, count)
@@ -1376,6 +1404,9 @@ def _build_fused_train_fn(key, mb_grad_step, mesh, learning_rate, reg,
     # attrs ride a plain closure: jit wrappers don't reliably accept them
     train_fn.bundle_fetch = True
     train_fn.loss_hist_len = int(max_iter)
+    #: the minibatch steps' collectives are in the program (not an
+    #: ``epoch_fn``'s own): _run_fused_train counts them from shapes
+    train_fn.step_psums = epoch_fn is None
     return _cache_put(key, train_fn, fused=True)
 
 
@@ -1485,6 +1516,7 @@ def _run_fused_train(train_fn, init_params, batch, mesh,
         )
         step["call_latency_ms"] = step["seconds"] * 1e3
         obs.counter_add("train.fused_runs")
+        _count_collectives(train_fn, mesh, placed, device_batch, n_epochs)
         # of those, the fits whose program holds the one-pass kernel (0
         # keeps the counter there for a reader to find)
         obs.counter_add("train.onepass_fits",
@@ -1521,6 +1553,30 @@ def _run_fused_train(train_fn, init_params, batch, mesh,
         final_delta=float(fetched[-1]),
         metrics=metrics,
     )
+
+
+def _count_collectives(train_fn, mesh, placed, device_batch,
+                       n_epochs: int) -> None:
+    """Beside ``train.fused_runs``: over how many shards of the ``data`` axis
+    the fit ran (``train.data_shards``) and, for a program whose minibatch
+    steps sum over it (:func:`_psum_step`), what the program ASKED of the
+    collective, from shapes alone: ``train.psum_calls`` (one a parameter
+    leaf and two more, a step, an epoch) and ``train.psum_bytes`` (what
+    those carry: a device's shard of every leaf, and two scalars of the
+    first leaf's type).  A trace's all-reduce time is set against them."""
+    from flink_ml_tpu.parallel.mesh import data_parallel_size
+
+    shards = data_parallel_size(mesh)
+    obs.counter_add("train.data_shards", shards)
+    if not getattr(train_fn, "step_psums", False):
+        return
+    leaves = jax.tree_util.tree_leaves(placed)
+    steps = jax.tree_util.tree_leaves(device_batch)[0].shape[0] // shards
+    sizes = [int(np.prod(a.sharding.shard_shape(a.shape))) * a.dtype.itemsize
+             for a in leaves]
+    a_step = sum(sizes) + 2 * leaves[0].dtype.itemsize
+    obs.counter_add("train.psum_calls", (len(leaves) + 2) * steps * n_epochs)
+    obs.counter_add("train.psum_bytes", a_step * steps * n_epochs)
 
 
 def make_glm_train_fn(
@@ -3378,9 +3434,8 @@ def _pressure_window_fn(grad_fn: GradFn, mesh, learning_rate: float,
             grads, loss_sum, w_sum = grad_fn(
                 p, mb[..., :-2], mb[..., -2], mb[..., -1]
             )
-            grads = jax.tree_util.tree_map(lambda g: psum(g, "data"), grads)
-            loss_sum = psum(loss_sum, "data")
-            w_sum = psum(w_sum, "data")
+            with jax.named_scope("fmt.train.grad"):
+                grads, loss_sum, w_sum = _psum_step(grads, loss_sum, w_sum)
             count = jnp.maximum(w_sum, 1.0)
             return sgd_update(p, grads, count), (loss_sum / count, w_sum)
 
@@ -3411,8 +3466,8 @@ def _pressure_grad_fn(grad_fn: GradFn, mesh, c: int):
         grads, loss_sum, w_sum = grad_fn(
             params, mb[..., :-2], mb[..., -2], mb[..., -1]
         )
-        grads = jax.tree_util.tree_map(lambda g: psum(g, "data"), grads)
-        return grads, psum(loss_sum, "data"), psum(w_sum, "data")
+        with jax.named_scope("fmt.train.grad"):
+            return _psum_step(grads, loss_sum, w_sum)
 
     from jax.sharding import PartitionSpec as P
 
@@ -3498,7 +3553,7 @@ def _train_glm_pressure(init_params, stack: MinibatchStack,
     from flink_ml_tpu.fault.retry import with_retry
     from flink_ml_tpu.parallel.mesh import replicate, shard_batch
 
-    comb = _combined_view_memo(stack)
+    comb = _combined_view(stack)
     steps, mb = stack.steps, stack.mb
     n_dev = comb.shape[0] // max(steps, 1)
     group_rows = n_dev * mb
@@ -3696,7 +3751,7 @@ def train_glm(
             return _run_fused_train(
                 train_fn, init_params,
                 device_batch if device_batch is not None
-                else _combined_view_memo(stack),
+                else _combined_view(stack),
                 mesh, batch_preplaced=device_batch is not None,
                 n_rows=stack.n_rows,
             )

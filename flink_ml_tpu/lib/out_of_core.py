@@ -51,6 +51,7 @@ from flink_ml_tpu.lib.common import (
     _cache_put,
     _combined_view,
     _meta_converged,
+    _psum_step,
     fetch_flat,
     make_sgd_update,
     pack_minibatches,
@@ -86,9 +87,8 @@ def make_chunk_step_fn(key, mb_grad_step, mesh, learning_rate: float, reg: float
         def mb_step(c, xs):
             p, loss_acc, w_acc = c
             grads, loss_sum, w_sum = mb_grad_step(p, xs)
-            grads = jax.tree_util.tree_map(lambda g: psum(g, "data"), grads)
-            loss_sum = psum(loss_sum, "data")
-            w_sum = psum(w_sum, "data")
+            with jax.named_scope("fmt.train.grad"):
+                grads, loss_sum, w_sum = _psum_step(grads, loss_sum, w_sum)
             count = jnp.maximum(w_sum, 1.0)
             new_p = sgd_update(p, grads, count)
             live = w_sum > 0.0

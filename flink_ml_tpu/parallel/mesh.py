@@ -223,45 +223,77 @@ def _concat_placed_fn(mesh: Mesh, spec: P, n_parts: int):
     consumes.  lru_cached so repeated placements reuse the compiled
     executable (jit's own cache then covers varying shapes per arity).
 
+    Over more than one shard of ``spec``'s row axis every slice holds a
+    piece of EACH shard's block (:func:`_put_slices`), and the concat runs
+    under ``shard_map``: every device joins the pieces it was sent, nothing
+    crosses between devices and none waits for another's.
+
     The assembly transiently holds the slices alongside the full output (a
     ~2x device-memory spike at exactly the sizes this path targets).  The
     slices are NOT donated: XLA can only alias a donated input to an output
     of its own shape, and no slice has the output's — on the v5e every
     slice came back "Some donated buffers were not usable" (PR 21), so the
     donation this function used to request never freed anything."""
-    sharding = NamedSharding(mesh, spec)
 
     def concat(*parts):
         import jax.numpy as jnp
 
         return jnp.concatenate(parts, axis=0)
 
-    return jax.jit(concat, out_shardings=sharding)
+    if _row_shards(mesh, spec) == 1:
+        return jax.jit(concat, out_shardings=NamedSharding(mesh, spec))
+    from flink_ml_tpu.parallel.collectives import shard_map
+
+    return jax.jit(shard_map(concat, mesh=mesh, in_specs=(spec,) * n_parts,
+                             out_specs=spec))
+
+
+def _row_shards(mesh: Mesh, spec: P) -> int:
+    """Over how many shards ``spec`` divides dim 0."""
+    return dict(mesh.shape).get(spec[0], 1) if len(spec) else 1
 
 
 def _put_slices(mesh: Mesh, x: np.ndarray, spec: P, chunk_bytes: int):
-    """Double-buffered H2D placement of one host array: dim 0 splits into
-    shard-aligned slices and a background thread enqueues each slice's
-    async device_put (the ``_prefetch`` idiom from lib/out_of_core.py —
-    host staging of slice N+1 overlaps the DMA of slice N).  Returns the
-    placed slices, for :func:`_concat_placed_fn` to reassemble under the
-    final sharding."""
+    """Double-buffered H2D placement of one host array: every shard's block
+    of dim 0 (one block on one device; the k-th ``1/n`` of dim 0 on the
+    k-th of ``n`` devices) is cut into slices, and a background thread
+    enqueues each slice's async device_put straight to the device that
+    will hold it (the ``_prefetch`` idiom from lib/out_of_core.py — host
+    staging of slice N+1 overlaps the DMA of slice N), the devices taking
+    turns slice by slice so that their copies run side by side.  Returns
+    the placed slices — the j-th an array whose shard on a device is the
+    j-th slice of that device's block — for :func:`_concat_placed_fn` to
+    reassemble under the final sharding.  ``place.h2d`` times the enqueue
+    of one device's slice."""
     from flink_ml_tpu.utils.prefetch import prefetch_iter
 
     sharding = NamedSharding(mesh, spec)
-    # slices must keep dim 0 divisible by the sharded axis size
-    unit = dict(mesh.shape).get(spec[0], 1) if len(spec) else 1
+    shards = _row_shards(mesh, spec)
+    block = x.shape[0] // shards  # dim-0 rows a shard holds
     row_bytes = max(x.nbytes // max(x.shape[0], 1), 1)
-    rows_per_chunk = max(unit, (chunk_bytes // (row_bytes * unit)) * unit)
-    bounds = list(range(0, x.shape[0], rows_per_chunk))
+    rows_per_chunk = max(1, chunk_bytes // (row_bytes * shards))
+    bounds = list(range(0, block, rows_per_chunk))
     if len(bounds) < 2:
-        return [jax.device_put(x, sharding)]
+        with obs.span("place.h2d"):
+            return [jax.device_put(x, sharding)]
+
+    # (device, where its block starts in dim 0), replicas included
+    owners = [(device, index[0].start or 0) for device, index in
+              sharding.addressable_devices_indices_map(x.shape).items()]
 
     def pieces():
         for lo in bounds:
-            # device_put returns immediately (async DMA); issuing it from
-            # the producer thread pipelines staging against the transfer
-            yield jax.device_put(x[lo : lo + rows_per_chunk], sharding)
+            hi = min(lo + rows_per_chunk, block)
+            placed = []
+            for device, start in owners:
+                # device_put returns immediately (async DMA); issuing it
+                # from the producer thread pipelines staging against the
+                # transfer
+                with obs.span("place.h2d"):
+                    placed.append(
+                        jax.device_put(x[start + lo:start + hi], device))
+            yield jax.make_array_from_single_device_arrays(
+                (shards * (hi - lo),) + x.shape[1:], sharding, placed)
 
     return list(prefetch_iter(pieces(), depth=2, name="h2d-prefetch"))
 
@@ -289,18 +321,20 @@ def shard_batch_prefetched(mesh: Mesh, batch, axis: str = "data",
         min_bytes = _CHUNKED_MIN_BYTES_DEFAULT
 
     def _put(x):
-        # the span is the host's side of a leaf's copy: device_put is
-        # asynchronous and nothing waits here, so it ends when the last
-        # slice is ENQUEUED, not when it has arrived
-        with obs.span("place.h2d"):
-            if getattr(x, "ndim", 0) < 1:
+        # ``place.h2d`` is the host's side of a copy, once a leaf or, of a
+        # leaf cut into slices, once a device's slice: device_put is
+        # asynchronous and nothing waits here, so it ends when the copy is
+        # ENQUEUED, not when it has arrived
+        if getattr(x, "ndim", 0) < 1:
+            with obs.span("place.h2d"):
                 return jax.device_put(x, NamedSharding(mesh, P()))
-            x = np.asarray(x)
-            if x.nbytes < max(min_bytes, 2 * chunk_bytes):
+        x = np.asarray(x)
+        if x.nbytes < max(min_bytes, 2 * chunk_bytes):
+            with obs.span("place.h2d"):
                 return jax.device_put(x, NamedSharding(mesh, P(axis)))
-            parts = _put_slices(mesh, x, P(axis), chunk_bytes)
-        # outside it: the reassembling program, which a cold compile cache
-        # compiles here
+        parts = _put_slices(mesh, x, P(axis), chunk_bytes)
+        # outside the spans: the reassembling program, which a cold compile
+        # cache compiles here
         if len(parts) == 1:
             return parts[0]
         return _concat_placed_fn(mesh, P(axis), len(parts))(*parts)

@@ -209,6 +209,17 @@ def pytree_nbytes(value) -> int:
     )
 
 
+def _devices_held(value) -> int:
+    """On how many devices a placed pytree lies (``place.devices``)."""
+    import jax
+
+    held = set()
+    for leaf in jax.tree_util.tree_leaves(value):
+        if isinstance(leaf, jax.Array):
+            held |= leaf.sharding.device_set
+    return len(held)
+
+
 # -- the pool -----------------------------------------------------------------
 
 
@@ -502,6 +513,7 @@ class SlabPool:
                                     jax.process_count() == 1)
             obs.counter_add("slab_pool.misses")
             obs.counter_add("slab_pool.bytes_placed", nbytes)
+            obs.counter_add("place.devices", _devices_held(value))
             self._record_gauges_locked()
         self._notify_evictions()
         return value

@@ -4,9 +4,11 @@
         --seed 3500000001 [--seconds 10] [--trace 1] [--cold] [--out DIR]
 
 Runs ``chipbench.run`` in this process as the driver would (TPU only), with
-the two per-layer entries that wait in
-``tests/chipbench_tests/test_setup_compile_readers.py`` appended to the
-benchmark IN MEMORY (``BENCHMARK.json`` on disk is not touched), and reads
+the per-layer entries that wait in
+``tests/chipbench_tests/test_setup_compile_readers.py`` (or what waits in
+every file named with ``--waiting``, e.g. ``--waiting test_dp4_cell.py``: a
+whole cell there, and two entries) appended to the benchmark IN MEMORY
+(``scripts/waiting.py``; ``BENCHMARK.json`` on disk is not touched), and reads
 what the harness does not print: the registry's ``compile.*`` timings and
 counters over set-up and over the window (the harness's own three snapshots),
 the same seconds by causing span (``compile.under/<span>``) and the programs
@@ -22,10 +24,10 @@ from __future__ import annotations
 
 import argparse
 import contextlib
-import importlib.util
 import io
 import json
 import os
+import resource
 import shutil
 import sys
 import time
@@ -37,13 +39,8 @@ EVENT_KEYS = ("program", "span", "trace_s", "lower_s", "backend_s", "cache",
               "cache_read_s")
 
 
-def _waiting_entries() -> list:
-    path = os.path.join(ROOT, "tests", "chipbench_tests",
-                        "test_setup_compile_readers.py")
-    spec = importlib.util.spec_from_file_location("_waiting", path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return list(module.WAITING.values())
+#: the test files whose waiting entries a run appends, by default
+WAITING_IN = ("test_setup_compile_readers.py",)
 
 
 def _delta(before: dict, after: dict) -> dict:
@@ -62,6 +59,17 @@ def _delta(before: dict, after: dict) -> dict:
     return out
 
 
+def _timings(before: dict, after: dict) -> dict:
+    """What a phase added under every timing that is no ``compile.*``."""
+    out = {}
+    for name, t in sorted(after["timings"].items()):
+        b = before["timings"].get(name, {"count": 0, "total_s": 0.0})
+        if not name.startswith("compile.") and t["count"] - b["count"]:
+            out[name] = {"count": t["count"] - b["count"],
+                         "seconds": t["total_s"] - b["total_s"]}
+    return out
+
+
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(prog="python scripts/setup_account.py")
     parser.add_argument("--workload", required=True)
@@ -69,6 +77,9 @@ def main(argv=None) -> int:
     parser.add_argument("--seconds", type=float, default=10.0)
     parser.add_argument("--trace", type=int, choices=(0, 1), default=1)
     parser.add_argument("--cold", action="store_true")
+    parser.add_argument("--waiting", action="append", default=None,
+                        help="a file of tests/chipbench_tests whose waiting "
+                             "entries to append (default: PR 35's two)")
     parser.add_argument("--out", default=os.path.join(
         ROOT, "chiprun_out", "setup_account"))
     args = parser.parse_args(argv)
@@ -84,15 +95,9 @@ def main(argv=None) -> int:
     sys.path.insert(0, ROOT)
     from chipbench import program, run
 
-    load_json = run.load_json
-    waiting = _waiting_entries()
+    from waiting import overlay  # scripts/waiting.py, beside this file
 
-    def with_the_waiting_entries(*parts):
-        loaded = load_json(*parts)
-        if parts[-1] == "BENCHMARK.json":
-            loaded["per_layer"] = loaded["per_layer"] + waiting
-        return loaded
-
+    overlay(args.waiting or WAITING_IN)
     marks = []  # the harness's snapshots: start, set-up's end, window's end
     snapshot = program.snapshot
 
@@ -101,7 +106,7 @@ def main(argv=None) -> int:
         marks.append((time.monotonic(), snap))
         return snap
 
-    run.load_json, program.snapshot = with_the_waiting_entries, marked
+    program.snapshot = marked
     printed = io.StringIO()
     with contextlib.redirect_stdout(printed):
         rc = run.main(["--workload", args.workload, "--seed", str(args.seed),
@@ -153,6 +158,15 @@ def main(argv=None) -> int:
         "check_programs": len(phases["check"]),  # the reference's own
         "metrics": {k: v["value"] for k, v in result["metrics"].items()},
         "window_fits": record["jobs_done"], "job_s": record["job_s"],
+        "device": result["device"], "breakdown": result.get("breakdown"),
+        "check_s": record["check_s"],
+        # set-up by the program's own spans (pack, host view, placement,
+        # dispatch, sync ...): seconds and observations of each
+        "setup_spans": _timings(start, setup),
+        # the process's largest resident set, KiB on Linux: the host's side
+        # of a table that fills a host's chips
+        "host_peak_rss_bytes": 1024 * resource.getrusage(
+            resource.RUSAGE_SELF).ru_maxrss,
     }
     os.makedirs(args.out, exist_ok=True)
     name = (f"{args.workload}-{'cold' if args.cold else 'warm'}-"

@@ -255,7 +255,7 @@ class TestTheStepInsideTheFusedFit:
         X = rng.randn(n, d).astype(np.float32)
         y = (X @ rng.randn(d) > 0).astype(np.float64)
         stack = common.pack_minibatches(X, y, n_dev, 128 * n_dev)
-        return common._combined_view_memo(stack), n
+        return common._combined_view(stack), n
 
     @pytest.mark.parametrize("kind", ["logistic", "squared"])
     @pytest.mark.parametrize("with_intercept", [True, False])

@@ -107,6 +107,46 @@ def test_four_chips_compile_under_strict_vma(topo, quiet_cache, monkeypatch):
     assert slab_layout(text) == "{1,2,0:T(8,128)}"
 
 
+def test_the_four_chip_cell_compiles_with_one_all_reduce_a_step(
+        topo, quiet_cache, monkeypatch):
+    """``mnist8m_lr_dp4.sweep``'s own program (PR 37): 62 steps of 32768 rows
+    a chip over a 2x2 host.  The kernel is in it, every chip holds its 6.39
+    GB of the slab where it lies, and the compiler joins the step's four
+    ``psum``s (weights, intercept, loss, count) into ONE all-reduce, named
+    under the program's ``fmt.train.psum`` scope."""
+    compiled = compiled_fit(topo, 4, 62, ROWS, 784, True, monkeypatch)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert slab_layout(text) == "{1,2,0:T(8,128)}"
+    reduces = re.findall(r"= .* all-reduce\(.*", text)
+    assert len(reduces) == 1
+    assert "f32[784]" in reduces[0] and "replica_groups={{0,1,2,3}}" in \
+        reduces[0]
+    assert "fmt.train.grad/fmt.train.psum" in reduces[0]
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes >= 62 * ROWS * 786 * 4  # a chip's
+    assert memory.temp_size_in_bytes < 1 << 20
+
+
+def test_a_sharded_slabs_slices_are_joined_with_no_collective(topo,
+                                                              quiet_cache):
+    """The placement's reassembly over four chips (PR 37): every device
+    joins the slices it was sent, into the layout the kernel reads."""
+    from flink_ml_tpu.parallel.mesh import _concat_placed_fn
+
+    mesh = Mesh(np.array(topo.devices[:4]), ("data",))
+    part = jax.ShapeDtypeStruct((4, ROWS, 786), jnp.float32,
+                                sharding=NamedSharding(mesh, P("data")))
+    compiled = _concat_placed_fn(mesh, P("data"), 62).lower(
+        *[part] * 62).compile()
+    text = compiled.as_text()
+    for collective in ("all-to-all", "collective-permute", "all-gather",
+                       "all-reduce"):
+        assert collective not in text
+    assert re.search(r"->\s*\(?f32\[62,32768,786\](\{[^}]*\})",
+                     text).group(1) == "{1,2,0:T(8,128)}"
+
+
 @pytest.mark.parametrize("shape,layout", [
     ((8, ROWS, 2002), "{1,0,2:T(8,128)}"),    # steps take the sublanes
     ((62, ROWS, 30), "{1,0,2:T(8,128)}"),     # chip_smoke's HIGGS shape

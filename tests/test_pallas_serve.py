@@ -433,7 +433,7 @@ class TestBundledTrainDispatch:
         C, grad_fn, stack = self._fit_ingredients()
         mesh = default_mesh(devices=jax.devices()[:width])
         init = (np.zeros(D), np.zeros(()))
-        batch = C._combined_view_memo(stack)
+        batch = C._combined_view(stack)
         plain = C._run_fused_train(
             C.make_glm_train_fn(grad_fn, mesh, 0.5, 0.0, 12, 0.0),
             init, batch, mesh, n_rows=N)
@@ -457,6 +457,6 @@ class TestBundledTrainDispatch:
         mesh = default_mesh(devices=jax.devices()[:1])
         fn = C.make_glm_train_fn(grad_fn, mesh, 0.5, 0.0, 3, 0.0)
         out = fn(replicate(mesh, (jnp.zeros(D), jnp.zeros(()))),
-                 shard_batch(mesh, C._combined_view_memo(stack)))
+                 shard_batch(mesh, C._combined_view(stack)))
         assert isinstance(out, tuple) and len(out) == 4
         assert not getattr(fn, "bundle_fetch", False)

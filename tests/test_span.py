@@ -42,7 +42,7 @@ CHILDREN = ("fit.prepare", "slab_pool.lookup", "train.place_params",
             "train.dispatch", "train.sync", "train.demux", "train.health",
             "fit.finish", "fit.report")
 MISS_ONLY = ("slab_pool.build", "place.host_view", "place.h2d")
-TRAIN_SCOPES = {"fmt.train", "fmt.train.scores", "fmt.train.grad",
+TRAIN_SCOPES = {"fmt.train", "fmt.train.scores", "fmt.train.grad", "fmt.train.psum",
                 "fmt.train.update", "fmt.train.bundle"}
 
 

@@ -39,7 +39,7 @@ CHILDREN = ("fit.prepare", "slab_pool.lookup", "train.place_params",
 MISS_ONLY = ("slab_pool.build", "place.h2d", "phase.pack_sparse",
              "phase.pack_sparse/pack_csr")
 SPARSE_SCOPES = {"fmt.train", "fmt.train.sparse.forward",
-                 "fmt.train.sparse.backward", "fmt.train.grad",
+                 "fmt.train.sparse.backward", "fmt.train.grad", "fmt.train.psum",
                  "fmt.train.update", "fmt.train.bundle"}
 #: segment-CSR's four random-access operations, inside forward and backward
 #: (PR 33); the row-regular step has one gather and one scatter, unnamed

@@ -509,6 +509,6 @@ def test_the_split_program_is_jit_bundled_and_carries_the_hot_scope(
                       "fmt.train.sparse.backward", "fmt.train.sparse.hot",
                       "fmt.train.sparse.take_weights",
                       "fmt.train.sparse.scatter",
-                      "fmt.train.grad", "fmt.train.update",
+                      "fmt.train.grad", "fmt.train.psum", "fmt.train.update",
                       "fmt.train.bundle"}
     assert "fmt.train.sparse.hot" in lowered.compile().as_text()
