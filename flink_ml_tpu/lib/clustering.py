@@ -339,7 +339,8 @@ def train_kmeans(
         result = _run_fused_train(
             make_kmeans_train_fn(mesh, k, n_epochs, tol,
                                  kernel_rows=kernel_rows),
-            (cents, jnp.zeros((n_epochs,) + cents.shape, jnp.float32)),
+            # host zeros: placed once, as the program frees none of them
+            (cents, np.zeros((n_epochs,) + cents.shape, np.float32)),
             batch if dev_batch is None else dev_batch, mesh,
             batch_preplaced=dev_batch is not None, n_rows=n_rows,
         )

@@ -22,6 +22,7 @@ rows applied in padded power-of-two buckets to bound jit recompiles.
 from __future__ import annotations
 
 import os
+import threading
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional, Sequence, Tuple
@@ -1278,7 +1279,10 @@ def _build_fused_train_fn(key, mb_grad_step, mesh, learning_rate, reg,
     forces it off.  The bundled program donates NOTHING: its one output is
     the flat result buffer, and XLA can only alias a donated input to an
     output of its own shape — on the v5e both the params and a donated
-    batch came back "Some donated buffers were not usable" (PR 21).
+    batch came back "Some donated buffers were not usable" (PR 21).  Every
+    fn built here says so in ``donates_params`` (``True`` unbundled,
+    ``False`` bundled): :func:`_run_fused_train` copies a caller's device
+    arrays only for a fn that donates them.
     """
     bundle = bundle and out_specs is None
     key = key + (bool(bundle),)
@@ -1372,8 +1376,9 @@ def _build_fused_train_fn(key, mb_grad_step, mesh, learning_rate, reg,
         check_vma=check_vma,
     )
     if not bundle:
-        return _cache_put(key, jax.jit(sharded, donate_argnums=(0,)),
-                          fused=True)
+        jitted = jax.jit(sharded, donate_argnums=(0,))
+        jitted.donates_params = True
+        return _cache_put(key, jitted, fused=True)
 
     # the dispatch-diet program (ISSUE 17): all four outputs are replicated
     # under the default out_specs, so raveling them into one buffer is
@@ -1401,13 +1406,47 @@ def _build_fused_train_fn(key, mb_grad_step, mesh, learning_rate, reg,
     def train_fn(placed, device_batch):
         return jitted(placed, device_batch)
 
-    # attrs ride a plain closure: jit wrappers don't reliably accept them
+    # the driver's attrs ride the closure the program is called through
     train_fn.bundle_fetch = True
     train_fn.loss_hist_len = int(max_iter)
+    train_fn.donates_params = False
     #: the minibatch steps' collectives are in the program (not an
     #: ``epoch_fn``'s own): _run_fused_train counts them from shapes
     train_fn.step_psums = epoch_fn is None
     return _cache_put(key, train_fn, fused=True)
+
+
+#: replicated zero starts, placed once a mesh, shape and dtype: read, never
+#: freed, by the programs that do not donate their params
+_PLACED_ZEROS: OrderedDict = OrderedDict()
+_PLACED_ZEROS_CAPACITY = 8
+_PLACED_ZEROS_LOCK = threading.Lock()
+
+
+def _place_start(mesh, init_params):
+    """``replicate(mesh, init_params)`` for a program that frees none of its
+    params, where a host leaf of zero bytes is placed once and the same
+    device array is handed to every later fit: a transfer a fit is a wait
+    of its own between the dispatch and the program's start (1.8 ms a fit
+    on epsilon's chip, PR 38), where a read-only start needs none."""
+    from flink_ml_tpu.parallel.mesh import replicate
+
+    def place(x):
+        if isinstance(x, jax.Array):
+            return replicate(mesh, x)
+        x = np.asarray(x)
+        if np.ascontiguousarray(x).reshape(-1).view(np.uint8).any():
+            return replicate(mesh, x)
+        key = (mesh, x.shape, x.dtype)
+        with _PLACED_ZEROS_LOCK:
+            placed = _PLACED_ZEROS.get(key)
+            if placed is None:
+                placed = _PLACED_ZEROS[key] = replicate(mesh, x)
+                if len(_PLACED_ZEROS) > _PLACED_ZEROS_CAPACITY:
+                    _PLACED_ZEROS.popitem(last=False)
+        return placed
+
+    return jax.tree_util.tree_map(place, init_params)
 
 
 def _run_fused_train(train_fn, init_params, batch, mesh,
@@ -1425,7 +1464,13 @@ def _run_fused_train(train_fn, init_params, batch, mesh,
     A ``train_fn`` built with ``bundle=True`` returns one flat device
     buffer instead of the 4-tuple; the driver reads its ``bundle_fetch`` /
     ``loss_hist_len`` attrs and splits the single ``np.asarray`` readback
-    by the placed leaves' shapes."""
+    by the placed leaves' shapes.
+
+    A ``train_fn`` whose ``donates_params`` is ``False`` (the bundled
+    program) reads its params and frees none of them, so the caller's
+    device arrays go in as they are and a replicated zero start is placed
+    once (:func:`_place_start`); one that donates (or says nothing) trains
+    on copies of them, counted in ``train.param_copies``."""
     from flink_ml_tpu.parallel.mesh import replicate
     from flink_ml_tpu.table import slab_pool
 
@@ -1433,22 +1478,31 @@ def _run_fused_train(train_fn, init_params, batch, mesh,
 
     metrics = StepMetrics("fused_train")
     metrics.start_step()
+    donates = getattr(train_fn, "donates_params", True)
     with obs.span("train.place_params"):
-        placed = (
-            place_params(init_params) if place_params is not None
-            else replicate(mesh, init_params)
-        )
-        # the train fn donates its params: when the caller passes
-        # already-placed device arrays, placement may alias their buffers
-        # (device_put returns a view-like Array for no-op placements) and
-        # donation would delete the CALLER's data — a second fit from the
-        # same initial params would crash.  Copy any leaf whose origin is
-        # a device array (host-sourced leaves were freshly copied by
-        # placement already).
-        placed = jax.tree_util.tree_map(
-            lambda p, o: jnp.copy(p) if isinstance(o, jax.Array) else p,
-            placed, init_params,
-        )
+        if place_params is not None:
+            placed = place_params(init_params)
+        elif donates:
+            placed = replicate(mesh, init_params)
+        else:
+            placed = _place_start(mesh, init_params)
+        # the unbundled fns donate their params (jit donate_argnums): when
+        # the caller passes already-placed device arrays, placement may
+        # alias their buffers (device_put returns the same buffer for a
+        # no-op placement) and donation would delete the CALLER's data — a
+        # second fit from the same initial params would crash.  Those fns
+        # train on a copy of any leaf whose origin is a device array
+        # (host-sourced leaves were freshly placed already).  The bundled
+        # program donates nothing, so there a copy protects nothing and is
+        # one more device program a fit
+        copies = 0
+        if donates:
+            origins = jax.tree_util.tree_leaves(init_params)
+            copies = sum(isinstance(o, jax.Array) for o in origins)
+            placed = jax.tree_util.tree_map(
+                lambda p, o: jnp.copy(p) if isinstance(o, jax.Array) else p,
+                placed, init_params,
+            )
     if batch_preplaced:
         device_batch = batch
     else:
@@ -1516,6 +1570,9 @@ def _run_fused_train(train_fn, init_params, batch, mesh,
         )
         step["call_latency_ms"] = step["seconds"] * 1e3
         obs.counter_add("train.fused_runs")
+        # the leaves copied because the program donates them (0 keeps the
+        # counter there: every bundled fit reads 0)
+        obs.counter_add("train.param_copies", copies)
         _count_collectives(train_fn, mesh, placed, device_batch, n_epochs)
         # of those, the fits whose program holds the one-pass kernel (0
         # keeps the counter there for a reader to find)
@@ -3151,10 +3208,7 @@ def train_glm_sparse(
                 learning_rate, reg, n_epochs, tol, with_intercept,
             )
     else:
-        def place(params):
-            from flink_ml_tpu.parallel.mesh import replicate
-
-            return replicate(mesh, params)
+        place = None  # replicated: the driver's own placement
 
         def factory(n_epochs):
             return make_sparse_glm_train_fn(

@@ -116,6 +116,16 @@ def make_model_table(weights: np.ndarray, intercept: float) -> Table:
     )
 
 
+def _zero_start(dim: int):
+    """Every GLM fit's start: zero weights and intercept, made on the host,
+    so that making them runs no device program.  The fused driver places a
+    replicated zero start once and hands the same device arrays to every
+    later fit of a program that frees none of its params
+    (``lib/common.py:_place_start``); a route's own placer places them as
+    any start."""
+    return np.zeros((dim,), np.float32), np.float32(0)
+
+
 # module-level + memoized so the jit cache is shared across mapper instances —
 # a fresh jit() per load_model would recompile on every transform call
 def _score_fn(x, w, b):
@@ -375,10 +385,8 @@ class GlmEstimatorBase(Estimator, GlmTrainParams):
             # the 'model' axis (train_glm_dense_2d) instead of replicating
             return functools.partial(self._fit_dense_2d, stack, mesh,
                                      layout_key, dim, table)
-        w0 = jnp.zeros((dim,), dtype=jnp.float32)
-        b0 = jnp.zeros((), dtype=jnp.float32)
         return functools.partial(self._fit_dense, table, stack, mesh,
-                                 layout_key, layout_cols, (w0, b0))
+                                 layout_key, layout_cols, _zero_start(dim))
 
     def _fit_dense(self, table, stack, mesh, layout_key, layout_cols,
                    init_params) -> GlmModelBase:
@@ -452,8 +460,7 @@ class GlmEstimatorBase(Estimator, GlmTrainParams):
             lambda: place_dense_2d_batch(mesh, stack, dim_pad),
             cols=getattr(self, "_layout_cols", None),
         )
-        w0 = jnp.zeros((dim,), dtype=jnp.float32)
-        b0 = jnp.zeros((), dtype=jnp.float32)
+        w0, b0 = _zero_start(dim)
         lr = self.get_learning_rate()
         result = fault.run_guarded(
             lambda lr_scale: train_glm_dense_2d(
@@ -552,10 +559,8 @@ class GlmEstimatorBase(Estimator, GlmTrainParams):
         if hot_k > 0:
             return functools.partial(self._fit_sparse_hotcold, table, mesh,
                                      layout_key, sstack, hot_k)
-        w0 = jnp.zeros((sstack.dim,), dtype=jnp.float32)
-        b0 = jnp.zeros((), dtype=jnp.float32)
         return functools.partial(self._fit_sparse, table, sstack, mesh,
-                                 layout_key, (w0, b0))
+                                 layout_key, _zero_start(sstack.dim))
 
     def _fit_sparse(self, table: Table, sstack, mesh, layout_key,
                     init_params) -> GlmModelBase:
@@ -690,8 +695,7 @@ class GlmEstimatorBase(Estimator, GlmTrainParams):
                 lambda: hotcold_entries_device_batch(mesh, hstack()),
                 cols=hot_cols,
             )
-        w0 = jnp.zeros((sstack.dim,), dtype=jnp.float32)
-        b0 = jnp.zeros((), dtype=jnp.float32)
+        w0, b0 = _zero_start(sstack.dim)
         lr = self.get_learning_rate()
         result = fault.run_guarded(
             lambda lr_scale: train_glm_sparse_hotcold(
@@ -945,8 +949,7 @@ class GlmEstimatorBase(Estimator, GlmTrainParams):
             trim = None
             key = ("chunk-dense", grad_fn, mesh, float(lr), float(reg))
 
-        w0 = jnp.zeros((dim,), dtype=jnp.float32)
-        b0 = jnp.zeros((), dtype=jnp.float32)
+        w0, b0 = _zero_start(dim)
         use_spill = getattr(table, "spill", False) and self.get_max_iter() > 1
         with oc.maybe_spill(blocks, use_spill) as blocks:
             # guarded: a rollback retries at a backed-off learning rate —
@@ -1064,8 +1067,7 @@ class GlmEstimatorBase(Estimator, GlmTrainParams):
             key = ("chunk-hotcold", self.LOSS_KIND, mesh, mb, nnz_pad,
                    hot_k_eff, dim_pad, float(lr), float(reg),
                    self.get_with_intercept())
-        w0 = jnp.zeros((dim_pad,), dtype=jnp.float32)
-        b0 = jnp.zeros((), dtype=jnp.float32)
+        w0, b0 = _zero_start(dim_pad)
         # checkpointed params are in PERMUTED space: stamp the layout into
         # the snapshot and refuse resumes under a different one (a changed
         # mesh model size or hot_k yields a shape-compatible but

@@ -1,6 +1,7 @@
 """Where the host's time between two warm fits goes, read from a profile.
 
     python scripts/fit_gaps.py --shape epsilon --shape mnist8m [--fits 50]
+    python scripts/fit_gaps.py --shape mnist8m_dp4    # on a host of four chips
 
 The benchmark (``chipbench/``) says THAT the chip waits for the host between
 two fits (``breakdown.idle_gaps``: ``job.fit``); its trace reader knows only
@@ -29,9 +30,9 @@ them without.  To see the scopes, run with ``JAX_COMPILATION_CACHE_DIR`` set
 to an empty directory (on the command line; the script sets none).
 
 Data are made from ``--seed`` (standard normal columns, so already
-standardised).  Tables go to standard output, all numbers to
-``chiprun_out/fit_gaps/<shape>.json``.  Runs on whatever JAX finds; times mean
-something only on the chip.
+standardised; over 2,097,152 rows one block of them repeated).  Tables go to
+standard output, all numbers to ``chiprun_out/fit_gaps/<shape>.json``.  Runs
+on whatever JAX finds; times mean something only on the chip.
 """
 
 from __future__ import annotations
@@ -49,10 +50,16 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-#: rows, features of the benchmark's configurations (chipbench/configs/)
+#: rows, features of the benchmark's configurations (chipbench/configs/);
+#: ``mnist8m_dp4`` is the four-chip cell's whole table, for a host of four
+#: chips (the default mesh lays it data-parallel over them)
 SHAPES = {"epsilon": (400_000, 2_000), "mnist8m": (2_025_000, 784),
-          "tiny": (8_192, 32)}
+          "mnist8m_dp4": (8_100_000, 784), "tiny": (8_192, 32)}
 BATCH, EPOCHS = 32_768, 10
+#: a global step of the four-chip cell: BATCH a chip
+BATCHES = {"mnist8m_dp4": 4 * BATCH}
+#: rows drawn from the seed; a larger table repeats them
+BLOCK = 1 << 21
 GRID = [(lr, reg) for lr in (0.05, 0.1, 0.2, 0.5) for reg in (0.0, 1e-4)]
 JOB_SPAN, FMT = "chipbench.job.fit", "fmt."
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
@@ -69,7 +76,11 @@ def make_table(rows, features, seed):
     from flink_ml_tpu.table.table import Table
 
     rng = np.random.default_rng(seed)
-    X = rng.standard_normal((rows, features), dtype=np.float32)
+    X = rng.standard_normal((min(rows, BLOCK), features), dtype=np.float32)
+    if rows > BLOCK:
+        # the four-chip table repeats one seeded block: the gaps read no
+        # value, and 25 GB drawn on one thread would take a minute
+        X = np.resize(X, (rows, features))
     w = rng.standard_normal(features, dtype=np.float32) / np.sqrt(features)
     y = (X @ w + 0.5 * rng.standard_normal(rows, dtype=np.float32) > 0)
     return Table.from_columns(
@@ -225,12 +236,15 @@ def _self_times(events):
 
 def read_profile(path):
     """Host spans (``fmt.*`` and the job span), device operations and
-    programs of one profile, times in ns on the profile's clock."""
+    programs of one profile, times in ns on the profile's clock.  The
+    device's are the first chip's: on a host of four every chip runs the
+    same programs (data-parallel), and a sum over them would count each
+    four times."""
     from jax.profiler import ProfileData
 
     host, ops, modules = [], [], []
     for plane in ProfileData.from_file(path).planes:
-        device = plane.name.startswith("/device:TPU:")
+        device = plane.name == "/device:TPU:0"
         for line in plane.lines:
             for e in line.events:
                 span = (e.name, float(e.start_ns),
@@ -356,11 +370,12 @@ def run_shape(shape, n_fits, seed, out_dir, cost=True):
     from flink_ml_tpu.table import slab_pool
 
     rows, features = SHAPES[shape]
-    batch = min(BATCH, rows // 4)
+    batch = BATCHES.get(shape, min(BATCH, rows // 4))
     result = {"shape": shape, "rows": rows, "features": features,
               "fits": n_fits, "seed": seed,
               "device": [jax.devices()[0].platform,
-                         jax.devices()[0].device_kind]}
+                         jax.devices()[0].device_kind],
+              "chips": len(jax.devices())}
     print(f"\n== {shape}: {rows} x {features}, batch {batch}, {EPOCHS} epochs, "
           f"{n_fits} fits a round, on {result['device']}")
     table = make_table(rows, features, seed)
