@@ -535,7 +535,8 @@ def phase_nothing_hid(ctx):
     assert not tripped, f"breakers left closed state: {tripped}"
     ctx["counters"] = {k: snap["counters"].get(k, 0) for k in MUST_BE_ZERO + (
         "train.fused_runs", "train.param_copies", "train.onepass_fits",
-        "train.onepass_declined",
+        "train.onepass_declined", "train.onepass_tiles",
+        "train.onepass_tiles_skipped",
         "train.sparse_ell_fits", "train.sparse_ell_declined",
         "train.sparse_ell_classes",
         "train.sparse_slots", "train.sparse_ell_slots_reckoned",

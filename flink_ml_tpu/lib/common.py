@@ -118,7 +118,11 @@ def pack_minibatches(
     mesh (0 = full batch).  Rows are padded to fill the last minibatch; pad
     rows carry weight 0 so sums/counts are exact.  ``min_steps`` floors the
     step count (whole-pad steps are all-zero-weight) — the out-of-core feed
-    uses it so every chunk shares one compiled program shape.
+    uses it so every chunk shares one compiled program shape.  The padding
+    is laid whole (the slab's shape is the program's), and the one-pass
+    kernel reads none of a minibatch's row tiles after its last weighted
+    row (``ops/pallas_kernels.py:glm_grad_schedule``); the XLA step still
+    reads all of them.
 
     The table is copied ONCE, straight into the slab the device will hold
     (features, label and weight side by side, device-major), a device's
@@ -1239,7 +1243,8 @@ def _combined_view(stack: MinibatchStack) -> np.ndarray:
 def _build_fused_train_fn(key, mb_grad_step, mesh, learning_rate, reg,
                           max_iter, tol, in_specs=None, out_specs=None,
                           delta_fn=None, epoch_fn=None, check_vma=True,
-                          bundle=False, whole_batch_step=None):
+                          bundle=False, whole_batch_step=None,
+                          per_fit=None):
     """The WHOLE training run as one compiled device program.
 
     Epochs are a ``lax.while_loop`` around the minibatch ``lax.scan``; the
@@ -1263,7 +1268,10 @@ def _build_fused_train_fn(key, mb_grad_step, mesh, learning_rate, reg,
     takes ``mb_grad_step``'s place for a step that reads its minibatch out of
     the whole batch itself (the dense one-pass kernel): the scan then runs
     over step numbers and slices nothing, since a slice the scan makes is a
-    copy of the minibatch before its first use.
+    copy of the minibatch before its first use.  ``per_fit(batch)``, where
+    given, is computed once a fit, before the epoch loop, and the scan runs
+    over its rows in place of the step numbers (the one-pass kernel's
+    schedule: a step's index and the row tiles it reads).
 
     ``bundle`` folds the result packing INTO the training program: the four
     outputs (params pytree, loss history, epochs, delta) ravel and
@@ -1301,8 +1309,11 @@ def _build_fused_train_fn(key, mb_grad_step, mesh, learning_rate, reg,
         if whole_batch_step is None:
             xs, grad_step = batch, mb_grad_step
         else:
-            xs = jnp.arange(jax.tree_util.tree_leaves(batch)[0].shape[0],
-                            dtype=jnp.int32)
+            if per_fit is None:
+                xs = jnp.arange(jax.tree_util.tree_leaves(batch)[0].shape[0],
+                                dtype=jnp.int32)
+            else:
+                xs = per_fit(batch)
 
             def grad_step(p, step):
                 return whole_batch_step(p, batch, step)
@@ -1578,6 +1589,7 @@ def _run_fused_train(train_fn, init_params, batch, mesh,
         # keeps the counter there for a reader to find)
         obs.counter_add("train.onepass_fits",
                         int(getattr(train_fn, "onepass", False)))
+        _count_onepass_tiles(train_fn, mesh, device_batch, n_rows, n_epochs)
         if getattr(train_fn, "pallas_interpret", False):
             # the kernel on the interpreter (the CPU parity harness); a
             # chip run asserts this is zero
@@ -1636,6 +1648,41 @@ def _count_collectives(train_fn, mesh, placed, device_batch,
     obs.counter_add("train.psum_bytes", a_step * steps * n_epochs)
 
 
+def onepass_tile_counts(n_rows: int, shards: int, steps: int, mb: int,
+                        tile: int) -> tuple:
+    """``(read, skipped)``: the one-pass kernel's row tiles of ``tile`` rows
+    in an epoch over a dense pack of ``n_rows`` rows, ``steps`` minibatches
+    of ``mb`` rows a shard over ``shards`` (as :func:`pack_minibatches`
+    lays them: step s, shard k holds the table's rows from ``(s * shards +
+    k) * mb``, then padding).  A minibatch reads its tiles up to its last
+    real row, and one at least (``pallas_kernels.glm_grad_schedule`` on the
+    device, here from the geometry alone)."""
+    per_mb = mb // tile
+    blocks = np.arange(shards * steps, dtype=np.int64)
+    real = np.clip(int(n_rows) - blocks * mb, 0, mb)
+    read = int(np.maximum(-(-real // tile), 1).sum())
+    return read, shards * steps * per_mb - read
+
+
+def _count_onepass_tiles(train_fn, mesh, device_batch, n_rows: int,
+                         n_epochs: int) -> None:
+    """Beside ``train.onepass_fits``: the row tiles the kernel read in the
+    fit (``train.onepass_tiles``) and those it passed over, weight-0
+    padding alone (``train.onepass_tiles_skipped``), reckoned from the
+    pack's geometry; 0 and 0 where the fit keeps the XLA step."""
+    from flink_ml_tpu.parallel.mesh import data_parallel_size
+
+    read = skipped = 0
+    tile = getattr(train_fn, "onepass_rows", 0)
+    if tile:
+        shards = data_parallel_size(mesh)
+        blocks, mb = device_batch.shape[:2]
+        read, skipped = onepass_tile_counts(n_rows, shards, blocks // shards,
+                                            mb, tile)
+    obs.counter_add("train.onepass_tiles", read * n_epochs)
+    obs.counter_add("train.onepass_tiles_skipped", skipped * n_epochs)
+
+
 def make_glm_train_fn(
     grad_fn: GradFn,
     mesh,
@@ -1657,7 +1704,12 @@ def make_glm_train_fn(
     the resident slab in place, once (``ops/pallas_kernels.py:glm_grad``,
     row tile ``onepass_rows``), where the XLA step copies the slice out of
     the slab and then reads it twice.  The psums, the update and the bundle
-    stay where they are; only the gradient's sums come from the kernel."""
+    stay where they are; only the gradient's sums come from the kernel.
+    Once a fit, before the epoch loop, the program counts from the slab's
+    weight row the row tiles each step holds weighted rows in
+    (``pallas_kernels.glm_grad_schedule``); the kernel reads no tile after
+    those.  The count is data: the program, and its cache key, are one for
+    every table of a slab shape."""
     key = ("train", grad_fn, mesh, float(learning_rate), float(reg),
            int(max_iter), float(tol), int(onepass_rows))
     if not onepass_rows:
@@ -1675,6 +1727,10 @@ def make_glm_train_fn(
     kind = grad_fn.glm_kind
     keep_b = 1.0 if grad_fn.with_intercept else 0.0
 
+    def schedule(slab):
+        with jax.named_scope("fmt.train.onepass_schedule"):
+            return pallas_kernels.glm_grad_schedule(slab, int(onepass_rows))
+
     def onepass_step(p, slab, step):
         wts, b = p
         with jax.named_scope("fmt.train.onepass"):
@@ -1691,12 +1747,14 @@ def make_glm_train_fn(
         # (a JAX-internal limit; the Mosaic lowering passes strict — seen on
         # 1- and 4-chip v5e meshes), so only the CPU parity harness relaxes
         check_vma=not interpret, bundle=bundle,
-        whole_batch_step=onepass_step,
+        whole_batch_step=onepass_step, per_fit=schedule,
     )
     if bundle:
         #: read by _run_fused_train, which counts the fits that hold the
-        #: kernel and, apart, those that ran it on the interpreter
+        #: kernel and the row tiles it read and passed over, and, apart,
+        #: those that ran it on the interpreter
         train_fn.onepass = True
+        train_fn.onepass_rows = int(onepass_rows)
         train_fn.pallas_interpret = interpret
     return train_fn
 

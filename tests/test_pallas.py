@@ -14,7 +14,8 @@ from flink_ml_tpu.lib import common
 from flink_ml_tpu.lib.classification import _log_loss_grads
 from flink_ml_tpu.lib.regression import _squared_loss_grads
 from flink_ml_tpu.ops import pallas_kernels
-from flink_ml_tpu.ops.pallas_kernels import glm_grad, glm_grad_tile
+from flink_ml_tpu.ops.pallas_kernels import (glm_grad, glm_grad_tile,
+                                             glm_grad_schedule)
 from flink_ml_tpu.parallel.mesh import default_mesh
 
 GRAD_FNS = {"logistic": _log_loss_grads, "squared": _squared_loss_grads}
@@ -95,6 +96,75 @@ class TestGlmGradKernel:
         with pytest.raises(ValueError, match="VMEM"):
             glm_grad(wide, jnp.int32(0), jnp.zeros((8192,), jnp.float32),
                      jnp.float32(0.0), interpret=True)
+
+
+def zero_signless(a):
+    """The bytes of ``a`` with every exact zero made +0.0."""
+    return (np.asarray(a) + 0.0).tobytes()
+
+
+class TestTrailingTilesAreSkipped:
+    """The kernel reads a minibatch's row tiles up to its last row of
+    nonzero weight (``glm_grad_schedule``, at least one) and passes over the
+    rest: its sums are those of a read of every tile, byte for byte up to
+    the sign of an exact zero."""
+
+    ROWS, TILE = 512, 128
+
+    @pytest.mark.parametrize("kind", ["logistic", "squared"])
+    @pytest.mark.parametrize("filled,fill,tiles", [
+        (512, 0.0, 4),   # every row: nothing to skip
+        (1, 0.0, 1),     # one row
+        (128, 0.0, 1),   # exactly one tile
+        (129, 0.0, 2),   # one row past a tile
+        (0, 0.0, 1),     # no row: a minibatch of padding reads one tile
+        (200, 7.0, 2),   # padding at weight 0 over rows of 7.0
+    ], ids=["every-row", "one-row", "one-tile", "one-past-a-tile",
+            "no-row", "weight-0-over-7"])
+    def test_skipping_returns_the_bytes_of_reading_every_tile(
+            self, kind, filled, fill, tiles):
+        slab, wts, b = slab_of(steps=2, rows=self.ROWS, d=37, seed=filled)
+        slab = slab.at[:, :, -1].set(1.0)
+        slab = slab.at[-1, filled:, :].set(fill).at[-1, filled:, -1].set(0.0)
+        schedule = glm_grad_schedule(slab, self.TILE)
+        assert schedule.dtype == jnp.int32
+        assert schedule.tolist() == [[0, self.ROWS // self.TILE], [1, tiles]]
+        for step in (0, 1):
+            skipping = glm_grad(slab, schedule[step], wts, b, kind=kind,
+                                tile_rows=self.TILE, interpret=True)
+            every = glm_grad(slab, jnp.int32(step), wts, b, kind=kind,
+                             tile_rows=self.TILE, interpret=True)
+            for a, c in zip(skipping, every):
+                assert zero_signless(a) == zero_signless(c)
+        assert float(skipping[3]) == float(filled)
+
+    @pytest.mark.parametrize("n,steps,tile,last", [
+        (400_000, 13, 512, 14),       # epsilon_lr: 6,784 rows in the last
+        (2_025_000, 62, 1024, 26),    # mnist8m_lr: 26,152 rows in the last
+    ], ids=["epsilon", "mnist8m"])
+    def test_the_count_by_hand_at_the_cells_geometries(self, n, steps, tile,
+                                                       last):
+        """The pack's weight row alone (three columns a row: the count reads
+        nothing else) at a cell's minibatch of 32,768 rows."""
+        mb = 32768
+        slab = np.zeros((steps, mb, 3), np.float32)
+        weights = np.zeros(steps * mb, np.float32)
+        weights[:n] = 1.0
+        slab[..., -1] = weights.reshape(steps, mb)
+        per_mb = mb // tile
+        counted = np.asarray(glm_grad_schedule(jnp.asarray(slab), tile))[:, 1]
+        assert counted.tolist() == [per_mb] * (steps - 1) + [last]
+        read, skipped = common.onepass_tile_counts(n, 1, steps, mb, tile)
+        assert (read, skipped) == (int(counted.sum()), per_mb - last)
+
+    def test_the_count_by_hand_over_four_chips(self):
+        """mnist8m whole over four chips: only the fourth chip's last
+        minibatch (6,304 rows) ends in padding, 7 tiles of 32 read."""
+        read, skipped = common.onepass_tile_counts(8_100_000, 4, 62, 32768,
+                                                   1024)
+        assert (read, skipped) == (4 * 62 * 32 - 25, 25)
+        # a shard of padding alone reads one tile
+        assert common.onepass_tile_counts(300, 4, 1, 256, 128) == (5, 3)
 
 
 class TestRowTileArithmetic:
@@ -235,8 +305,9 @@ class TestSelectionRule:
         assert "train.onepass_declined" not in c
 
 
-def fused_fit(grad_fn, onepass_rows, slab_host, n_rows, d, max_iter=5):
-    mesh = default_mesh()
+def fused_fit(grad_fn, onepass_rows, slab_host, n_rows, d, max_iter=5,
+              mesh=None):
+    mesh = mesh or default_mesh()
     fn = common.make_glm_train_fn(grad_fn, mesh, 0.2, 0.01, max_iter, 0.0,
                                   bundle=True, onepass_rows=onepass_rows)
     p0 = (jnp.zeros((d,), jnp.float32), jnp.zeros((), jnp.float32))
@@ -289,6 +360,61 @@ class TestTheStepInsideTheFusedFit:
         assert c["train.onepass_fits"] == 1
         assert c["train.pallas_interpreted"] == 1
 
+    @staticmethod
+    def skipping_stack(n_dev, n, d=12, batch=4096, seed=3):
+        """A pack whose last step ends in whole row tiles of padding."""
+        rng = np.random.RandomState(seed)
+        X = rng.randn(n, d).astype(np.float32)
+        y = (X @ rng.randn(d) > 0).astype(np.float64)
+        return common._combined_view(
+            common.pack_minibatches(X, y, n_dev, batch))
+
+    def test_the_tiles_read_and_skipped_are_counted(self, counters):
+        """Eight shards of 512 rows, two steps, 7,492 rows: the last step's
+        seventh shard holds 324 rows (3 tiles of 128 read, 1 passed over),
+        its eighth none (1 read, 3 passed over); the 14 full minibatches
+        read all 4 of theirs.  Two epochs; the XLA step counts 0 and 0."""
+        n_dev = jax.device_count()
+        assert n_dev == 8
+        n = 2 * 4096 - 700
+        slab = self.skipping_stack(n_dev, n)
+        grad_fn = _log_loss_grads(True)
+        one = fused_fit(grad_fn, 128, slab, n, 12, max_iter=2)
+        c = counters()
+        assert c["train.onepass_tiles"] == 2 * (14 * 4 + 3 + 1)
+        assert c["train.onepass_tiles_skipped"] == 2 * (1 + 3)
+        obs.reset()
+        xla = fused_fit(grad_fn, 0, slab, n, 12, max_iter=2)
+        c = counters()
+        assert c["train.onepass_tiles"] == 0
+        assert c["train.onepass_tiles_skipped"] == 0
+        np.testing.assert_allclose(one.params[0], xla.params[0], rtol=0,
+                                   atol=2e-6)
+        np.testing.assert_allclose(one.losses, xla.losses, rtol=2e-6)
+
+    def test_four_shards_with_padding_in_the_last_alone_match_one_device(
+            self):
+        """2,048 rows a step over four shards of 512, 3,796 rows: the last
+        step's fourth shard alone ends in padding (212 rows, 2 of its 4
+        tiles read), and the fit matches the same table's on one device."""
+        from jax.sharding import Mesh
+
+        n, d = 2 * 2048 - 300, 12
+        fits = []
+        for n_dev in (4, 1):
+            mesh = Mesh(np.array(jax.devices()[:n_dev]), ("data",))
+            slab = self.skipping_stack(n_dev, n, d=d, batch=2048)
+            assert slab.shape == (2 * n_dev, 2048 // n_dev, d + 2)
+            fits.append(fused_fit(_log_loss_grads(True), 128, slab, n, d,
+                                  max_iter=3, mesh=mesh))
+        four, one = fits
+        np.testing.assert_allclose(four.params[0], one.params[0], rtol=0,
+                                   atol=2e-6)
+        np.testing.assert_allclose(four.params[1], one.params[1], rtol=0,
+                                   atol=2e-6)
+        np.testing.assert_allclose(four.losses, one.losses, rtol=2e-6)
+        assert four.epochs == one.epochs == 3
+
     def test_the_program_keeps_its_name(self):
         """``jit_bundled``: three readers of the benchmark match on it."""
         mesh = default_mesh()
@@ -313,8 +439,8 @@ class TestBuildCost:
                 gw, gb, loss, _w = glm_grad(slab, i, *params)
                 return (params[0] - gw, params[1] - gb), loss
 
-            return jax.lax.scan(step, (wts, b),
-                                jnp.arange(steps, dtype=jnp.int32))
+            return jax.lax.scan(step, (wts, b), glm_grad_schedule(
+                slab, glm_grad_tile(rows, d)))
 
         args = (jax.ShapeDtypeStruct((steps, rows, d + 2), jnp.float32),
                 jax.ShapeDtypeStruct((d,), jnp.float32),

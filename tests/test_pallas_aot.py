@@ -86,7 +86,71 @@ def test_the_kernel_compiles_and_reads_the_slab_in_place(
     assert slab_layout(text) == "{1,2,0:T(8,128)}"
     minibatch = ROWS * (d + 2) * 4
     assert compiled.memory_analysis().temp_size_in_bytes < minibatch // 8
-    assert "dynamic-slice_bitcast_fusion" not in text
+    # no slice of the slab: the scan's one slice a step is the schedule's
+    # row, the kernel's (2,) int32 operand
+    assert not re.search(r"%dynamic-slice_bitcast_fusion\S* = f32", text)
+    assert len(re.findall(r"%dynamic-slice_bitcast_fusion\S* = s32\[2\]",
+                          text)) <= 1
+
+
+def test_two_tables_of_one_slab_shape_build_one_program(topo, quiet_cache,
+                                                         monkeypatch):
+    """The row tiles a step reads are counted from the slab's weight row in
+    the program: two tables of other row counts packed to one slab shape
+    share the cache key, the program letter for letter and, fitted on the
+    CPU's interpreter, the one compile."""
+    from flink_ml_tpu import obs
+
+    d, tables = 12, []
+    for n in (3 * 512 - 40, 3 * 512 - 400):
+        rng = np.random.RandomState(n)
+        X = rng.randn(n, d).astype(np.float32)
+        y = (X @ rng.randn(d) > 0).astype(np.float64)
+        tables.append((n, common._combined_view(
+            common.pack_minibatches(X, y, 1, 512))))
+    assert tables[0][1].shape == tables[1][1].shape == (3, 512, d + 2)
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+    monkeypatch.setattr(pallas_kernels, "launch_interpreted", lambda: False)
+    texts = []
+    for _n, slab in tables:
+        fn = common.make_glm_train_fn(_log_loss_grads(True), mesh, 0.1, 0.0,
+                                      10, 0.0, onepass_rows=128)
+        texts.append((fn, fn.lower(
+            (jax.ShapeDtypeStruct((d,), jnp.float32,
+                                  sharding=NamedSharding(mesh, P())),
+             jax.ShapeDtypeStruct((), jnp.float32,
+                                  sharding=NamedSharding(mesh, P()))),
+            jax.ShapeDtypeStruct(slab.shape, slab.dtype,
+                                 sharding=NamedSharding(mesh, P("data"))),
+        ).as_text()))
+    assert texts[0][0] is texts[1][0]
+    assert texts[0][1] == texts[1][1]
+    monkeypatch.undo()
+
+    from flink_ml_tpu.parallel.mesh import default_mesh
+
+    cpu = Mesh(np.array(default_mesh().devices.flat[:1]), ("data",))
+    obs.enable()
+    obs.reset()
+    try:
+        for n, slab in tables:
+            fn = common.make_glm_train_fn(_log_loss_grads(True), cpu, 0.1,
+                                          0.0, 2, 0.0, bundle=True,
+                                          onepass_rows=128)
+            p0 = (jnp.zeros((d,), jnp.float32), jnp.zeros((), jnp.float32))
+            common._run_fused_train(fn, p0, slab, cpu, n_rows=n)
+        counters = obs.registry().snapshot()["counters"]
+    finally:
+        obs.disable()
+        obs.reset()
+    jitted = fn.__closure__[0].cell_contents
+    assert jitted._cache_size() == 1
+    assert counters["train.fused_runs"] == 2
+    assert counters.get("train.compile_runs", 0) <= 1
+    # two epochs each: the first table's last step holds 472 rows (all 4
+    # tiles read), the second's 112 (1 read, 3 passed over)
+    assert counters["train.onepass_tiles_skipped"] == 2 * (0 + 3)
+    assert counters["train.onepass_tiles"] == 2 * (12 + 9)
 
 
 def test_the_xla_step_it_replaces_copies_a_minibatch(topo, quiet_cache,
