@@ -542,6 +542,7 @@ def phase_nothing_hid(ctx):
         "train.sparse_slots", "train.sparse_ell_slots_reckoned",
         "train.sparse_hot_fits", "train.sparse_hot_entries",
         "train.sparse_hot_declined", "train.sparse_cold_slots",
+        "train.sparse_hot_slots",
         "train.kmeans_fits", "train.kmeans_row_iters",
         "train.kmeans_onepass_fits", "train.kmeans_onepass_declined",
         "slab_pool.hits", "pipeline.fused_dispatches",

@@ -365,6 +365,14 @@ class EllMinibatchStack:
         return 0 if self.hot_ids is None else self.cold_idx.shape[-1]
 
     @property
+    def hot_slots(self) -> int:
+        """The slots the hot kernels walk over the steps, an epoch, pads
+        included: every plane of every step (0 unsplit)."""
+        if self.hot_ids is None:
+            return 0
+        return self.width * self.mb * len(self.ints)
+
+    @property
     def step_slots(self) -> int:
         """Slots a device's step walks, pads included."""
         return self.width * self.mb + self.cold_slots
@@ -416,8 +424,50 @@ class ClassedEllMinibatchStack:
     intercept's gradient are summed over the rows in the order every other
     layout (and a plain reference) sums them: summed in the step's order
     the loss read up to two float32 places off the reference's on the chip,
-    of the three its limit allows (my chip runs, PR 34).  Never split by
-    frequency.
+    of the three its limit allows (my chip runs, PR 34).
+
+    **The frequency split** (``hot_ids`` set; the pack lays it where the
+    feature counts pass :func:`_hot_split_wins` for the classes' slots, as
+    :class:`EllMinibatchStack` lays its own).  A hot entry is looked up by
+    comparison, every other one goes to the step's cold list, and each part
+    has its own order of the step's rows; the classes cut neither (they
+    stay the rule's reckoning).  ``ints`` and ``floats`` then hold a step's
+    rows alone:
+
+      ints      (n_dev*steps, 4, mb) int32 -- the HOT order (the row at
+                each place), the place of each row in it, then the same two
+                for the COLD order.
+      floats    (n_dev*steps, 2, mb) -- labels and row weights in the
+                table's order.
+      hot_codes (n_dev*steps, nb, P, T) int32 -- codes, in BLOCKS of ``P``
+                (:data:`_HOT_BLOCK_PLANES`) planes of one row tile of ``T``
+                (:func:`_hot_block_tile`) places of the hot order
+                (rows in descending order of their hot width, stable; the
+                pad rows of a short step last): plane ``j`` of a tile holds
+                the ``j``-th hot entry, in stored order, of each of its
+                rows, and a tile has as many blocks as its first (widest)
+                row fills, at least one; the tiles' blocks follow one
+                another, then pads, up to ``nb`` (the fullest step's count
+                rounded up to a multiple of 8).  A shorter row pads with
+                code 0 at value 0.0.
+      hot_vals  (n_dev*steps, nb, P, T) float32, at the codes' places.
+      hot_sched (n_dev*steps, 2, nb) int32 -- DATA: the block each of the
+                kernels' grid steps reads and its row tile; past the
+                step's blocks the last of both again
+                (``pallas_kernels.hot_scores_blocks``).
+      cold_idx / cold_vals / cold_cuts -- the cold list as
+                :class:`EllMinibatchStack` lays it, plane by plane over the
+                COLD order, ``cold_cuts`` ``(n_dev*steps, 2, planes)`` with
+                the planes rounded up to a multiple of 128.
+      hot_ids   (n_dev, K) int32, as :class:`EllMinibatchStack`'s.
+
+    The kernels walk the blocks a step's rows fill, not a rectangle of its
+    widest row, and no constant of the program comes from the widths a
+    table drew: its shapes are ``mb``, ``nb``, ``T``, the cold list's
+    slots and planes, ``dim`` and K.  The scores of both parts go into the
+    table's order for the loss and the error back into each part's (four
+    takes of ``mb``); float32 throughout, every stored entry in exactly one
+    part.
     """
 
     ints: np.ndarray
@@ -429,25 +479,48 @@ class ClassedEllMinibatchStack:
     dim: int
     n_rows: int = 0  # true (un-padded) row count, for throughput metrics
     n_entries: int = 0  # stored entries (pads not counted), likewise
+    hot_codes: Optional[np.ndarray] = None
+    hot_vals: Optional[np.ndarray] = None
+    hot_sched: Optional[np.ndarray] = None
+    cold_idx: Optional[np.ndarray] = None
+    cold_vals: Optional[np.ndarray] = None
+    cold_cuts: Optional[np.ndarray] = None
+    hot_ids: Optional[np.ndarray] = None
+    n_hot_entries: int = 0  # stored entries that hold a code
+    #: the slots the hot kernels walk over the steps, an epoch: the live
+    #: blocks', pads of a block included (``train.sparse_hot_slots``)
+    hot_slots: int = 0
+    #: as :attr:`EllMinibatchStack.hot_declined`
+    hot_declined: bool = False
 
     row_regular = True
     ell_declined = False
-    hot_ids = None
-    hot_declined = False
 
     @property
     def batch(self):
         """As :attr:`SparseMinibatchStack.batch`."""
-        return self.ints, self.floats
+        if self.hot_ids is None:
+            return self.ints, self.floats
+        return (self.hot_codes, self.hot_vals, self.hot_sched, self.cold_idx,
+                self.cold_vals, self.cold_cuts, self.ints, self.floats,
+                self.hot_ids)
 
     @property
     def ell_classes(self) -> int:
         return len(self.classes)
 
     @property
+    def cold_slots(self) -> int:
+        """As :attr:`EllMinibatchStack.cold_slots`."""
+        return 0 if self.hot_ids is None else self.cold_idx.shape[-1]
+
+    @property
     def step_slots(self) -> int:
-        """Slots a device's step walks, the pads of the tail included."""
-        return self.slots
+        """Slots a device's step walks, the pads of the tail included (the
+        split: its blocks' and its cold list's, pads of both included)."""
+        if self.hot_ids is None:
+            return self.slots
+        return int(np.prod(self.hot_codes.shape[1:])) + self.cold_slots
 
     @property
     def ell_step_slots(self) -> int:
@@ -457,10 +530,16 @@ class ClassedEllMinibatchStack:
 
     def grad_step(self, kind: str, with_intercept: bool = True):
         """As :meth:`SparseMinibatchStack.grad_step`, for this layout."""
-        return (("sparse-ell-classed", self.mb, self.classes, self.slots,
-                 self.dim),
-                make_classed_ell_grad_step(kind, self.mb, self.classes,
-                                           self.slots, self.dim,
+        if self.hot_ids is None:
+            return (("sparse-ell-classed", self.mb, self.classes, self.slots,
+                     self.dim),
+                    make_classed_ell_grad_step(kind, self.mb, self.classes,
+                                               self.slots, self.dim,
+                                               with_intercept))
+        return (("sparse-ell-classed-hot", self.mb, self.hot_codes.shape[1:],
+                 self.cold_idx.shape[-1], self.cold_cuts.shape[-1],
+                 self.hot_ids.shape[-1], self.dim),
+                make_classed_hot_grad_step(kind, self.mb, self.dim,
                                            with_intercept))
 
 
@@ -550,13 +629,19 @@ def _hot_split_measured() -> bool:
 def _hot_features(indices, dim: int):
     """``(hot_ids (_HOT_K,) int32, their share of the stored entries)``:
     the most frequent features of a validated CSR column's ``indices``,
-    ties to the lower id; past ``dim`` features, id 0.  Counted block by
+    ties to the lower id; past ``dim`` features, id 0.  Counted on the
+    machine's cores where the native library counts int32 ids
+    (``native.count_ids``: 277 M ids took one core 2.5-3 s), else block by
     block, in the indices' own dtype (``np.bincount`` makes an int64 copy
     of what it is given)."""
-    counts = np.zeros(dim, np.int64)
-    for lo in range(0, len(indices), _ORDER_CHECK_BLOCK):
-        counts += np.bincount(indices[lo : lo + _ORDER_CHECK_BLOCK],
-                              minlength=dim)
+    from flink_ml_tpu import native
+
+    counts = native.count_ids(indices, dim)
+    if counts is None:
+        counts = np.zeros(dim, np.int64)
+        for lo in range(0, len(indices), _ORDER_CHECK_BLOCK):
+            counts += np.bincount(indices[lo : lo + _ORDER_CHECK_BLOCK],
+                                  minlength=dim)
     top = np.argsort(-counts, kind="stable")[:_HOT_K]
     hot_ids = np.zeros(_HOT_K, np.int32)
     hot_ids[: len(top)] = top
@@ -614,17 +699,19 @@ def pack_sparse_minibatches(
     are ordered by width and laid in a few width classes
     (:func:`_width_classes`) pass the same rule, and as segment-CSR, byte
     for byte what it is without the flag and marked ``ell_declined``, where
-    they fail it too.  A per-object column keeps segment-CSR.  Where the
-    one-width layout is taken, on a TPU, the pack also counts the stored
+    they fail it too.  A per-object column keeps segment-CSR.  Where either
+    row-regular layout is taken, on a TPU, the pack also counts the stored
     entries a feature and lays the frequency split
-    (:class:`EllMinibatchStack`) where :func:`_hot_split_wins` says it
-    pays: the hot features' entries as codes, each step's rows ordered by
-    their cold width and its cold entries plane by plane, the planes' cuts
-    as data (:func:`_pack_ell_split`; gauges ``pack_sparse.cold_step_slots``
-    and ``pack_sparse.cold_planes`` beside ``.ell_step_slots``).  A table
-    that fails the split's rule keeps the unsplit leaves byte for byte and
-    is marked ``hot_declined``.  A classed table is neither counted nor
-    split.
+    (:class:`EllMinibatchStack`, :class:`ClassedEllMinibatchStack`) where
+    :func:`_hot_split_wins` says it pays for the slots the layout walks:
+    the hot features' entries as codes, each step's rows ordered by their
+    cold width and its cold entries plane by plane, the planes' cuts as
+    data (:func:`_pack_ell_split`, :func:`_pack_ell_classed_split`: the
+    classed table's codes in blocks of a row tile over its rows ordered by
+    their hot width; gauges ``pack_sparse.cold_step_slots`` and
+    ``pack_sparse.cold_planes`` beside ``.ell_step_slots``).  A table that
+    fails the split's rule keeps the unsplit leaves byte for byte and is
+    marked ``hot_declined``.
     """
     from flink_ml_tpu.ops.batch import CsrRows
 
@@ -826,19 +913,26 @@ def _pack_sparse_minibatches_csr(
         obs.gauge_set("pack_sparse.ell_step_slots", slots)
         obs.gauge_set("pack_sparse.csr_step_slots", nnz_pad)
         if slots <= _ELL_MAX_SLOT_RATIO * nnz_pad:
-            if len(classes) > 1:
-                return _pack_ell_classed(rows, y, bounds, counts, orders,
-                                         classes, mb, steps, dim,
-                                         pad_multiple)
             # and the feature counts decide whether the hot features leave
-            # the gather and the scatter (EllMinibatchStack's split)
+            # the gather and the scatter (either layout's split)
             hot_ids = None
             counted = _hot_split_measured()
             if counted:
+                # a split needs the kernels' module: its import on a thread
+                # beside the count, which holds no GIL
+                _start_kernels_import()
                 hot_ids, hot_share = _hot_features(indices[:nnz_total], dim)
-                if not _hot_split_wins(hot_share, mb * width, nnz_max):
+                if not _hot_split_wins(hot_share, slots, nnz_max):
                     hot_ids = None
-            if hot_ids is None:
+            if len(classes) > 1 and hot_ids is None:
+                stack = _pack_ell_classed(rows, y, bounds, counts, orders,
+                                          classes, mb, steps, dim,
+                                          pad_multiple)
+            elif len(classes) > 1:
+                stack = _pack_ell_classed_split(
+                    rows, y, bounds, counts, classes, mb, steps, dim, n_dev,
+                    pad_multiple, hot_ids)
+            elif hot_ids is None:
                 stack = _pack_ell(rows, y, bounds, counts, width, mb, steps,
                                   dim)
             else:
@@ -1116,6 +1210,160 @@ def _pack_ell_split(rows, y, bounds, counts, width: int, mb: int, steps: int,
         cold_vals=cold_vals, cold_cuts=cuts, order=order,
         hot_ids=np.tile(hot_ids, (n_dev, 1)),
         n_hot_entries=n_entries - sum(n_cold),
+    )
+
+
+#: the cold planes of a classed split step, rounded up to this many: the
+#: cuts' shape, so that tables of one shape share a program
+_COLD_PLANES_MULTIPLE = _LANE_BLOCK
+#: a classed split step's hot blocks, rounded up to this many, likewise
+_HOT_BLOCKS_MULTIPLE = 8
+#: planes a block of the classed split's codes holds: one sublane tile
+_HOT_BLOCK_PLANES = 8
+
+
+def _hot_block_tile(mb: int) -> int:
+    """The row tile of the classed split's codes: 1024 places, as the hot
+    kernels cut a plane (``pallas_kernels._HOT_TILE``), or a step's rows
+    padded to whole lane blocks where fewer."""
+    return min(1024, -(-mb // _LANE_BLOCK) * _LANE_BLOCK)
+
+
+def _pack_ell_classed_split(rows, y, bounds, counts, classes, mb: int,
+                            steps: int, dim: int, n_dev: int,
+                            pad_multiple: int,
+                            hot_ids) -> ClassedEllMinibatchStack:
+    """Lay a validated CSR column out as the classed layout's frequency
+    split (see :class:`ClassedEllMinibatchStack`).  In two passes over a
+    device's steps, spread over threads as :func:`_pack_ell_split`'s: the
+    first finds each row's hot and cold widths, the two orders of rows and
+    the blocks each row tile fills, from which the leaves' shapes follow;
+    the second lays every entry where its part and its rank in its row put
+    it, both parts by a scatter of the step's entries."""
+    indptr, indices, values = rows.indptr, rows.indices, rows.values
+    n_blocks = len(bounds)
+    planes, tile = _HOT_BLOCK_PLANES, _hot_block_tile(mb)
+    n_tiles = -(-mb // tile)
+    code_of = np.full(dim, -1, np.int32)
+    n_hot = min(len(hot_ids), dim)
+    code_of[hot_ids[:n_hot]] = np.arange(n_hot, dtype=np.int32)
+
+    def split(g):
+        """A step's codes (cold: -1) and which entries are cold."""
+        _lo, _hi, e0, e1 = bounds[g]
+        codes = code_of[indices[e0:e1]]
+        return codes, codes < 0
+
+    def widths(g):
+        """A step's two orders of rows both ways, its rows' cold widths,
+        and the hot width of each row tile's first (widest) row."""
+        lo, hi, e0, _e1 = bounds[g]
+        m = hi - lo
+        ints = np.tile(np.arange(mb, dtype=np.int32), (4, 1))
+        tile_w = np.zeros(n_tiles, np.int32)
+        if not m:
+            return ints, np.zeros(0, np.int32), tile_w
+        _codes, is_cold = split(g)
+        # a row's cold entries: a sum over its run (an empty row's run is
+        # the next row's first entry, or the zero appended)
+        cold_w = np.add.reduceat(
+            np.append(is_cold, False).view(np.uint8),
+            (indptr[lo:hi] - e0).astype(np.int64), dtype=np.int32)
+        cold_w[counts[lo:hi] == 0] = 0
+        hot_w = counts[lo:hi] - cold_w
+        for part, w in enumerate((hot_w, cold_w)):
+            order = np.argsort(-w, kind="stable").astype(np.int32)
+            ints[2 * part, :m] = order
+            ints[2 * part + 1, order] = np.arange(m, dtype=np.int32)
+        widest = hot_w[ints[0, np.arange(0, m, tile)]]
+        tile_w[: len(widest)] = widest
+        return ints, cold_w, tile_w
+
+    with ThreadPoolExecutor(min(n_blocks, os.cpu_count() or 1)) as pool:
+        firsts = list(pool.map(widths, range(n_blocks)))
+        ints = np.stack([f[0] for f in firsts])
+        cold_max = max([int(f[1].max(initial=0)) for f in firsts] + [1])
+        n_cold = [int(f[1].sum()) for f in firsts]
+        # a row tile's blocks: as many as its widest row fills, at least
+        # one (a tile with no hot entry zeroes its scores and walks none)
+        blocks = [np.maximum(1, -(-f[2] // planes)) for f in firsts]
+        nb = max(int(b.sum()) for b in blocks)
+        nb = -(-nb // _HOT_BLOCKS_MULTIPLE) * _HOT_BLOCKS_MULTIPLE
+        n_planes = -(-cold_max // _COLD_PLANES_MULTIPLE) \
+            * _COLD_PLANES_MULTIPLE
+        cold_slots = padded_nnz(max(n_cold), pad_multiple)
+        hot_codes = np.zeros((n_blocks, nb, planes, tile), np.int32)
+        hot_vals = np.zeros((n_blocks, nb, planes, tile), np.float32)
+        sched = np.zeros((n_blocks, 2, nb), np.int32)
+        cold_idx = np.zeros((n_blocks, cold_slots), np.int32)
+        cold_vals = np.zeros((n_blocks, cold_slots), np.float32)
+        cuts = np.zeros((n_blocks, 2, n_planes), np.int32)
+        floats = np.zeros((n_blocks, 2, mb), np.float32)
+        walked = np.zeros(n_blocks, np.int64)
+
+        def lay(g):
+            lo, hi, e0, e1 = bounds[g]
+            m = hi - lo
+            _ints, cold_w, tile_w = firsts[g]
+            hot_place, cold_place = ints[g, 1], ints[g, 3]
+            # the schedule: a tile's blocks in a run, then pads that name
+            # the last block and tile again
+            at = np.zeros(n_tiles + 1, np.int32)
+            np.cumsum(blocks[g], out=at[1:])
+            used = int(at[-1])
+            sched[g, 0, :used] = np.arange(used, dtype=np.int32)
+            sched[g, 0, used:] = used - 1
+            sched[g, 1, :used] = np.repeat(np.arange(n_tiles, dtype=np.int32),
+                                           blocks[g])
+            sched[g, 1, used:] = n_tiles - 1
+            # (a tile with no hot entry is walked, all pads: it holds none
+            # of the slots counted)
+            walked[g] = int(blocks[g][tile_w > 0].sum()) * planes * tile
+            if not m:
+                return
+            floats[g, 0, :m] = y[lo:hi]
+            floats[g, 1, :m] = 1.0
+            # plane j of the cold list holds the rows with more than j
+            # cold entries: the first n_j places of the cold order
+            wider = m - np.cumsum(np.bincount(cold_w, minlength=n_planes + 1))
+            cuts[g, 1] = wider[:n_planes]
+            cuts[g, 0, 1:] = np.cumsum(wider[: n_planes - 1])
+            codes, is_cold = split(g)
+            is_hot = ~is_cold
+            # the cold and the hot entries stored before a row's first
+            cold_start = np.zeros(m, np.int64)
+            np.cumsum(cold_w[:-1], out=cold_start[1:])
+            hot_start = indptr[lo:hi] - e0 - cold_start
+            # a row's k-th hot entry lies k planes past its place in its
+            # tile's first block: (block, plane, lane) flat, row by row
+            hot_place = hot_place[:m]
+            base = (at[hot_place // tile].astype(np.int64) * planes * tile
+                    + hot_place % tile - hot_start * tile)
+            flat = np.repeat(base, counts[lo:hi] - cold_w) \
+                + np.arange(len(codes) - cold_start[-1] - cold_w[-1],
+                            dtype=np.int64) * tile
+            hot_codes[g].reshape(-1)[flat] = codes[is_hot]
+            hot_vals[g].reshape(-1)[flat] = values[e0:e1][is_hot]
+            # and its k-th cold entry at its place in plane k
+            rank = np.arange(cold_start[-1] + cold_w[-1]) - np.repeat(
+                cold_start, cold_w)
+            dest = cuts[g, 0][rank] + np.repeat(cold_place[:m], cold_w)
+            cold_idx[g, dest] = indices[e0:e1][is_cold]
+            cold_vals[g, dest] = values[e0:e1][is_cold]
+
+        list(pool.map(lay, range(n_blocks)))
+    n_entries = int(indptr[-1]) if len(rows) else 0
+    obs.gauge_set("pack_sparse.cold_step_slots", cold_slots)
+    obs.gauge_set("pack_sparse.cold_planes",
+                  int(np.count_nonzero(cuts[:, 1].max(axis=0))))
+    return ClassedEllMinibatchStack(
+        ints=ints, floats=floats, steps=steps, mb=mb, classes=classes,
+        slots=0, dim=dim, n_rows=len(rows), n_entries=n_entries,
+        hot_codes=hot_codes, hot_vals=hot_vals, hot_sched=sched,
+        cold_idx=cold_idx, cold_vals=cold_vals, cold_cuts=cuts,
+        hot_ids=np.tile(hot_ids, (n_dev, 1)),
+        n_hot_entries=n_entries - sum(n_cold),
+        hot_slots=int(walked.sum()),
     )
 
 
@@ -2028,6 +2276,101 @@ def _cold_planes_backward(err, idx, vals, cuts, dim: int):
                                    num_segments=dim)
 
 
+def make_classed_hot_grad_step(kind: str, mb: int, dim: int,
+                               with_intercept: bool = True,
+                               interpret: Optional[bool] = None):
+    """:func:`make_classed_ell_grad_step`'s gradient over one step of a
+    SPLIT :class:`ClassedEllMinibatchStack`, with
+    :func:`make_hot_ell_grad_step`'s signature (the step slices its own
+    leaves, and reads the hot blocks where they lie).  The hot part is ONE
+    Pallas call a direction whatever the widths
+    (``ops/pallas_kernels.py:hot_scores_blocks`` / ``hot_grad_blocks``,
+    under ``fmt.train.sparse.hot``), walking the blocks the step's
+    schedule lists; the cold list pays ONE take and ONE scatter a slot, its
+    planes summed and written by loops whose trip count is the step's own
+    planes (:func:`_cold_loop_forward` / :func:`_cold_loop_backward`).
+    The scores of both parts go into the table's order of rows for the
+    loss, and the error back into each part's, as the unsplit classed step
+    does its one order (four takes of ``mb``, ``fmt.train.sparse.orders``);
+    float32 throughout."""
+    from flink_ml_tpu.ops import pallas_kernels
+
+    if interpret is None:
+        interpret = pallas_kernels.launch_interpreted()
+    keep_b = 1.0 if with_intercept else 0.0
+
+    def grad_step(params, batch, step):
+        codes, vals = batch[0], batch[1]
+        sched, cold_idx, cold_vals, cuts, ints, floats = (
+            jax.lax.dynamic_index_in_dim(leaf, step, keepdims=False)
+            for leaf in batch[2:8])
+        hot_ids = batch[8][0]
+        hot_order, hot_place, cold_order, cold_place = (
+            ints[0], ints[1], ints[2], ints[3])
+        y, w = floats[0], floats[1]
+        wts, b = params
+        with jax.named_scope("fmt.train.sparse.forward"):
+            with jax.named_scope("fmt.train.sparse.hot"):
+                hot = pallas_kernels.hot_scores_blocks(
+                    jnp.take(wts, hot_ids, axis=0), codes, vals, step,
+                    sched, mb=mb, interpret=interpret)
+            cold = _cold_loop_forward(wts, cold_idx, cold_vals, cuts, mb)
+            with jax.named_scope("fmt.train.sparse.orders"):
+                logits = (jnp.take(hot, hot_place, axis=0)
+                          + jnp.take(cold, cold_place, axis=0) + b)
+        err, loss_sum = _sparse_loss(kind, logits, y, w)
+        with jax.named_scope("fmt.train.sparse.backward"):
+            with jax.named_scope("fmt.train.sparse.orders"):
+                err_hot = jnp.take(err, hot_order, axis=0)
+                err_cold = jnp.take(err, cold_order, axis=0)
+            g_w = _cold_loop_backward(err_cold, cold_idx, cold_vals, cuts,
+                                      dim)
+            with jax.named_scope("fmt.train.sparse.hot"):
+                g_w = g_w.at[hot_ids].add(pallas_kernels.hot_grad_blocks(
+                    err_hot, codes, vals, step, sched, k=hot_ids.shape[0],
+                    interpret=interpret))
+        g_b = jnp.sum(err) * keep_b
+        return (g_w, g_b), loss_sum, jnp.sum(w)
+
+    grad_step.pallas_interpret = interpret
+    return grad_step
+
+
+def _cold_loop_forward(wts, idx, vals, cuts, mb: int):
+    """:func:`_cold_planes_forward` with the planes summed by a loop whose
+    trip count is DATA, the step's planes (a plane holds at least one
+    place), not one slice a plane the program holds."""
+    starts, lengths = cuts[0], cuts[1]
+    with jax.named_scope("fmt.train.sparse.take_weights"):
+        prods = vals * jnp.take(wts, idx, axis=0)
+    prods = jnp.concatenate([prods, jnp.zeros((mb,), prods.dtype)])
+    place = jnp.arange(mb, dtype=jnp.int32)
+
+    def plane(j, scores):
+        here = jax.lax.dynamic_slice(prods, (starts[j],), (mb,))
+        return scores + jnp.where(place < lengths[j], here, 0.0)
+
+    return jax.lax.fori_loop(0, jnp.sum(lengths > 0, dtype=jnp.int32), plane,
+                             jnp.zeros_like(prods[:mb]))
+
+
+def _cold_loop_backward(err, idx, vals, cuts, dim: int):
+    """:func:`_cold_planes_backward` with the error's writes made by a loop
+    whose trip count is the step's planes, as :func:`_cold_loop_forward`'s
+    sums."""
+    starts, slots = cuts[0], vals.shape[0]
+
+    def plane(j, spread):
+        return jax.lax.dynamic_update_slice(spread, err, (starts[j],))
+
+    spread = jax.lax.fori_loop(
+        0, jnp.sum(cuts[1] > 0, dtype=jnp.int32), plane,
+        jnp.zeros_like(jnp.concatenate([vals, err])))
+    with jax.named_scope("fmt.train.sparse.scatter"):
+        return jax.ops.segment_sum(spread[:slots] * vals, idx,
+                                   num_segments=dim)
+
+
 def _segment_csr_unpack(ints, floats, nnz_pad: int, mb: int):
     """Unpack one packed sparse minibatch slice into (idx, rid, vals, y, w)
     — the ONE copy of the [values | y | w] layout decode (sparse, 2-D, and
@@ -2083,7 +2426,7 @@ def make_sparse_glm_train_fn(
     ``kind`` picks the loss ('logistic' | 'squared'); the minibatch math is
     :func:`make_sparse_mb_grad_step`, :func:`make_ell_mb_grad_step`,
     :func:`make_classed_ell_grad_step` or, on a stack split by frequency,
-    :func:`make_hot_ell_grad_step`.
+    :func:`make_hot_ell_grad_step` / :func:`make_classed_hot_grad_step`.
     Program structure is shared with the dense path via
     :func:`_build_fused_train_fn`, bundled as the dense estimator fit is
     (one program named ``jit_bundled``, one buffer to fetch):
@@ -3307,9 +3650,12 @@ def train_glm_sparse(
         if sstack.hot_ids is not None:
             obs.counter_add("train.sparse_hot_entries",
                             sstack.n_hot_entries * r.epochs)
-            # and the cold list's slots walked, its tail of pads included
+            # and the cold list's slots walked, its tail of pads included,
+            # and the hot kernels', beside the hot entries
             obs.counter_add("train.sparse_cold_slots",
                             sstack.cold_slots * len(sstack.ints) * r.epochs)
+            obs.counter_add("train.sparse_hot_slots",
+                            sstack.hot_slots * r.epochs)
         obs.counter_add("train.sparse_entries", sstack.n_entries * r.epochs)
         obs.counter_add("train.sparse_slots",
                         sstack.step_slots * len(sstack.ints) * r.epochs)

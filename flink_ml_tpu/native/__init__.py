@@ -11,6 +11,7 @@ sources fall back to pure Python when it is unavailable —
       (None = input not representable in the native transport — control
       bytes inside quoted cells — caller must fall back to the pure parser)
   read_libsvm(path, n_features, zero_based) -> (labels ndarray, CsrRows)
+  count_ids(ids, dim) -> int64 ndarray | None  (a threaded bincount)
 
 Streaming (bounded memory — the out-of-core path):
 
@@ -139,6 +140,15 @@ def _load():
             lib._fml_streaming = True
         except AttributeError:
             lib._fml_streaming = False
+        try:  # likewise the count
+            lib.fml_count_ids.restype = ctypes.c_int64
+            lib.fml_count_ids.argtypes = [
+                ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
+                ctypes.c_void_p,
+            ]
+            lib._fml_count = True
+        except AttributeError:
+            lib._fml_count = False
         _lib = lib
         return _lib
 
@@ -159,6 +169,40 @@ class NativeFallback(Exception):
 
 def available() -> bool:
     return _load() is not None
+
+
+def count_ids(ids: np.ndarray, dim: int) -> Optional[np.ndarray]:
+    """``np.bincount(ids, minlength=dim)`` of int32 ids, all in ``[0,
+    dim)``, counted in chunks on the machine's cores (the native call holds
+    no GIL; ``np.bincount`` holds it, so threads do not share its work).
+    None where the library or its count is not available, or the ids are
+    not int32 in one piece of memory: the caller counts them itself."""
+    lib = _load()
+    if (lib is None or not getattr(lib, "_fml_count", False)
+            or ids.dtype != np.int32 or not ids.flags.c_contiguous):
+        return None
+    from concurrent.futures import ThreadPoolExecutor
+
+    n = len(ids)
+    threads = max(1, min(os.cpu_count() or 1, n >> 20))
+    cuts = np.linspace(0, n, threads + 1).astype(np.int64)
+
+    def count(t):
+        counts = np.zeros(dim, np.int64)
+        lo, hi = int(cuts[t]), int(cuts[t + 1])
+        bad = lib.fml_count_ids(ids[lo:hi].ctypes.data, hi - lo, dim,
+                                counts.ctypes.data)
+        if bad >= 0:
+            raise ValueError(f"feature id {int(ids[lo + bad])} out of range "
+                             f"[0, {dim})")
+        return counts
+
+    with ThreadPoolExecutor(threads) as pool:
+        parts = list(pool.map(count, range(threads)))
+    total = parts[0]
+    for part in parts[1:]:
+        total += part
+    return total
 
 
 def read_csv(path: str, delimiter: str, skip_header: bool, arity: int):

@@ -522,4 +522,18 @@ void fml_close_csv_stream(void* handle) {
     }
 }
 
+// Adds one to counts[id] for each of the n ids, all in [0, dim); returns
+// the place of the first id outside it (counted up to there), or -1.
+// Holds no lock: callers count chunks of one column on threads, each into
+// counts of its own.
+int64_t fml_count_ids(const int32_t* ids, int64_t n, int64_t dim,
+                      int64_t* counts) {
+    for (int64_t i = 0; i < n; ++i) {
+        const int32_t id = ids[i];
+        if (id < 0 || id >= dim) return i;
+        ++counts[id];
+    }
+    return -1;
+}
+
 }  // extern "C"

@@ -600,6 +600,13 @@ def _hot_grad_kernel(width: int, rows: int, codes_ref, vals_ref, err_ref,
         g_ref[...] = jnp.zeros_like(g_ref)
 
     err = err_ref[...]
+    g_ref[...] += _hot_grad_planes(width, rows, codes_ref, vals_ref, err,
+                                   g_ref.shape)
+
+
+def _hot_grad_planes(width: int, rows: int, codes_ref, vals_ref, err, shape):
+    """:func:`_hot_grad_kernel`'s sum over a tile's ``width`` planes of the
+    error ``err`` (1, T): ``shape`` (rows, 3*128) float32."""
 
     def plane(carry):
         j, acc = carry
@@ -611,8 +618,7 @@ def _hot_grad_kernel(width: int, rows: int, codes_ref, vals_ref, err_ref,
             row_of, spread, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    g_ref[...] += _over_planes(
-        width, plane, jnp.zeros(g_ref.shape, jnp.float32))
+    return _over_planes(width, plane, jnp.zeros(shape, jnp.float32))
 
 
 def _hot_tile(mb: int) -> int:
@@ -694,6 +700,157 @@ def hot_grad(err, codes, vals, k: int, interpret: bool = False):
         interpret=interpret,
         name="hot_grad",
     )(promote(codes), promote(vals), promote(err))
+    g = g.reshape(rows, 3, _LANES)
+    return (g[:, 0] + g[:, 1] + g[:, 2]).reshape(k)
+
+
+# -- the same lookup over rows of many widths, a row tile's planes as data ----
+
+
+def _hot_block_runs(nb: int, sched_ref):
+    """``(first, live)`` of the grid step: its block is its row tile's
+    first (the tile's scores start from zero there), and it is the block
+    the schedule lists for this step (a pad past the step's blocks reads
+    the last one again and adds nothing)."""
+    i = pl.program_id(0)
+    before = jnp.maximum(i - _I32_ONE, _I32_ZERO)
+    first = (i == 0) | (sched_ref[nb + i] != sched_ref[nb + before])
+    return first, sched_ref[i] == i
+
+
+def _hot_scores_blocks_kernel(nb: int, rows: int, step_ref, sched_ref,
+                              table_ref, codes_ref, vals_ref, out_ref,
+                              part_ref):
+    """A block of planes of one row tile, summed by
+    :func:`_hot_scores_kernel` into ``part_ref`` and added to the tile's
+    scores."""
+    first, live = _hot_block_runs(nb, sched_ref)
+
+    @pl.when(first)
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    @pl.when(live)
+    def _():
+        _hot_scores_kernel(codes_ref.shape[0], rows, table_ref, codes_ref,
+                           vals_ref, part_ref)
+        out_ref[...] += part_ref[...]
+
+
+def _hot_grad_blocks_kernel(nb: int, rows: int, step_ref, sched_ref,
+                            codes_ref, vals_ref, err_ref, g_ref):
+    """:func:`_hot_grad_kernel` over the listed blocks alone, the
+    accumulator zeroed at the first grid step whatever it holds."""
+    _first, live = _hot_block_runs(nb, sched_ref)
+
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        g_ref[...] = jnp.zeros_like(g_ref)
+
+    @pl.when(live)
+    def _():
+        err = err_ref[...]
+        g_ref[...] += _hot_grad_planes(codes_ref.shape[0], rows, codes_ref,
+                                       vals_ref, err, g_ref.shape)
+
+
+def _hot_blocks_specs(nb: int, planes: int, tile: int):
+    """The index maps of the two block kernels: a block of the whole batch
+    by its step and the schedule's first row, a row tile by its second."""
+
+    def block(i, step, sched):
+        return step[0], sched[i], _I32_ZERO, _I32_ZERO
+
+    def row_tile(i, step, sched):
+        return _I32_ZERO, sched[nb + i]
+
+    def same_block(i, step, sched):
+        return _I32_ZERO, _I32_ZERO
+
+    return (pl.BlockSpec((None, None, planes, tile), block), row_tile,
+            same_block)
+
+
+@functools.partial(jax.jit, static_argnames=("mb", "interpret"))
+def hot_scores_blocks(w_hot, codes, vals, step, sched, mb: int,
+                      interpret: bool = False):
+    """:func:`hot_scores` over rows of many widths, ``(mb,)`` scores of one
+    step's rows in the order the blocks lay them.
+
+    ``codes`` / ``vals`` are the WHOLE batch's blocks, ``(steps, nb,
+    planes, tile)``: a block is ``planes`` planes of ``tile`` places (a
+    multiple of 128) of one row tile, and a tile's blocks follow one
+    another, as many as its widest row fills.  ``sched`` ``(2, nb)`` int32
+    is the step's schedule, DATA: the block each grid step reads, and its
+    row tile, tiles in ascending order; past the step's blocks, pads that
+    name the last block and tile again (no copy, nothing added).  ``step``
+    picks the step's blocks where they lie: no slice of them is made.  One
+    program serves every table of one shape, whatever widths its rows draw;
+    exact as :func:`hot_scores`."""
+    _steps, nb, planes, tile = codes.shape
+    rows = w_hot.shape[0] // _LANES
+    table = jnp.concatenate(
+        [p.reshape(rows, _LANES).T
+         for p in _f32_pieces(w_hot.astype(jnp.float32))]
+    ).astype(jnp.bfloat16)
+    step = jnp.reshape(step, (1,)).astype(jnp.int32)
+    sched = jnp.reshape(sched, (-1,)).astype(jnp.int32)
+    operands = [step, sched, table, codes.astype(jnp.int32),
+                vals.astype(jnp.float32)]
+    vma, promote = _vma_of(*operands)
+    block, row_tile, same_block = _hot_blocks_specs(nb, planes, tile)
+    out = pl.pallas_call(
+        functools.partial(_hot_scores_blocks_kernel, nb, rows),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(nb,),
+            in_specs=[pl.BlockSpec(table.shape, same_block), block, block],
+            out_specs=pl.BlockSpec((1, tile), row_tile),
+            scratch_shapes=[pltpu.VMEM((1, tile), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((1, _round_up(mb, tile)), jnp.float32,
+                                       vma=vma),
+        # a row tile's blocks carry its scores: sequential
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="hot_scores",
+    )(*(promote(a) for a in operands))
+    return out[0, :mb]
+
+
+@functools.partial(jax.jit, static_argnames=("k", "interpret"))
+def hot_grad_blocks(err, codes, vals, step, sched, k: int,
+                    interpret: bool = False):
+    """:func:`hot_scores_blocks` transposed, as :func:`hot_grad` is
+    :func:`hot_scores`: the gradient ``(k,)`` of the hot weights from the
+    error ``(mb,)`` of the step's rows in the blocks' order."""
+    _steps, nb, planes, tile = codes.shape
+    rows = k // _LANES
+    n = err.shape[0]
+    err = jnp.pad(err.astype(jnp.float32), (0, _round_up(n, tile) - n))[None]
+    step = jnp.reshape(step, (1,)).astype(jnp.int32)
+    sched = jnp.reshape(sched, (-1,)).astype(jnp.int32)
+    operands = [step, sched, codes.astype(jnp.int32),
+                vals.astype(jnp.float32), err]
+    vma, promote = _vma_of(*operands)
+    block, row_tile, same_block = _hot_blocks_specs(nb, planes, tile)
+    g = pl.pallas_call(
+        functools.partial(_hot_grad_blocks_kernel, nb, rows),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(nb,),
+            in_specs=[block, block, pl.BlockSpec((1, tile), row_tile)],
+            out_specs=pl.BlockSpec((rows, 3 * _LANES), same_block),
+        ),
+        out_shape=jax.ShapeDtypeStruct((rows, 3 * _LANES), jnp.float32,
+                                       vma=vma),
+        # the grid axis carries the accumulator: sequential
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="hot_grad",
+    )(*(promote(a) for a in operands))
     g = g.reshape(rows, 3, _LANES)
     return (g[:, 0] + g[:, 1] + g[:, 2]).reshape(k)
 

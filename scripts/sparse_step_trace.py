@@ -5,7 +5,7 @@ its program listed: the per-operation split of PERF.md §5's sparse cell.
     python scripts/sparse_step_trace.py --seed <n> [--rows N]
                                         [--config criteo_sparse_lr]
                                         [--segment-csr | --unsplit]
-                                        [--classes N]
+                                        [--classes N] [--out DIR]
 
 The benchmark's breakdown (``chipbench/trace_reduce.py``) keeps one of two
 programs' operations where both name one alike (PERF.md §7 (e)), so half the
@@ -25,9 +25,9 @@ profile
                      metadata's ``tf_op``, ``fit_gaps.py:op_metadata``);
 * ``self_by_scope``  self seconds summed by scope: forward, backward, the
                      step's slices (``fmt.train``), the update;
-* ``step_parts_ms``  the same by the step's parts, milliseconds a step (a
-                     step: one run of the dearest operation that runs every
-                     step).  On the split step (PR 36): ``cold_take``
+* ``step_parts_ms``  the same by the step's parts, milliseconds a step
+                     (over the steps the fit ran).  On the split step (PR
+                     36): ``cold_take``
                      (``.take_weights``: the ONE take over the cold list),
                      ``cold_scatter`` (``.scatter``), ``planes`` (what lies
                      under ``.forward`` / ``.backward`` themselves: the
@@ -35,16 +35,25 @@ profile
                      ``kernels`` (``.hot``: both Pallas calls, the hot
                      weights' take, the scatter of their sums), ``rest``;
                      on segment-CSR also ``row_sum`` and ``take_error``;
+                     on the classed split (PR 43) also ``orders``
+                     (``.orders``: the four takes of ``mb`` between the
+                     table's order of rows and each part's), and
+                     ``planes`` are the cold list's two loops;
 * ``one_step``       the operations of one step in order, microseconds from
-                     the step's start.
+                     the step's start (a step: between two starts of the
+                     dearest operation that runs once a step).
 
 Data are made from ``--seed`` by the benchmark's generator at the
 configuration's size (``--rows`` cuts it for a rehearsal); ``--config
 url_ragged_lr`` takes the ragged table (PR 33), which the pack lays
 row-regular in width classes by its own rule since PR 34 (a step's rows
-ordered by width; ``--classes N`` moves the cap on their number inside this
-script): the step's two random-access operations then read under
-``fmt.train.sparse.take_weights`` and ``.scatter``.  With ``--segment-csr``
+ordered by width), and since PR 43 splits by frequency where the features'
+counts pass the split's rule (on a TPU): the hot entries in blocks of a row
+tile read by the two kernels, the cold list's take and scatter under
+``fmt.train.sparse.take_weights`` and ``.scatter``.  ``--unsplit`` lifts the
+split's rule inside this script and lays the classed step as the parent
+did; ``--classes N`` moves the cap on the classes' number and lays that
+unsplit step too, at N classes.  With ``--segment-csr``
 the same table is laid as the parent laid it, and segment-CSR's four read
 under ``.take_weights``, ``.row_sum``, ``.take_error`` and ``.scatter``: one
 tree gives both sides.  A summary goes to standard output, everything to
@@ -78,13 +87,15 @@ PARTS = {"fmt.train.sparse.take_weights": "cold_take",
          "fmt.train.sparse.row_sum": "row_sum",
          "fmt.train.sparse.take_error": "take_error",
          "fmt.train.sparse.hot": "kernels",
+         "fmt.train.sparse.orders": "orders",
          "fmt.train.sparse.forward": "planes",
          "fmt.train.sparse.backward": "planes"}
 
 
-def read_program(path):
+def read_program(path, steps):
     """The report's fields from the profile at ``path``: the longest
-    ``jit_bundled`` module and the operations that lie inside it."""
+    ``jit_bundled`` module and the operations that lie inside it, a fit of
+    ``steps`` steps."""
     import fit_gaps
 
     _host, ops, modules = fit_gaps.read_profile(path)
@@ -109,15 +120,17 @@ def read_program(path):
         by_scope[scope.get(key, "")] = by_scope.get(scope.get(key, ""), 0.0) + s
     covered = sum(b - a for a, b in fit_gaps._union(
         [(a, b) for _n, a, b in inside])) / 1e9
-    # one step: between two neighbouring starts of the dearest operation
-    # that runs every step
-    anchor = max((k for k in inclusive if count[k] >= 100),
+    # a step's parts: the scopes' seconds over the steps run; one step:
+    # between two neighbouring starts of the dearest operation that runs
+    # once a step (an operation inside a loop of the step runs more often)
+    parts = {}
+    for name, s in by_scope.items():
+        part = PARTS.get(name, "rest")
+        parts[part] = parts.get(part, 0.0) + 1e3 * s / steps
+    anchor = max((k for k in inclusive if count[k] == steps),
                  key=inclusive.get, default=None)
-    step_s, one_step, parts = None, [], {}
-    if anchor is not None:
-        for name, s in by_scope.items():
-            part = PARTS.get(name, "rest")
-            parts[part] = parts.get(part, 0.0) + 1e3 * s / count[anchor]
+    step_s, one_step = None, []
+    if anchor is not None and steps > 2:
         starts = sorted(a for n, a, _b in inside if _short(n) == anchor)
         s_lo, s_hi = starts[len(starts) // 2], starts[len(starts) // 2 + 1]
         step_s = (s_hi - s_lo) / 1e9
@@ -164,7 +177,7 @@ def main(argv=None) -> int:
     indptr, indices, values, y = maker.make_rows(
         config["data"], args.rows or int(config["rows"]), dim, args.seed)
     mesh = MLEnvironmentFactory.get_default().get_mesh()
-    if args.unsplit:
+    if args.unsplit or args.classes:
         common._hot_split_wins = lambda *a: False
     if args.classes:
         common._ELL_MAX_CLASSES = args.classes
@@ -190,6 +203,9 @@ def main(argv=None) -> int:
 
     report = {"layout": layout, "step_slots": stack.step_slots,
               "cold_slots": getattr(stack, "cold_slots", 0),
+              "hot_slots": getattr(stack, "hot_slots", 0),
+              "hot_entries": getattr(stack, "n_hot_entries", 0),
+              "entries": stack.n_entries,
               "classes": getattr(stack, "classes", None),
               "pack_s": pack_s,
               "steps": len(stack.ints), "first_fit_s": fit(),
@@ -197,7 +213,7 @@ def main(argv=None) -> int:
     os.makedirs(args.out, exist_ok=True)
     trace_dir = os.path.join(args.out, "trace")
     report["traced_fit_s"], path = fit_gaps.traced(trace_dir, fit)
-    report.update(read_program(path))
+    report.update(read_program(path, stack.steps * int(config["maxIter"])))
     shutil.rmtree(trace_dir)  # read; the report is what goes back
     with open(os.path.join(args.out, f"{args.config}.{layout}.json"),
               "w") as f:

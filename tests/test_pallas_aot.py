@@ -1,6 +1,6 @@
 """The one-pass GLM kernel, the sparse step's hot lookup (PR 30), the
-Lloyd iteration's one-read kernel (PR 32) and the ragged table's step in
-width classes (PR 34),
+Lloyd iteration's one-read kernel (PR 32), the ragged table's step in
+width classes (PR 34) and that step split by frequency (PR 43),
 compiled for a TPU v5e that is described, not attached: what the chip's compiler (Mosaic, XLA:TPU) accepts, which layout it
 gives the slab, and that the kernel's view of the slab copies nothing.  No
 chip time, about two seconds a compile.  Nothing runs, so nothing here is a
@@ -404,6 +404,169 @@ def test_the_classed_sparse_step_keeps_one_gather_and_one_scatter(
     assert "fmt.train.sparse.scatter" in text
     if n_dev > 1:
         assert len(re.findall(r"= .* all-reduce\(", text)) >= 1
+
+
+# -- the ragged table's classed step split by frequency (PR 43) ----------------
+
+#: the cell's split leaves at seed 3405000003 on one chip and a quarter of a
+#: step on each of four: (mb, steps, hot blocks, cold slots, cold planes)
+URL_SPLIT = {1: (ROWS, 74, 432, 773_632, 128),
+             4: (ROWS // 4, 19, 112, 196_096, 128)}
+URL_DIM = 3_231_961
+
+
+def lowered_classed_split_fit(topo, n_dev, mb, steps, nb, cold_slots,
+                              planes, dim, k, monkeypatch):
+    """The classed split's fused fit (unbundled, as above) lowered for
+    ``n_dev`` described chips from the shapes of its nine leaves alone."""
+    monkeypatch.setattr(pallas_kernels, "launch_interpreted", lambda: False)
+    mesh = Mesh(np.array(topo.devices[:n_dev]), ("data",))
+    step = common.make_classed_hot_grad_step("logistic", mb, dim, True,
+                                             interpret=False)
+    tile = common._hot_block_tile(mb)
+    fn = common._build_fused_train_fn(
+        ("aot-sparse-ell-classed-hot", n_dev, steps, mb, nb, cold_slots,
+         planes, dim, k, mesh), None, mesh, 0.1, 1e-4, 1, 0.0,
+        whole_batch_step=step)
+    replicated = NamedSharding(mesh, P())
+    sharded = NamedSharding(mesh, P("data"))
+
+    def leaf(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharded)
+
+    blocks = n_dev * steps
+    hot = (blocks, nb, common._HOT_BLOCK_PLANES, tile)
+    args = ((jax.ShapeDtypeStruct((dim,), jnp.float32, sharding=replicated),
+             jax.ShapeDtypeStruct((), jnp.float32, sharding=replicated)),
+            (leaf(hot, jnp.int32), leaf(hot, jnp.float32),
+             leaf((blocks, 2, nb), jnp.int32),
+             leaf((blocks, cold_slots), jnp.int32),
+             leaf((blocks, cold_slots), jnp.float32),
+             leaf((blocks, 2, planes), jnp.int32),
+             leaf((blocks, 4, mb), jnp.int32),
+             leaf((blocks, 2, mb), jnp.float32),
+             leaf((n_dev, k), jnp.int32)))
+    return fn.lower(*args)
+
+
+@pytest.mark.parametrize("n_dev", [1, 4], ids=["url", "four-chips"])
+def test_the_classed_split_step_compiles_with_one_call_a_direction(
+        topo, quiet_cache, monkeypatch, n_dev):
+    """At the ragged cell's shapes: ONE ``hot_scores`` and ONE ``hot_grad``
+    whatever the widths, ONE gather and ONE scatter over the cold list (and
+    the hot weights' take, the scatter of their sums, four takes of ``mb``),
+    the planes in loops: one slice of the products and one write of the
+    error in the program, not one a plane; the hot blocks read where they
+    lie (no slice of a step's blocks is made)."""
+    mb, steps, nb, cold_slots, planes = URL_SPLIT[n_dev]
+    assert cold_slots == common.padded_nnz(cold_slots - 600, 512)
+    k = 16384
+    compiled = lowered_classed_split_fit(
+        topo, n_dev, mb, steps, nb, cold_slots, planes, URL_DIM, k,
+        monkeypatch).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "hot_scores" in text and "hot_grad" in text
+    gathers = re.findall(r"= f32\[(\d+)\]\S* gather\(", text)
+    assert sorted(gathers) == sorted([str(cold_slots), str(k)]
+                                     + [str(mb)] * 4)
+    scatters = re.findall(r"= f32\[(\d+)\]\S* scatter\(", text)
+    assert scatters == [str(URL_DIM)] * 2  # the cold slots', the hot sums'
+    assert "fmt.train.sparse.take_weights" in text
+    assert "fmt.train.sparse.scatter" in text
+    assert "fmt.train.sparse.row_sum" not in text
+    buffer = rf"f32\[{cold_slots + mb}\]"
+    assert len(re.findall(rf"= {buffer}\S* dynamic-update-slice\(", text)) \
+        == 1
+    assert not re.findall(rf"= {buffer}\S* copy\(", text)
+    assert len(re.findall(rf"= f32\[{mb}\]\S* dynamic-slice\(", text)) == 1
+    hot_leaf = nb * common._HOT_BLOCK_PLANES * \
+        common._hot_block_tile(mb) * 4  # a step's codes
+    assert compiled.memory_analysis().temp_size_in_bytes < hot_leaf
+    if n_dev > 1:
+        assert len(re.findall(r"= .* all-reduce\(", text)) >= 1
+
+
+def _classed_split_stack(seed, zipf, width_max, monkeypatch):
+    """A small ragged table split by frequency in width classes: 2048 rows
+    of 8 to ``width_max`` entries, a power law over 3000 features, 256 of
+    them hot, one device's steps of 1024 rows."""
+    from flink_ml_tpu.ops.batch import CsrRows
+
+    rng = np.random.RandomState(seed)
+    rows, dim = 2048, 3000
+    widths = np.minimum(width_max, 8 + rng.geometric(0.08, rows))
+    indptr = np.concatenate([[0], np.cumsum(widths)]).astype(np.int64)
+    n = int(indptr[-1])
+    ids = np.where(rng.rand(n) < 0.8, (rng.zipf(zipf, n) - 1) * 7919 % dim,
+                   rng.randint(0, dim, n)).astype(np.int32)
+    values = rng.randn(n).astype(np.float32)
+    y = (rng.rand(rows) < 0.35).astype(np.float64)
+    monkeypatch.setattr(common, "_HOT_K", 256)
+    monkeypatch.setattr(common, "_hot_split_measured", lambda: True)
+    monkeypatch.setattr(common, "_hot_split_wins", lambda *a: True)
+    stack = common.pack_sparse_minibatches(
+        CsrRows(dim, indptr, ids, values), y, 1, 1024, dim=dim,
+        row_regular=True)
+    assert type(stack) is common.ClassedEllMinibatchStack
+    assert stack.hot_ids is not None and stack.ell_classes > 1
+    return stack
+
+
+def test_two_classed_tables_of_one_shape_lower_to_the_same_program(
+        topo, quiet_cache, monkeypatch):
+    """The hot blocks' schedule and the cold planes' cuts are data: two
+    tables of one shape whose rows draw other hot and cold widths (and
+    other width classes) give ONE program, letter for letter."""
+    one = _classed_split_stack(1, 1.4, 60, monkeypatch)
+    other = _classed_split_stack(2, 1.45, 52, monkeypatch)
+    assert [a.shape for a in one.batch] == [a.shape for a in other.batch]
+    assert one.classes != other.classes
+    assert not np.array_equal(one.hot_sched, other.hot_sched)
+    assert not np.array_equal(one.cold_cuts[:, 1].max(axis=0) > 0,
+                              other.cold_cuts[:, 1].max(axis=0) > 0)
+    texts = []
+    for stack in (one, other):
+        key, _step = stack.grad_step("logistic")
+        assert key == one.grad_step("logistic")[0]
+        common._EPOCH_STEP_CACHE.clear()
+        nb = stack.hot_codes.shape[1]
+        texts.append(lowered_classed_split_fit(
+            topo, 1, stack.mb, stack.steps, nb, stack.cold_slots,
+            stack.cold_cuts.shape[-1], stack.dim, 256,
+            monkeypatch).as_text())
+    assert texts[0] == texts[1]
+
+
+#: the operations of the one-width split's program (Criteo's) as PR 36 left
+#: them, lowered for one described chip at the cell's shapes: the classed
+#: split leaves them as they were
+CRITEO_OPS = {
+    "func.call": 1, "stablehlo.abs": 1, "stablehlo.add": 145,
+    "stablehlo.all_reduce": 4, "stablehlo.and": 5,
+    "stablehlo.bitcast_convert": 6, "stablehlo.broadcast_in_dim": 81,
+    "stablehlo.compare": 133, "stablehlo.concatenate": 2,
+    "stablehlo.constant": 265, "stablehlo.convert": 4,
+    "stablehlo.custom_call": 2, "stablehlo.divide": 5,
+    "stablehlo.dynamic_slice": 45, "stablehlo.dynamic_update_slice": 41,
+    "stablehlo.exponential": 2, "stablehlo.gather": 2, "stablehlo.iota": 2,
+    "stablehlo.log_plus_one": 1, "stablehlo.maximum": 3,
+    "stablehlo.multiply": 13, "stablehlo.negate": 2, "stablehlo.pad": 1,
+    "stablehlo.reduce": 9, "stablehlo.reshape": 138, "stablehlo.scatter": 3,
+    "stablehlo.select": 91, "stablehlo.slice": 129, "stablehlo.sqrt": 1,
+    "stablehlo.subtract": 10, "stablehlo.transpose": 3, "stablehlo.while": 2}
+
+
+def test_the_one_width_split_lowers_to_the_operations_it_had(
+        topo, quiet_cache, monkeypatch):
+    import collections
+
+    text = lowered_split_fit(topo, 1, 8, ROWS, 39, 1_000_000, COLD_SLOTS,
+                             16384, monkeypatch).as_text()
+    ops = collections.Counter(re.findall(r'= "?([a-z_]+\.[a-z_]+)', text))
+    assert dict(ops) == CRITEO_OPS
+    # its kernels are the one-width calls, not the blocks' own
+    assert "hot_scores_blocks" not in text and "hot_grad_blocks" not in text
 
 
 # -- the centroid fit (PR 31) --------------------------------------------------

@@ -340,6 +340,49 @@ def test_the_rule_reckons_a_cold_slot_at_two_random_accesses():
     assert common._HOT_SPLIT_ROOM == 0.8 and common._HOT_SLOT_NS == 1.5
 
 
+@pytest.mark.parametrize("hot_share,wins", [
+    (0.821, True),    # the ragged cell's table at 16384 features (PR 43)
+    (0.664, True),    # at 1024
+    (0.40, True),
+    (0.30, False),    # the rule's room on the classes' slots
+    (0.1, False)])
+def test_the_rule_on_the_ragged_cells_classed_slots(hot_share, wins):
+    """The classes' slots a step (4,069,888 at seed 3405000003) against
+    the fullest step's entries (about 4.2 M): the split engages from a hot
+    share of 0.33."""
+    assert common._hot_split_wins(hot_share, 4_069_888, 4_200_000) is wins
+
+
+@pytest.mark.parametrize("native_count", [True, False],
+                         ids=["threads", "one_thread"])
+def test_the_count_on_threads_gives_the_one_thread_count(native_count,
+                                                         monkeypatch):
+    """The native count (chunks on the machine's cores) and numpy's,
+    block by block, pick the same hot ids and the same share."""
+    from flink_ml_tpu import native
+
+    monkeypatch.setattr(common, "_HOT_K", 256)
+    monkeypatch.setattr(common, "_ORDER_CHECK_BLOCK", 1 << 16)
+    dim = 5000
+    _indptr, ids, _values, _y = _skewed(40000, dim, 9, 7, ragged=True)
+    want = np.bincount(ids, minlength=dim)
+    if native_count:
+        counted = native.count_ids(ids, dim)
+        if not native.available():
+            assert counted is None
+            pytest.skip("no native library here")
+        assert np.array_equal(counted, want)
+        with pytest.raises(ValueError, match="out of range"):
+            native.count_ids(np.array([3, dim], np.int32), dim)
+        assert native.count_ids(ids.astype(np.int64), dim) is None
+    else:
+        monkeypatch.setattr(native, "count_ids", lambda *a: None)
+    hot, share = common._hot_features(ids, dim)
+    top = np.argsort(-want, kind="stable")[:256]
+    assert np.array_equal(hot, top)
+    assert share == want[top].sum() / len(ids)
+
+
 def test_a_ragged_table_pays_the_hot_lookup_on_every_slot():
     # half the slots hold entries: the cold list is half as long for the
     # same share, and the split wins from a lower share
@@ -440,6 +483,11 @@ def test_an_estimator_fit_takes_the_split_and_counts_it(
     assert counted["train.sparse_cold_slots"] // (blocks * epochs) == \
         common.padded_nnz(int(held.max()), 512)
     assert held.sum() == stack.n_entries - stack.n_hot_entries
+    # and the hot kernels' slots: every plane of every step, pads included
+    assert counted["train.sparse_hot_slots"] == 5 * 8 * blocks * epochs \
+        == stack.hot_slots * epochs
+    assert counted["train.sparse_hot_entries"] <= \
+        counted["train.sparse_hot_slots"]
     # and the pack's gauges, said once in its own phase
     gauges = obs.registry().snapshot()["gauges"]
     assert gauges["pack_sparse.cold_step_slots"] == stack.cold_slots
@@ -479,6 +527,7 @@ def test_a_declined_fit_counts_itself_and_runs_the_unsplit_step(
     assert counted["train.sparse_hot_declined"] == 1
     assert "train.sparse_hot_entries" not in counted
     assert "train.sparse_cold_slots" not in counted
+    assert "train.sparse_hot_slots" not in counted
     assert "pack_sparse.cold_step_slots" not in \
         obs.registry().snapshot()["gauges"]
     assert "train.pallas_interpreted" not in counted

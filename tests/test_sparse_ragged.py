@@ -25,7 +25,15 @@ entry counts differ, made by the benchmark's generator
   matches the fit on one device;
 * segment-CSR's four random-access operations carry their scopes in the
   step's jaxpr; the split step's cold list (laid plane by plane since PR
-  36) and the classed step carry the two that remain.
+  36) and the classed step carry the two that remain;
+* the classed layout split by frequency (PR 43, forced here as on a TPU,
+  ``_HOT_K`` cut to 256): every stored entry in exactly one slot of the hot
+  blocks or the cold list, restored through both orders of rows, the
+  blocks' schedule and the planes' cuts; its step against the unsplit
+  classed step, both losses, with and without intercept; its fit against
+  the plain reference and the unsplit fit, the same bytes again; a table
+  whose hot share fails the rule keeps the parent's leaves and cache key
+  and counts ``train.sparse_hot_declined``; the counters.
 """
 
 import os
@@ -43,7 +51,8 @@ if ROOT not in sys.path:
 
 from chipbench import data_ragged, references  # noqa: E402
 from flink_ml_tpu import obs  # noqa: E402
-from flink_ml_tpu.lib import LogisticRegression, common  # noqa: E402
+from flink_ml_tpu.lib import (  # noqa: E402
+    LinearRegression, LogisticRegression, common)
 from flink_ml_tpu.ops.batch import CsrRows  # noqa: E402
 from flink_ml_tpu.table.schema import DataTypes, Schema  # noqa: E402
 from flink_ml_tpu.table.table import Table  # noqa: E402
@@ -501,3 +510,370 @@ def test_the_random_access_operations_carry_their_scopes(step):
                   "backward/fmt.train.sparse.take_error/mul"]
     for path in paths:
         assert "/fmt.train.sparse." + path in text, path
+
+
+# -- the classed layout split by frequency (PR 43) -----------------------------
+
+#: features looked up by comparison on these 4000-feature tables: two rows of
+#: the hot table, so that a table has a cold part
+HOT_K = 256
+
+
+@pytest.fixture
+def split(monkeypatch):
+    """The split's costs count as measured (as on a TPU), K cut to 256."""
+    monkeypatch.setattr(common, "_HOT_K", HOT_K)
+    monkeypatch.setattr(common, "_hot_split_measured", lambda: True)
+
+
+def _entries(stack, n_dev):
+    """The table a split classed stack holds, as (row, feature, value)
+    triplets sorted by row and feature: every slot of the hot blocks read
+    through its step's schedule and hot order, every slot of the cold list
+    through the planes' cuts and the cold order."""
+    out = []
+    nb, planes, tile = stack.hot_codes.shape[1:]
+    for g in range(len(stack.ints)):
+        k, s = divmod(g, stack.steps)
+        lo = s * n_dev * stack.mb + k * stack.mb  # the step's first row
+        hot_order, cold_order = stack.ints[g, 0], stack.ints[g, 2]
+        sched = stack.hot_sched[g]
+        for b in np.flatnonzero(sched[0] == np.arange(nb)):
+            tile_at = sched[1, b] * tile + np.arange(tile)
+            inside = tile_at < stack.mb
+            for p in range(planes):
+                vals = stack.hot_vals[g, b, p][inside]
+                held = vals != 0
+                rows = hot_order[tile_at[inside][held]]
+                ids = stack.hot_ids[0][stack.hot_codes[g, b, p][inside][held]]
+                out.append(np.stack([lo + rows, ids, vals[held]], axis=1))
+        for start, length in stack.cold_cuts[g].T:
+            plane = slice(start, start + length)
+            out.append(np.stack([lo + cold_order[:length],
+                                 stack.cold_idx[g, plane],
+                                 stack.cold_vals[g, plane]], axis=1))
+    triplets = np.concatenate(out)
+    return triplets[np.lexsort((triplets[:, 1], triplets[:, 0]))]
+
+
+@pytest.mark.parametrize("n_dev", [1, 0], ids=["one_device", "mesh"])
+def test_the_classed_split_holds_every_entry_once_through_both_orders(
+        n_dev, split):
+    indptr, indices, values, y = _rows("ragged")
+    widths = np.diff(indptr)
+    stack = _pack("ragged", n_dev=n_dev or None, row_regular=True)
+    n_dev = n_dev or _n_dev()
+    assert type(stack) is common.ClassedEllMinibatchStack
+    assert stack.hot_ids is not None and not stack.hot_declined
+    assert stack.ell_classes > 1 and stack.row_regular
+    mb, steps, blocks = stack.mb, stack.steps, len(stack.ints)
+    nb, planes, tile = stack.hot_codes.shape[1:]
+    assert blocks == n_dev * steps
+    # the leaves' shapes: blocks of eight planes of one row tile, their
+    # count a multiple of 8; the cold list an odd multiple of 512 slots,
+    # its planes a multiple of 128
+    assert planes == common._HOT_BLOCK_PLANES == 8
+    assert tile == min(1024, mb) and tile % 128 == 0 and nb % 8 == 0
+    assert stack.hot_codes.shape == stack.hot_vals.shape == \
+        (blocks, nb, planes, tile)
+    assert stack.hot_sched.shape == (blocks, 2, nb)
+    assert stack.ints.shape == (blocks, 4, mb)
+    assert stack.floats.shape == (blocks, 2, mb)
+    assert stack.cold_slots % 1024 == 512
+    assert stack.cold_cuts.shape[:2] == (blocks, 2)
+    assert stack.cold_cuts.shape[2] % 128 == 0
+    assert stack.hot_ids.shape == (n_dev, HOT_K)
+    for leaf in (stack.hot_codes, stack.hot_sched, stack.cold_idx,
+                 stack.cold_cuts, stack.ints):
+        assert leaf.dtype == np.int32
+    assert 0 <= stack.hot_codes.min() and stack.hot_codes.max() < HOT_K
+    # the hot ids: the most frequent features, ties to the lower id
+    want = np.argsort(-np.bincount(indices, minlength=DIM),
+                      kind="stable")[:HOT_K]
+    assert np.array_equal(stack.hot_ids[0], want)
+    is_hot = np.isin(indices, want)
+    hot_before = np.concatenate([[0], np.cumsum(is_hot)])[indptr]
+    walked = 0
+    for block, lo, hi in _device_steps(indptr, n_dev, mb, steps):
+        m = hi - lo
+        hot_w = np.diff(hot_before[lo : hi + 1])
+        cold_w = widths[lo:hi] - hot_w
+        # each part's order both ways: rows by descending width in it,
+        # stable, the pad rows of a short step after them where they stood
+        for part, w in ((0, hot_w), (2, cold_w)):
+            order = stack.ints[block, part]
+            assert np.array_equal(order[:m], np.argsort(-w, kind="stable"))
+            assert np.array_equal(order[m:], np.arange(m, mb))
+            assert np.array_equal(stack.ints[block, part + 1][order],
+                                  np.arange(mb))
+        # labels and row weights in the table's order
+        assert np.array_equal(stack.floats[block, 0, :m],
+                              y[lo:hi].astype(np.float32))
+        assert np.array_equal(stack.floats[block, 1],
+                              (np.arange(mb) < m).astype(np.float32))
+        # the schedule: each row tile's blocks in a run, as many as its
+        # widest row fills (at least one), then pads that name the last
+        # block and tile again; past the blocks, no value
+        sched = stack.hot_sched[block]
+        tiles = -(-mb // tile)
+        widest = np.zeros(tiles, np.int64)
+        if m:
+            widest[: -(-m // tile)] = hot_w[
+                stack.ints[block, 0, np.arange(0, m, tile)]]
+        need = np.maximum(1, -(-widest // planes))
+        used = int(need.sum())
+        assert np.array_equal(sched[0, :used], np.arange(used))
+        assert (sched[0, used:] == used - 1).all()
+        assert np.array_equal(sched[1, :used],
+                              np.repeat(np.arange(tiles), need))
+        assert (sched[1, used:] == tiles - 1).all()
+        assert not stack.hot_vals[block, used:].any()
+        walked += int(need[widest > 0].sum()) * planes * tile
+        # the cold planes: a plane holds the first places and starts where
+        # the one before it ends
+        starts, lengths = stack.cold_cuts[block]
+        assert np.array_equal(lengths, [np.count_nonzero(cold_w > j)
+                                        for j in range(len(lengths))])
+        assert np.array_equal(starts[1:], np.cumsum(lengths)[:-1])
+        assert not stack.cold_vals[block, lengths.sum():].any()
+    # every stored entry in exactly one slot, and nothing else held
+    held = _entries(stack, n_dev)
+    rows = np.repeat(np.arange(ROWS), widths)
+    order = np.lexsort((indices, rows))
+    assert len(held) == len(indices) == stack.n_entries
+    assert np.array_equal(held[:, 0], rows[order])
+    assert np.array_equal(held[:, 1], indices[order])
+    assert np.array_equal(held[:, 2].astype(np.float32),
+                          values[order].astype(np.float32))
+    assert stack.n_hot_entries == int(is_hot.sum())
+    assert np.count_nonzero(stack.hot_vals) == stack.n_hot_entries
+    assert np.count_nonzero(stack.cold_vals) == \
+        stack.n_entries - stack.n_hot_entries
+    # what the kernels walk: the blocks of tiles that hold a hot entry,
+    # pads of a block included
+    assert stack.hot_slots == walked
+    assert stack.n_hot_entries <= stack.hot_slots
+    assert stack.step_slots == nb * planes * tile + stack.cold_slots
+
+
+def _classed_pair(monkeypatch, n_dev=None, rows=ROWS):
+    """The "ragged" table's first ``rows`` rows laid classed, unsplit and
+    split."""
+    indptr, indices, values, y = _rows("ragged")
+    column = CsrRows(DIM, indptr[: rows + 1], indices[: indptr[rows]],
+                     values[: indptr[rows]])
+
+    def pack():
+        return common.pack_sparse_minibatches(
+            column, y[:rows], n_dev or _n_dev(), BATCH, dim=DIM,
+            row_regular=True)
+
+    plain = pack()
+    monkeypatch.setattr(common, "_HOT_K", HOT_K)
+    monkeypatch.setattr(common, "_hot_split_measured", lambda: True)
+    split = pack()
+    assert plain.hot_ids is None and split.hot_ids is not None
+    assert plain.classes == split.classes
+    return plain, split
+
+
+@pytest.mark.parametrize("with_intercept", [True, False],
+                         ids=["intercept", "no_intercept"])
+@pytest.mark.parametrize("kind", ["logistic", "squared"])
+def test_a_classed_split_step_gives_the_classed_steps_loss_and_gradient(
+        kind, with_intercept, monkeypatch):
+    """Every step of the table from the same weights, split and unsplit:
+    the squared loss holds the scores themselves to rounding."""
+    plain, split = _classed_pair(monkeypatch)
+    _same_steps(plain, split, kind, with_intercept)
+
+
+def test_a_classed_split_step_over_row_tiles_gives_the_classed_step(
+        monkeypatch):
+    """On one device a step is four row tiles of 1024 places, and the last
+    step's 2808 rows leave its last tile empty: the kernels' blocks change
+    tile, and a tile with no row zeroes its scores."""
+    plain, split = _classed_pair(monkeypatch, n_dev=1, rows=11000)
+    assert split.hot_codes.shape[-1] == 1024 and split.mb == 4096
+    last = split.hot_sched[-1]
+    assert last[1].max() == 3 and split.ints[-1, 1].max() == 4095
+    assert split.floats[-1, 1].sum() == 11000 - 2 * 4096
+    _same_steps(plain, split, "squared", True)
+
+
+def _same_steps(plain, split, kind, with_intercept):
+    rng = np.random.default_rng(43)
+    params = (jnp.asarray(rng.normal(0, 0.3, DIM), jnp.float32),
+              jnp.asarray(0.25, jnp.float32))
+    _key, step_plain = plain.grad_step(kind, with_intercept)
+    _key, step_split = split.grad_step(kind, with_intercept)
+    assert step_split.pallas_interpret is True  # on the CPU, and said
+    batch = tuple(jnp.asarray(a) for a in split.batch)
+    step_split = jax.jit(step_split)
+    for block in range(len(plain.ints)):
+        (g_a, b_a), l_a, n_a = step_plain(
+            params, tuple(jnp.asarray(leaf[block]) for leaf in plain.batch))
+        (g_b, b_b), l_b, n_b = step_split(params, batch, jnp.int32(block))
+        assert g_b.dtype == jnp.float32 and g_b.shape == (DIM,)
+        assert float(n_b) == float(n_a)
+        g_a, g_b = np.asarray(g_a, np.float64), np.asarray(g_b, np.float64)
+        assert np.linalg.norm(g_a - g_b) / np.linalg.norm(g_a) < 1e-6
+        assert float(b_b) == pytest.approx(float(b_a), rel=1e-5, abs=1e-5)
+        assert float(l_b) == pytest.approx(float(l_a), rel=1e-6)
+        if not with_intercept:
+            assert float(b_b) == 0.0
+
+
+def test_a_split_classed_fit_agrees_with_the_plain_reference(split):
+    obs.enable()
+    indptr, indices, values, y = _rows("ragged")
+    table = _table(indptr, indices, values, y)
+    got = _answer(_logreg().fit(table))
+    counted = obs.registry().snapshot()["counters"]
+    assert counted["train.sparse_fits"] == counted["train.sparse_hot_fits"] \
+        == counted["train.sparse_ell_fits"] == 1
+    assert counted["train.sparse_ell_classes"] > 1
+    reference = references.load("csr_glm_sgd")
+    plain = reference.Table(indptr, indices, values, y, DIM, BATCH)
+    gaps = reference.gaps(got, plain.fit(LR, REG, EPOCHS))
+    assert gaps["coef_gap"] < COEF_TOL and gaps["loss_gap"] < LOSS_TOL, gaps
+    # the precision below the one stated fails at least one of the two
+    control = reference.gaps(plain.fit(LR, REG, EPOCHS, precision="bf16"),
+                             plain.fit(LR, REG, EPOCHS))
+    assert control["coef_gap"] > COEF_TOL or control["loss_gap"] > LOSS_TOL
+    # and a repeated fit of the same table returns the same bytes
+    again = _answer(_logreg().fit(table))
+    assert again["coef"].tobytes() == got["coef"].tobytes()
+    assert again["losses"].tobytes() == got["losses"].tobytes()
+
+
+@pytest.mark.parametrize("kind,with_intercept", [
+    ("logistic", True), ("logistic", False), ("squared", True),
+    ("squared", False)])
+def test_a_split_classed_fit_equals_the_unsplit_classed_fit(
+        kind, with_intercept, monkeypatch):
+    plain, split = _classed_pair(monkeypatch)
+    mesh = MLEnvironmentFactory.get_default().get_mesh()
+    start = (jnp.zeros((DIM,), jnp.float32), jnp.zeros((), jnp.float32))
+    fits = [common.train_glm_sparse(start, s, kind, mesh, LR, EPOCHS,
+                                    reg=REG, with_intercept=with_intercept)
+            for s in (plain, split, split)]
+    a, b, again = ([np.asarray(r.params[0], np.float64), float(r.params[1]),
+                    np.asarray(r.losses, np.float64)] for r in fits)
+    assert np.linalg.norm(a[0] - b[0]) / np.linalg.norm(a[0]) < 1e-6
+    assert abs(a[1] - b[1]) < 1e-6
+    assert np.allclose(a[2], b[2], rtol=1e-6)
+    assert b[0].tobytes() == again[0].tobytes()
+    assert b[2].tobytes() == again[2].tobytes()
+
+
+def _uniform_ids(indptr, seed=43):
+    """The "ragged" table's widths with ids drawn uniformly, distinct in a
+    row: 256 of 4000 features hold about 6% of its entries."""
+    rng = np.random.default_rng(seed)
+    return np.concatenate([
+        np.sort(rng.choice(DIM, w, replace=False)) for w in np.diff(indptr)
+    ]).astype(np.int32)
+
+
+def test_a_classed_table_whose_hot_share_fails_the_rule_packs_as_the_parent(
+        split, monkeypatch):
+    """On one device, where a step's 32 lane blocks cut into classes that
+    walk 1.06 slots for one (on the suite's eight, four blocks walk 1.47,
+    and the hot lookup's cheap slots win even a share of 7%)."""
+    from flink_ml_tpu.parallel.mesh import create_mesh
+
+    obs.enable()
+    indptr, _indices, values, y = _rows("ragged")
+    column = CsrRows(DIM, indptr, _uniform_ids(indptr), values)
+    stack = common.pack_sparse_minibatches(column, y, 1, BATCH, dim=DIM,
+                                           row_regular=True)
+    assert type(stack) is common.ClassedEllMinibatchStack
+    assert stack.hot_ids is None and stack.hot_declined
+    _ids, share = common._hot_features(column.indices, DIM)
+    fullest = int(np.diff(indptr[np.minimum(
+        np.arange(0, ROWS + BATCH, BATCH), ROWS)]).max())
+    assert share < 0.1
+    assert not common._hot_split_wins(share, stack.ell_step_slots, fullest)
+    # the parent's leaves and cache key, byte for byte
+    monkeypatch.setattr(common, "_hot_split_measured", lambda: False)
+    parent = common.pack_sparse_minibatches(column, y, 1, BATCH, dim=DIM,
+                                            row_regular=True)
+    assert not parent.hot_declined and parent.hot_ids is None
+    assert stack.ints.tobytes() == parent.ints.tobytes()
+    assert stack.floats.tobytes() == parent.floats.tobytes()
+    assert stack.grad_step("logistic")[0] == parent.grad_step("logistic")[0]
+    assert stack.grad_step("logistic")[0][0] == "sparse-ell-classed"
+    assert len(stack.batch) == 2 and stack.batch[0] is stack.ints
+    assert stack.step_slots == parent.step_slots and stack.cold_slots == 0
+    _fit_stack(stack, create_mesh({"data": 1}, jax.devices()[:1]))
+    counted = obs.registry().snapshot()["counters"]
+    assert counted["train.sparse_hot_declined"] == 1
+    assert counted["train.sparse_hot_fits"] == 0
+    for name in ("train.sparse_hot_entries", "train.sparse_cold_slots",
+                 "train.sparse_hot_slots"):
+        assert name not in counted
+
+
+@pytest.mark.parametrize("cls", [LogisticRegression, LinearRegression])
+def test_an_estimator_fit_takes_the_classed_split_and_counts_it(cls, split):
+    obs.enable()
+    indptr, indices, values, y = _rows("ragged")
+    table = _table(indptr, indices, values, y)
+    (cls().set_vector_col("features").set_label_col("label")
+     .set_prediction_col("pred").set_num_features(DIM)
+     .set_global_batch_size(BATCH).set_max_iter(EPOCHS)
+     .set_learning_rate(LR).fit(table))
+    (stack,) = table._pack_cache.values()
+    assert type(stack) is common.ClassedEllMinibatchStack
+    assert stack.hot_ids is not None
+    counted = obs.registry().snapshot()["counters"]
+    blocks = len(stack.ints)
+    assert counted["train.sparse_fits"] == counted["train.sparse_ell_fits"] \
+        == counted["train.sparse_hot_fits"] == 1
+    assert "train.sparse_hot_declined" not in counted
+    assert counted["train.sparse_ell_classes"] == stack.ell_classes > 1
+    assert counted["train.sparse_entries"] == len(indices) * EPOCHS
+    assert counted["train.sparse_hot_entries"] == \
+        stack.n_hot_entries * EPOCHS
+    assert 0.5 < counted["train.sparse_hot_entries"] \
+        / counted["train.sparse_entries"] < 1.0
+    assert counted["train.sparse_cold_slots"] == \
+        stack.cold_slots * blocks * EPOCHS
+    # the hot kernels' slots: the live blocks', at least the hot entries
+    assert counted["train.sparse_hot_slots"] == stack.hot_slots * EPOCHS
+    assert counted["train.sparse_hot_entries"] <= \
+        counted["train.sparse_hot_slots"]
+    assert counted["train.sparse_slots"] == \
+        stack.step_slots * blocks * EPOCHS
+    assert counted["train.sparse_ell_slots_reckoned"] == \
+        stack.ell_step_slots * blocks * EPOCHS
+    assert counted["train.pallas_interpreted"] == 1  # on the CPU, and said
+    gauges = obs.registry().snapshot()["gauges"]
+    assert gauges["pack_sparse.cold_step_slots"] == stack.cold_slots
+    assert gauges["pack_sparse.cold_planes"] == \
+        np.count_nonzero(stack.cold_cuts[:, 1].max(axis=0))
+    assert gauges["pack_sparse.ell_classes"] == stack.ell_classes
+
+
+def test_the_split_classed_step_carries_the_hot_scope_and_no_unrolled_planes(
+        split):
+    stack = _pack("ragged", row_regular=True)
+    key, step = stack.grad_step("logistic")
+    assert key[0] == "sparse-ell-classed-hot"
+    params = (jnp.zeros((DIM,), jnp.float32), jnp.zeros((), jnp.float32))
+    text = _lowered(step, params, (tuple(jnp.asarray(a) for a in stack.batch),
+                                   jnp.int32(0)))
+    scopes = set(re.findall(r"fmt\.[a-z_.]+", text))
+    assert scopes == {"fmt.train.sparse.forward", "fmt.train.sparse.backward",
+                      "fmt.train.sparse.hot", "fmt.train.sparse.take_weights",
+                      "fmt.train.sparse.scatter", "fmt.train.sparse.orders"}
+    # the planes in two loops: ONE slice of the products and ONE write of
+    # the error into the cold buffer in all of the program's text (the
+    # kernels' calls are pinned compiled for the chip: test_pallas_aot.py)
+    assert text.count("stablehlo.while") >= 2
+    mb, buffer = stack.mb, stack.cold_slots + stack.mb
+    assert len(re.findall(rf"stablehlo.dynamic_slice.*-> tensor<{mb}xf32>",
+                          text)) == 1
+    assert len(re.findall(rf"stablehlo.dynamic_update_slice.*"
+                          rf"-> tensor<{buffer}xf32>", text)) == 1
