@@ -25,7 +25,7 @@ import os
 import threading
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
-from typing import Any, Callable, Optional, Sequence, Tuple
+from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -690,9 +690,9 @@ def pack_sparse_minibatches(
     static shape (one compiled program) across chunks.
 
     ``row_regular`` is the caller saying that its step may be either
-    layout (the plain route on one process and a 1-D mesh; hot/cold, the
-    2-D mesh, multi-process and out-of-core fits read segment-CSR and leave
-    it off).  A CSR column then packs as an :class:`EllMinibatchStack`
+    layout (the plain route on one process and a 1-D mesh; the 2-D mesh,
+    multi-process and out-of-core fits read segment-CSR and leave it
+    off).  A CSR column then packs as an :class:`EllMinibatchStack`
     where ``mb * width <= _ELL_MAX_SLOT_RATIO * nnz_pad`` — a choice made
     from the row widths the pack observes — else as a
     :class:`ClassedEllMinibatchStack` where the slots of a step whose rows
@@ -943,8 +943,8 @@ def _pack_sparse_minibatches_csr(
             return stack
 
     if nnz_total:
-        # per-row ascending ids are a layout invariant downstream (the
-        # hot-slab scatter declares its (rid, pos) tuples sorted); the
+        # per-row ascending ids are a layout invariant (the per-object
+        # pack's rows ascend, and this pack lays the same bytes); the
         # SparseVector path sorts at construction, but CSR columns from
         # the native loader carry file order verbatim — sort here when a
         # file violates it (one vectorized pass detects; per-row argsort
@@ -2373,8 +2373,8 @@ def _cold_loop_backward(err, idx, vals, cuts, dim: int):
 
 def _segment_csr_unpack(ints, floats, nnz_pad: int, mb: int):
     """Unpack one packed sparse minibatch slice into (idx, rid, vals, y, w)
-    — the ONE copy of the [values | y | w] layout decode (sparse, 2-D, and
-    hot/cold builders all read it, so the layouts cannot drift)."""
+    — the ONE copy of the [values | y | w] layout decode (the sparse and
+    2-D builders both read it, so the layouts cannot drift)."""
     idx = ints[0]
     rid = ints[1]
     vals = floats[:nnz_pad]
@@ -2548,859 +2548,6 @@ def _feature_sharded_delta(params, start):
     return jnp.sqrt(
         jax.lax.psum(jnp.sum((params[0] - start[0]) ** 2), "model")
         + (params[1] - start[1]) ** 2
-    )
-
-
-@dataclass
-class HotColdStack:
-    """Hot/cold split of a :class:`SparseMinibatchStack` (VERDICT r3 item 1).
-
-    The v5e has no SparseCore: random gathers/scatters run at ~100M
-    accesses/s (~10 cycles each), which caps the all-segment-CSR path at
-    <1M rows/s on the Criteo shape while a CPU keeps the ~200KB hot set in
-    L2.  The escape is to make the hot traffic STREAM instead of hop: the
-    ``hot_k`` most frequent features become a dense per-minibatch slab
-    ``(mb, hot_k)`` in bf16 — built once on device — and the forward/
-    backward over them are two MXU GEMMs reading the slab at HBM stream
-    bandwidth; only the cold tail (a few nnz/row) still pays random access.
-    That holds for the RESIDENT slab.  The ``stream`` form densifies a
-    step's slab with one scatter a hot entry
-    (:func:`make_hotcold_stream_mb_grad_step`), so it still pays a random
-    access a slot: on the chip a fit of the Criteo-shaped table lasts
-    3.234 s at ``hot_k`` 4096 against the plain route's 3.112 s unsplit
-    (``PERF.md`` §6, PR 27 and 28), and the resident slab of that table is
-    46.98 GB.  The plain route's own frequency split
-    (:class:`EllMinibatchStack`, PR 30) looks the hot features up by
-    comparison instead, in float32 and in the table's own ids: 0.93 s.
-
-    Features are permuted so hot ids occupy [0, hot_k) (slab position =
-    feature id) and cold ids [hot_k, dim); ``perm``/``inv_perm`` map
-    original->permuted and back — training runs in permuted space, the
-    returned coefficients are unpermuted.
-
-    Numerics: the slab and the two GEMM operands are bf16 with f32
-    accumulation (exact for 0/1-valued hashed features, ~2^-8 relative
-    rounding otherwise); everything else stays f32.  ``slab_dtype``
-    exists for equivalence tests (f32 slab).
-
-    With ``model_size > 1`` the layout is feature-sharded over a
-    ('data','model') mesh: slab columns split evenly (shard i owns columns
-    [i*hot_k_local, (i+1)*hot_k_local)) and the permuted weight space
-    interleaves per shard — shard i owns permuted ids
-    [i*dim_local, (i+1)*dim_local), locally [0, hot_k_local) hot and
-    [hot_k_local, dim_local) cold — so each shard's weight slice is
-    [its slab columns | its cold range] and weight traffic never crosses
-    chips.  ``dim_pad >= dim`` absorbs the rounding (dead positions carry
-    zero weight and zero gradient forever).  ``model_size == 1`` reduces to
-    the single-chip layout above (``dim_pad == dim``).
-    """
-
-    hot_ints: np.ndarray   # (n_groups, 2, hot_pad) int32 [slab col, row id]
-    hot_vals: np.ndarray   # (n_groups, hot_pad) f32; pad rows carry rid=mb
-    cold: SparseMinibatchStack  # permuted cold entries + [y | w] tail
-    perm: np.ndarray       # original feature id -> permuted id [0, dim_pad)
-    inv_perm: np.ndarray   # permuted id -> original feature id (dead -> 0)
-    hot_k: int             # slab columns (incl. dead tail when rounded up)
-    slab_dtype: Any = jnp.bfloat16
-    model_size: int = 1    # 'model' mesh-axis size the layout targets
-    dim_pad: int = 0       # permuted weight-space size (== dim when 1-D)
-
-    @property
-    def mb(self) -> int:
-        return self.cold.mb
-
-    @property
-    def dim(self) -> int:
-        """Permuted weight-space size (``cold.dim == dim_pad``); the
-        original feature count is ``len(perm)``."""
-        return self.cold.dim
-
-    @property
-    def n_rows(self) -> int:
-        return self.cold.n_rows
-
-    @property
-    def hot_k_local(self) -> int:
-        return self.hot_k // self.model_size
-
-    @property
-    def dim_local(self) -> int:
-        return self.dim_pad // self.model_size
-
-
-def hotcold_entry_counts(sstack: SparseMinibatchStack) -> np.ndarray:
-    """Stored-entry count per feature over the stack's valid entries — THE
-    frequency vector the hot/cold split selects from (multi-process callers
-    ``agree_sum`` this before splitting)."""
-    valid = sstack.ints[:, 1, :] < sstack.mb
-    return np.bincount(
-        sstack.ints[:, 0, :][valid].ravel(), minlength=sstack.dim
-    )
-
-
-def hotcold_hot_k_eff(dim: int, hot_k: int, model_size: int) -> int:
-    """The effective slab width the feature plan will choose — the ONE
-    rounding rule (clamp to [1, dim], round up to a model-axis multiple),
-    shared with :func:`hotcold_feature_plan` so budget estimates cannot
-    drift from the real layout."""
-    model_size = int(max(model_size, 1))
-    n_hot = int(min(max(hot_k, 1), dim))
-    return -(-n_hot // model_size) * model_size
-
-
-def hotcold_feature_plan(dim: int, hot_k: int, model_size: int,
-                         counts: np.ndarray) -> dict:
-    """The feature-level half of the hot/cold split — hot selection and
-    permutation from a frequency vector, independent of any packed stack.
-    Deterministic in ``counts``, so out-of-core fits compute it ONCE from
-    a counting pre-pass and reuse it for every streamed block (and a
-    checkpoint resume re-derives the identical permutation)."""
-    model_size = int(max(model_size, 1))
-    counts = np.asarray(counts)
-    if counts.shape != (dim,):
-        raise ValueError(
-            f"counts must have shape ({dim},), got {counts.shape}"
-        )
-    n_hot = int(min(max(hot_k, 1), dim))
-    hot_k_eff = hotcold_hot_k_eff(dim, hot_k, model_size)
-    hk_l = hot_k_eff // model_size
-    cold_count = dim - n_hot
-    cold_l = -(-cold_count // model_size) if cold_count else 0
-    dim_local = hk_l + cold_l
-    dim_pad = model_size * dim_local
-
-    order = np.lexsort((np.arange(dim), -counts))  # by count desc, id asc
-    hot_ids = np.sort(order[:n_hot])
-    # slab column per hot feature (rank in id order); -1 marks cold
-    slab_col = np.full(dim, -1, dtype=np.int32)
-    slab_col[hot_ids] = np.arange(n_hot, dtype=np.int32)
-    perm = np.empty(dim, dtype=np.int32)
-    c = np.arange(n_hot, dtype=np.int32)
-    perm[hot_ids] = (c // hk_l) * dim_local + (c % hk_l)
-    cold_mask_ids = np.ones(dim, dtype=bool)
-    cold_mask_ids[hot_ids] = False
-    cold_ids = np.nonzero(cold_mask_ids)[0]
-    if cold_ids.size:
-        r = np.arange(cold_ids.size, dtype=np.int32)
-        perm[cold_ids] = (r // cold_l) * dim_local + hk_l + (r % cold_l)
-    inv_perm = np.zeros(dim_pad, dtype=np.int32)
-    inv_perm[perm] = np.arange(dim, dtype=np.int32)
-    return dict(
-        hot_k_eff=hot_k_eff, dim_pad=dim_pad, perm=perm, inv_perm=inv_perm,
-        slab_col=slab_col,
-    )
-
-
-def _hotcold_plan(sstack: SparseMinibatchStack, hot_k: int,
-                  pad_multiple: int, model_size: int,
-                  counts: Optional[np.ndarray],
-                  feature_plan: Optional[dict] = None):
-    """The deterministic first half of the hot/cold split: hot selection,
-    permutation, per-entry masks, and the NATURAL pad widths — everything
-    except materializing the entry arrays.  Shared by :func:`split_hot_cold`
-    (which fills) and :func:`hotcold_layout_floors` (the multi-process
-    pre-scan), so the two cannot drift.  ``counts`` overrides the local
-    frequency analysis with externally-agreed (global) counts;
-    ``feature_plan`` short-circuits the feature-level work entirely (the
-    out-of-core per-block path, which reuses one plan across the stream)."""
-    ints = sstack.ints
-    mb, dim = sstack.mb, sstack.dim
-    if feature_plan is None:
-        if counts is None:
-            counts = hotcold_entry_counts(sstack)
-        feature_plan = hotcold_feature_plan(dim, hot_k, model_size, counts)
-    slab_col = feature_plan["slab_col"]
-    perm = feature_plan["perm"]
-
-    idx = ints[:, 0, :]
-    rid = ints[:, 1, :]
-    valid = rid < mb
-    ranks = np.where(valid, slab_col[idx], -1)
-    new_idx = np.where(valid, perm[idx], 0)
-    is_hot = ranks >= 0
-    is_cold = valid & (ranks < 0)
-    hot_counts = is_hot.sum(axis=1)
-    cold_counts = is_cold.sum(axis=1)
-    hot_pad = max(-(-int(hot_counts.max(initial=1)) // pad_multiple)
-                  * pad_multiple, pad_multiple)
-    cold_pad = max(-(-int(cold_counts.max(initial=1)) // pad_multiple)
-                   * pad_multiple, pad_multiple)
-    return dict(
-        feature_plan,
-        ranks=ranks, new_idx=new_idx, is_hot=is_hot, is_cold=is_cold,
-        hot_counts=hot_counts, cold_counts=cold_counts,
-        hot_pad=hot_pad, cold_pad=cold_pad,
-    )
-
-
-def hotcold_layout_floors(sstack: SparseMinibatchStack, hot_k: int,
-                          pad_multiple: int = 512, model_size: int = 1,
-                          counts: Optional[np.ndarray] = None):
-    """((hot_pad, cold_pad), plan) the split WOULD choose — the
-    multi-process pre-scan (same contract as :func:`sparse_layout_floors`):
-    each process computes its local pads from the globally-agreed
-    ``counts``, agree_max reconciles them, and the one split runs with the
-    agreed floors.  Pass the returned ``plan`` back to
-    :func:`split_hot_cold` so the O(entries) mask/permutation work runs
-    once, not twice."""
-    plan = _hotcold_plan(sstack, hot_k, pad_multiple, model_size, counts)
-    return (plan["hot_pad"], plan["cold_pad"]), plan
-
-
-@obs.phased("split_hot_cold")
-def split_hot_cold(sstack: SparseMinibatchStack, hot_k: int,
-                   pad_multiple: int = 512,
-                   slab_dtype=jnp.bfloat16,
-                   model_size: int = 1,
-                   counts: Optional[np.ndarray] = None,
-                   min_hot_pad: int = 0,
-                   min_cold_pad: int = 0,
-                   plan: Optional[dict] = None,
-                   feature_plan: Optional[dict] = None) -> HotColdStack:
-    """Frequency analysis + feature permutation + per-group entry split.
-
-    The ``hot_k`` features with the most stored entries (ties broken by
-    lower id) become slab columns; everything else keeps segment-CSR form
-    with ids remapped into the permuted cold range.  ``model_size > 1``
-    produces the feature-sharded layout documented on
-    :class:`HotColdStack` (``hot_k`` rounds up to a model-axis multiple;
-    the extra slab columns are dead).  Multi-process: pass the globally
-    summed ``counts`` (every process must select the same hot set) and the
-    agreed pad floors (``min_hot_pad``/``min_cold_pad``) so all processes
-    fill identical shapes.  ``plan`` short-circuits the analysis phase with
-    the plan :func:`hotcold_layout_floors` already computed — the caller
-    owns the invariant that it came from the same (sstack, hot_k,
-    model_size, counts)."""
-    ints, floats = sstack.ints, sstack.floats
-    mb, nnz_pad, dim = sstack.mb, sstack.nnz_pad, sstack.dim
-    n_groups = ints.shape[0]
-    model_size = int(max(model_size, 1))
-    if plan is None:
-        plan = _hotcold_plan(sstack, hot_k, pad_multiple, model_size, counts,
-                             feature_plan=feature_plan)
-    hot_k_eff = plan["hot_k_eff"]
-    dim_pad = plan["dim_pad"]
-    perm, inv_perm = plan["perm"], plan["inv_perm"]
-    ranks, new_idx = plan["ranks"], plan["new_idx"]
-    is_hot, is_cold = plan["is_hot"], plan["is_cold"]
-    hot_counts, cold_counts = plan["hot_counts"], plan["cold_counts"]
-    rid = ints[:, 1, :]
-    hot_pad = max(plan["hot_pad"], int(min_hot_pad))
-    cold_pad = max(plan["cold_pad"], int(min_cold_pad))
-
-    hot_ints = np.zeros((n_groups, 2, hot_pad), dtype=np.int32)
-    hot_ints[:, 1, :] = mb  # pad row id -> dropped row
-    hot_vals = np.zeros((n_groups, hot_pad), dtype=np.float32)
-    cold_ints = np.zeros((n_groups, 2, cold_pad), dtype=np.int32)
-    cold_ints[:, 1, :] = mb
-    cold_floats = np.zeros((n_groups, cold_pad + 2 * mb), dtype=np.float32)
-    vals = floats[:, :nnz_pad]
-    for g in range(n_groups):
-        h = is_hot[g]
-        c = is_cold[g]
-        nh, nc = int(hot_counts[g]), int(cold_counts[g])
-        hot_ints[g, 0, :nh] = ranks[g, h]  # global slab column
-        hot_ints[g, 1, :nh] = rid[g, h]
-        hot_vals[g, :nh] = vals[g, h]
-        cold_ints[g, 0, :nc] = new_idx[g, c]  # permuted feature id
-        cold_ints[g, 1, :nc] = rid[g, c]
-        cold_floats[g, :nc] = vals[g, c]
-        cold_floats[g, cold_pad:] = floats[g, nnz_pad:]  # [y | w] tail
-
-    # the cold stack's ids live in PERMUTED space [hot ranges excluded],
-    # which spans [0, dim_pad) — dim must be dim_pad (== dim when 1-D) or
-    # a rounded-up 2-D layout would violate the col-index < dim invariant
-    cold = SparseMinibatchStack(
-        ints=cold_ints, floats=cold_floats, steps=sstack.steps, mb=mb,
-        nnz_pad=cold_pad, dim=dim_pad, n_rows=sstack.n_rows,
-    )
-    return HotColdStack(
-        hot_ints=hot_ints, hot_vals=hot_vals, cold=cold, perm=perm,
-        inv_perm=inv_perm, hot_k=hot_k_eff, slab_dtype=slab_dtype,
-        model_size=model_size, dim_pad=dim_pad,
-    )
-
-
-@obs.phased("densify_hot_slabs")
-def densify_hot_slabs(mesh, hstack: HotColdStack):
-    """Build the per-minibatch hot slabs ON DEVICE, sharded over 'data'
-    (and over 'model' on slab columns when the layout is feature-sharded).
-
-    The host ships only the compact hot entry arrays (~entries x 12B); the
-    10s-of-GB slab materializes device-side via one sequential scatter pass
-    (zeros + at[].add per group), so the host->device hop stays the size
-    of the sparse data, not the slab."""
-    from jax.sharding import PartitionSpec as P
-
-    from flink_ml_tpu.parallel.mesh import shard_batch
-
-    mb, hot_k, dtype = hstack.mb, hstack.hot_k, hstack.slab_dtype
-
-    hot_ints_d, hot_vals_d = shard_batch(
-        mesh, (hstack.hot_ints, hstack.hot_vals)
-    )
-    if hstack.model_size > 1:
-        if dict(mesh.shape).get("model", 1) != hstack.model_size:
-            raise ValueError(
-                f"HotColdStack laid out for model_size={hstack.model_size} "
-                f"but mesh has model axis {dict(mesh.shape).get('model', 1)}"
-            )
-        hk_l = hstack.hot_k_local
-
-        def local_sharded(hot_ints, hot_vals):
-            lo = jax.lax.axis_index("model") * hk_l
-
-            def one(args):
-                ig, vg = args
-                pos, rid = ig[0], ig[1]
-                lpos = pos - lo
-                mine = jnp.logical_and(lpos >= 0, lpos < hk_l)
-                slab = jnp.zeros((mb + 1, hk_l), dtype)  # row mb = pad sink
-                return slab.at[
-                    jnp.where(mine, rid, mb), jnp.clip(lpos, 0, hk_l - 1)
-                ].add(jnp.where(mine, vg, 0.0).astype(dtype))[:mb]
-
-            return jax.lax.map(one, (hot_ints, hot_vals))
-
-        fn = jax.jit(shard_map(
-            local_sharded, mesh=mesh, in_specs=(P("data"), P("data")),
-            out_specs=P("data", None, "model"), check_vma=True,
-        ))
-        return fn(hot_ints_d, hot_vals_d)
-
-    def local(hot_ints, hot_vals):
-        def one(args):
-            ig, vg = args
-            pos, rid = ig[0], ig[1]
-            slab = jnp.zeros((mb + 1, hot_k), dtype)  # row mb = pad sink
-            return slab.at[rid, pos].add(vg.astype(dtype))[:mb]
-
-        return jax.lax.map(one, (hot_ints, hot_vals))
-
-    if dict(mesh.shape).get("data", 1) > 1:
-        fn = jax.jit(shard_map(
-            local, mesh=mesh, in_specs=(P("data"), P("data")),
-            out_specs=P("data"), check_vma=True,
-        ))
-    else:
-        fn = jax.jit(local)
-    return fn(hot_ints_d, hot_vals_d)
-
-
-def hotcold_device_batch(mesh, hstack: HotColdStack):
-    """Device placement for the hot/cold batch: build the slab on device,
-    shard the cold segment-CSR arrays over 'data'."""
-    from flink_ml_tpu.parallel.mesh import shard_batch
-
-    slab = densify_hot_slabs(mesh, hstack)
-    cold_ints, cold_floats = shard_batch(
-        mesh, (hstack.cold.ints, hstack.cold.floats)
-    )
-    return (slab, cold_ints, cold_floats)
-
-
-def _hotcold_core(kind: str, slab, wts, b, idx, rid, vals, y, w,
-                  mb: int, hot_k: int, dim: int, keep_b: float):
-    """The hot/cold minibatch math: two MXU GEMMs over the slab (forward
-    logits, backward feature gradient) + segment-CSR for the cold tail.
-    The vectors are widened to 128 GEMM columns — the N=1 matvec lowers to
-    a catastrophic lane-reduction on TPU (measured 400x slower), while
-    N=128 engages the MXU at stream bandwidth; the extra columns are free
-    (the pass is memory-bound on the slab).  Shared by the in-memory step
-    (slab pre-densified, HBM-resident across epochs) and the out-of-core
-    step (slab densified in-program per minibatch)."""
-    dtype = slab.dtype
-    w_hot = jnp.broadcast_to(
-        wts[:hot_k].astype(dtype)[:, None], (hot_k, 128)
-    )
-    hot_logits = jax.lax.dot_general(
-        slab, w_hot, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )[:, 0]
-    logits = hot_logits + _segment_csr_forward(wts, idx, rid, vals, mb) + b
-    err, loss_sum = _sparse_loss(kind, logits, y, w)
-    err_m = jnp.broadcast_to(err.astype(dtype)[:, None], (mb, 128))
-    g_hot = jax.lax.dot_general(
-        slab, err_m, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )[:, 0]
-    g_w = _segment_csr_backward(err, idx, rid, vals, dim)
-    g_w = g_w.at[:hot_k].add(g_hot)
-    g_b = jnp.sum(err) * keep_b
-    return (g_w, g_b), loss_sum, jnp.sum(w)
-
-
-def make_hotcold_mb_grad_step(kind: str, mb: int, cold_nnz_pad: int,
-                              hot_k: int, dim: int,
-                              with_intercept: bool = True):
-    """The in-memory hot/cold minibatch gradient over a PRE-DENSIFIED slab
-    (built once on device, resident across epochs — see
-    :func:`densify_hot_slabs`); math in :func:`_hotcold_core`."""
-    keep_b = 1.0 if with_intercept else 0.0
-
-    def mb_grad_step(params, xs):
-        slab, ints, floats = xs
-        wts, b = params
-        idx, rid, vals, y, w = _segment_csr_unpack(
-            ints, floats, cold_nnz_pad, mb
-        )
-        return _hotcold_core(
-            kind, slab, wts, b, idx, rid, vals, y, w, mb, hot_k, dim, keep_b
-        )
-
-    return mb_grad_step
-
-
-def make_hotcold_stream_mb_grad_step(kind: str, mb: int,
-                                     cold_nnz_pad: int, hot_k: int,
-                                     dim: int,
-                                     with_intercept: bool = True,
-                                     slab_dtype=jnp.bfloat16):
-    """Out-of-core hot/cold minibatch gradient: the slab densifies
-    IN-PROGRAM from the minibatch's packed hot entries (one scatter over
-    ~hot entries), then the same GEMM+segment-CSR math as the in-memory
-    step runs (:func:`_hotcold_core`).
-
-    The in-memory path builds slabs once and keeps them HBM-resident
-    across epochs; out-of-core the data must not stay resident anywhere,
-    so each epoch re-streams the entries and pays one scatter per
-    minibatch — still one random-access pass where the all-segment-CSR
-    step pays three (weight gather, forward segment_sum, gradient
-    scatter) over the hot traffic.  ``xs`` is one scanned slice of the
-    hot/cold block layout: (hot ints (2, hot_pad), hot vals (hot_pad,),
-    cold ints (2, cold_nnz_pad), cold floats (cold_nnz_pad + 2*mb,));
-    pad entries carry row id ``mb`` (the scatter sink row, sliced away).
-    """
-    keep_b = 1.0 if with_intercept else 0.0
-    dtype = jnp.dtype(slab_dtype)
-
-    def mb_grad_step(params, xs):
-        h_ints, h_vals, ints, floats = xs
-        wts, b = params
-        pos, hrid = h_ints[0], h_ints[1]
-        # (rid, pos) tuples are lexicographically sorted by construction
-        # (row-major packing; per-row feature ids ascending; pads at the
-        # tail with rid == mb) — the sorted lowering keeps the scatter's
-        # writes row-localized instead of random over the whole slab
-        slab = (
-            jnp.zeros((mb + 1, hot_k), dtype)  # row mb = pad sink
-            .at[hrid, pos]
-            .add(h_vals.astype(dtype), indices_are_sorted=True)[:mb]
-        )
-        idx, rid, vals, y, w = _segment_csr_unpack(
-            ints, floats, cold_nnz_pad, mb
-        )
-        return _hotcold_core(
-            kind, slab, wts, b, idx, rid, vals, y, w, mb, hot_k, dim, keep_b
-        )
-
-    return mb_grad_step
-
-
-def hotcold_entries_device_batch(mesh, hstack: HotColdStack):
-    """Device placement for the SCALABLE hot/cold formulation: the packed
-    entry arrays (hot + cold) shard over 'data' and stay the only resident
-    copy of the data — HBM holds O(nnz), never O(n_rows x hot_k).  The
-    slab materializes in-program per minibatch
-    (:func:`make_hotcold_stream_mb_grad_step`)."""
-    from flink_ml_tpu.parallel.mesh import shard_batch
-
-    return shard_batch(
-        mesh,
-        (hstack.hot_ints, hstack.hot_vals,
-         hstack.cold.ints, hstack.cold.floats),
-    )
-
-
-def hotcold_slab_bytes(n_rows: int, hot_k: int,
-                       slab_dtype=jnp.bfloat16) -> int:
-    """HBM footprint of the resident-slab formulation's slabs — the number
-    the auto policy compares against the budget (the packed entry arrays
-    are negligible next to it)."""
-    return int(n_rows) * int(hot_k) * jnp.dtype(slab_dtype).itemsize
-
-
-def make_hotcold_stream_glm_train_fn(
-    kind: str,
-    mesh,
-    mb: int,
-    cold_nnz_pad: int,
-    hot_k: int,
-    dim: int,
-    learning_rate: float,
-    reg: float,
-    max_iter: int,
-    tol: float,
-    with_intercept: bool = True,
-    slab_dtype=jnp.bfloat16,
-):
-    """Fused training over packed hot/cold ENTRY batches (slab densified
-    in-program per minibatch) — the scalable in-memory formulation: the
-    resident-slab variant's HBM cost grows O(n_rows x hot_k) (~100 GB at
-    1M rows x 50k hot), this one holds only the entries (~12 B/nnz).  Same
-    loop scaffolding as every other path (:func:`_build_fused_train_fn`);
-    the per-step extra over the resident variant is one zeros+scatter
-    (~3x slab traffic per step vs 2x)."""
-    if kind not in ("logistic", "squared"):
-        raise ValueError(f"unknown loss kind {kind!r}")
-    key = ("hotcold-stream", kind, mesh, mb, cold_nnz_pad, hot_k, dim,
-           float(learning_rate), float(reg), int(max_iter), float(tol),
-           bool(with_intercept), jnp.dtype(slab_dtype).name)
-    mb_grad_step = make_hotcold_stream_mb_grad_step(
-        kind, mb, cold_nnz_pad, hot_k, dim, with_intercept,
-        slab_dtype=slab_dtype,
-    )
-    return _build_fused_train_fn(
-        key, mb_grad_step, mesh, learning_rate, reg, max_iter, tol
-    )
-
-
-def make_hotcold_glm_train_fn(
-    kind: str,
-    mesh,
-    mb: int,
-    cold_nnz_pad: int,
-    hot_k: int,
-    dim: int,
-    learning_rate: float,
-    reg: float,
-    max_iter: int,
-    tol: float,
-    with_intercept: bool = True,
-    slab_dtype=jnp.bfloat16,
-):
-    """Fused training over (slab, cold ints, cold floats) batches; loop
-    scaffolding shared with every other path via
-    :func:`_build_fused_train_fn`."""
-    if kind not in ("logistic", "squared"):
-        raise ValueError(f"unknown loss kind {kind!r}")
-    key = ("hotcold", kind, mesh, mb, cold_nnz_pad, hot_k, dim,
-           float(learning_rate), float(reg), int(max_iter), float(tol),
-           bool(with_intercept), jnp.dtype(slab_dtype).name)
-    mb_grad_step = make_hotcold_mb_grad_step(
-        kind, mb, cold_nnz_pad, hot_k, dim, with_intercept
-    )
-    return _build_fused_train_fn(
-        key, mb_grad_step, mesh, learning_rate, reg, max_iter, tol
-    )
-
-
-def make_hotcold_mb_grad_step_2d(kind: str, mb: int, cold_nnz_pad: int,
-                                 hot_k_local: int, dim_local: int,
-                                 with_intercept: bool = True):
-    """Feature-sharded hot/cold minibatch gradient.
-
-    Shard i of the ``model`` axis owns slab columns
-    [i*hot_k_local, (i+1)*hot_k_local) (arriving pre-sliced: the slab leaf
-    is sharded on its column axis) and the permuted weight range
-    [i*dim_local, (i+1)*dim_local) — locally [0, hot_k_local) are its slab
-    columns, [hot_k_local, dim_local) its cold features.  The slab GEMMs
-    stay node-local; cold entries are masked to local ownership exactly
-    like :func:`make_sparse_mb_grad_step_2d`; one ``psum`` over ``model``
-    (the TP allreduce riding ICI) completes the logits.  The 128-column
-    GEMM widening matches the 1-D step (the N=1 matvec lowers to a
-    catastrophic lane reduction)."""
-    keep_b = 1.0 if with_intercept else 0.0
-
-    def mb_grad_step(params, xs):
-        slab, ints, floats = xs  # slab local: (mb, hot_k_local)
-        wts_local, b = params    # (dim_local,), ()
-        idx, rid, vals, y, w = _segment_csr_unpack(
-            ints, floats, cold_nnz_pad, mb
-        )
-        return _hotcold_core_2d(
-            kind, slab, wts_local, b, idx, rid, vals, y, w,
-            mb, hot_k_local, dim_local, keep_b,
-        )
-
-    return mb_grad_step
-
-
-def _hotcold_core_2d(kind: str, slab, wts_local, b, idx, rid, vals, y, w,
-                     mb: int, hot_k_local: int, dim_local: int,
-                     keep_b: float):
-    """The feature-sharded hot/cold minibatch math (the model-axis analog
-    of :func:`_hotcold_core`): shard-local slab GEMMs + cold entries masked
-    to local ownership + one psum over ``model`` completing the logits.
-    Shared by the in-memory step (pre-densified slab) and the out-of-core
-    step (slab densified in-program), so the two cannot drift — the
-    streamed-vs-in-memory bit-match contract depends on it."""
-    lo = jax.lax.axis_index("model") * dim_local
-    local_idx = idx - lo
-    mine = jnp.logical_and(local_idx >= 0, local_idx < dim_local)
-    safe_idx = jnp.clip(local_idx, 0, dim_local - 1)
-    dtype = slab.dtype
-    w_hot = jnp.broadcast_to(
-        wts_local[:hot_k_local].astype(dtype)[:, None], (hot_k_local, 128)
-    )
-    hot_partial = jax.lax.dot_general(
-        slab, w_hot, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )[:, 0]
-    contrib = jnp.where(
-        mine, vals * jnp.take(wts_local, safe_idx, axis=0), 0.0
-    )
-    cold_partial = jax.ops.segment_sum(contrib, rid, num_segments=mb)
-    # the TP allreduce: complete logits across feature shards
-    logits = jax.lax.psum(hot_partial + cold_partial, "model") + b
-    err, loss_sum = _sparse_loss(kind, logits, y, w)
-    err_m = jnp.broadcast_to(err.astype(dtype)[:, None], (mb, 128))
-    g_hot = jax.lax.dot_general(
-        slab, err_m, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )[:, 0]
-    err_ext = jnp.concatenate([err, jnp.zeros((1,), err.dtype)])
-    scatter = jnp.where(mine, vals * jnp.take(err_ext, rid, axis=0), 0.0)
-    g_w = jax.ops.segment_sum(scatter, safe_idx, num_segments=dim_local)
-    g_w = g_w.at[:hot_k_local].add(g_hot)
-    g_b = jnp.sum(err) * keep_b
-    return (g_w, g_b), loss_sum, jnp.sum(w)
-
-
-def make_hotcold_stream_mb_grad_step_2d(kind: str, mb: int,
-                                        cold_nnz_pad: int, hot_k_local: int,
-                                        dim_local: int,
-                                        with_intercept: bool = True,
-                                        slab_dtype=jnp.bfloat16):
-    """Feature-sharded out-of-core hot/cold minibatch gradient: the
-    model-axis composition of :func:`make_hotcold_stream_mb_grad_step`
-    (in-program slab densify from packed entries) and
-    :func:`make_hotcold_mb_grad_step_2d` (shard-local slab columns + cold
-    range, one psum completing logits).  Consumes the SAME block layout as
-    the 1-D stream step — entries carry global slab columns / permuted
-    ids, and each shard masks to its ownership in-program."""
-    keep_b = 1.0 if with_intercept else 0.0
-    dtype = jnp.dtype(slab_dtype)
-
-    def mb_grad_step(params, xs):
-        h_ints, h_vals, ints, floats = xs
-        wts_local, b = params  # (dim_local,), ()
-        pos, hrid = h_ints[0], h_ints[1]
-        lo_col = jax.lax.axis_index("model") * hot_k_local
-        lpos = pos - lo_col
-        mine_h = jnp.logical_and(lpos >= 0, lpos < hot_k_local)
-        slab = (
-            jnp.zeros((mb + 1, hot_k_local), dtype)  # row mb = pad sink
-            .at[
-                jnp.where(mine_h, hrid, mb),
-                jnp.clip(lpos, 0, hot_k_local - 1),
-            ]
-            .add(jnp.where(mine_h, h_vals, 0.0).astype(dtype))[:mb]
-        )
-        idx, rid, vals, y, w = _segment_csr_unpack(
-            ints, floats, cold_nnz_pad, mb
-        )
-        return _hotcold_core_2d(
-            kind, slab, wts_local, b, idx, rid, vals, y, w,
-            mb, hot_k_local, dim_local, keep_b,
-        )
-
-    return mb_grad_step
-
-
-def make_hotcold_glm_train_fn_2d(
-    kind: str,
-    mesh,
-    mb: int,
-    cold_nnz_pad: int,
-    hot_k: int,
-    dim_pad: int,
-    learning_rate: float,
-    reg: float,
-    max_iter: int,
-    tol: float,
-    with_intercept: bool = True,
-    slab_dtype=jnp.bfloat16,
-):
-    """Fused hot/cold training over a ('data','model') mesh: minibatch
-    groups shard over ``data``, slab columns and the permuted weight vector
-    over ``model``.  Loop scaffolding shared with every other path via
-    :func:`_build_fused_train_fn`."""
-    if kind not in ("logistic", "squared"):
-        raise ValueError(f"unknown loss kind {kind!r}")
-    model_size = dict(mesh.shape)["model"]
-    if hot_k % model_size or dim_pad % model_size:
-        raise ValueError(
-            f"hot_k={hot_k} / dim_pad={dim_pad} not divisible by model "
-            f"axis size {model_size} (use split_hot_cold(model_size=...))"
-        )
-    key = ("hotcold2d", kind, mesh, mb, cold_nnz_pad, hot_k, dim_pad,
-           float(learning_rate), float(reg), int(max_iter), float(tol),
-           bool(with_intercept), jnp.dtype(slab_dtype).name)
-    mb_grad_step = make_hotcold_mb_grad_step_2d(
-        kind, mb, cold_nnz_pad, hot_k // model_size, dim_pad // model_size,
-        with_intercept,
-    )
-
-    from jax.sharding import PartitionSpec as P
-
-    return _build_fused_train_fn(
-        key, mb_grad_step, mesh, learning_rate, reg, max_iter, tol,
-        in_specs=(
-            (P("model"), P()),
-            (P("data", None, "model"), P("data"), P("data")),
-        ),
-        out_specs=((P("model"), P()), P(), P(), P()),
-        delta_fn=_feature_sharded_delta,
-    )
-
-
-def make_hotcold_stream_glm_train_fn_2d(
-    kind: str,
-    mesh,
-    mb: int,
-    cold_nnz_pad: int,
-    hot_k: int,
-    dim_pad: int,
-    learning_rate: float,
-    reg: float,
-    max_iter: int,
-    tol: float,
-    with_intercept: bool = True,
-    slab_dtype=jnp.bfloat16,
-):
-    """Feature-sharded counterpart of
-    :func:`make_hotcold_stream_glm_train_fn`: packed entries shard over
-    ``data`` (replicated over ``model`` — they carry global slab columns;
-    each shard masks to its ownership in-program), the permuted weight
-    vector over ``model``."""
-    if kind not in ("logistic", "squared"):
-        raise ValueError(f"unknown loss kind {kind!r}")
-    model_size = dict(mesh.shape)["model"]
-    if hot_k % model_size or dim_pad % model_size:
-        raise ValueError(
-            f"hot_k={hot_k} / dim_pad={dim_pad} not divisible by model "
-            f"axis size {model_size} (use split_hot_cold(model_size=...))"
-        )
-    key = ("hotcold-stream2d", kind, mesh, mb, cold_nnz_pad, hot_k, dim_pad,
-           float(learning_rate), float(reg), int(max_iter), float(tol),
-           bool(with_intercept), jnp.dtype(slab_dtype).name)
-    mb_grad_step = make_hotcold_stream_mb_grad_step_2d(
-        kind, mb, cold_nnz_pad, hot_k // model_size, dim_pad // model_size,
-        with_intercept, slab_dtype=slab_dtype,
-    )
-
-    from jax.sharding import PartitionSpec as P
-
-    return _build_fused_train_fn(
-        key, mb_grad_step, mesh, learning_rate, reg, max_iter, tol,
-        in_specs=(
-            (P("model"), P()),
-            (P("data"), P("data"), P("data"), P("data")),
-        ),
-        out_specs=((P("model"), P()), P(), P(), P()),
-        delta_fn=_feature_sharded_delta,
-    )
-
-
-def train_glm_sparse_hotcold(
-    init_params,
-    hstack: HotColdStack,
-    kind: str,
-    mesh,
-    learning_rate: float,
-    max_iter: int,
-    reg: float = 0.0,
-    tol: float = 0.0,
-    with_intercept: bool = True,
-    checkpoint=None,
-    device_batch=None,
-    resident_slabs: bool = True,
-) -> TrainResult:
-    """Hot/cold counterpart of :func:`train_glm_sparse`.  Training runs in
-    permuted feature space; ``run`` unpermutes before returning, so BOTH
-    the returned coefficients and any saved checkpoints are in the
-    ORIGINAL feature space (each chunk's placement re-permutes on entry —
-    the permutation is deterministic from the packed data).  ``hstack``
-    may be a zero-arg thunk: the expensive host split is resolved only
-    when training actually runs, so a no-op checkpoint resume skips it
-    entirely.  A stack laid out with ``model_size > 1`` trains
-    feature-sharded over the mesh's ``model`` axis (slab columns and the
-    permuted weight vector sharded, one psum completing logits).
-
-    ``resident_slabs=False`` selects the SCALABLE formulation: HBM holds
-    only the packed entry arrays and each minibatch's slab densifies
-    in-program — O(nnz) device memory instead of O(n_rows x hot_k), the
-    only variant that exists at shapes where the slabs cannot fit (the
-    estimator's ``hotSlabMode`` auto policy decides; see
-    :func:`hotcold_slab_bytes`)."""
-    resolved: list = [None]
-
-    def hs() -> HotColdStack:
-        if resolved[0] is None:
-            resolved[0] = _resolve_thunk(hstack)
-        return resolved[0]
-
-    def place(params):
-        from jax.sharding import PartitionSpec as P
-
-        from flink_ml_tpu.parallel.mesh import global_put, replicate
-
-        w0, b0 = params
-        h = hs()
-        # scatter (not gather-by-inv_perm): dead positions of a rounded-up
-        # 2-D layout must hold zero, not a duplicated weight
-        w_perm = np.zeros((h.dim_pad,), np.float32)
-        w_perm[h.perm] = np.asarray(w0, np.float32)
-        if h.model_size > 1:
-            # multi-process-safe: every process derives the same permuted
-            # vector and materializes only its model-axis slice
-            return (
-                global_put(mesh, w_perm, P("model")),
-                global_put(mesh, np.asarray(b0, np.float32), P()),
-            )
-        return replicate(
-            mesh, (jnp.asarray(w_perm), jnp.asarray(b0, jnp.float32))
-        )
-
-    def trim(params):
-        return (np.asarray(params[0])[hs().perm], params[1])
-
-    def factory(n_epochs):
-        h = hs()
-        if h.model_size > 1:
-            maker = (
-                make_hotcold_glm_train_fn_2d if resident_slabs
-                else make_hotcold_stream_glm_train_fn_2d
-            )
-            return maker(
-                kind, mesh, h.cold.mb, h.cold.nnz_pad, h.hot_k, h.dim_pad,
-                learning_rate, reg, n_epochs, tol, with_intercept,
-                slab_dtype=h.slab_dtype,
-            )
-        maker = (
-            make_hotcold_glm_train_fn if resident_slabs
-            else make_hotcold_stream_glm_train_fn
-        )
-        return maker(
-            kind, mesh, h.cold.mb, h.cold.nnz_pad, h.hot_k, h.cold.dim,
-            learning_rate, reg, n_epochs, tol, with_intercept,
-            slab_dtype=h.slab_dtype,
-        )
-
-    def default_batch():
-        if resident_slabs:
-            return hotcold_device_batch(mesh, hs())
-        return hotcold_entries_device_batch(mesh, hs())
-
-    def run(n_epochs, params, dev_batch=None):
-        r = _run_fused_train(
-            factory(n_epochs), params,
-            dev_batch if dev_batch is not None else default_batch(),
-            mesh, place_params=place, batch_preplaced=True,
-            n_rows=hs().n_rows,
-        )
-        return TrainResult(params=trim(r.params), epochs=r.epochs,
-                           losses=r.losses, final_delta=r.final_delta,
-                           metrics=r.metrics)
-
-    if checkpoint is None:
-        return run(max_iter, init_params, _resolve_thunk(device_batch))
-    return run_chunked_checkpoint(
-        run, init_params, max_iter, tol, checkpoint, mesh, None,
-        device_batch=(
-            device_batch if device_batch is not None else default_batch
-        ),
     )
 
 

@@ -237,8 +237,7 @@ class StringIndexer(Estimator, StringIndexerParams):
     """Estimator: one vectorized unique+count pass per selected column.
 
     Vocabulary order follows ``stringOrderType`` (default frequencyDesc —
-    index 0 is the most frequent value, the layout a downstream hot/cold
-    split likes); ties always break lexicographically ascending, so the
+    index 0 is the most frequent value); ties always break lexicographically ascending, so the
     fit is deterministic.
     """
 

@@ -39,7 +39,6 @@ from flink_ml_tpu.lib.params import (
     HasCheckpoint,
     HasFeatureColsDefaultAsNull,
     HasNumFeatures,
-    HasNumHotFeatures,
     HasGlobalBatchSize,
     HasLabelCol,
     HasLearningRate,
@@ -59,7 +58,6 @@ from flink_ml_tpu.params.shared import (
 from flink_ml_tpu.table.schema import DataTypes, Schema
 from flink_ml_tpu.table.table import Table
 from flink_ml_tpu.utils.environment import MLEnvironmentFactory
-from flink_ml_tpu.utils import knobs
 
 MODEL_SCHEMA = Schema.of(
     ("coefficients", DataTypes.DENSE_VECTOR), ("intercept", DataTypes.DOUBLE)
@@ -86,7 +84,6 @@ class GlmTrainParams(
     HasReg,
     HasWithIntercept,
     HasNumFeatures,
-    HasNumHotFeatures,
     HasCheckpoint,
     HasSeed,
 ):
@@ -359,12 +356,6 @@ class GlmEstimatorBase(Estimator, GlmTrainParams):
         if vector_col is not None and _col_is_sparse(table, vector_col):
             return self._prepare_sparse(table, y, mesh, n_dev, batch_share)
 
-        if int(self.get_num_hot_features() or 0) > 0:
-            raise ValueError(
-                "numHotFeatures applies only to sparse vector columns "
-                "(dense features already stream through the MXU); unset it "
-                "for dense training"
-            )
         model_sharded = dict(mesh.shape).get("model", 1) > 1
         X, dim = resolve_features(table, self)
         layout_key = ("dense", vector_col, tuple(self.get_feature_cols() or ()),
@@ -530,11 +521,10 @@ class GlmEstimatorBase(Estimator, GlmTrainParams):
             )
         else:
             nnz_pad, steps = 0, 0  # pack's own natural layout
-        hot_k = int(self.get_num_hot_features() or 0)
         # the plain step takes either layout, and the pack picks by the row
-        # widths it observes; hot/cold's split and the 2-D step read
-        # segment-CSR, and processes agree on nnz_pad and steps only
-        row_regular = (hot_k == 0 and jax.process_count() == 1
+        # widths it observes; the 2-D step reads segment-CSR, and processes
+        # agree on nnz_pad and steps only
+        row_regular = (jax.process_count() == 1
                        and dict(mesh.shape).get("model", 1) == 1)
         layout_key = ("sparse", self.get_vector_col(), self.get_label_col(),
                       n_dev, batch_share, num_features, nnz_pad, steps,
@@ -556,9 +546,6 @@ class GlmEstimatorBase(Estimator, GlmTrainParams):
                 f"steps={steps}) but the pack chose "
                 f"({sstack.nnz_pad}, {sstack.steps})"
             )
-        if hot_k > 0:
-            return functools.partial(self._fit_sparse_hotcold, table, mesh,
-                                     layout_key, sstack, hot_k)
         return functools.partial(self._fit_sparse, table, sstack, mesh,
                                  layout_key, _zero_start(sstack.dim))
 
@@ -595,127 +582,6 @@ class GlmEstimatorBase(Estimator, GlmTrainParams):
         )
         return self._finish(result)
 
-    def _fit_sparse_hotcold(self, table, mesh, layout_key, sstack,
-                            hot_k: int) -> GlmModelBase:
-        """Hot/cold sparse fit (VERDICT r3 item 1): the top-``hot_k``
-        frequent features stream through a dense bf16 MXU slab, the cold
-        tail stays segment-CSR.  On a ('data','model') mesh the slab
-        columns and the weight vector shard over ``model`` — the hot/cold
-        formulation AND the wider-than-one-chip story at once.  See
-        lib/common.HotColdStack."""
-        from flink_ml_tpu.lib.common import (
-            hotcold_device_batch,
-            split_hot_cold,
-            train_glm_sparse_hotcold,
-        )
-
-        model_size = dict(mesh.shape).get("model", 1)
-        counts = None
-        plan = None
-        min_hot_pad = min_cold_pad = 0
-        if jax.process_count() > 1:
-            # every process must select the same hot set and fill the same
-            # shapes: agree on the GLOBAL frequency vector (sum of local
-            # entry counts) and the max pad widths before splitting; the
-            # model-axis weight placement rides global_put, so the 2-D
-            # layout works across processes too
-            from flink_ml_tpu.lib.common import (
-                hotcold_entry_counts,
-                hotcold_layout_floors,
-            )
-            from flink_ml_tpu.parallel.mesh import agree_max, agree_sum
-
-            counts = agree_sum(hotcold_entry_counts(sstack))
-            (hp, cp), plan = hotcold_layout_floors(
-                sstack, hot_k, model_size=model_size, counts=counts
-            )
-            min_hot_pad, min_cold_pad = agree_max(hp, cp)
-        # thunks: the host split AND the device slab build resolve lazily,
-        # so a no-op checkpoint resume pays neither
-        hstack = lambda: table.cached_pack(  # noqa: E731
-            layout_key + ("hot", hot_k, model_size, min_hot_pad,
-                          min_cold_pad),
-            lambda: split_hot_cold(
-                sstack, hot_k, model_size=model_size, counts=counts,
-                min_hot_pad=min_hot_pad, min_cold_pad=min_cold_pad,
-                plan=plan,
-            ),
-        )
-        # formulation choice (VERDICT r4 #1): resident slabs are fastest
-        # but their HBM footprint grows O(rows x hot_k); the streamed
-        # (in-program-densify) formulation holds only the packed entries.
-        # 'auto' keeps resident only while the slabs fit the budget.
-        mode = self.get_hot_slab_mode()
-        if mode == "auto":
-            from flink_ml_tpu.lib.common import (
-                hotcold_hot_k_eff,
-                hotcold_slab_bytes,
-            )
-
-            budget = knobs.knob_int("FMT_HOT_SLAB_BUDGET_MB") * (1 << 20)
-            # padded rows = groups x mb; slab width from the plan's own rule
-            slab_bytes = hotcold_slab_bytes(
-                sstack.ints.shape[0] * sstack.mb,
-                hotcold_hot_k_eff(sstack.dim, hot_k, model_size),
-            )
-            resident = slab_bytes <= budget
-            if jax.process_count() > 1:
-                # local budget env vars / near-boundary slab sizes can
-                # disagree across processes; divergent resident-vs-stream
-                # booleans build fused programs with different collective
-                # schedules — a hang.  Stream wins ties: any process voting
-                # stream (its slabs don't fit) forces stream everywhere.
-                from flink_ml_tpu.parallel.mesh import agree_max
-
-                (want_stream,) = agree_max(int(not resident))
-                resident = not want_stream
-            obs.gauge_set("train.hot_slab_bytes", float(slab_bytes))
-        else:
-            resident = mode == "resident"
-        # the agreed decision, visible in every RunReport: 1.0 = resident
-        # slabs, 0.0 = in-program densify (stream)
-        obs.gauge_set("train.hot_slab_resident", float(resident))
-        from flink_ml_tpu.table import slab_pool
-
-        hot_cols = [self.get_vector_col(), self.get_label_col()]
-        if resident:
-            # the pool's multi-process hit agreement matters HERE: the
-            # resident builder dispatches the densify device program, which
-            # every process must enter together
-            device_batch = lambda: slab_pool.get_or_place(  # noqa: E731
-                table, layout_key + ("hotdev", hot_k), mesh,
-                lambda: hotcold_device_batch(mesh, hstack()),
-                cols=hot_cols,
-            )
-        else:
-            from flink_ml_tpu.lib.common import hotcold_entries_device_batch
-
-            device_batch = lambda: slab_pool.get_or_place(  # noqa: E731
-                table, layout_key + ("hotdev-stream", hot_k), mesh,
-                lambda: hotcold_entries_device_batch(mesh, hstack()),
-                cols=hot_cols,
-            )
-        w0, b0 = _zero_start(sstack.dim)
-        lr = self.get_learning_rate()
-        result = fault.run_guarded(
-            lambda lr_scale: train_glm_sparse_hotcold(
-                (w0, b0),
-                hstack,
-                self.LOSS_KIND,
-                mesh,
-                learning_rate=lr * lr_scale,
-                max_iter=self.get_max_iter(),
-                reg=self.get_reg(),
-                tol=self.get_tol(),
-                with_intercept=self.get_with_intercept(),
-                checkpoint=self._checkpoint_config(),
-                device_batch=device_batch,
-                resident_slabs=resident,
-            ),
-            what=type(self).__name__,
-        )
-        return self._finish(result)
-
     def _fit_out_of_core(self, table) -> GlmModelBase:
         """Streaming fit over a :class:`~flink_ml_tpu.table.sources.ChunkedTable`.
 
@@ -729,18 +595,15 @@ class GlmEstimatorBase(Estimator, GlmTrainParams):
         ``globalBatchSize`` (full-batch SGD needs the entire dataset
         resident by definition).
 
-        Configurations with a full layout pre-pass (hot/cold frequency
-        scan, multi-process shape/count scans) run under a
+        Configurations with a full layout pre-pass (the multi-process
+        shape/count scans) run under a
         :func:`~flink_ml_tpu.table.sources.chunk_cache`: the scan's text
         parse records binary chunks, the pack pass replays them — ONE text
         read of the source total (VERDICT r4 #3).
         """
         from flink_ml_tpu.table.sources import chunk_cache
 
-        hot_k = int(self.get_num_hot_features() or 0)
-        with chunk_cache(
-            table, enabled=jax.process_count() > 1 or hot_k > 0
-        ) as table:
+        with chunk_cache(table, enabled=jax.process_count() > 1) as table:
             return self._fit_out_of_core_impl(table)
 
     def _fit_out_of_core_impl(self, table) -> GlmModelBase:
@@ -765,7 +628,6 @@ class GlmEstimatorBase(Estimator, GlmTrainParams):
                 "out-of-core training requires an explicit globalBatchSize "
                 "(full batch would need the whole dataset resident)"
             )
-        hot_k = int(self.get_num_hot_features() or 0)
         mb = max(1, -(-gbs // n_dev))
         G_local = mb * n_dev_pack
         steps_per_chunk = max(1, table.chunk_rows // G_local)
@@ -811,34 +673,21 @@ class GlmEstimatorBase(Estimator, GlmTrainParams):
                     "per-row sparse vectors)"
                 )
             pad_to_blocks = None
-            counts = None
             if jax.process_count() > 1:
                 from flink_ml_tpu.parallel.mesh import agree_max
 
                 # every process must compile the same block shapes AND
                 # dispatch the same number of collective chunk calls per
                 # epoch: ONE exact scan of the local shard (the sampled
-                # estimate would disagree across processes; the hot/cold
-                # frequency vector rides the same pass), then agree on
+                # estimate would disagree across processes), then agree on
                 # the pad and the per-epoch block count — short shards pad
                 # their epochs with gated no-op blocks
-                scanned = oc.scan_sparse_stream(
-                    table, vector_col, mb,
-                    count_dim=dim if hot_k > 0 else None,
+                nnz_local, rows_local = oc.scan_sparse_stream(
+                    table, vector_col, mb
                 )
-                nnz_local, rows_local = scanned[0], scanned[1]
-                counts = scanned[2] if hot_k > 0 else None
                 rows_per_block = steps_per_chunk * mb * n_dev_pack
                 nnz_pad, pad_to_blocks = agree_max(
                     nnz_local, -(-rows_local // rows_per_block)
-                )
-            elif hot_k > 0:
-                # the hot/cold counting pass doubles as an EXACT pad scan:
-                # one read yields both (out-of-core means every pass is a
-                # full disk/network read — never pay two), and the exact
-                # pad removes the sampled estimate's mid-fit failure mode
-                nnz_pad, _, counts = oc.scan_sparse_stream(
-                    table, vector_col, mb, count_dim=dim
                 )
             else:
                 nnz_pad = oc.estimate_nnz_pad(table, vector_col, mb, n_dev)
@@ -851,12 +700,6 @@ class GlmEstimatorBase(Estimator, GlmTrainParams):
                     np.asarray(t.col(label), dtype=np.float64),
                 )
 
-            if hot_k > 0:
-                return self._fit_out_of_core_hotcold(
-                    table, mesh, extract, n_dev_pack, mb, steps_per_chunk,
-                    dim, nnz_pad, hot_k, lr, reg, checkpoint,
-                    pad_to_blocks, local_counts=counts,
-                )
             blocks = oc.sparse_blocks_factory(
                 table, extract, n_dev_pack, mb, steps_per_chunk, dim,
                 nnz_pad, pad_to_blocks=pad_to_blocks,
@@ -910,13 +753,6 @@ class GlmEstimatorBase(Estimator, GlmTrainParams):
                 if first is None:
                     raise ValueError("empty source")
                 _, dim = resolve_features(first, self)
-
-            if hot_k > 0:
-                raise ValueError(
-                    "numHotFeatures applies only to sparse vector columns "
-                    "(dense features already stream through the MXU); "
-                    "unset it for dense training"
-                )
 
             def extract(t):
                 X, _ = resolve_features(t, self, dim=dim)
@@ -974,144 +810,6 @@ class GlmEstimatorBase(Estimator, GlmTrainParams):
         if trim is not None:  # the placer's own inverse: trim 2-D padding
             w_t, b_t = trim(result.params)
             result.params = (np.asarray(w_t), b_t)
-        return self._finish(result)
-
-    def _fit_out_of_core_hotcold(self, table, mesh, extract, n_dev, mb,
-                                 steps_per_chunk, dim, nnz_pad, hot_k,
-                                 lr, reg, checkpoint,
-                                 pad_to_blocks=None,
-                                 local_counts=None) -> GlmModelBase:
-        """Out-of-core hot/cold fit: the stream's frequency head rides the
-        MXU slab while the data never materializes.
-
-        The caller's ONE layout pre-pass (scan_sparse_stream with
-        count_dim) yields both the exact pad and the frequency vector that
-        fixes the hot set and permutation for the whole fit (a prefix
-        sample would bias selection on sorted files — the KMeans
-        reservoir-init reasoning); each streamed block
-        then packs and splits with that one plan, and the chunk program
-        densifies each minibatch's slab IN-PROGRAM (the in-memory path's
-        HBM-resident slabs cannot exist here by contract — the slab
-        footprint stays one (mb, hot_k) scratch).  Training runs in
-        permuted feature space start to finish: the initial params are
-        zeros (permutation-invariant), STREAM CHECKPOINTS HOLD THE
-        PERMUTED representation (a resume re-derives the identical
-        permutation from the deterministic pre-pass), and only the final
-        coefficients unpermute."""
-        from flink_ml_tpu.lib import out_of_core as oc
-        from flink_ml_tpu.lib.common import (
-            hotcold_feature_plan,
-            make_hotcold_stream_mb_grad_step,
-        )
-
-        # counts always arrive from the caller's combined layout scan —
-        # one stream pass yields the pad AND the frequency vector
-        if local_counts is None:
-            raise ValueError(
-                "hot/cold out-of-core fits require the caller's "
-                "scan-derived frequency vector"
-            )
-        counts = local_counts
-        if jax.process_count() > 1:
-            # the hot set must come from the GLOBAL frequency vector;
-            # pads need no extra agreement (both ride the agreed nnz_pad)
-            from flink_ml_tpu.parallel.mesh import agree_sum
-
-            counts = agree_sum(counts)
-        model_size = dict(mesh.shape).get("model", 1)
-        fplan = hotcold_feature_plan(dim, hot_k, model_size, counts)
-        dim_pad = fplan["dim_pad"]
-        hot_k_eff = fplan["hot_k_eff"]
-        # the SAME block layout serves 1-D and 2-D (entries carry global
-        # slab columns / permuted ids; the 2-D step masks to its shard
-        # ownership in-program)
-        blocks = oc.hotcold_blocks_factory(
-            table, extract, n_dev, mb, steps_per_chunk, dim, nnz_pad,
-            hot_k, fplan, pad_to_blocks=pad_to_blocks,
-        )
-        if model_size > 1:
-            from jax.sharding import PartitionSpec as P
-
-            from flink_ml_tpu.lib.common import (
-                make_hotcold_stream_mb_grad_step_2d,
-            )
-            from flink_ml_tpu.parallel.mesh import global_put
-
-            mb_grad = make_hotcold_stream_mb_grad_step_2d(
-                self.LOSS_KIND, mb, nnz_pad, hot_k_eff // model_size,
-                dim_pad // model_size, self.get_with_intercept(),
-            )
-            param_spec = (P("model"), P())
-
-            def place_params(params):
-                # params are ALREADY in permuted space (zeros init or a
-                # permuted-representation checkpoint): place, don't permute
-                w0, b0 = params
-                return (
-                    global_put(
-                        mesh, np.asarray(w0, np.float32), P("model")
-                    ),
-                    global_put(mesh, np.asarray(b0, np.float32), P()),
-                )
-
-            key = ("chunk-hotcold2d", self.LOSS_KIND, mesh, mb, nnz_pad,
-                   hot_k_eff, dim_pad, float(lr), float(reg),
-                   self.get_with_intercept())
-        else:
-            mb_grad = make_hotcold_stream_mb_grad_step(
-                self.LOSS_KIND, mb, nnz_pad, hot_k_eff, dim_pad,
-                self.get_with_intercept(),
-            )
-            param_spec = None
-            place_params = None
-            key = ("chunk-hotcold", self.LOSS_KIND, mesh, mb, nnz_pad,
-                   hot_k_eff, dim_pad, float(lr), float(reg),
-                   self.get_with_intercept())
-        w0, b0 = _zero_start(dim_pad)
-        # checkpointed params are in PERMUTED space: stamp the layout into
-        # the snapshot and refuse resumes under a different one (a changed
-        # mesh model size or hot_k yields a shape-compatible but
-        # differently-permuted vector — silently wrong without this)
-        import zlib
-
-        layout_sig = {
-            "model_size": model_size,
-            "hot_k_eff": hot_k_eff,
-            "dim_pad": dim_pad,
-            "perm_crc": int(zlib.crc32(fplan["perm"].tobytes())),
-        }
-
-        def validate_meta(meta):
-            stored = meta.get("hotcold_layout")
-            if stored is not None and stored != layout_sig:
-                raise ValueError(
-                    "checkpoint was written under a different hot/cold "
-                    f"layout ({stored} != {layout_sig}); resume with the "
-                    "original mesh/numHotFeatures or start fresh"
-                )
-
-        use_spill = getattr(table, "spill", False) and self.get_max_iter() > 1
-        with oc.maybe_spill(blocks, use_spill) as blocks:
-            result = fault.run_guarded(
-                lambda lr_scale: oc.train_out_of_core(
-                    (w0, b0),
-                    blocks,
-                    lambda: oc.make_chunk_step_fn(
-                        key + ("lrs", lr_scale), mb_grad, mesh,
-                        lr * lr_scale, reg, param_spec=param_spec,
-                    ),
-                    mesh,
-                    max_iter=self.get_max_iter(),
-                    tol=self.get_tol(),
-                    checkpoint=checkpoint,
-                    place_params=place_params,
-                    meta_extra={"hotcold_layout": layout_sig},
-                    validate_meta=validate_meta,
-                ),
-                what=type(self).__name__,
-            )
-        w_t = np.asarray(result.params[0])[fplan["perm"]]
-        result.params = (w_t, result.params[1])
         return self._finish(result)
 
     def _finish(self, result) -> GlmModelBase:

@@ -224,8 +224,6 @@ def train_out_of_core(
     finalize: Optional[Callable] = None,
     place_params: Optional[Callable] = None,
     max_inflight_chunks: int = 4,
-    meta_extra: Optional[dict] = None,
-    validate_meta: Optional[Callable[[dict], None]] = None,
 ) -> TrainResult:
     """The streaming epoch engine.
 
@@ -288,13 +286,16 @@ def train_out_of_core(
         latest = agreed_latest_checkpoint(checkpoint.directory)
         if latest is not None:
             init_params, meta = load_checkpoint(latest, like=init_params)
-            if validate_meta is not None:
-                # the caller's chance to reject a checkpoint whose params
-                # encode a configuration-dependent representation (e.g. the
-                # hot/cold permuted layout) that no longer matches — a
-                # shape-compatible mismatch would otherwise resume silently
-                # wrong
-                validate_meta(meta)
+            if "hotcold_layout" in meta:
+                # written by the retired numHotFeatures route, whose
+                # streamed checkpoints hold the weights in a permuted
+                # feature order that can have the table's own shape
+                raise ValueError(
+                    f"checkpoint {latest} was written by the retired "
+                    "numHotFeatures (hot/cold) route and holds its weights "
+                    "in a permuted feature order; start the fit afresh "
+                    "in an empty checkpointDir"
+                )
             start_epoch = int(meta["epoch"]) + 1
             losses = list(meta.get("losses", []))
             if _meta_converged(meta, tol) or start_epoch >= max_iter:
@@ -413,8 +414,7 @@ def train_out_of_core(
                     save_checkpoint(
                         checkpoint.directory, epoch - 1, host_params,
                         meta={"losses": losses, "converged": converged,
-                              "tol": tol, "final_delta": final_delta,
-                              **(meta_extra or {})},
+                              "tol": tol, "final_delta": final_delta},
                     )
                     prune_checkpoints(checkpoint.directory, checkpoint.keep)
 
@@ -547,8 +547,7 @@ def dense_blocks_factory(
 def _pack_sparse_block(vectors, y, n_dev: int, mb: int,
                        steps_per_chunk: int, dim: int, nnz_pad: int):
     """Pack one streamed block into the segment-CSR layout with the
-    stream-wide fixed ``nnz_pad`` — the shared prologue of the sparse and
-    hot/cold block factories.  A block denser than ``nnz_pad`` fails
+    stream-wide fixed ``nnz_pad``.  A block denser than ``nnz_pad`` fails
     loudly rather than silently recompiling per block."""
     if not isinstance(vectors, CsrRows):
         vectors = list(vectors)
@@ -1027,9 +1026,8 @@ class BlockSpill:
 
 
 def scan_sparse_stream(chunked_table, vector_col: str, mb: int,
-                       pad_multiple: int = 512,
-                       count_dim: Optional[int] = None):
-    """One full pass over the stream: (exact nnz_pad, total rows[, counts]).
+                       pad_multiple: int = 512):
+    """One full pass over the stream: (exact nnz_pad, total rows).
 
     The multi-process replacement for :func:`estimate_nnz_pad`'s
     sampled+safety heuristic — processes must agree on EXACT block shapes,
@@ -1037,41 +1035,16 @@ def scan_sparse_stream(chunked_table, vector_col: str, mb: int,
     windows the packer budgets; block boundaries are mb-aligned, so the
     window set equals the packer's group set) and ``agree_max`` reconciles
     the results.  Also the row count, from which the per-epoch block count
-    derives (short shards pad their epochs with empty no-op blocks).
-
-    ``count_dim`` additionally accumulates the per-feature frequency
-    vector in the SAME pass (the hot/cold selection input) — out-of-core
-    means every pass is a full disk/network read, so the hot/cold
-    multi-process path must not pay two."""
+    derives (short shards pad their epochs with empty no-op blocks)."""
     worst = 1
     n_rows = 0
     carry = np.zeros((0,), dtype=np.int64)  # partial trailing mb-window
-    freq = (
-        np.zeros((count_dim,), dtype=np.int64)
-        if count_dim is not None else None
-    )
     from flink_ml_tpu.lib.common import sparse_row_counts
 
     chunks = chunked_table.chunks()
     try:
         for t in chunks:
-            col = t.col(vector_col)
-            counts = sparse_row_counts(col)
-            if freq is not None:
-                if isinstance(col, CsrRows):
-                    idx = col.indices
-                else:
-                    idx = np.concatenate(
-                        [v.indices for v in col]
-                    ) if len(col) else np.zeros((0,), np.int64)
-                if idx.size and (
-                    int(idx.min()) < 0 or int(idx.max()) >= count_dim
-                ):
-                    raise ValueError(
-                        "feature index out of range for "
-                        f"numFeatures={count_dim}"
-                    )
-                freq += np.bincount(idx, minlength=count_dim)
+            counts = sparse_row_counts(t.col(vector_col))
             n_rows += len(counts)
             arr = np.concatenate([carry, np.asarray(counts, np.int64)])
             n_full = len(arr) // mb
@@ -1086,72 +1059,7 @@ def scan_sparse_stream(chunked_table, vector_col: str, mb: int,
     if carry.size:
         worst = max(worst, int(carry.sum()))
     nnz_pad = -(-worst // pad_multiple) * pad_multiple
-    if freq is not None:
-        return nnz_pad, n_rows, freq
     return nnz_pad, n_rows
-
-
-def hotcold_blocks_factory(
-    chunked_table,
-    extract: Callable[[Table], Tuple[list, np.ndarray]],
-    n_dev: int,
-    mb: int,
-    steps_per_chunk: int,
-    dim: int,
-    nnz_pad: int,
-    hot_k: int,
-    feature_plan: dict,
-    pad_to_blocks: Optional[int] = None,
-):
-    """Hot/cold counterpart of :func:`sparse_blocks_factory`: each block
-    packs to the segment-CSR layout, then splits into (hot ints, hot vals,
-    cold ints, cold floats) using the stream-wide ``feature_plan`` (one
-    permutation for the whole fit) with BOTH pads fixed at ``nnz_pad`` —
-    a group's hot (or cold) entries can never exceed its total entries, so
-    the ceiling is safe and every block reuses one compiled program.  Cold
-    ids are in PERMUTED space; the chunk program's weight vector lives
-    there too."""
-    from flink_ml_tpu.lib.common import split_hot_cold
-
-    rows_per_block = steps_per_chunk * mb * n_dev
-
-    def factory():
-        def gen():
-            for vectors, y in _block_rows(
-                chunked_table.chunks(), extract, rows_per_block
-            ):
-                stack = _pack_sparse_block(
-                    vectors, y, n_dev, mb, steps_per_chunk, dim, nnz_pad
-                )
-                h = split_hot_cold(
-                    stack, hot_k, feature_plan=feature_plan,
-                    min_hot_pad=nnz_pad, min_cold_pad=nnz_pad,
-                )
-                if (h.hot_ints.shape[2] != nnz_pad
-                        or h.cold.nnz_pad != nnz_pad):
-                    # only possible when nnz_pad is not pad-multiple-aligned
-                    raise ValueError(
-                        f"hot/cold block pads ({h.hot_ints.shape[2]}, "
-                        f"{h.cold.nnz_pad}) diverged from nnz_pad="
-                        f"{nnz_pad}; nnz_pad must be a pad-multiple-"
-                        "aligned ceiling"
-                    )
-                yield (
-                    (h.hot_ints, h.hot_vals, h.cold.ints, h.cold.floats),
-                    stack.n_rows,
-                )
-
-        def make_empty():
-            n_groups = n_dev * steps_per_chunk
-            ci, cf = _empty_sparse_block(n_groups, mb, nnz_pad)
-            hi = np.zeros((n_groups, 2, nnz_pad), dtype=np.int32)
-            hi[:, 1, :] = mb  # pad rows -> the scatter sink row
-            hv = np.zeros((n_groups, nnz_pad), dtype=np.float32)
-            return hi, hv, ci, cf
-
-        return _pad_stream_to(gen(), pad_to_blocks, make_empty)
-
-    return factory
 
 
 def estimate_nnz_pad(

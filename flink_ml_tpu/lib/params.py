@@ -187,43 +187,6 @@ class HasNumFeatures(WithParams):
         return self.set(self.NUM_FEATURES, value)
 
 
-class HasNumHotFeatures(WithParams):
-    NUM_HOT_FEATURES: ParamInfo = param_info(
-        "numHotFeatures",
-        "Hot/cold sparse training: the this-many most frequent features "
-        "stream through a dense bf16 MXU slab instead of random "
-        "gather/scatter (0 disables the split). Pick roughly the size of "
-        "the frequency head; the slab costs ~2*numHotFeatures bytes/row "
-        "of HBM traffic and rows*numHotFeatures*2 bytes of HBM residency.",
-        default=0, value_type=int,
-    )
-
-    def get_num_hot_features(self) -> int:
-        return self.get(self.NUM_HOT_FEATURES)
-
-    def set_num_hot_features(self, value: int):
-        return self.set(self.NUM_HOT_FEATURES, int(value))
-
-    HOT_SLAB_MODE: ParamInfo = param_info(
-        "hotSlabMode",
-        "Hot/cold in-memory formulation: 'resident' pre-densifies every "
-        "minibatch's slab once and keeps them HBM-resident across epochs "
-        "(fastest; footprint rows*numHotFeatures*2 bytes grows with the "
-        "dataset), 'stream' densifies each slab in-program per step (HBM "
-        "holds only the packed entries — the scalable formulation), "
-        "'auto' picks resident only while the slabs fit the budget "
-        "(FMT_HOT_SLAB_BUDGET_MB, default 4096).",
-        default="auto", value_type=str,
-        validator=lambda v: v in ("auto", "resident", "stream"),
-    )
-
-    def get_hot_slab_mode(self) -> str:
-        return self.get(self.HOT_SLAB_MODE)
-
-    def set_hot_slab_mode(self, value: str):
-        return self.set(self.HOT_SLAB_MODE, value)
-
-
 class HasWindowMs(WithParams):
     WINDOW_MS: ParamInfo = param_info(
         "windowMs", "Event-time tumbling window size in milliseconds.",

@@ -1,8 +1,8 @@
 """Process-wide metrics registry + the program's one span API.
 
 Counters (monotonic totals: chunks parsed, spill blocks written, epochs
-run), gauges (last-value observations: HBM watermarks, the agreed hot-slab
-decision), and timing histograms (count/total/min/max per named phase).
+run), gauges (last-value observations: HBM watermarks), and timing
+histograms (count/total/min/max per named phase).
 
 **Off by default.**  Every hook in a hot path reduces to one module-level
 boolean check when disabled — ``span()`` / ``phase()`` return a shared

@@ -139,9 +139,9 @@ def agree_max(*values: int):
 
 def agree_sum(array: np.ndarray) -> np.ndarray:
     """Cross-process element-wise SUM (identity single-process) — e.g. the
-    global feature-frequency vector every process must derive identically
-    before a hot/cold split (each process only sees its own shard's
-    counts).  Same ``FMT_AGREE_TIMEOUT_S`` watchdog as :func:`agree_max`."""
+    global row count every process must derive identically before the
+    centroid fit's streamed init (each process only sees its own shard's
+    rows).  Same ``FMT_AGREE_TIMEOUT_S`` watchdog as :func:`agree_max`."""
     maybe_fail("agree")
     if jax.process_count() == 1:
         return np.asarray(array)

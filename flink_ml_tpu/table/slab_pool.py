@@ -19,15 +19,14 @@ into a first-class, PROCESS-WIDE pool of placed training batches:
     buffer it describes (dead weakref => the entry silently drops);
   * **budget** — entries are LRU-evicted once the pool exceeds
     ``FMT_SLAB_POOL_BUDGET_MB`` (default 4096).  Multi-process the budget
-    is agreed once via :func:`~flink_ml_tpu.parallel.mesh.agree_max` (the
-    same divergence class PR 1 fixed for ``hotSlabMode``: per-process env
-    drift must not produce per-process cache behavior);
+    is agreed once via :func:`~flink_ml_tpu.parallel.mesh.agree_max`
+    (per-process env drift must not produce per-process cache behavior);
   * **multi-process hit agreement** — builders may dispatch collective
-    device programs (the hot-slab densify); a process that hit the pool
-    while a peer missed would skip its half of the collective and hang the
-    mesh.  Under ``jax.process_count() > 1`` every lookup agrees hit/miss
-    via ``agree_max`` — any miss forces a (re)build everywhere (miss wins
-    ties, mirroring the hotSlabMode rule);
+    device programs; a process that hit the pool while a peer missed would
+    skip its half of the collective and hang the mesh.  Under
+    ``jax.process_count() > 1`` every lookup agrees hit/miss via
+    ``agree_max`` — any miss forces a (re)build everywhere (miss wins
+    ties);
   * **refcounting** — drivers pin a checked-out slab for the duration of
     the device call (:meth:`SlabPool.pinned`); eviction skips pinned
     entries and never calls ``.delete()`` — it only drops the pool's
@@ -274,7 +273,7 @@ class SlabPool:
         """``FMT_SLAB_POOL_BUDGET_MB`` (default 4096), agreed ONCE across
         processes via ``agree_max`` — divergent per-process budgets would
         evict (and later re-place, possibly with collectives) on different
-        fits, the hotSlabMode divergence class PR 1 fixed.
+        fits.
 
         ``collective_ok=False`` (the ``agreed=False`` insert path —
         inference, contractually collective-free) must not fire the

@@ -711,8 +711,8 @@ def _atomic_np_save(path: str, arr) -> None:
 class ChunkSpillCache:
     """Binary replay cache of PARSED source chunks — one text parse total.
 
-    Fit paths with a layout pre-pass (the hot/cold frequency scan, the
-    multi-process shape/count scans, the KMeans reservoir init) used to
+    Fit paths with a layout pre-pass (the multi-process shape/count scans,
+    the KMeans reservoir init) used to
     read the text source twice before the packed :class:`BlockSpill` took
     over: once to scan, once to pack.  Out-of-core means every pass is a
     full disk/network read — never pay two.  Wrapping the chunked table in

@@ -246,8 +246,6 @@ DECLARATIONS: Tuple[Knob, ...] = (
          "Device-memory budget for the slab pool (LRU beyond it)."),
     Knob("FMT_SLAB_CHUNK_MB", "0", "int",
          "Chunk size for double-buffered cold placement (0 = one shot)."),
-    Knob("FMT_HOT_SLAB_BUDGET_MB", "4096", "int",
-         "HBM budget for the resident hot slab in hot/cold training."),
     Knob("FMT_SERVE_PALLAS", "0", "bool",
          "Pallas-fused serving kernel: scan+scale+score in one HBM pass."),
     Knob("FMT_SERVE_PALLAS_TILE", "512", "int",

@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """The sparse formulations of ``LogisticRegression.fit`` on ONE table, by
-hand on the chip (ROADMAP D1 waits for these numbers; no cell):
+hand on the chip (no cell):
 
-    python scripts/sparse_routes.py --seed <n> [--hot 4096] [--keep 0.67 ...]
+    python scripts/sparse_routes.py --seed <n> [--keep 0.67 ...]
                                     [--thin 0.33 ...]
 
 Makes ``criteo_sparse_lr``'s table from the seed with the benchmark's own
@@ -10,21 +10,15 @@ generator, then fits it (first fit: pack, split, place, compile; then
 ``--fits`` warm fits, timed on the host clock from the call to the model)
 through
 
-* the plain route as the estimator takes it (``numHotFeatures`` unset: the
-  default; the pack lays this table row-regular since PR 28, and split by
-  frequency since PR 30: the 16384 most frequent features looked up by
-  comparison, the rest in a cold list),
+* the plain route as the estimator takes it (the pack lays this table
+  row-regular since PR 28, and split by frequency since PR 30: the 16384
+  most frequent features looked up by comparison, the rest in a cold
+  list),
 * the plain route's unsplit row-regular step (``plain_unsplit``) and plain
   segment-CSR, which the estimator no longer takes for this table: packed
   and trained by the builders themselves (``pack_sparse_minibatches``,
   ``train_glm_sparse``), the pack's rules lifted inside this script, no
-  switch in the program,
-* hot/cold with ``hotSlabMode`` ``stream`` (the hot columns densified inside
-  the program, a step at a time),
-* hot/cold with ``hotSlabMode`` ``resident`` (the hot columns as bf16 slabs
-  on the device) where the slabs fit the chip: ``rows x hot x 2`` bytes, by
-  the program's own ``hotcold_slab_bytes``; a slab that cannot fit is
-  reported, not tried.  ``--hot 0`` leaves both hot/cold routes out.
+  switch in the program.
 
 Each ``--keep p`` then makes a RAGGED table (every stored entry of the
 cell's table kept with probability ``p``) and fits it through both step
@@ -122,7 +116,6 @@ def by_builders(name, column, y, config, row_regular, fits, split=False):
 def main() -> int:
     parser = argparse.ArgumentParser()
     parser.add_argument("--seed", type=int, required=True)
-    parser.add_argument("--hot", type=int, default=4096)
     parser.add_argument("--fits", type=int, default=2)
     parser.add_argument("--keep", type=float, action="append", default=[])
     parser.add_argument("--thin", type=float, action="append", default=[])
@@ -134,7 +127,6 @@ def main() -> int:
     from chipbench import data_sparse, program, program_sparse, run
     from flink_ml_tpu import obs
     from flink_ml_tpu.lib import common
-    from flink_ml_tpu.lib.common import hotcold_slab_bytes
     from flink_ml_tpu.ops.batch import CsrRows
 
     device = jax.devices()[0]
@@ -143,7 +135,7 @@ def main() -> int:
         return 3
     config = run.load_json(run.HERE, "configs", "criteo_sparse_lr.json")
     program.prepare(os.path.join(run.OUT, "sparse_routes"))
-    dim, batch = int(config["numFeatures"]), int(config["globalBatchSize"])
+    dim = int(config["numFeatures"])
     t0 = time.perf_counter()
     indptr, indices, values, y = data_sparse.make_rows(
         config["data"], int(config["rows"]), dim, args.seed)
@@ -151,65 +143,40 @@ def main() -> int:
     print(json.dumps({"rows": len(y), "entries": entries,
                       "data_s": time.perf_counter() - t0,
                       "device": device.device_kind}), flush=True)
-    limit = (device.memory_stats() or {}).get("bytes_limit", 0)
-    padded_rows = -(-len(y) // batch) * batch
-    slab = hotcold_slab_bytes(padded_rows, args.hot)
-    routes = [("plain", None, None)]
-    if args.hot:
-        routes.append(("hotcold_stream", args.hot, "stream"))
-    if args.hot and limit and slab > 0.8 * limit:
-        print(json.dumps({"route": "hotcold_resident", "hot": args.hot,
-                          "slab_bytes": slab, "device_bytes": limit,
-                          "ran": False, "why": "the slab does not fit"}),
-              flush=True)
-    elif args.hot:
-        routes.append(("hotcold_resident", args.hot, "resident"))
-    # one table: the hot/cold routes share its segment-CSR pack, the plain
-    # route packs its own (row-regular), and nothing placed is shared
     table = program_sparse.table(dim, indptr, indices, values, y)
-    for name, hot, mode in routes:
-        program.release()
-        before = program.snapshot()["counters"]
+    program.release()
+    before = program.snapshot()["counters"]
 
-        def fit():
-            estimator = program_sparse.logreg(config, 0.1, 0.0)
-            if hot:
-                estimator = (estimator.set_num_hot_features(hot)
-                             .set_hot_slab_mode(mode))
-            t = time.perf_counter()
-            answer = program.fit_answer(estimator.fit(table))
-            return time.perf_counter() - t, answer
+    def fit():
+        estimator = program_sparse.logreg(config, 0.1, 0.0)
+        t = time.perf_counter()
+        answer = program.fit_answer(estimator.fit(table))
+        return time.perf_counter() - t, answer
 
-        line = {"route": name, "hot": hot}
-        try:
-            first_s, first = fit()
-            warm = [fit() for _ in range(args.fits)]
-        except Exception as exc:  # noqa: BLE001 - reported, next route
-            line.update(ran=False, error=repr(exc)[:300])
-            print(json.dumps(line), flush=True)
-            continue
-        seconds = statistics.median(s for s, _a in warm)
-        after = program.snapshot()["counters"]
-        line.update(
-            ran=True, first_fit_s=first_s, warm_fit_s=seconds,
-            entries_per_s=entries / seconds,
-            same_bytes=all(np.array_equal(a["coef"], first["coef"])
-                           for _s, a in warm),
-            loss=float(first["losses"][-1]),
-            coef_norm=float(np.linalg.norm(first["coef"])),
-            peak_bytes=(device.memory_stats() or {}).get(
-                "peak_bytes_in_use", 0),
-            **{short: after.get(f"train.sparse_{short}", 0)
-               - before.get(f"train.sparse_{short}", 0)
-               for short in ("ell_fits", "hot_fits", "hot_declined",
-                             "hot_entries", "entries")},
-            hidden={k: after[k] - before.get(k, 0)
-                    for k in program.MUST_BE_ZERO
-                    if after.get(k, 0) - before.get(k, 0)},
-            timings={k: round(v["total_s"], 3) for k, v in
-                     obs.registry().snapshot()["timings"].items()
-                     if k.startswith("phase.")})
-        print(json.dumps(line), flush=True)
+    first_s, first = fit()
+    warm = [fit() for _ in range(args.fits)]
+    seconds = statistics.median(s for s, _a in warm)
+    after = program.snapshot()["counters"]
+    line = dict(
+        route="plain", ran=True, first_fit_s=first_s, warm_fit_s=seconds,
+        entries_per_s=entries / seconds,
+        same_bytes=all(np.array_equal(a["coef"], first["coef"])
+                       for _s, a in warm),
+        loss=float(first["losses"][-1]),
+        coef_norm=float(np.linalg.norm(first["coef"])),
+        peak_bytes=(device.memory_stats() or {}).get(
+            "peak_bytes_in_use", 0),
+        **{short: after.get(f"train.sparse_{short}", 0)
+           - before.get(f"train.sparse_{short}", 0)
+           for short in ("ell_fits", "hot_fits", "hot_declined",
+                         "hot_entries", "entries")},
+        hidden={k: after[k] - before.get(k, 0)
+                for k in program.MUST_BE_ZERO
+                if after.get(k, 0) - before.get(k, 0)},
+        timings={k: round(v["total_s"], 3) for k, v in
+                 obs.registry().snapshot()["timings"].items()
+                 if k.startswith("phase.")})
+    print(json.dumps(line), flush=True)
     table = None
     column = CsrRows(dim, indptr, indices, values)
     for name, row_regular in (("plain_unsplit", True),

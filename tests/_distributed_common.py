@@ -201,8 +201,7 @@ def fit_kmeans_shard_table(table):
     return cents, float(model.train_cost_)
 
 
-def fit_sparse_shard_table(table, hot_k: int = 0, checkpoint_dir=None,
-                           max_iter=None):
+def fit_sparse_shard_table(table, checkpoint_dir=None, max_iter=None):
     from flink_ml_tpu.lib import LogisticRegression
 
     est = (
@@ -213,8 +212,6 @@ def fit_sparse_shard_table(table, hot_k: int = 0, checkpoint_dir=None,
         .set_max_iter(SHARD_EPOCHS if max_iter is None else max_iter)
         .set_global_batch_size(SHARD_G)
     )
-    if hot_k:
-        est.set_num_hot_features(hot_k)
     if checkpoint_dir is not None:
         est.set_checkpoint_dir(str(checkpoint_dir)).set_checkpoint_interval(1)
     model = est.fit(table)

@@ -154,19 +154,6 @@ if len(sys.argv) > 4:
         flush=True,
     )
 
-    # hot/cold fit across processes: the hot set must come from the GLOBAL
-    # frequency vector (agree_sum of per-shard counts — each shard's local
-    # top-K differs) and both processes must fill the agreed pad widths
-    w_hc, b_hc = fit_sparse_shard_table(sparse_table, hot_k=16)
-    digest = [float(np.sum(w_hc)), float(np.sum(w_hc * w_hc))]
-    probe = [float(v) for v in w_hc[:8]]
-    print(
-        "FITHOT " + " ".join(
-            f"{v:.9e}" for v in digest + probe + [b_hc]
-        ),
-        flush=True,
-    )
-
     # sparse OUT-OF-CORE across processes: one exact local stream scan +
     # agree_max fixes the block shapes; equal shards here, so the result
     # must bit-match the in-memory sparse fit (the OOC engine's
@@ -183,22 +170,6 @@ if len(sys.argv) > 4:
     probe = [float(v) for v in w_so[:8]]
     print(
         "FITSOOC " + " ".join(f"{v:.9e}" for v in digest + probe + [b_so]),
-        flush=True,
-    )
-
-    # hot/cold OUT-OF-CORE across processes: the scan-derived local counts
-    # agree_sum into the global frequency vector, the shared feature plan
-    # permutes identically everywhere, and the streamed fit must bit-match
-    # the in-memory hot/cold fit (-> the parent's FITHOT reference digest)
-    ooc_hot = ChunkedTable(
-        CollectionSource(list(zip(svecs, sy)), sparse_shard_schema()),
-        chunk_rows=64,
-    )
-    w_ho, b_ho = fit_sparse_shard_table(ooc_hot, hot_k=16)
-    digest = [float(np.sum(w_ho)), float(np.sum(w_ho * w_ho))]
-    probe = [float(v) for v in w_ho[:8]]
-    print(
-        "FITHOOC " + " ".join(f"{v:.9e}" for v in digest + probe + [b_ho]),
         flush=True,
     )
 
@@ -298,33 +269,22 @@ if len(sys.argv) > 4:
             ),
             flush=True,
         )
-        w_h2, b_h2 = fit_sparse_shard_table(sparse_table, hot_k=16)
-        digest = [float(np.sum(w_h2)), float(np.sum(w_h2 * w_h2))]
-        probe = [float(v) for v in w_h2[:8]]
-        print(
-            "FITH2D " + " ".join(
-                f"{v:.9e}" for v in digest + probe + [b_h2]
-            ),
-            flush=True,
-        )
-        # the full formulation matrix's last corner: hot/cold +
-        # out-of-core + 2-D mesh + multi-process (agree_sum'd counts feed
-        # the model_size-aware plan; the streamed 2-D chunk program masks
-        # to shard ownership; model-axis params ride global_put)
-        w_ho2, b_ho2 = fit_sparse_shard_table(
+        # out-of-core + 2-D mesh + multi-process: the streamed 2-D chunk
+        # program reads each process's blocks at the agreed pad, masks to
+        # shard ownership, and places model-axis params via global_put
+        w_so2, b_so2 = fit_sparse_shard_table(
             ChunkedTable(
                 CollectionSource(
                     list(zip(svecs, sy)), sparse_shard_schema()
                 ),
                 chunk_rows=64,
-            ),
-            hot_k=16,
+            )
         )
-        digest = [float(np.sum(w_ho2)), float(np.sum(w_ho2 * w_ho2))]
-        probe = [float(v) for v in w_ho2[:8]]
+        digest = [float(np.sum(w_so2)), float(np.sum(w_so2 * w_so2))]
+        probe = [float(v) for v in w_so2[:8]]
         print(
-            "FITH2DOOC " + " ".join(
-                f"{v:.9e}" for v in digest + probe + [b_ho2]
+            "FITS2DOOC " + " ".join(
+                f"{v:.9e}" for v in digest + probe + [b_so2]
             ),
             flush=True,
         )

@@ -162,28 +162,6 @@ def test_two_process_mesh_psum(tmp_path):
             ),
         )
 
-    # hot/cold across processes: hot selection from the globally-summed
-    # frequency vector, pad widths from agree_max — must equal the
-    # single-process hot/cold fit over the interleaved order (f32 slab
-    # rounding differs only in summation grouping; the bf16 slab is used
-    # on both sides, so results are bit-comparable)
-    w_href, b_href = fit_sparse_shard_table(sref, hot_k=16)
-    expected_hot = (
-        [float(np.sum(w_href)), float(np.sum(w_href * w_href))]
-        + [float(v) for v in w_href[:8]] + [b_href]
-    )
-    for pid, out in enumerate(outs):
-        line = [ln for ln in out.splitlines() if ln.startswith("FITHOT ")]
-        assert line, f"worker {pid} printed no FITHOT line:\n{out}"
-        got = [float(v) for v in line[0].split()[1:]]
-        np.testing.assert_allclose(
-            got, expected_hot, rtol=1e-5, atol=1e-7,
-            err_msg=(
-                f"worker {pid} FITHOT: per-process hot/cold fit diverged "
-                "from the single-process interleaved-order fit"
-            ),
-        )
-
     # sparse out-of-core: equal shards, so the streamed fit bit-matches
     # the in-memory fit and shares its expected digest
     for pid, out in enumerate(outs):
@@ -195,20 +173,6 @@ def test_two_process_mesh_psum(tmp_path):
             err_msg=(
                 f"worker {pid} FITSOOC: per-process sparse out-of-core fit "
                 "diverged from the single-process interleaved-order fit"
-            ),
-        )
-
-    # hot/cold out-of-core: streamed hot/cold bit-matches the in-memory
-    # hot/cold fit, so it shares FITHOT's expected digest
-    for pid, out in enumerate(outs):
-        line = [ln for ln in out.splitlines() if ln.startswith("FITHOOC ")]
-        assert line, f"worker {pid} printed no FITHOOC line:\n{out}"
-        got = [float(v) for v in line[0].split()[1:]]
-        np.testing.assert_allclose(
-            got, expected_hot, rtol=1e-5, atol=1e-7,
-            err_msg=(
-                f"worker {pid} FITHOOC: per-process hot/cold out-of-core "
-                "fit diverged from the single-process in-memory fit"
             ),
         )
 
@@ -318,27 +282,20 @@ def test_two_process_mesh_psum(tmp_path):
             [float(np.sum(w_s2)), float(np.sum(w_s2 * w_s2))]
             + [float(v) for v in w_s2[:8]] + [b_s2]
         )
-        w_h2, b_h2 = fit_sparse_shard_table(sref, hot_k=16)
-        expected_h2 = (
-            [float(np.sum(w_h2)), float(np.sum(w_h2 * w_h2))]
-            + [float(v) for v in w_h2[:8]] + [b_h2]
-        )
-        w_ho2, b_ho2 = fit_sparse_shard_table(
+        w_so2, b_so2 = fit_sparse_shard_table(
             ChunkedTable(
                 CollectionSource(list(zip(svecs, sy)), sparse_shard_schema()),
                 chunk_rows=64,
-            ),
-            hot_k=16,
+            )
         )
-        expected_ho2 = (
-            [float(np.sum(w_ho2)), float(np.sum(w_ho2 * w_ho2))]
-            + [float(v) for v in w_ho2[:8]] + [b_ho2]
+        expected_so2 = (
+            [float(np.sum(w_so2)), float(np.sum(w_so2 * w_so2))]
+            + [float(v) for v in w_so2[:8]] + [b_so2]
         )
     finally:
         env.set_mesh(old_mesh)
     for tag, expected in (("FITD2D", expected_d2), ("FITS2D", expected_s2),
-                          ("FITH2D", expected_h2),
-                          ("FITH2DOOC", expected_ho2)):
+                          ("FITS2DOOC", expected_so2)):
         for pid, out in enumerate(outs):
             line = [ln for ln in out.splitlines() if ln.startswith(tag + " ")]
             assert line, f"worker {pid} printed no {tag} line:\n{out}"
@@ -355,8 +312,6 @@ def test_two_process_mesh_psum(tmp_path):
     # concatenated order on both sides), Lloyd accumulation differs only
     # in per-device grouping — looser float tolerance than the GLMs'
     # schedule-exact paths (see KMeans._fit_out_of_core docstring)
-    from flink_ml_tpu.table.sources import ChunkedTable, CollectionSource
-
     km_rows = [tuple(Xc[i]) + (yc[i],) for i in range(len(yc))]
     cents_oref, cost_oref = fit_kmeans_shard_table(
         ChunkedTable(CollectionSource(km_rows, shard_schema()), chunk_rows=64)

@@ -2,6 +2,8 @@
 match the dense path on identical data, scale to wide feature spaces without
 densifying, and score sparsely at transform time."""
 
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -141,408 +143,251 @@ class TestSparseLogisticRegression:
         assert model.train_epochs_ < 500
 
 
-class TestHotColdSplit:
-    """Hot/cold sparse training (VERDICT r3 item 1): the top-K frequent
-    features stream through a dense MXU slab; the cold tail stays
-    segment-CSR.  On the CPU test mesh the slab path runs the identical
-    program (bf16 emulated)."""
+def _power_law_data(n=400, dim=64, seed=3):
+    """A skewed table: features [0, 8) stand in most rows (three of each
+    row's five stored values), the rest are drawn from the long tail."""
+    rng = np.random.RandomState(seed)
+    true_w = rng.randn(dim)
+    vecs, ys = [], []
+    for _ in range(n):
+        hot = rng.choice(8, 3, replace=False)
+        cold = 8 + rng.choice(dim - 8, 2, replace=False)
+        idx = np.sort(np.concatenate([hot, cold]))
+        val = np.ones(idx.size)
+        x = np.zeros(dim)
+        x[idx] = val
+        vecs.append(SparseVector(dim, idx.astype(np.int64), val))
+        ys.append(float((x @ true_w) > 0))
+    return vecs, np.asarray(ys)
 
-    def _power_law_data(self, n=400, dim=64, seed=3):
-        """Skewed frequencies: features [0, 8) appear in most rows."""
-        rng = np.random.RandomState(seed)
-        true_w = rng.randn(dim)
-        vecs, ys = [], []
-        for _ in range(n):
-            hot = rng.choice(8, 3, replace=False)
-            cold = 8 + rng.choice(dim - 8, 2, replace=False)
-            idx = np.sort(np.concatenate([hot, cold]))
-            val = np.ones(idx.size)
-            x = np.zeros(dim)
-            x[idx] = val
-            vecs.append(SparseVector(dim, idx.astype(np.int64), val))
-            ys.append(float((x @ true_w) > 0))
-        return vecs, np.asarray(ys)
 
-    def test_split_conserves_entries_and_picks_frequent(self):
-        import jax.numpy as jnp
+def _skewed_est(dim=64, max_iter=20, batch=64, **kw):
+    est = (
+        LogisticRegression().set_vector_col("features")
+        .set_label_col("label").set_prediction_col("pred")
+        .set_num_features(dim).set_learning_rate(0.5)
+        .set_max_iter(max_iter).set_global_batch_size(batch)
+    )
+    for k, v in kw.items():
+        getattr(est, f"set_{k}")(v)
+    return est
 
-        from flink_ml_tpu.lib.common import split_hot_cold
 
-        vecs, ys = self._power_law_data()
-        s = pack_sparse_minibatches(vecs, ys, n_dev=2, global_batch_size=64)
-        h = split_hot_cold(s, hot_k=8, pad_multiple=8,
-                           slab_dtype=jnp.float32)
-        # the 8 ever-present features become slab positions
-        assert h.hot_k == 8
-        np.testing.assert_array_equal(np.sort(h.perm[:8]), np.arange(8))
-        np.testing.assert_array_equal(h.inv_perm[h.perm], np.arange(s.dim))
-        # entry conservation: every valid entry lands exactly once
-        valid = (s.ints[:, 1, :] < s.mb).sum()
-        hot_n = (h.hot_ints[:, 1, :] < s.mb).sum()
-        cold_n = (h.cold.ints[:, 1, :] < s.mb).sum()
-        assert hot_n + cold_n == valid
-        assert hot_n == 400 * 3 and cold_n == 400 * 2
-        # y/w tails preserved
-        np.testing.assert_array_equal(
-            h.cold.floats[:, h.cold.nnz_pad:], s.floats[:, s.nnz_pad:]
-        )
+def _skewed_stream(vecs, ys, chunk_rows=64):
+    from flink_ml_tpu.table.sources import ChunkedTable, CollectionSource
 
-    def test_f32_slab_matches_plain_sparse_fit(self):
-        """With an f32 slab the hot/cold program is the same math as the
-        plain segment-CSR program (different summation grouping only)."""
-        import jax.numpy as jnp
+    return ChunkedTable(CollectionSource(list(zip(vecs, ys)), SCHEMA),
+                        chunk_rows=chunk_rows)
 
-        from flink_ml_tpu.lib.common import (
-            split_hot_cold,
-            train_glm_sparse,
-            train_glm_sparse_hotcold,
-        )
-        from flink_ml_tpu.parallel.mesh import default_mesh
 
-        vecs, ys = self._power_law_data()
-        mesh = default_mesh()
-        s = pack_sparse_minibatches(vecs, ys, n_dev=8, global_batch_size=64)
-        h = split_hot_cold(s, hot_k=8, pad_multiple=8, slab_dtype=jnp.float32)
-        p0 = (jnp.zeros((s.dim,), jnp.float32), jnp.zeros((), jnp.float32))
-        rp = train_glm_sparse(
-            (jnp.copy(p0[0]), jnp.copy(p0[1])), s, "logistic", mesh,
-            learning_rate=0.5, max_iter=15,
-        )
-        rh = train_glm_sparse_hotcold(
-            (jnp.copy(p0[0]), jnp.copy(p0[1])), h, "logistic", mesh,
-            learning_rate=0.5, max_iter=15,
-        )
-        np.testing.assert_allclose(rh.params[0], rp.params[0],
-                                   rtol=1e-4, atol=1e-5)
-        np.testing.assert_allclose(rh.params[1], rp.params[1], atol=1e-5)
-        np.testing.assert_allclose(rh.losses, rp.losses, rtol=1e-4)
+@contextlib.contextmanager
+def _on_mesh(axes):
+    from flink_ml_tpu.parallel.mesh import create_mesh
+    from flink_ml_tpu.utils.environment import MLEnvironmentFactory
 
-    def test_estimator_hot_split_bf16(self):
-        """numHotFeatures routes the fit through the slab path; binary
-        feature values are exact in bf16, so predictions agree with the
-        plain path."""
-        vecs, ys = self._power_law_data(n=500)
-        t = Table.from_columns(SCHEMA, {"features": vecs, "label": ys})
+    env = MLEnvironmentFactory.get_default()
+    old = env.get_mesh()
+    env.set_mesh(create_mesh(axes))
+    try:
+        yield
+    finally:
+        env.set_mesh(old)
 
-        def fit(hot):
-            return (
-                LogisticRegression().set_vector_col("features")
-                .set_label_col("label").set_prediction_col("pred")
-                .set_learning_rate(0.5).set_max_iter(40)
-                .set_global_batch_size(64).set_num_hot_features(hot)
-                .fit(t)
-            )
 
-        m_hot = fit(16)
-        m_plain = fit(0)
-        (ph,) = m_hot.transform(t)
-        (pp,) = m_plain.transform(t)
-        agree = np.mean(
-            np.asarray(ph.col("pred")) == np.asarray(pp.col("pred"))
-        )
-        assert agree >= 0.98, agree
-        acc = np.mean(np.asarray(ph.col("pred")) == ys)
-        assert acc > 0.85, acc
+def _skewed_checkpoint_resume(tmp_path):
+    """In memory: a fit stopped at epoch 6 and resumed from its
+    checkpoint lands on the uninterrupted fit."""
+    vecs, ys = _power_law_data(n=200)
+    t = Table.from_columns(SCHEMA, {"features": vecs, "label": ys})
+    full = _skewed_est(max_iter=12).fit(t)
+    ck = str(tmp_path / "ck")
+    _skewed_est(max_iter=6, checkpoint_dir=ck,
+                checkpoint_interval=3).fit(t)
+    resumed = _skewed_est(max_iter=12, checkpoint_dir=ck,
+                          checkpoint_interval=3).fit(t)
+    assert resumed.train_epochs_ == full.train_epochs_ == 12
+    np.testing.assert_allclose(resumed.coefficients(), full.coefficients(),
+                               rtol=1e-6, atol=1e-7)
 
-    def test_hot_k_covering_all_features(self):
-        """hot_k >= dim: everything is hot, the cold stack is empty pads."""
-        import jax.numpy as jnp
 
-        import jax
+def _skewed_out_of_core_bit_matches_in_memory(tmp_path):
+    """Streamed training equals the in-memory fit of the same object
+    column bit for bit: both step segment-CSR on one schedule."""
+    vecs, ys = _power_law_data(n=400)
+    t = Table.from_columns(SCHEMA, {"features": vecs, "label": ys})
+    m_mem = _skewed_est().fit(t)
+    m_ooc = _skewed_est().fit(_skewed_stream(vecs, ys, chunk_rows=96))
+    np.testing.assert_array_equal(m_ooc.coefficients(), m_mem.coefficients())
+    assert m_ooc.intercept() == m_mem.intercept()
 
-        from flink_ml_tpu.lib.common import split_hot_cold, train_glm_sparse_hotcold
-        from flink_ml_tpu.parallel.mesh import create_mesh
 
-        vecs, ys = self._power_law_data(n=200, dim=32)
-        s = pack_sparse_minibatches(vecs, ys, n_dev=2, global_batch_size=32)
-        h = split_hot_cold(s, hot_k=999, pad_multiple=8, slab_dtype=jnp.float32)
-        assert h.hot_k == 32
-        assert (h.cold.ints[:, 1, :] < s.mb).sum() == 0
-        r = train_glm_sparse_hotcold(
-            (jnp.zeros((32,), jnp.float32), jnp.zeros((), jnp.float32)),
-            h, "logistic", create_mesh({"data": 2}, jax.devices()[:2]),
-            learning_rate=0.5, max_iter=10,
-        )
-        assert np.all(np.isfinite(r.params[0]))
+def _skewed_out_of_core_checkpoint_resume(tmp_path):
+    """A streamed fit killed after epoch 6 and resumed lands on the
+    uninterrupted result."""
+    vecs, ys = _power_law_data(n=300)
+    full = _skewed_est(max_iter=12).fit(_skewed_stream(vecs, ys))
+    ck = str(tmp_path / "ck")
+    _skewed_est(max_iter=6, checkpoint_dir=ck,
+                checkpoint_interval=3).fit(_skewed_stream(vecs, ys))
+    resumed = _skewed_est(max_iter=12, checkpoint_dir=ck,
+                          checkpoint_interval=3).fit(_skewed_stream(vecs, ys))
+    # a resumed engine re-places loaded host params, which can fuse
+    # differently at the sub-ulp level (test_out_of_core.py's resume test)
+    np.testing.assert_allclose(resumed.coefficients(), full.coefficients(),
+                               rtol=1e-6, atol=1e-9)
 
-    def test_checkpoint_resume(self, tmp_path):
-        import jax.numpy as jnp
 
-        from flink_ml_tpu.iteration.checkpoint import CheckpointConfig
-        from flink_ml_tpu.lib.common import split_hot_cold, train_glm_sparse_hotcold
-        from flink_ml_tpu.parallel.mesh import default_mesh
+def _skewed_out_of_core_resume_under_another_layout(tmp_path):
+    """A streamed checkpoint holds the weights in the table's own ids: a
+    resume under another width is refused, and one on a ('data', 'model')
+    mesh continues where the 1-D fit stopped."""
+    vecs, ys = _power_law_data(n=200)
+    full = _skewed_est(max_iter=12).fit(_skewed_stream(vecs, ys))
+    ck = str(tmp_path / "ck")
+    _skewed_est(max_iter=6, checkpoint_dir=ck,
+                checkpoint_interval=3).fit(_skewed_stream(vecs, ys))
+    with pytest.raises(TypeError, match="incompatible shapes"):
+        _skewed_est(dim=65, max_iter=12, checkpoint_dir=ck,
+                    checkpoint_interval=3).fit(_skewed_stream(vecs, ys))
+    with _on_mesh({"data": 4, "model": 2}):
+        resumed = _skewed_est(max_iter=12, checkpoint_dir=ck,
+                              checkpoint_interval=3).fit(
+            _skewed_stream(vecs, ys))
+    np.testing.assert_allclose(resumed.coefficients(), full.coefficients(),
+                               rtol=1e-6, atol=1e-7)
 
-        vecs, ys = self._power_law_data(n=200)
-        mesh = default_mesh()
-        s = pack_sparse_minibatches(vecs, ys, n_dev=8, global_batch_size=64)
-        h = split_hot_cold(s, hot_k=8, pad_multiple=8, slab_dtype=jnp.float32)
-        p0 = (jnp.zeros((s.dim,), jnp.float32), jnp.zeros((), jnp.float32))
-        full = train_glm_sparse_hotcold(
-            (jnp.copy(p0[0]), jnp.copy(p0[1])), h, "logistic", mesh,
-            learning_rate=0.5, max_iter=12,
-        )
-        cfg = CheckpointConfig(directory=str(tmp_path / "ck"), every_n_epochs=5)
-        chunked = train_glm_sparse_hotcold(
-            (jnp.copy(p0[0]), jnp.copy(p0[1])), h, "logistic", mesh,
-            learning_rate=0.5, max_iter=12, checkpoint=cfg,
-        )
-        np.testing.assert_allclose(chunked.params[0], full.params[0],
-                                   rtol=1e-6, atol=1e-7)
-        assert chunked.epochs == full.epochs == 12
 
-    def test_dense_features_with_hot_k_rejected(self):
-        rng = np.random.RandomState(0)
-        X = rng.randn(40, 4)
-        schema = Schema.of(("features", DataTypes.DENSE_VECTOR), ("label", "double"))
-        t = Table.from_columns(
-            schema,
-            {"features": [DenseVector(r) for r in X],
-             "label": (X[:, 0] > 0).astype(np.float64)},
-        )
-        with pytest.raises(ValueError, match="sparse vector columns"):
-            (
-                LogisticRegression().set_vector_col("features")
-                .set_label_col("label").set_prediction_col("p")
-                .set_num_hot_features(2).fit(t)
-            )
+def _skewed_out_of_core_2d_matches_1d(tmp_path):
+    """Rows streamed over 'data' with the weights sharded over 'model'
+    give the 1-D streamed fit to float32 rounding."""
+    vecs, ys = _power_law_data(n=300)
+    m1 = _skewed_est().fit(_skewed_stream(vecs, ys))
+    with _on_mesh({"data": 4, "model": 2}):
+        m2 = _skewed_est().fit(_skewed_stream(vecs, ys))
+    np.testing.assert_allclose(m2.coefficients(), m1.coefficients(),
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(m2.intercept(), m1.intercept(), atol=1e-6)
 
-    def _ooc_est(self, hot, dim, max_iter=20, **kw):
-        est = (
-            LogisticRegression().set_vector_col("features")
-            .set_label_col("label").set_prediction_col("pred")
-            .set_num_features(dim).set_learning_rate(0.5)
-            .set_max_iter(max_iter).set_global_batch_size(64)
-            .set_num_hot_features(hot)
-        )
-        for k, v in kw.items():
-            getattr(est, f"set_{k}")(v)
-        return est
 
-    def test_out_of_core_bit_matches_in_memory(self):
-        """Streamed hot/cold training equals the in-memory hot/cold fit
-        bit for bit: same permutation (the counting pre-pass sees the same
-        entries), same update schedule (step-major packing), same slab
-        values (the in-program per-minibatch scatter adds the same bf16
-        entries the resident-slab build does)."""
-        from flink_ml_tpu.table.sources import ChunkedTable, CollectionSource
+def _skewed_in_memory_2d_matches_1d(tmp_path):
+    """The builders on a ('data', 'model') mesh give the 1-D fit of the
+    same stack to float32 rounding: only the sums' grouping changes."""
+    import jax
+    import jax.numpy as jnp
 
-        vecs, ys = self._power_law_data(n=400)
-        t = Table.from_columns(SCHEMA, {"features": vecs, "label": ys})
-        rows = list(zip(vecs, ys))
-        m_mem = self._ooc_est(8, 64).fit(t)
-        m_ooc = self._ooc_est(8, 64).fit(
-            ChunkedTable(CollectionSource(rows, SCHEMA), chunk_rows=96)
-        )
-        np.testing.assert_array_equal(
-            m_ooc.coefficients(), m_mem.coefficients()
-        )
-        assert m_ooc.intercept() == m_mem.intercept()
+    from flink_ml_tpu.lib.common import train_glm_sparse
+    from flink_ml_tpu.parallel.mesh import create_mesh
 
-    def test_out_of_core_checkpoint_resume(self, tmp_path):
-        """A killed-and-resumed streamed hot/cold fit lands on the
-        uninterrupted result: the resume re-derives the identical
-        permutation from the deterministic counting pre-pass and continues
-        from the permuted-space checkpoint."""
-        from flink_ml_tpu.table.sources import ChunkedTable, CollectionSource
+    vecs, ys = _power_law_data()
+    s = pack_sparse_minibatches(vecs, ys, n_dev=4, global_batch_size=64)
 
-        vecs, ys = self._power_law_data(n=300)
-        rows = list(zip(vecs, ys))
+    def fit(mesh):
+        start = (jnp.zeros((s.dim,), jnp.float32), jnp.zeros((), jnp.float32))
+        return train_glm_sparse(start, s, "logistic", mesh,
+                                learning_rate=0.5, max_iter=15)
 
-        def chunked():
-            return ChunkedTable(CollectionSource(rows, SCHEMA), chunk_rows=64)
+    r1 = fit(create_mesh({"data": 4}, jax.devices()[:4]))
+    r2 = fit(create_mesh({"data": 4, "model": 2}))
+    np.testing.assert_allclose(r2.params[0], r1.params[0],
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(r2.params[1], r1.params[1], atol=1e-6)
+    np.testing.assert_allclose(r2.losses, r1.losses, rtol=1e-5)
 
-        full = self._ooc_est(8, 64, max_iter=12).fit(chunked())
-        ck = str(tmp_path / "ck")
-        # run half, then resume to completion
-        self._ooc_est(8, 64, max_iter=6, checkpoint_dir=ck,
-                      checkpoint_interval=3).fit(chunked())
-        resumed = self._ooc_est(8, 64, max_iter=12, checkpoint_dir=ck,
-                                checkpoint_interval=3).fit(chunked())
-        # same tolerance as the plain OOC resume test: a resumed engine
-        # re-places loaded host params, which can fuse differently at the
-        # sub-ulp level (test_out_of_core.py:164)
-        np.testing.assert_allclose(
-            resumed.coefficients(), full.coefficients(),
-            rtol=1e-6, atol=1e-9,
-        )
 
-    def test_out_of_core_checkpoint_rejects_layout_change(self, tmp_path):
-        """A permuted-space stream checkpoint must refuse to resume under
-        a different hot/cold layout (changed mesh model size permutes the
-        same-shaped vector differently — silently wrong without the
-        stamp)."""
-        from flink_ml_tpu.parallel.mesh import create_mesh
-        from flink_ml_tpu.table.sources import ChunkedTable, CollectionSource
-        from flink_ml_tpu.utils.environment import MLEnvironmentFactory
+def _skewed_2d_width_no_multiple_of_the_model_axis(tmp_path):
+    """33 features over a model axis of 2: the placer pads the weight
+    vector to 34, and the fit returns the table's 33 weights, as the 1-D
+    fit does."""
+    vecs, ys = _power_law_data(n=200, dim=33)
+    t = Table.from_columns(SCHEMA, {"features": vecs, "label": ys})
+    m1 = _skewed_est(dim=33, max_iter=8, batch=32).fit(t)
+    with _on_mesh({"data": 4, "model": 2}):
+        m2 = _skewed_est(dim=33, max_iter=8, batch=32).fit(t)
+    assert m2.coefficients().shape == (33,)
+    assert np.all(np.isfinite(m2.coefficients()))
+    np.testing.assert_allclose(m2.coefficients(), m1.coefficients(),
+                               rtol=1e-5, atol=1e-6)
 
-        vecs, ys = self._power_law_data(n=200)
-        rows = list(zip(vecs, ys))
 
-        def chunked():
-            return ChunkedTable(CollectionSource(rows, SCHEMA),
-                                chunk_rows=64)
+def _skewed_estimator_on_a_data_model_mesh(tmp_path):
+    """The estimator on a ('data', 'model') mesh predicts as the 1-D fit
+    and agrees with its weights to float32 rounding."""
+    vecs, ys = _power_law_data(n=300)
+    t = Table.from_columns(SCHEMA, {"features": vecs, "label": ys})
+    m1 = _skewed_est(max_iter=30, batch=32).fit(t)
+    with _on_mesh({"data": 2, "model": 4}):
+        m2 = _skewed_est(max_iter=30, batch=32).fit(t)
+    (p1,) = m1.transform(t)
+    (p2,) = m2.transform(t)
+    np.testing.assert_array_equal(np.asarray(p2.col("pred")),
+                                  np.asarray(p1.col("pred")))
+    np.testing.assert_allclose(m2.coefficients(), m1.coefficients(),
+                               rtol=1e-5, atol=1e-6)
 
-        ck = str(tmp_path / "ck")
-        self._ooc_est(8, 64, max_iter=6, checkpoint_dir=ck,
-                      checkpoint_interval=3).fit(chunked())
-        env = MLEnvironmentFactory.get_default()
-        old = env.get_mesh()
-        env.set_mesh(create_mesh({"data": 4, "model": 2}))
-        try:
-            with pytest.raises(ValueError, match="different hot/cold"):
-                self._ooc_est(8, 64, max_iter=12, checkpoint_dir=ck,
-                              checkpoint_interval=3).fit(chunked())
-        finally:
-            env.set_mesh(old)
 
-    def test_out_of_core_2d_mesh_matches_1d(self):
-        """The full formulation matrix closes: hot/cold + out-of-core +
-        feature-sharded (2-D) mesh.  The same streamed blocks feed the
-        model-sharded chunk program (shard-local slab densify + masked
-        cold + one psum), and predictions match the 1-D streamed fit."""
-        from flink_ml_tpu.parallel.mesh import create_mesh
-        from flink_ml_tpu.table.sources import ChunkedTable, CollectionSource
-        from flink_ml_tpu.utils.environment import MLEnvironmentFactory
+def _skewed_out_of_core_text_fit_parses_once(tmp_path):
+    """A spilled LIBSVM stream parses its text in ONE full pass (beside
+    the pad estimate's two-chunk head): later epochs replay the packed
+    spill, and the fit equals the unspilled one, which parses the text
+    every epoch."""
+    from flink_ml_tpu.table.sources import ChunkedTable, LibSvmSource
 
-        vecs, ys = self._power_law_data(n=300)
-        t = Table.from_columns(SCHEMA, {"features": vecs, "label": ys})
-        rows = list(zip(vecs, ys))
+    vecs, ys = _power_law_data(n=1500)
+    path = tmp_path / "skewed.svm"
+    with open(path, "w") as f:
+        for label, v in zip(ys, vecs):
+            feats = " ".join(f"{int(i) + 1}:{val:.17g}"
+                             for i, val in zip(v.indices, v.vals))
+            f.write(f"{label:g} {feats}\n")
 
-        def chunked():
-            return ChunkedTable(CollectionSource(rows, SCHEMA),
-                                chunk_rows=64)
+    class Counting:
+        def __init__(self):
+            self.inner = LibSvmSource(str(path), n_features=64)
+            self.passes, self.chunks = 0, 0
 
-        m1 = self._ooc_est(8, 64).fit(chunked())
-        env = MLEnvironmentFactory.get_default()
-        old = env.get_mesh()
-        env.set_mesh(create_mesh({"data": 4, "model": 2}))
-        try:
-            m2 = self._ooc_est(8, 64).fit(chunked())
-        finally:
-            env.set_mesh(old)
-        (p1,) = m1.transform(t)
-        (p2,) = m2.transform(t)
-        agree = np.mean(
-            np.asarray(p1.col("pred")) == np.asarray(p2.col("pred"))
-        )
-        assert agree >= 0.98, agree
-        np.testing.assert_allclose(
-            m2.coefficients(), m1.coefficients(), rtol=0.05, atol=0.02
-        )
+        def schema(self):
+            return self.inner.schema()
 
-    def test_out_of_core_dense_with_hot_k_rejected(self):
-        from flink_ml_tpu.table.sources import ChunkedTable, CollectionSource
+        def read_chunks(self, max_rows):
+            self.passes += 1
+            for chunk in self.inner.read_chunks(max_rows):
+                self.chunks += 1
+                yield chunk
 
-        rng = np.random.RandomState(0)
-        X = rng.randn(40, 4)
-        schema = Schema.of(("features", DataTypes.DENSE_VECTOR),
-                           ("label", "double"))
-        rows = [(DenseVector(r), float(r[0] > 0)) for r in X]
-        with pytest.raises(ValueError, match="sparse vector columns"):
-            (
-                LogisticRegression().set_vector_col("features")
-                .set_label_col("label").set_prediction_col("p")
-                .set_global_batch_size(16).set_num_hot_features(2)
-                .fit(ChunkedTable(CollectionSource(rows, schema),
-                                  chunk_rows=16))
-            )
+        def read(self):
+            return self.inner.read()
 
-    def test_2d_f32_slab_matches_1d(self):
-        """Feature-sharded hot/cold training (slab columns + weights over
-        the 'model' axis, one psum completing logits) matches the 1-D path
-        to f32 rounding — only the summation grouping changes."""
-        import jax
-        import jax.numpy as jnp
+    spilled, unspilled = Counting(), Counting()
+    est = lambda: _skewed_est(max_iter=3, batch=256)  # noqa: E731
+    m_spill = est().fit(ChunkedTable(spilled, 500, spill=True))
+    m_text = est().fit(ChunkedTable(unspilled, 500))
+    assert (spilled.passes, spilled.chunks) == (2, 2 + 3)
+    assert (unspilled.passes, unspilled.chunks) == (1 + 3, 2 + 3 * 3)
+    np.testing.assert_array_equal(m_spill.coefficients(),
+                                  m_text.coefficients())
 
-        from flink_ml_tpu.lib.common import (
-            split_hot_cold,
-            train_glm_sparse_hotcold,
-        )
-        from flink_ml_tpu.parallel.mesh import create_mesh
 
-        vecs, ys = self._power_law_data()
-        s = pack_sparse_minibatches(vecs, ys, n_dev=4, global_batch_size=64)
-        p0 = lambda: (  # noqa: E731
-            jnp.zeros((s.dim,), jnp.float32), jnp.zeros((), jnp.float32)
-        )
-        mesh1 = create_mesh({"data": 4}, jax.devices()[:4])
-        h1 = split_hot_cold(s, hot_k=8, pad_multiple=8,
-                            slab_dtype=jnp.float32)
-        r1 = train_glm_sparse_hotcold(
-            p0(), h1, "logistic", mesh1, learning_rate=0.5, max_iter=15
-        )
-        mesh2 = create_mesh({"data": 4, "model": 2})
-        h2 = split_hot_cold(s, hot_k=8, pad_multiple=8,
-                            slab_dtype=jnp.float32, model_size=2)
-        assert h2.dim_pad >= s.dim and h2.hot_k % 2 == 0
-        r2 = train_glm_sparse_hotcold(
-            p0(), h2, "logistic", mesh2, learning_rate=0.5, max_iter=15
-        )
-        np.testing.assert_allclose(r2.params[0], r1.params[0],
-                                   rtol=1e-5, atol=1e-6)
-        np.testing.assert_allclose(r2.params[1], r1.params[1], atol=1e-6)
-        np.testing.assert_allclose(r2.losses, r1.losses, rtol=1e-5)
+_SKEWED_CASES = {
+    "checkpoint_resume": _skewed_checkpoint_resume,
+    "out_of_core_bit_matches_in_memory":
+        _skewed_out_of_core_bit_matches_in_memory,
+    "out_of_core_checkpoint_resume": _skewed_out_of_core_checkpoint_resume,
+    "out_of_core_resume_under_another_layout":
+        _skewed_out_of_core_resume_under_another_layout,
+    "out_of_core_2d_matches_1d": _skewed_out_of_core_2d_matches_1d,
+    "in_memory_2d_matches_1d": _skewed_in_memory_2d_matches_1d,
+    "2d_width_no_multiple_of_the_model_axis":
+        _skewed_2d_width_no_multiple_of_the_model_axis,
+    "estimator_on_a_data_model_mesh": _skewed_estimator_on_a_data_model_mesh,
+    "out_of_core_text_fit_parses_once": _skewed_out_of_core_text_fit_parses_once,
+}
 
-    def test_2d_rounded_hot_k_dead_columns(self):
-        """hot_k not divisible by the model axis rounds up; the dead slab
-        columns stay at zero weight."""
-        import jax.numpy as jnp
 
-        from flink_ml_tpu.lib.common import (
-            split_hot_cold,
-            train_glm_sparse_hotcold,
-        )
-        from flink_ml_tpu.parallel.mesh import create_mesh
-
-        vecs, ys = self._power_law_data(n=200, dim=33)
-        s = pack_sparse_minibatches(vecs, ys, n_dev=4, global_batch_size=32)
-        h = split_hot_cold(s, hot_k=7, pad_multiple=8,
-                           slab_dtype=jnp.float32, model_size=2)
-        assert h.hot_k == 8 and h.dim_pad % 2 == 0 and h.dim_pad >= 33
-        r = train_glm_sparse_hotcold(
-            (jnp.zeros((33,), jnp.float32), jnp.zeros((), jnp.float32)),
-            h, "logistic", create_mesh({"data": 4, "model": 2}),
-            learning_rate=0.5, max_iter=8,
-        )
-        assert r.params[0].shape == (33,)
-        assert np.all(np.isfinite(r.params[0]))
-
-    def test_model_sharded_mesh_estimator(self):
-        """numHotFeatures on a ('data','model') mesh routes through the
-        feature-sharded slab path; predictions agree with the 1-D fit."""
-        from flink_ml_tpu.parallel.mesh import create_mesh
-        from flink_ml_tpu.utils.environment import MLEnvironmentFactory
-
-        vecs, ys = self._power_law_data(n=300)
-        t = Table.from_columns(SCHEMA, {"features": vecs, "label": ys})
-
-        def fit():
-            return (
-                LogisticRegression().set_vector_col("features")
-                .set_label_col("label").set_prediction_col("pred")
-                .set_learning_rate(0.5).set_max_iter(30)
-                .set_global_batch_size(32).set_num_hot_features(8)
-                .fit(t)
-            )
-
-        m1 = fit()
-        env = MLEnvironmentFactory.get_default()
-        old = env.get_mesh()
-        env.set_mesh(create_mesh({"data": 2, "model": 4}))
-        try:
-            m2 = fit()
-        finally:
-            env.set_mesh(old)
-        (p1,) = m1.transform(t)
-        (p2,) = m2.transform(t)
-        agree = np.mean(
-            np.asarray(p1.col("pred")) == np.asarray(p2.col("pred"))
-        )
-        assert agree >= 0.98, agree
-        # bf16 slab rounding differs only in grouping: coefficients close
-        np.testing.assert_allclose(
-            m2.coefficients(), m1.coefficients(), rtol=0.05, atol=0.02
-        )
+@pytest.mark.parametrize("case", sorted(_SKEWED_CASES))
+def test_the_plain_route_on_a_skewed_table(case, tmp_path):
+    """The one sparse route on a power-law table, wherever a fit can run:
+    checkpointed, streamed, on a ('data', 'model') mesh."""
+    _SKEWED_CASES[case](tmp_path)
 
 
 class TestLayoutFloors:
@@ -582,49 +427,6 @@ class TestLayoutFloors:
         from flink_ml_tpu.parallel.mesh import agree_max
 
         assert agree_max(512, 7) == (512, 7)
-
-    def test_hotcold_floors_and_counts_are_neutral(self):
-        """split_hot_cold with explicit (local) counts and the natural pads
-        as floors reproduces the default split exactly — the multi-process
-        agreement path is a no-op when there is one process."""
-        import jax
-        import jax.numpy as jnp
-
-        from flink_ml_tpu.lib.common import (
-            hotcold_entry_counts,
-            hotcold_layout_floors,
-            split_hot_cold,
-            train_glm_sparse_hotcold,
-        )
-        from flink_ml_tpu.parallel.mesh import create_mesh
-
-        vecs, ys, _ = sparse_data(n=200, dim=48, nnz=5, seed=12)
-        s = pack_sparse_minibatches(vecs, ys, n_dev=4, global_batch_size=32)
-        counts = hotcold_entry_counts(s)
-        (hp, cp), plan = hotcold_layout_floors(s, 8, counts=counts)
-        h_def = split_hot_cold(s, 8, slab_dtype=jnp.float32)
-        h_agr = split_hot_cold(s, 8, slab_dtype=jnp.float32, counts=counts,
-                               min_hot_pad=hp, min_cold_pad=cp, plan=plan)
-        np.testing.assert_array_equal(h_agr.perm, h_def.perm)
-        np.testing.assert_array_equal(h_agr.hot_ints, h_def.hot_ints)
-        np.testing.assert_array_equal(h_agr.hot_vals, h_def.hot_vals)
-        np.testing.assert_array_equal(h_agr.cold.ints, h_def.cold.ints)
-        np.testing.assert_array_equal(h_agr.cold.floats, h_def.cold.floats)
-        # larger floors widen the pads but keep training identical
-        h_wide = split_hot_cold(s, 8, slab_dtype=jnp.float32, counts=counts,
-                                min_hot_pad=hp * 2, min_cold_pad=cp * 2)
-        assert h_wide.hot_ints.shape[2] == hp * 2
-        mesh = create_mesh({"data": 4}, jax.devices()[:4])
-        p0 = lambda: (  # noqa: E731
-            jnp.zeros((s.dim,), jnp.float32), jnp.zeros((), jnp.float32)
-        )
-        r1 = train_glm_sparse_hotcold(p0(), h_def, "logistic", mesh,
-                                      learning_rate=0.5, max_iter=8)
-        r2 = train_glm_sparse_hotcold(p0(), h_wide, "logistic", mesh,
-                                      learning_rate=0.5, max_iter=8)
-        np.testing.assert_array_equal(
-            np.asarray(r1.params[0]), np.asarray(r2.params[0])
-        )
 
     def test_layout_prescan_predicts_pack_exactly(self):
         """sparse_layout_floors must predict the pack's natural layout for
@@ -728,106 +530,10 @@ class TestNativeMalformed:
             native.read_libsvm(str(p), None, False)
 
 
-class TestHotColdStreamFormulation:
-    """VERDICT r4 #1: the scalable in-memory formulation — slabs densify
-    in-program per minibatch (HBM holds O(nnz), never O(rows x hot_k))."""
-
-    def _data(self, n=500, dim=64, seed=3):
-        rng = np.random.RandomState(seed)
-        true_w = rng.randn(dim)
-        vecs, ys = [], []
-        for _ in range(n):
-            hot = rng.choice(8, 3, replace=False)
-            cold = 8 + rng.choice(dim - 8, 2, replace=False)
-            idx = np.sort(np.concatenate([hot, cold]))
-            x = np.zeros(dim)
-            x[idx] = 1.0
-            vecs.append(SparseVector(dim, idx.astype(np.int64), np.ones(5)))
-            ys.append(float((x @ true_w) > 0))
-        return vecs, np.asarray(ys)
-
-    def _fit(self, t, mode, hot=16):
-        return (
-            LogisticRegression().set_vector_col("features")
-            .set_label_col("label").set_prediction_col("pred")
-            .set_learning_rate(0.5).set_max_iter(30)
-            .set_global_batch_size(64).set_num_hot_features(hot)
-            .set_hot_slab_mode(mode)
-            .fit(t)
-        )
-
-    def test_stream_mode_matches_resident_mode(self):
-        vecs, ys = self._data()
-        t = Table.from_columns(SCHEMA, {"features": vecs, "label": ys})
-        m_res = self._fit(t, "resident")
-        m_str = self._fit(t, "stream")
-        np.testing.assert_allclose(
-            m_str.coefficients(), m_res.coefficients(), rtol=1e-5, atol=1e-7
-        )
-
-    def test_auto_mode_picks_stream_over_budget(self, monkeypatch):
-        from flink_ml_tpu.lib import common as lc
-
-        calls = {}
-        orig = lc.train_glm_sparse_hotcold
-
-        def spy(*a, **kw):
-            calls["resident"] = kw.get("resident_slabs")
-            return orig(*a, **kw)
-
-        monkeypatch.setattr(
-            "flink_ml_tpu.lib.glm.train_glm_sparse_hotcold", spy,
-            raising=False,
-        )
-        # glm imports inside the method; patch at source module
-        monkeypatch.setattr(lc, "train_glm_sparse_hotcold", spy)
-        vecs, ys = self._data()
-        t = Table.from_columns(SCHEMA, {"features": vecs, "label": ys})
-        monkeypatch.setenv("FMT_HOT_SLAB_BUDGET_MB", "0")
-        self._fit(t, "auto")
-        assert calls["resident"] is False
-        calls.clear()
-        monkeypatch.setenv("FMT_HOT_SLAB_BUDGET_MB", "100000")
-        self._fit(t, "auto")
-        assert calls["resident"] is True
-
-    def test_stream_mode_2d_matches_1d(self):
-        import jax
-
-        from flink_ml_tpu.lib.common import (
-            split_hot_cold,
-            train_glm_sparse_hotcold,
-        )
-        from flink_ml_tpu.parallel.mesh import create_mesh
-
-        vecs, ys = self._data(n=300, dim=32)
-        mesh = create_mesh({"data": 2, "model": 2},
-                           devices=jax.devices()[:4])
-        s = pack_sparse_minibatches(vecs, ys, n_dev=2, global_batch_size=32)
-        import jax.numpy as jnp
-
-        kw = dict(
-            kind="logistic", learning_rate=0.5, max_iter=10, reg=0.0,
-            tol=0.0, with_intercept=True, resident_slabs=False,
-        )
-        h2 = split_hot_cold(s, hot_k=8, pad_multiple=8,
-                            slab_dtype=jnp.float32, model_size=2)
-        w0 = (jnp.zeros((32,), jnp.float32), jnp.zeros((), jnp.float32))
-        r2 = train_glm_sparse_hotcold(w0, h2, mesh=mesh, **kw)
-        mesh1 = create_mesh({"data": 2}, devices=jax.devices()[:2])
-        h1 = split_hot_cold(s, hot_k=8, pad_multiple=8,
-                            slab_dtype=jnp.float32)
-        r1 = train_glm_sparse_hotcold(w0, h1, mesh=mesh1, **kw)
-        np.testing.assert_allclose(
-            np.asarray(r2.params[0]), np.asarray(r1.params[0]),
-            rtol=1e-5, atol=1e-7,
-        )
-
-
 def test_unsorted_csr_rows_pack_sorted():
     """CSR columns from file order may carry per-row ids out of order; the
-    pack must restore the per-row ascending invariant (the hot-slab
-    scatter declares its index tuples sorted)."""
+    pack must restore the per-row ascending invariant (the per-object
+    pack's rows ascend, and the CSR pack lays the same bytes)."""
     from flink_ml_tpu.lib.common import pack_sparse_minibatches
     from flink_ml_tpu.ops.batch import CsrRows
 
@@ -1240,22 +946,6 @@ def _assert_segment_csr_as_the_parent_packs(stack, parts, dim, n_dev, batch):
     assert stack.floats.tobytes() == want.floats.tobytes()
 
 
-def test_hot_cold_still_reads_a_segment_csr_stack(sparse_counters):
-    from flink_ml_tpu.lib import common
-
-    n_dev = _mesh_devices()
-    parts = _csr_table_parts(np.full(600, 5), 70, 41)  # uniform: ELL's case
-    table = _sparse_table(70, *parts)
-    _sparse_est(70, 16 * n_dev).set_num_hot_features(8).fit(table)
-    stacks = [s for s in _packed(table)
-              if isinstance(s, (common.SparseMinibatchStack,
-                                common.EllMinibatchStack))]
-    (stack,) = stacks
-    _assert_segment_csr_as_the_parent_packs(stack, parts, 70, n_dev,
-                                            16 * n_dev)
-    assert "train.sparse_ell_fits" not in sparse_counters()
-
-
 def test_a_two_d_mesh_still_reads_a_segment_csr_stack(sparse_counters):
     import jax
 
@@ -1307,3 +997,94 @@ def test_the_out_of_core_path_still_packs_segment_csr(monkeypatch):
         assert asked is False
         assert isinstance(stack, common.SparseMinibatchStack)
         assert not stack.ell_declined
+
+
+def _resume_from_a_retired_route_checkpoint(tmp_path, vecs, ys):
+    """The retired route's streamed checkpoints held permuted weights of
+    the table's own shape on a 1-D mesh: a resume from one (its meta
+    carries ``hotcold_layout``) is refused, and the same checkpoint
+    without the stamp resumes as usual."""
+    import glob
+    import json
+
+    from flink_ml_tpu.api import load_stage
+
+    ck = str(tmp_path / "ck")
+    _skewed_est(max_iter=2, checkpoint_dir=ck,
+                checkpoint_interval=1).fit(_skewed_stream(vecs, ys))
+    metas = sorted(glob.glob(f"{ck}/*.json"))
+    assert metas
+    for path in metas:
+        with open(path) as f:
+            meta = json.load(f)
+        meta["hotcold_layout"] = {"model_size": 1, "hot_k_eff": 16,
+                                  "dim_pad": 64, "perm_crc": 0}
+        with open(path, "w") as f:
+            json.dump(meta, f)
+    legacy = load_stage(str(tmp_path / "legacy"))
+    legacy.set_checkpoint_dir(ck).set_checkpoint_interval(1)
+    with pytest.raises(ValueError, match="retired numHotFeatures"):
+        legacy.fit(_skewed_stream(vecs, ys))
+    for path in metas:
+        with open(path) as f:
+            meta = json.load(f)
+        del meta["hotcold_layout"]
+        with open(path, "w") as f:
+            json.dump(meta, f)
+    resumed = legacy.fit(_skewed_stream(vecs, ys))
+    full = load_stage(str(tmp_path / "plain")).fit(_skewed_stream(vecs, ys))
+    assert resumed.train_epochs_ == full.train_epochs_ == 4
+    np.testing.assert_allclose(resumed.coefficients(), full.coefficients(),
+                               rtol=1e-6, atol=1e-9)
+
+
+@pytest.mark.parametrize("where",
+                         ["in_memory", "out_of_core", "out_of_core_resume"])
+def test_a_saved_estimator_with_the_retired_hot_keys_fits_as_without(
+        where, tmp_path, sparse_counters):
+    """An estimator saved while ``numHotFeatures`` / ``hotSlabMode`` were
+    parameters still loads: the keys ride along unread, and the fit is
+    the one the same JSON without them gives, to the byte, on the one
+    sparse route (row-regular in memory on a 1-D mesh).  A streamed
+    checkpoint that route wrote is refused, not resumed."""
+    import json
+
+    from flink_ml_tpu.api import load_stage
+
+    vecs, ys = _power_law_data(n=256)
+    counts = np.asarray([len(v.indices) for v in vecs])
+    indptr = np.concatenate([[0], np.cumsum(counts)]).astype(np.int64)
+    indices = np.concatenate([v.indices for v in vecs]).astype(np.int32)
+    values = np.concatenate([v.vals for v in vecs]).astype(np.float32)
+    _skewed_est(max_iter=4).save(str(tmp_path / "plain"))
+    with open(tmp_path / "plain" / "stage.json") as f:
+        stage = json.load(f)
+    params = json.loads(stage["params"])
+    params.update(numHotFeatures=json.dumps(16),
+                  hotSlabMode=json.dumps("stream"))
+    stage["params"] = json.dumps(params)
+    (tmp_path / "legacy").mkdir()
+    with open(tmp_path / "legacy" / "stage.json", "w") as f:
+        json.dump(stage, f)
+
+    def fit(name):
+        est = load_stage(str(tmp_path / name))
+        if where == "in_memory":
+            table = _sparse_table(64, indptr, indices, values, ys)
+        else:
+            table = _skewed_stream(vecs, ys)
+        before = sparse_counters().get("train.sparse_ell_fits", 0)
+        model = est.fit(table)
+        return est, model, sparse_counters().get("train.sparse_ell_fits",
+                                                 0) - before
+
+    if where == "out_of_core_resume":
+        _resume_from_a_retired_route_checkpoint(tmp_path, vecs, ys)
+        return
+    legacy, m_legacy, ell_legacy = fit("legacy")
+    assert {"numHotFeatures", "hotSlabMode"} <= set(legacy.get_params().keys())
+    _plain, m_plain, ell_plain = fit("plain")
+    np.testing.assert_array_equal(m_legacy.coefficients(),
+                                  m_plain.coefficients())
+    assert m_legacy.intercept() == m_plain.intercept()
+    assert ell_legacy == ell_plain == (1 if where == "in_memory" else 0)

@@ -816,42 +816,6 @@ class TestChunkSpillCache:
         assert a == b == 400
         assert source.chunk_reads == 2  # no caching: both passes parse
 
-    def test_hotcold_ooc_fit_parses_text_once(self, tmp_path):
-        source, dim = self._libsvm(tmp_path, n=1500)
-        counting = _ParseCountingSource(source)
-        est = (
-            LogisticRegression()
-            .set_vector_col("features")
-            .set_label_col("label")
-            .set_prediction_col("pred")
-            .set_num_features(dim)
-            .set_learning_rate(0.1)
-            .set_global_batch_size(256)
-            .set_max_iter(3)
-            .set_num_hot_features(64)
-        )
-        cached_fit = est.fit(ChunkedTable(counting, 500, spill=True))
-        # the frequency/layout scan is the ONE text parse; the pack pass
-        # replays its binary recording and steady epochs read the packed
-        # BlockSpill
-        assert counting.chunk_reads == 1
-        # result identical to the uncached fit
-        est2 = (
-            LogisticRegression()
-            .set_vector_col("features")
-            .set_label_col("label")
-            .set_prediction_col("pred")
-            .set_num_features(dim)
-            .set_learning_rate(0.1)
-            .set_global_batch_size(256)
-            .set_max_iter(3)
-            .set_num_hot_features(64)
-        )
-        plain_fit = est2.fit(ChunkedTable(source, 500))
-        np.testing.assert_array_equal(
-            cached_fit.coefficients(), plain_fit.coefficients()
-        )
-
     def test_kmeans_ooc_fit_parses_text_once(self, tmp_path):
         rng = np.random.RandomState(0)
         X = rng.randn(900, 8)
